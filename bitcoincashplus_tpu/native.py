@@ -52,7 +52,15 @@ def load() -> Optional[ctypes.CDLL]:
                 fcntl.flock(lk, fcntl.LOCK_EX)
                 subprocess.run(["make", "-C", _NATIVE_DIR],
                                capture_output=True, timeout=120, check=True)
-        except Exception:
+        except Exception as e:
+            # once per process (_load_attempted): say why, then keep a
+            # prebuilt library if one is on disk
+            from .util.log import log_printf
+
+            log_printf("WARNING: native library build failed (%s): %s",
+                       type(e).__name__,
+                       (getattr(e, "stderr", b"") or b"").decode(
+                           "utf-8", "replace")[-2000:] or e)
             if not os.path.exists(_LIB_PATH):
                 return None  # no toolchain and no prebuilt library
     try:

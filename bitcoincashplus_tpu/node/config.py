@@ -94,8 +94,10 @@ Options:
                          eviction verdict through the per-tx oracle and log
                          divergence (debug, like -checkmempool; default: 0)
   -minrelaytxfee=<amt>   Minimum relay fee rate in satoshis/kB (default: 1000)
-  -tpu=<0|1>             Use the TPU batch backend for sig verification and
-                         mining sweeps (default: auto-detect)
+  -tpu=<0|1>             1 = require a TPU: refuse to start without one,
+                         verify block-connect signature batches on it, and
+                         stop if its compiler refuses a kernel; 0 = CPU
+                         only (default: auto-detect)
   -ecdsakernel=<glv|w4|msm>
                          Device ECDSA verify kernel: glv = endomorphism-split
                          ladder + fixed-base G comb (default), w4 = the
@@ -105,11 +107,13 @@ Options:
                          batches bisect to the per-lane oracle — worth it from
                          a few dozen Schnorr sigs per batch, ECDSA lanes keep
                          riding glv); unknown values are rejected at startup
-  -compilecache=<dir>    Persistent XLA compilation cache directory (default:
-                         off). First compile of each kernel shape writes the
-                         cache; every later process start reads it instead of
-                         re-paying the ~90 s cold GLV compile. Seeds
-                         BCP_COMPILE_CACHE for child processes; cache hits
+  -compilecache=<dir>    Persistent XLA compilation cache directory (on by
+                         default: JAX_COMPILATION_CACHE_DIR if set, which
+                         beats this flag, else <checkout>/.jax_cache). First
+                         compile of each kernel shape writes the cache; every
+                         later process start reads it instead of re-paying
+                         minutes of cold GLV compile per bucket. The resolved
+                         directory is exported to child processes; cache hits
                          surface in gettpuinfo.device.compilation_cache
   -residentminer=<on|off|force>  Device-resident mining loop: the nonce sweep
                          runs as a persistent segment pipeline over

@@ -34,10 +34,7 @@ def _shard_body(midstate, tail, target_limbs, start_nonce, n_tiles, tile: int):
     (deterministic winner regardless of which chip finds one first).
     """
     chip = jax.lax.axis_index(CHIP_AXIS).astype(jnp.uint32)
-    if hasattr(jax.lax, "axis_size"):
-        n_chips = jnp.uint32(jax.lax.axis_size(CHIP_AXIS))
-    else:  # pre-0.6 jax: count the axis with an all-ones psum
-        n_chips = jax.lax.psum(jnp.uint32(1), CHIP_AXIS)
+    n_chips = jnp.uint32(jax.lax.axis_size(CHIP_AXIS))
     stripe = start_nonce + chip * n_tiles * np.uint32(tile)
 
     tgt = [target_limbs[j] for j in range(8)]

@@ -120,21 +120,19 @@ def gettpuinfo(node, params):
     from ..ops import dispatch, ecdsa_batch
     from ..util import faults
 
-    stats = ecdsa_batch.STATS.snapshot()
-    devices = []
-    try:
-        import jax
+    import jax
 
-        devices = [str(d) for d in jax.devices()]
-    except Exception:
-        pass
+    stats = ecdsa_batch.STATS.snapshot()
+    # no try: a JAX that cannot list its devices is an RPC error here,
+    # not an empty list
+    jax_devices = jax.devices()
     from ..mempool.accept import accept_latency_quantiles, accept_stage_quantiles
     from ..mining.assembler import template_build_quantiles
     from ..util import devicewatch, lockwatch, telemetry
 
     return {
         "backend": node.backend,
-        "devices": devices,
+        "devices": [str(d) for d in jax_devices],
         # active verify-kernel selection (-ecdsakernel) + GLV health: the
         # fixed-base comb build cost (0.0 until the first GLV dispatch
         # builds it), host decompose/pack stage times, fallback tallies
@@ -196,9 +194,12 @@ def gettpuinfo(node, params):
         # device-lane monitor (util/devicewatch): per-program compile
         # counts + distinct-shape signatures vs declared budgets (+ any
         # first-compile cost-analysis FLOPs/bytes), host<->device
-        # transfer byte totals per site, profiler state, and the stall
-        # watchdog
-        "device": devicewatch.snapshot(),
+        # transfer byte totals per site, profiler state, the stall
+        # watchdog — and which device JAX runs on, as JAX reports it
+        "device": {**devicewatch.snapshot(),
+                   "platform": jax_devices[0].platform,
+                   "kind": jax_devices[0].device_kind,
+                   "count": len(jax_devices)},
         # runtime lock-order sentinel (util/lockwatch): locks watched,
         # acquisition counts, max held-depth, the live ordering edges,
         # and any inversions/cycles; {"enabled": False} unless the

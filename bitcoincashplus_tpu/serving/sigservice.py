@@ -535,8 +535,7 @@ class SigService:
             log_printf("sigservice flush failed (%s: %s) — %d lane(s) "
                        "degrade to caller-side CPU re-verify",
                        type(err).__name__, str(err)[:160], len(batch))
-            if isinstance(err, (NameError, AttributeError,
-                                UnboundLocalError)):
+            if isinstance(err, ecdsa_batch.SURFACE_ERRORS):
                 raise err  # programming errors must surface, not degrade
 
     # -- observability --------------------------------------------------

@@ -191,7 +191,7 @@ class BlockSigJob:
                 try:
                     ok = handle.result()
                 except (KeyboardInterrupt, SystemExit,
-                        NameError, AttributeError, UnboundLocalError):
+                        *ecdsa_batch.SURFACE_ERRORS):
                     raise  # programming errors must surface, not degrade
                 except Exception:
                     # settle-time failure the handle could not self-heal:
@@ -331,7 +331,7 @@ class BlockScriptVerifier:
                             batch, backend=self.backend, kernel=self.kernel
                         )
                     except (KeyboardInterrupt, SystemExit,
-                            NameError, AttributeError, UnboundLocalError):
+                            *ecdsa_batch.SURFACE_ERRORS):
                         raise  # programming errors surface, not degrade
                     except Exception:
                         ecdsa_batch.STATS.fault_fallback_sigs += len(batch)

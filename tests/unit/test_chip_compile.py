@@ -1,0 +1,133 @@
+"""What the chip's compiler accepts, asked without the chip.
+
+The main path's device programs are compiled ahead of time for a
+*described* TPU v5e (jax.experimental.topologies) at the shapes the node
+dispatches: the resident miner's h7 sweep, the Pallas sweep, the w4 Pallas
+verify kernel and (slow-marked: minutes each) the fused GLV verify buckets.
+Nothing runs — a passing compile is not a chip run — but a kernel the
+compiler refuses fails here instead of becoming a silent rung down on the
+chip (the looped field form below is exactly that: it lowers on the CPU
+and Mosaic refuses its dynamic_slice).
+
+All in ONE file, topology described inside a fixture: the worker that gets
+this file loads the TPU library and keeps its lock; no other may. The
+kernel forms hang on ops/sha256.backend_is_cpu(), which sees the CPU here,
+so the tests steer them with the existing BCP_SECP_PARALLEL /
+BCP_SHA_UNROLL switches and clear jax's trace caches (module-level jits
+would otherwise reuse a trace made under the other form).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever stops the describe
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """An AOT compile for a described chip is written to the persistent
+    cache but cannot be read back without the chip (the next one warns and
+    recompiles): keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def chip_forms(monkeypatch, no_persistent_cache):
+    """Trace the accelerator forms, from a clean trace cache."""
+    monkeypatch.setenv("BCP_SECP_PARALLEL", "1")
+    monkeypatch.setenv("BCP_SHA_UNROLL", "1")
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+def _sweep_args(one_chip):
+    def s(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.uint32, sharding=one_chip)
+
+    return s((8,)), s((3,)), s(()), s(()), s(())
+
+
+def _verify_args(one_chip, bucket: int):
+    m = jax.ShapeDtypeStruct((bucket, 32), jnp.uint8, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((bucket,), jnp.uint8, sharding=one_chip)
+    return m, m, m, m, v, m, m, v
+
+
+def test_resident_sweep_compiles_for_v5e(one_chip, chip_forms):
+    """mining/resident's program: sweep_fast_jit at the node's TPU tile."""
+    from bitcoincashplus_tpu.ops.sha256_sweep import sweep_fast_jit
+
+    compiled = sweep_fast_jit.lower(*_sweep_args(one_chip),
+                                    tile=1 << 16).compile()
+    assert compiled.memory_analysis().generated_code_size_in_bytes > 0
+
+
+def test_pallas_sweep_compiles_for_v5e(one_chip, chip_forms):
+    from bitcoincashplus_tpu.ops.pallas_sweep import pallas_sweep_jit
+
+    compiled = pallas_sweep_jit.lower(*_sweep_args(one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_w4_verify_kernel_compiles_for_v5e(one_chip, chip_forms):
+    """The fallback rung's Pallas kernel, accelerator (parallel) form."""
+    from bitcoincashplus_tpu.ops import secp256k1 as dev
+
+    compiled = dev._w4_bytes_program.lower(
+        *_verify_args(one_chip, 1024)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_w4_looped_form_is_refused_by_mosaic(one_chip, chip_forms,
+                                             monkeypatch):
+    """The form the CPU environment picks does not lower for the chip —
+    why the form must follow the backend JAX really runs on, and why a
+    refusal under -tpu=1 is fatal instead of a rung down."""
+    from bitcoincashplus_tpu.ops import secp256k1 as dev
+
+    monkeypatch.setenv("BCP_SECP_PARALLEL", "0")
+    jax.clear_caches()
+    with pytest.raises(NotImplementedError, match="dynamic_slice"):
+        dev._w4_bytes_program.lower(*_verify_args(one_chip, 1024)).compile()
+
+
+# the node's reindex buckets (node.py _import_block_files_native); compile
+# seconds measured on this 8-core sandbox for PR 22: 215 / 228 / 242 s
+@pytest.mark.slow(reason="AOT compile 215-242 s per bucket (PR 22, sandbox)")
+@pytest.mark.parametrize("bucket", [1024, 2048, 8192])
+def test_glv_verify_bucket_compiles_for_v5e(one_chip, chip_forms, bucket):
+    """The DEFAULT verify kernel (fused decompose + GLV ladder) fits one
+    chip: temp stays far below the 16 GB of HBM at every bucket."""
+    from bitcoincashplus_tpu.ops import secp256k1 as dev
+
+    compiled = dev._glv_dev_program.lower(
+        *_verify_args(one_chip, bucket)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 << 30, mem

@@ -378,17 +378,18 @@ def test_chainstate_registers_pipeline_watchdog():
     assert clk_entry["pending_fn"]() == 0
 
 
-def test_persistent_cache_hits_surface_in_snapshot(tmp_path):
+def test_persistent_cache_hits_surface_in_snapshot(tmp_path, monkeypatch):
     """Second compile of the same program is served from the persistent
     cache and the monitoring listener tallies it — the cache_hits field
     gettpuinfo.device.compilation_cache exposes (and that the functional
-    suite asserts > 0 on re-spawned nodes via conftest's seeded
-    BCP_COMPILE_CACHE). Toy jit, so the 2 s min-compile-time floor is
+    suite asserts > 0 on re-spawned nodes via the directory conftest
+    resolves and exports). Toy jit, so the 2 s min-compile-time floor is
     lowered for the duration; all cache config is restored after."""
     saved_dir = jax.config.jax_compilation_cache_dir
     saved_min = jax.config.jax_persistent_cache_min_compile_time_secs
     saved_cc = dict(dir=dw._COMPILE_CACHE["dir"],
                     enabled=dw._COMPILE_CACHE["enabled"])
+    monkeypatch.delenv(dw.CACHE_ENV, raising=False)  # let the flag decide
     try:
         dw.enable_compile_cache(str(tmp_path / "cache"))
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)

@@ -166,5 +166,5 @@ def test_pallas_programming_errors_are_not_swallowed(monkeypatch):
         eb._note_pallas_failure(AttributeError("no attribute"))
     # toolchain-class failures still fall back (and latch when Mosaic)
     before = eb.STATS.pallas_fallbacks
-    eb._note_pallas_failure(RuntimeError("remote compile service sneeze"))
+    eb._note_pallas_failure(RuntimeError("transient compile hiccup"))
     assert eb.STATS.pallas_fallbacks == before + 1

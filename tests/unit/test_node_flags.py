@@ -3,6 +3,8 @@ advertised flags must be consumed, not help-text-only)."""
 
 import os
 
+import pytest
+
 from bitcoincashplus_tpu import native
 from bitcoincashplus_tpu.node.config import Config
 from bitcoincashplus_tpu.node.node import Node
@@ -15,6 +17,157 @@ def _mk_node(tmp_path, **args):
     for k, v in args.items():
         cfg.args[k] = [str(v)]
     return Node(config=cfg)
+
+
+# The -tpu / compile-cache cases come first: the tests further down mine
+# blocks through the generic XLA-CPU sweep, which can sit for the rest of a
+# run on a slow host, and --dist loadfile runs a file in order on one worker.
+
+
+@pytest.mark.parametrize("source", ["env", "flag", "default"])
+def test_compilecache_knob(tmp_path, monkeypatch, source):
+    """One resolver places the persistent compilation cache (default ON):
+    JAX_COMPILATION_CACHE_DIR beats -compilecache=<dir> beats
+    <checkout>/.jax_cache. When the environment names the directory the
+    code makes NO jax_compilation_cache_dir update (jax reads the variable
+    itself); otherwise the resolved directory is exported for child
+    processes. gettpuinfo.device carries the compilation_cache block."""
+    import jax
+
+    from bitcoincashplus_tpu.util import devicewatch as dw
+
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    env_dir, flag_dir = tmp_path / "env-cache", tmp_path / "flag-cache"
+    want = {"env": str(env_dir), "flag": str(flag_dir),
+            "default": os.path.join(repo, ".jax_cache")}[source]
+    monkeypatch.delenv(dw.CACHE_ENV, raising=False)
+    if source == "env":
+        monkeypatch.setenv(dw.CACHE_ENV, str(env_dir))
+    dir_updates = []
+    real_update = jax.config.update
+
+    def spy(name, value):
+        if name == "jax_compilation_cache_dir":
+            dir_updates.append(value)
+        real_update(name, value)
+
+    old_dir = jax.config.jax_compilation_cache_dir
+    monkeypatch.setattr(jax.config, "update", spy)
+    try:
+        args = {} if source == "default" else {"compilecache": flag_dir}
+        node = _mk_node(tmp_path / "cc", **args)
+        try:
+            assert node.compile_cache == want
+            assert dir_updates == ([] if source == "env" else [want])
+            assert os.environ[dw.CACHE_ENV] == want  # children inherit
+            assert os.path.isdir(want)
+            snap = dw.snapshot()["compilation_cache"]
+            assert snap["enabled"] and snap["dir"] == want
+            assert "cache_hits" in snap
+        finally:
+            node.close()
+    finally:
+        real_update("jax_compilation_cache_dir", old_dir)
+
+
+def test_tpu_flag_requires_tpu(tmp_path):
+    """-tpu=1 on a machine whose first JAX device is not a TPU refuses to
+    start, naming the platform it found."""
+    from bitcoincashplus_tpu.node.node import InitError
+
+    with pytest.raises(InitError, match="'cpu'"):
+        _mk_node(tmp_path / "t", tpu=1)
+
+
+@pytest.mark.parametrize("tpu,connect,serve", [
+    ("tpu", "device", "auto"),
+    ("auto", "auto", "auto"),
+    ("cpu", "cpu", "cpu"),
+])
+def test_tpu_backend_mapping(tpu, connect, serve):
+    """One meaning for -tpu: block connect is forced to the device under
+    -tpu=1, the mempool/SigService sites keep the lane floor."""
+    from bitcoincashplus_tpu.ops import ecdsa_batch as eb
+
+    assert eb.dispatch_backend(tpu) == connect
+    assert eb.dispatch_backend(tpu, lane_floor=True) == serve
+    assert connect in eb.BACKENDS and serve in eb.BACKENDS
+
+
+def test_unknown_backend_rejected():
+    """The -tpu flag's own string used to reach dispatch_batch and fall
+    silently to the CPU oracle; an unknown backend now raises."""
+    import numpy as np
+
+    from bitcoincashplus_tpu.ops import ecdsa_batch as eb
+
+    with pytest.raises(ValueError):
+        eb.dispatch_backend("gpu")
+    with pytest.raises(ValueError):
+        eb.dispatch_batch([object()], backend="tpu")
+    z = np.zeros((1, 64), np.uint8)
+    with pytest.raises(ValueError):
+        eb.dispatch_packed(z, z, z[:, :32], z[:, :32], z[:, 0],
+                           backend="tpu")
+
+
+def test_tpu_flag_0_keeps_cpu(tmp_path):
+    """-tpu=0 / unset reach the verifier as backends dispatch_batch knows."""
+    from bitcoincashplus_tpu.ops import ecdsa_batch as eb
+
+    node = _mk_node(tmp_path / "u", tpu=0)
+    try:
+        assert node.connect_backend == node.serve_backend == "cpu"
+        assert node.chainstate.script_verifier.backend == "cpu"
+        assert eb._REQUIRE_DEVICE is False
+    finally:
+        node.close()
+
+
+def test_require_device_makes_refusal_fatal():
+    """Under -tpu=1 (require_device) a deterministic compiler refusal
+    raises KernelRefused instead of latching the rung broken; transient
+    errors and the auto policy are unchanged."""
+    from bitcoincashplus_tpu.ops import ecdsa_batch as eb
+
+    refusal = NotImplementedError("Unimplemented primitive in Pallas TPU "
+                                  "lowering: dynamic_slice")
+    assert eb._compiler_refused(refusal) is True  # auto: latch-worthy
+    assert eb._compiler_refused(RuntimeError("socket closed")) is False
+    eb.require_device(True)
+    try:
+        assert eb._compiler_refused(RuntimeError("socket closed")) is False
+        for note in (eb._note_glv_dev_failure, eb._note_glv_failure,
+                     eb._note_pallas_failure):
+            with pytest.raises(eb.KernelRefused, match="dynamic_slice"):
+                note(refusal)
+        assert not (eb._GLV_DEV_BROKEN or eb._GLV_BROKEN
+                    or eb._PALLAS_BROKEN)
+        assert issubclass(eb.KernelRefused, eb.SURFACE_ERRORS)
+    finally:
+        eb.require_device(False)
+        s = eb.STATS
+        s.glv_dev_fallbacks -= 1
+        s.glv_fallbacks -= 1
+        s.pallas_fallbacks -= 1
+
+
+def test_gettpuinfo_reports_device(tmp_path):
+    """gettpuinfo.device names the device as JAX reports it."""
+    import jax
+
+    from bitcoincashplus_tpu.rpc.control import gettpuinfo
+
+    node = _mk_node(tmp_path / "g")
+    try:
+        dev = gettpuinfo(node, [])["device"]
+        d0 = jax.devices()[0]
+        assert (dev["platform"], dev["kind"], dev["count"]) == (
+            d0.platform, d0.device_kind, len(jax.devices()))
+        assert dev["platform"] == "cpu" and dev["count"] == 8
+    finally:
+        node.close()
 
 
 def test_par_sets_native_thread_budget(tmp_path):
@@ -122,29 +275,3 @@ def test_txindex_backfill_background(tmp_path):
             node.chainstate.chain[7].hash
     finally:
         node.close()
-
-
-def test_compilecache_knob(tmp_path, monkeypatch):
-    """-compilecache=<dir>: jax's persistent compilation cache points at
-    the directory, BCP_COMPILE_CACHE is seeded for child processes, and
-    gettpuinfo.device gains the compilation_cache block (default: off)."""
-    import jax
-
-    from bitcoincashplus_tpu.util import devicewatch as dw
-
-    monkeypatch.delenv("BCP_COMPILE_CACHE", raising=False)
-    old_dir = jax.config.jax_compilation_cache_dir
-    try:
-        cache_dir = tmp_path / "xla-cache"
-        node = _mk_node(tmp_path / "cc", compilecache=str(cache_dir))
-        try:
-            assert jax.config.jax_compilation_cache_dir == str(cache_dir)
-            assert os.environ["BCP_COMPILE_CACHE"] == str(cache_dir)
-            assert cache_dir.is_dir()
-            snap = dw.snapshot()["compilation_cache"]
-            assert snap["enabled"] and snap["dir"] == str(cache_dir)
-            assert "cache_hits" in snap
-        finally:
-            node.close()
-    finally:
-        jax.config.update("jax_compilation_cache_dir", old_dir)

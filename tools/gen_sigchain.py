@@ -36,6 +36,7 @@ from bitcoincashplus_tpu.consensus.params import (  # noqa: E402
     regtest_params,
 )
 from bitcoincashplus_tpu.consensus.pow import compact_to_target  # noqa: E402
+from bitcoincashplus_tpu.consensus.serialize import hash_to_hex  # noqa: E402
 from bitcoincashplus_tpu.consensus.tx import (  # noqa: E402
     COutPoint,
     CTransaction,
@@ -185,8 +186,9 @@ def generate(datadir: str, total_sigs: int, inputs_per_tx: int = 250,
     index_kv = KVStore(os.path.join(blocks_dir, "index.sqlite"))
     coins_kv = KVStore(os.path.join(net_dir, "chainstate.sqlite"))
     store = BlockStore(net_dir, params.netmagic)
+    coins_db = CoinsDB(coins_kv)
     cs = ChainstateManager(
-        params, CoinsDB(coins_kv), store, script_verifier=None,
+        params, coins_db, store, script_verifier=None,
         index_db=BlockIndexDB(index_kv),
     )
 
@@ -213,6 +215,26 @@ def generate(datadir: str, total_sigs: int, inputs_per_tx: int = 250,
         n_txs[0] += len(blk.vtx)
         n_bytes[0] += len(blk.serialize())
         return blk
+
+    def finish(n_sigs: int, **extra) -> dict:
+        """Flush, close, and summarize what a -reindex over this datadir
+        must reproduce (tip, and the UTXO count of this chainstate)."""
+        store.flush()
+        cs.flush()
+        summary = {
+            "blocks": n_blocks[0],
+            "txs": n_txs[0],
+            "sigs": n_sigs,
+            "bytes": n_bytes[0],
+            "tip_height": n_blocks[0],
+            "tip_hash": hash_to_hex(cs.tip().hash),
+            "txouts": coins_db.count_coins(),
+            **extra,
+        }
+        store.close()
+        index_kv.close()
+        coins_kv.close()
+        return summary
 
     # Phase 1: coinbase runway. Fan-out txs each consume one MATURE (100+
     # deep) coinbase, so mint enough and add the maturity padding.
@@ -256,19 +278,7 @@ def generate(datadir: str, total_sigs: int, inputs_per_tx: int = 250,
     if mixed:
         n_sigs = _mixed_phase(utxos, push, key, spk, total_sigs,
                               inputs_per_tx, progress)
-        store.flush()
-        cs.flush()
-        store.close()
-        index_kv.close()
-        coins_kv.close()
-        return {
-            "blocks": n_blocks[0],
-            "txs": n_txs[0],
-            "sigs": n_sigs,
-            "bytes": n_bytes[0],
-            "tip_height": n_blocks[0],
-            "mixed": True,
-        }
+        return finish(n_sigs, mixed=True)
     progress(f"dense: {len(utxos)} sig-inputs, "
              f"{sigs_per_dense_block} per block")
     sigs_done = 0
@@ -297,18 +307,7 @@ def generate(datadir: str, total_sigs: int, inputs_per_tx: int = 250,
         progress(f"dense block {n_blocks[0]}: {sigs_done}/{len(utxos)} sigs "
                  f"({sigs_done / (time.monotonic() - t0):.0f} sigs/s gen)")
 
-    store.flush()
-    cs.flush()
-    store.close()
-    index_kv.close()
-    coins_kv.close()
-    return {
-        "blocks": n_blocks[0],
-        "txs": n_txs[0],
-        "sigs": len(utxos),
-        "bytes": n_bytes[0],
-        "tip_height": n_blocks[0],
-    }
+    return finish(len(utxos))
 
 
 def main():
