@@ -1,0 +1,83 @@
+"""What decides ``correct``: numbers compared with limits of their own.
+
+``no_fallback`` is chip_smoke.no_fallback_checks, reading counter deltas
+over the window instead of totals; the comparisons with the plain reference
+(chipbench/reference.py) live in each driver's ``check``.
+"""
+
+from __future__ import annotations
+
+
+def compared(name: str, value, limit, ok: bool = None, note: str = "") -> dict:
+    """One number beside its limit. ``ok`` defaults to value <= limit."""
+    if ok is None:
+        ok = value <= limit
+    return {"name": name, "value": value, "limit": limit, "ok": bool(ok),
+            **({"note": note} if note else {})}
+
+
+def programs_compiled(before: dict, after: dict) -> dict:
+    """{program: compiles inside the window}, the non-zero ones."""
+    b, a = before["device"]["programs"], after["device"]["programs"]
+    delta = {n: a[n]["compiles"] - b.get(n, {}).get("compiles", 0)
+             for n in a}
+    return {n: d for n, d in delta.items() if d}
+
+
+def no_fallback(before: dict, after: dict, *, sigs: int = 0,
+                lanes: int = 8190, cache_dir: str = None) -> list:
+    """Every way the window could have run somewhere else than on the TPU,
+    from two gettpuinfo snapshots; returns the names of the checks that
+    failed, each with what was read."""
+    bad: list = []
+
+    def check(name: str, ok: bool, read) -> None:
+        if not ok:
+            bad.append({"check": name, "read": read})
+
+    dev, batch, ecdsa = after["device"], after["batch"], after["ecdsa"]
+    b_batch, b_dd = before["batch"], before["ecdsa"]["dev_decompose"]
+    dd = ecdsa["dev_decompose"]
+    check("device.platform == tpu", dev["platform"] == "tpu",
+          dev["platform"])
+    verified = batch["sigs_verified"] - b_batch["sigs_verified"]
+    check("batch.sigs_verified moved by the window's signatures",
+          verified == sigs, verified)
+    for key in ("cpu_fallback_sigs", "fault_fallback_sigs", "kat_failures",
+                "pallas_fallbacks"):
+        check(f"batch.{key} did not move", batch[key] == b_batch[key],
+              batch[key] - b_batch[key])
+    if sigs:
+        check("ecdsa.kernel == glv", ecdsa["kernel"] == "glv",
+              ecdsa["kernel"])
+    check("ecdsa.glv_broken false", ecdsa["glv_broken"] is False,
+          ecdsa["glv_broken"])
+    check("ecdsa.glv_fallbacks did not move",
+          ecdsa["glv_fallbacks"] == before["ecdsa"]["glv_fallbacks"],
+          ecdsa["glv_fallbacks"])
+    check("dev_decompose.broken false", dd["broken"] is False, dd["broken"])
+    check("dev_decompose.fallbacks did not move",
+          dd["fallbacks"] == b_dd["fallbacks"], dd["fallbacks"])
+    check("dev_decompose.dispatches moved by the full buckets",
+          dd["dispatches"] - b_dd["dispatches"] == -(-sigs // lanes),
+          dd["dispatches"] - b_dd["dispatches"])
+    for name, br in after["breakers"].items():
+        was = before["breakers"].get(name, {})
+        check(f"breaker {name} closed, no fallbacks",
+              br["state"] == "closed"
+              and br["fallback_calls"] == was.get("fallback_calls", 0)
+              and br["fallback_items"] == was.get("fallback_items", 0),
+              {k: br[k] for k in ("state", "fallback_calls",
+                                  "fallback_items")})
+    for name, pw in dev["programs"].items():
+        check(f"program {name} within its shape budget",
+              pw["retraces_unexpected"] == 0
+              and (pw["shape_budget"] is None
+                   or pw["shapes"] <= pw["shape_budget"]),
+              {k: pw[k] for k in ("shapes", "shape_budget",
+                                  "retraces_unexpected")})
+    cc = dev["compilation_cache"]
+    check("compilation_cache enabled at the expected dir",
+          cc["enabled"] is True
+          and (cache_dir is None or cc["dir"] == cache_dir), cc)
+    return bad
