@@ -979,8 +979,9 @@ class Node:
         a persistent segment pipeline over long-lived template buffers,
         h7-truncated kernel on a real accelerator (fewest ops/nonce,
         candidates host-verified bit-identical) and the exact-compare
-        kernel on CPU backends (where the unrolled h7 program's XLA
-        compile is pathologically slow — ops/sha256._use_unrolled). With
+        kernel on CPU backends (where the sweep's digest is the looped
+        compress, which has no truncated form to save ops with —
+        ops/miner._sweep_tile). With
         -residentminer=off, the PR<=9 per-dispatch shapes: truncated-h7
         sweep_header_fast on the accelerator, the generic looped sweep on
         CPU. Every choice runs under miner-breaker supervision
@@ -1016,10 +1017,9 @@ class Node:
                 from ..mining.resident import ResidentSweep
 
                 kernel = "exact" if on_cpu else "h7"
-                # CPU backends take a smaller tile: the looped-
-                # compress kernel executes ~6k vector ops/nonce on
-                # host ALUs, so a 64Ki tile would make each segment
-                # settle hundreds of ms
+                # CPU backends take a smaller tile: a hit costs at
+                # least one whole tile of the looped compress, 48 ms
+                # at 16Ki against 82 ms at 64Ki (XLA:CPU, 8 cores)
                 self.resident_miner = ResidentSweep(
                     tile=(1 << 14) if on_cpu else (1 << 16),
                     kernel=kernel)

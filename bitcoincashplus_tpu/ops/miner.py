@@ -27,8 +27,10 @@ import numpy as np
 
 from ..crypto.hashes import header_midstate
 from .sha256 import (
+    _use_unrolled,
     bytes_to_words_np,
     digest_to_limbs,
+    header_sweep_digest,
     le256,
     target_to_limbs_np,
 )
@@ -49,7 +51,13 @@ def _sweep_tile(pre, target_limbs, base_nonce, tile: int):
     the resident loop), never per nonce."""
     lanes = jax.lax.broadcasted_iota(jnp.uint32, (tile, 1), 0).squeeze(-1)
     nonces = base_nonce + lanes
-    h8 = sweep_digest_hoisted(pre, nonces)
+    if _use_unrolled():
+        h8 = sweep_digest_hoisted(pre, nonces)
+    else:
+        # XLA:CPU never returns from the unrolled per-nonce programs: its
+        # instruction fusion duplicates their memoised schedule words
+        # into every consumer. The looped compress, from the same template
+        h8 = header_sweep_digest(pre["mid"], pre["tail"], nonces)
     ok = le256(digest_to_limbs(h8), target_limbs)
     hit = jnp.any(ok)
     idx = jnp.argmax(ok)

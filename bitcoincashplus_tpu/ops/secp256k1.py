@@ -1172,6 +1172,11 @@ def _glv_q_tables(qx, qy, ydiff_u, q_inf_u, one):
     tab = [q_jac, pt_double(q_jac)]
     for _j in range(3, 16):
         added, _hz = _pt_add_mixed_cheap_u(tab[-1], qx, qy, q_inf_u, one)
+        if not field_parallel():
+            # j·Q' is at infinity exactly where Q' is, so the flag the add
+            # derives is q_inf_u again; XLA:CPU's fusion emitter refuses
+            # ("Unknown MLIR failure") the 13-deep select chain behind it
+            added["inf"] = q_inf_u
         tab.append(added)
     entries = [tab[0]] + tab  # dummy 0 = 1·Q'
     t1 = tuple(

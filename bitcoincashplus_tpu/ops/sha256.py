@@ -74,7 +74,8 @@ def _use_unrolled() -> bool:
     superlinearly slowly (minutes per variant — measured this session), while
     the TPU (Mosaic/XLA-TPU) handles it fine. The looped form compiles in ms
     everywhere and is the CI/test path; numerics are identical and both forms
-    are differential-tested against hashlib.
+    are differential-tested against hashlib. The nonce sweep's tile bodies
+    (ops/miner._sweep_tile, ops/sha256_sweep.sweep_fast_jit) ask here too.
     """
     override = os.environ.get("BCP_SHA_UNROLL")
     if override is not None:
@@ -209,8 +210,8 @@ def header_sweep_digest(midstate8: list, tail3: list, nonces):
     compressions per nonce (vs 3 without midstate) — the optimization the
     scalar reference loop (src/rpc/mining.cpp:~120) misses.
 
-    This is the UNHOISTED reference form: the production sweep tile
-    (ops/miner._sweep_tile) rides ops/sha256_sweep.sweep_digest_hoisted,
+    This is the UNHOISTED form, the sweep tile's digest on the CPU backend;
+    on accelerators ops/miner._sweep_tile rides sweep_digest_hoisted,
     which additionally hoists the chunk-2 sweep-constant rounds/schedule
     legs per template (ROOFLINE.md §8); tests differential the two.
     """

@@ -58,7 +58,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..crypto.hashes import SHA256_INIT, SHA256_K, header_midstate, sha256d
-from .sha256 import bswap32, bytes_to_words_np, target_to_limbs_np
+from .sha256 import _use_unrolled, bswap32, bytes_to_words_np
+from .sha256 import header_sweep_digest, target_to_limbs_np
 
 _K = [np.uint32(k) for k in SHA256_K]
 _IV = [np.uint32(v) for v in SHA256_INIT]
@@ -150,6 +151,7 @@ def hoist_template(midstate8, tail3):
     (merkle tail, nTime, nBits). Returns a dict of sweep-constant scalars:
 
       mid    the midstate (for the chunk-2 feedback add)
+      tail   the tail words as given (with mid, all the looped form needs)
       st3    compression state after rounds 0..2 (they consume only
              w0..w2 — hoisted entirely)
       c3t1   round 3's folded scalar leg: h3 + Σ1(e3) + ch(e3,f3,g3) + K3
@@ -201,8 +203,8 @@ def hoist_template(midstate8, tail3):
         sc[32] = sc[16] + _s0(sc[17]) + sc[25]     # w16 + σ0(w17) + sc(w25)
         kwsc = {i: (_K[i] + sc[i]) if i in sc else _K[i]
                 for i in range(16, 33)}
-        return {"mid": list(midstate8), "st3": st, "c3t1": c3t1,
-                "t2_3": t2_3, "kw": kw, "sc": sc, "kwsc": kwsc}
+        return {"mid": list(midstate8), "tail": list(tail3), "st3": st,
+                "c3t1": c3t1, "t2_3": t2_3, "kw": kw, "sc": sc, "kwsc": kwsc}
 
 
 def _chunk2_digest_hoisted(pre, nonces):
@@ -346,7 +348,10 @@ def sweep_fast_jit(midstate, tail, t7, start_nonce, n_tiles, tile: int):
     def tile_fn(base):
         lanes = jax.lax.broadcasted_iota(jnp.uint32, (tile, 1), 0).squeeze(-1)
         nonces = base + lanes
-        h7 = sweep_h7_hoisted(pre, nonces)
+        if _use_unrolled():
+            h7 = sweep_h7_hoisted(pre, nonces)
+        else:  # as ops/miner._sweep_tile; the looped form has no h7 twin
+            h7 = header_sweep_digest(pre["mid"], pre["tail"], nonces)[7]
         ok = bswap32(h7) <= t7
         return jnp.any(ok), nonces[jnp.argmax(ok)]
 

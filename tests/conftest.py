@@ -29,7 +29,45 @@ from bitcoincashplus_tpu.util import devicewatch as _dw  # noqa: E402  (env firs
 
 _dw.enable_compile_cache()
 
+import faulthandler  # noqa: E402
+
 import pytest  # noqa: E402
+
+# Wall limit of every test, in seconds: three times what the longest
+# took from an empty compile cache on the 8-core sandbox (PR 25). A thread
+# inside jax.block_until_ready never runs a Python signal handler, so the
+# limit is the process's own: past it faulthandler dumps every thread's
+# stack and exits, xdist reports the test failed ("node down") and starts
+# a new worker, and the rest of the run goes on.
+# ``@pytest.mark.wall_limit(seconds, reason=...)`` sets another limit for
+# one named test; nothing switches the limit off.
+TEST_WALL_LIMIT_S = 600
+
+
+_STDERR_FD = pytest.StashKey[int]()
+
+
+def pytest_configure(config):
+    # the real stderr, before capture redirects fd 2 for each test
+    config.stash[_STDERR_FD] = os.dup(2)
+
+
+def pytest_unconfigure(config):
+    os.close(config.stash[_STDERR_FD])
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_protocol(item):
+    # around setup, call and teardown: the fixtures that mine a chain
+    # (module-scoped ones too) are inside the limit
+    marker = item.get_closest_marker("wall_limit")
+    limit = marker.args[0] if marker else TEST_WALL_LIMIT_S
+    faulthandler.dump_traceback_later(
+        limit, exit=True, file=item.config.stash[_STDERR_FD])
+    try:
+        return (yield)
+    finally:
+        faulthandler.cancel_dump_traceback_later()
 
 
 def pytest_collection_modifyitems(config, items):

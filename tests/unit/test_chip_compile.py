@@ -89,6 +89,27 @@ def test_resident_sweep_compiles_for_v5e(one_chip, chip_forms):
     assert compiled.memory_analysis().generated_code_size_in_bytes > 0
 
 
+@pytest.mark.parametrize("module,name,target_shape", [
+    ("sha256_sweep", "sweep_fast_jit", ()), ("miner", "sweep_jit", (8,))])
+def test_sweep_lowers_unrolled_for_v5e(one_chip, chip_forms, module, name,
+                                       target_shape):
+    """Both sweep programs keep their unrolled, hoisted per-nonce form for
+    the chip: the tile loop is the lowered text's only loop. The looped
+    compress that ops/miner._sweep_tile picks on the CPU backend would
+    bring two more. (PR 25 held the whole text of both, at tiles 2^16 and
+    2^12, equal before and after it: CHANGES.md.)"""
+    import importlib
+
+    program = getattr(
+        importlib.import_module(f"bitcoincashplus_tpu.ops.{module}"), name)
+    mid, tail, _, start, n_tiles = _sweep_args(one_chip)
+    target = jax.ShapeDtypeStruct(target_shape, jnp.uint32,
+                                  sharding=one_chip)
+    text = program.lower(mid, tail, target, start, n_tiles,
+                         tile=1 << 16).as_text()
+    assert text.count("stablehlo.while") == 1
+
+
 def test_pallas_sweep_compiles_for_v5e(one_chip, chip_forms):
     from bitcoincashplus_tpu.ops.pallas_sweep import pallas_sweep_jit
 

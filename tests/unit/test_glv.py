@@ -403,9 +403,11 @@ def test_glv_dev_retrace_sentinel_and_packer():
     the bounded-shape design); one of them rides the cross-block
     LanePacker so the aggregation layer provably feeds the fused
     program; host decompose stays untouched the whole time."""
-    from bitcoincashplus_tpu.util import devicewatch as dw
-
-    pw = dw.program("ecdsa_glv_decompose")
+    # the handle the dispatch leg holds, not dw.program(name): a
+    # dw.reset() by a suite earlier in this worker mints a fresh watch
+    # under the same name, and the leg's counts stay on the old one
+    pw = ecdsa_batch._PW_GLV_DEV
+    assert pw.name == "ecdsa_glv_decompose"
     d0 = pw.snapshot()["dispatches"]
     dev0 = ecdsa_batch.STATS.glv_dev_dispatches
     host_dec0 = ecdsa_batch.STATS.glv_decompose_s

@@ -75,7 +75,9 @@ def test_hoist_state_matches_cpu_oracle():
 def test_hoisted_digests_bit_identical():
     """Randomized 80-byte headers: hoisted full-digest and h7 kernels are
     bit-identical to BOTH the hashlib oracle and the unhoisted generic
-    sweep digest (ops/sha256.header_sweep_digest)."""
+    sweep digest (ops/sha256.header_sweep_digest). Eager on purpose: the
+    subject is the unrolled form's arithmetic, which XLA:CPU cannot run
+    jitted (ops/miner._sweep_tile)."""
     rng = np.random.default_rng(12)
     with jax.disable_jit():
         for _ in range(4):
@@ -97,15 +99,14 @@ def test_hoisted_digests_bit_identical():
 
 
 def test_hoisted_hits_identical_nonces():
-    """Hoisted sweeps find hits at the same nonces as the scalar CPU
+    """The jitted sweeps find hits at the same nonces as the scalar CPU
     reference loop (sweep_header_cpu) — generic and h7 paths."""
     header = b"\xab" * 80
-    with jax.disable_jit():
-        n_cpu, _ = miner.sweep_header_cpu(header, EASY, max_nonces=1 << 10)
-        n_gen, _ = miner.sweep_header(header, EASY, max_nonces=1 << 10,
-                                      tile=1 << 7)
-        n_fast, _ = sweep_header_fast(header, EASY, max_nonces=1 << 10,
-                                      tile=1 << 7)
+    n_cpu, _ = miner.sweep_header_cpu(header, EASY, max_nonces=1 << 10)
+    n_gen, _ = miner.sweep_header(header, EASY, max_nonces=1 << 10,
+                                  tile=1 << 7)
+    n_fast, _ = sweep_header_fast(header, EASY, max_nonces=1 << 10,
+                                  tile=1 << 7)
     assert n_cpu is not None
     assert n_gen == n_cpu
     assert n_fast == n_cpu
@@ -135,24 +136,22 @@ def test_sweep_header_clamps_at_boundary():
     tile = 1 << 7
     start = (1 << 32) - 4 * tile
     space = (1 << 32) - start
-    with jax.disable_jit():
-        # impossible target: full clamped sweep, honest accounting
-        nonce, hashes = miner.sweep_header(header, 0, start_nonce=start,
-                                           max_nonces=1 << 32, tile=tile)
-        assert nonce is None
-        assert hashes <= space
-        # the fast path clamps identically
-        nonce_f, hashes_f = sweep_header_fast(header, 0, start_nonce=start,
-                                              max_nonces=1 << 32, tile=tile)
+    # impossible target: full clamped sweep, honest accounting
+    nonce, hashes = miner.sweep_header(header, 0, start_nonce=start,
+                                       max_nonces=1 << 32, tile=tile)
+    assert nonce is None
+    assert hashes <= space
+    # the fast path clamps identically
+    nonce_f, hashes_f = sweep_header_fast(header, 0, start_nonce=start,
+                                          max_nonces=1 << 32, tile=tile)
     assert nonce_f is None
     assert hashes_f <= space
     # a hit that exists only BELOW the start (i.e. past the wrap) must
     # NOT be found by the clamped per-dispatch sweep
     low_hit = _first_hit_from(header, EASY, 0, 1 << 10)
     assert low_hit is not None and low_hit < start
-    with jax.disable_jit():
-        nonce, _ = miner.sweep_header(header, EASY, start_nonce=start,
-                                      max_nonces=1 << 32, tile=tile)
+    nonce, _ = miner.sweep_header(header, EASY, start_nonce=start,
+                                  max_nonces=1 << 32, tile=tile)
     if nonce is not None:  # a hit inside [start, 2^32) is legitimate
         assert nonce >= start
 
@@ -380,6 +379,36 @@ def test_residentminer_force_engages_loop(tmp_path):
 
         fams = telemetry.REGISTRY.snapshot()
         assert fams["bcp_mining_state_tiles_swept"]["type"] == "gauge"
+    finally:
+        node.close()
+
+
+def test_testnet_cpu_node_mines_with_resident_exact(tmp_path):
+    """Outside regtest a node on the CPU backend picks the resident loop
+    with the exact kernel (ops/miner.sweep_jit), and that choice mines:
+    one jitted segment over a trivial target returns the scalar loop's
+    nonce. (A real testnet target is 2^32 hashes away.)"""
+    from bitcoincashplus_tpu.node.config import Config
+    from bitcoincashplus_tpu.node.node import Node
+
+    cfg = Config()
+    cfg.args.update({"datadir": [str(tmp_path)], "testnet": ["1"],
+                     "listen": ["0"], "connect": ["0"], "dnsseed": ["0"]})
+    node = Node(config=cfg)
+    try:
+        assert node.params.network == "test"
+        sweep = node._select_sweep()
+        assert node.sweep_engine == "resident-exact"
+        assert node.resident_miner.kernel == "exact"
+        header = b"\x5a" * 80
+        for target in ((1 << 255) - 1, EASY):
+            n, hashes = sweep(header, target, max_nonces=1 << 15)
+            n_cpu, _ = miner.sweep_header_cpu(header, target,
+                                              max_nonces=1 << 15)
+            assert n is not None and n == n_cpu
+            assert hashes >= node.resident_miner.tile
+        assert node.resident_miner.polls >= 2   # the loop itself ran
+        assert node.resident_miner.hits == 2
     finally:
         node.close()
 

@@ -17,6 +17,7 @@ from bitcoincashplus_tpu.crypto.hashes import header_midstate, sha256d
 from bitcoincashplus_tpu.ops import miner as tpu_miner
 from bitcoincashplus_tpu.ops import sha256 as ops_sha
 from bitcoincashplus_tpu.ops.merkle import compute_merkle_root_tpu
+from bitcoincashplus_tpu.ops.sha256_sweep import sweep_header_fast
 
 import jax.numpy as jnp
 
@@ -87,8 +88,16 @@ class TestSweepDigest:
 
 
 class TestSweep:
-    def test_finds_known_nonce_regtest(self):
-        """Mine a regtest-difficulty header and verify the found nonce."""
+    # both host APIs, jitted, on the backend the tests run on (CPU), at
+    # the tile generate_blocks gives them: the pair that stood still for
+    # fifteen PRs (ops/miner._sweep_tile)
+    SWEEPS = pytest.mark.parametrize(
+        "sweep", [tpu_miner.sweep_header, sweep_header_fast],
+        ids=["generic", "fast"])
+
+    @SWEEPS
+    def test_finds_known_nonce_regtest(self, sweep):
+        """Mine a regtest-difficulty header: the scalar loop's nonce."""
         params = regtest_params()
         hdr = CBlockHeader(
             version=0x20000000,
@@ -99,22 +108,34 @@ class TestSweep:
             nonce=0,
         )
         target, _ = compact_to_target(hdr.bits)
-        nonce, hashes = tpu_miner.sweep_header(
+        nonce, hashes = sweep(
             hdr.serialize(), target, tile=4096, max_nonces=1 << 20
         )
         assert nonce is not None
         mined = hdr.with_nonce(nonce)
         assert int.from_bytes(mined.get_hash(), "little") <= target
-        # First-hit semantics: no smaller nonce passes within the swept range
-        # (spot-check the tile that contained the hit).
-        base = (nonce // 4096) * 4096
-        for n in range(base, nonce):
-            cand = hdr.with_nonce(n)
-            assert int.from_bytes(cand.get_hash(), "little") > target
+        want, _ = tpu_miner.sweep_header_cpu(hdr.serialize(), target,
+                                             max_nonces=4096)
+        assert nonce == want
 
-    def test_not_found_at_impossible_target(self):
+    @SWEEPS
+    def test_first_hit_and_the_next_equal_scalar_loop(self, sweep):
         hdr = _random_headers(1)[0].tobytes()
-        nonce, hashes = tpu_miner.sweep_header(hdr, target=0, max_nonces=1 << 14, tile=4096)
+        target, _ = compact_to_target(0x1F7FFFFF)  # a hit every ~512
+        want, _ = tpu_miner.sweep_header_cpu(hdr, target, max_nonces=1 << 14)
+        nonce, hashes = sweep(hdr, target, tile=4096, max_nonces=1 << 14)
+        assert want is not None and nonce == want
+        assert hashes == (want // 4096 + 1) * 4096
+        want2, _ = tpu_miner.sweep_header_cpu(
+            hdr, target, start_nonce=want + 1, max_nonces=1 << 14)
+        nonce2, _ = sweep(hdr, target, start_nonce=want + 1, tile=4096,
+                          max_nonces=1 << 14)
+        assert nonce2 == want2
+
+    @SWEEPS
+    def test_not_found_at_impossible_target(self, sweep):
+        hdr = _random_headers(1)[0].tobytes()
+        nonce, hashes = sweep(hdr, target=0, max_nonces=1 << 14, tile=4096)
         assert nonce is None
         assert hashes == 1 << 14
 
