@@ -1,13 +1,14 @@
-"""Device-resident mining loop (the ISSUE 10 tentpole).
+"""Device-resident mining loop (the ISSUE 10 tentpole; sized by ISSUE 26).
 
-BENCH_r05 measured the nonce sweep at 0.59 MH/s device-resident but
-0.04 MH/s end-to-end — ~15x lost to host dispatch — and BENCH_r08's
-per-phase decomposition pinned the blame on per-call enqueue/fetch, not
-the kernel (ROOFLINE.md has the kernel at 88% of its op-bound ceiling).
 The per-call shape (``ops/miner.sweep_header``) pays, on EVERY poll:
 host->device staging of the template (midstate/tail/target), a fresh
 program dispatch, a blocking scalar fetch, and the full devicewatch/
 breaker bookkeeping — serially, with the device idle between calls.
+The loss is above the kernel, not in it: on one TPU v5e the h7 kernel
+runs at 0.6565 GH/s (62.56% of its op-bound ceiling) while segments of a
+fixed 8 tiles, 0.8 ms of kernel against ~2.5 ms of host each, gave
+0.209-0.211 GH/s end to end with the chip idle 73% of the window
+(PERF_LEDGER.jsonl, PR 25, cell ``mine.diff1_solo``).
 
 ``ResidentSweep`` keeps the sweep resident instead:
 
@@ -18,11 +19,21 @@ breaker bookkeeping — serially, with the device idle between calls.
   devicewatch compile sentinel as the ``miner_resident`` program with a
   shape budget. The retrace-sentinel test asserts repeated swaps stay
   inside it.
-- **Pipelined segments.** The nonce space is swept in fixed-size
-  segments (``seg_tiles`` tiles per dispatch); up to ``inflight``
-  segments ride the device queue at once (JAX async dispatch), so the
-  host settles segment k while k+1 already executes — enqueue/fetch
-  overhead overlaps the hash work instead of serializing with it.
+- **Pipelined segments.** The nonce space is swept in segments of
+  ``seg_tiles`` tiles, one program dispatch and one blocking fetch each;
+  up to ``inflight`` segments ride the device queue at once (JAX async
+  dispatch), so the host settles segment k while k+1 already executes —
+  enqueue/fetch overhead overlaps the hash work instead of serializing
+  with it.
+- **A segment is a time, not a tile count.** How much device work one
+  host round trip buys follows what the loop observes (``_resize``):
+  while the host spends under ``DEVICE_PACED_SHARE`` of its poll cadence
+  blocked on the device, the host sets the pace and the chip starves, so
+  the segment doubles (``n_tiles`` is a traced argument: no new compile)
+  until one segment reaches ``SEG_CEILING_S`` of device time. A backend
+  on which the host always waits (XLA:CPU, ~48 ms a tile) never grows.
+  The learned length lives on the long-lived object, so only a
+  process's first call ramps. An explicit ``seg_tiles=`` pins it.
 - **On-chip nonce-space rollover.** Segment arithmetic is uint32; the
   host cursor clamps each segment at the 2^32 boundary
   (``ops/miner._boundary_tiles`` semantics) and wraps to 0, counting
@@ -58,12 +69,39 @@ from ..crypto.hashes import header_midstate, sha256d
 from ..util import devicewatch as dw
 from ..util import telemetry as tm
 
+_now = time.perf_counter
+
 PROGRAM = "miner_resident"
 # compiled-shape budget for the resident program: (kernel, tile)
 # specializations — a node mints at most the exact + h7 kernels at the
 # production tile plus a regtest/bench tile each; a template swap that
 # starts recompiling trips the sentinel (asserted in the mining tests)
 SHAPE_BUDGET = 4
+
+# where a loop that sizes its own segments starts (what every segment was
+# before ISSUE 26): a short budget or a slow backend stays here
+SEG_TILES_START = 8
+# most device time one segment may carry, in seconds. Two segments ride
+# the queue, so at a template swap or behind a hit at most ~130 ms of
+# stale work stands before the new template's first nonce (0.02% of a
+# 600 s block interval), and the ``miner`` watchdog still beats more than
+# ten times a second. On a v5e that is 512 tiles of 2^16 (51 ms at
+# 0.6565 GH/s): 16 round trips for a 2^29-nonce call instead of 1,024.
+SEG_CEILING_S = 0.064
+# the device sets the pace once the host spends at least this share of
+# its poll cadence blocked in the fetch: what is left, the host's own work
+# a round trip, is all a longer segment could still hide, and under a
+# hundredth it is not worth the staleness
+DEVICE_PACED_SHARE = 0.99
+# consecutive polls one sizing decision sums over. A host that settles
+# late shortens the next gap, but any n consecutive gaps of a two-deep
+# queue hold n-1 whole segments, so their mean is at least 3/4 of a
+# segment's device time: a hiccup cannot ratchet the length past 4/3 of
+# the ceiling, and a doubling is judged only on segments that have it
+SIZING_POLLS = 4
+_NO_POLLS = (0, 0.0, 0.0)
+# weight of the newest poll in the cadence and wait averages
+_EMA = 0.2
 
 _TILES_C = tm.counter(
     "bcp_mining_tiles_swept_total",
@@ -90,6 +128,11 @@ _POLL_H = tm.histogram(
 _FIFO_G = tm.gauge(
     "bcp_mining_fifo_depth",
     "Confirmed candidate hits parked in the resident loop's FIFO")
+
+
+def _ema(avg: float, x: float) -> float:
+    """``avg`` moved toward ``x``; an average still at 0.0 starts at x."""
+    return x if avg == 0.0 else (1 - _EMA) * avg + _EMA * x
 
 
 def _clamp_segment(cursor: int, want: int, tile: int, cap: int):
@@ -121,15 +164,20 @@ class ResidentSweep:
     top-limb kernel (ops/sha256_sweep.sweep_fast_jit — fewer ops/nonce,
     candidates host-verified). ``tile`` is the STATIC compiled shape;
     the loop never recompiles for a template swap, only for a new
-    (kernel, tile) pair, bounded by the devicewatch shape budget."""
+    (kernel, tile) pair, bounded by the devicewatch shape budget.
+    ``seg_tiles`` pins the tiles a segment carries; left out, the loop
+    sizes its segments from its own poll timing (``_resize``)."""
 
-    def __init__(self, tile: int = 1 << 16, seg_tiles: int = 8,
+    def __init__(self, tile: int = 1 << 16,
+                 seg_tiles: Optional[int] = None,
                  inflight: int = 2, fifo_depth: int = 16,
                  kernel: str = "exact"):
         if kernel not in ("exact", "h7"):
             raise ValueError(f"resident kernel {kernel!r}: exact or h7")
         self.tile = int(tile)
-        self.seg_tiles = max(1, int(seg_tiles))
+        self._sized = seg_tiles is None
+        self.seg_tiles = (SEG_TILES_START if self._sized
+                          else max(1, int(seg_tiles)))
         self.inflight = max(1, int(inflight))
         self.kernel = kernel
         self.fifo = deque(maxlen=max(1, int(fifo_depth)))
@@ -152,8 +200,10 @@ class ResidentSweep:
         self.stale_hits = 0
         self.segments_discarded = 0
         self.fifo_dropped = 0
-        self._poll_ema_s = 0.0      # inter-poll cadence (EMA)
-        self._last_poll_t = 0.0
+        self._poll_ema_s = 0.0      # gap between settles of a call (EMA)
+        self._wait_ema_s = 0.0      # the part of it blocked in the fetch
+        self._last_poll_t: Optional[float] = None  # last settle, this call
+        self._win = _NO_POLLS       # sizing window: polls, gaps, waits
 
     # -- template lifecycle (buffer swap, never a retrace) --------------
 
@@ -163,7 +213,7 @@ class ResidentSweep:
         program — bumps the generation, and invalidates in-flight
         segments (their results are counted stale, never trusted).
         Idempotent for an unchanged template."""
-        import jax.numpy as jnp
+        import jax
 
         from ..ops.sha256 import bytes_to_words_np, target_to_limbs_np
 
@@ -183,10 +233,11 @@ class ResidentSweep:
                      + np.asarray(self._tgt_np).nbytes)
         dw.note_transfer("miner_resident", "h2d", nbytes)
         # the swap: fresh same-shape device buffers replace the old ones
-        # (the old buffers are freed once their in-flight segments settle)
-        self._mid = jnp.asarray(self._mid_np)
-        self._tail = jnp.asarray(self._tail_np)
-        self._tgt = jnp.asarray(self._tgt_np)
+        # (the old buffers are freed once their in-flight segments settle);
+        # a plain transfer, no program (jnp.asarray of the h7 target's
+        # numpy scalar ran a convert_element_type a template)
+        self._mid, self._tail, self._tgt = jax.device_put(
+            (self._mid_np, self._tail_np, self._tgt_np))
         self.generation += 1
         self.buffer_swaps += 1
         _SWAPS_C.inc()
@@ -205,22 +256,32 @@ class ResidentSweep:
         return sweep_jit
 
     def _dispatch(self, start: int, n_tiles: int):
-        """Enqueue one segment dispatch under the compile sentinel; the
-        shape signature is (kernel, tile) — template swaps re-dispatch
-        the SAME signature, so the shapes count must stay flat."""
-        import jax.numpy as jnp
-
+        """Enqueue one segment: ONE program under the compile sentinel.
+        The shape signature is (kernel, tile) — template swaps and
+        segment lengths re-dispatch the SAME signature, so the shapes
+        count must stay flat. ``start`` and ``n_tiles`` go in as numpy
+        scalars, arguments of that one call (a ``jnp.uint32(...)`` each
+        would be a ``convert_element_type`` program of its own)."""
         jitfn = self._jitfn()
-        args = (self._mid_np, self._tail_np, self._tgt_np,
-                np.uint32(start), np.uint32(n_tiles))
+        start, n_tiles = np.uint32(start), np.uint32(n_tiles)
         with dw.program(PROGRAM, shape_budget=SHAPE_BUDGET).dispatch(
-                self.kernel, self.tile, jitfn=jitfn, args=args,
+                self.kernel, self.tile, jitfn=jitfn,
+                args=(self._mid_np, self._tail_np, self._tgt_np,
+                      start, n_tiles),
                 kwargs={"tile": self.tile}):
-            out = jitfn(self._mid, self._tail, self._tgt,
-                        jnp.uint32(start), jnp.uint32(n_tiles),
+            out = jitfn(self._mid, self._tail, self._tgt, start, n_tiles,
                         tile=self.tile)
         dw.note_transfer("miner_resident", "h2d", 8)  # 2 uint32 scalars
         return out
+
+    @staticmethod
+    def _fetch(out):
+        """ONE blocking fetch of a segment's (found, nonce, tiles): the
+        three copies are started together and waited for once."""
+        import jax
+
+        found, nonce, tiles = jax.device_get(out)
+        return bool(found), int(nonce), int(tiles)
 
     def _pump(self, budget_left: int) -> int:
         """Enqueue segments (rollover-aware) until the in-flight window
@@ -246,28 +307,53 @@ class ResidentSweep:
         """Block on the oldest in-flight segment; returns (seg, found,
         cand_nonce, tiles_done). Meters the poll, beats the watchdog."""
         seg = self._segments.popleft()
-        t0 = time.perf_counter()
-        found, nonce, tiles = seg.out
-        found = bool(found)
-        nonce = int(nonce)
-        tiles = int(tiles)
-        dt = time.perf_counter() - t0
+        t0 = _now()
+        found, nonce, tiles = self._fetch(seg.out)
+        now = _now()
+        dt = now - t0
         _POLL_H.observe(dt)
         _POLLS_C.inc()
         dw.note_transfer("miner_resident", "d2h", 12, seconds=dt)
         dw.note_phase("miner_resident", "fetch", dt)
-        now = time.perf_counter()
-        if self._last_poll_t:
+        if self._last_poll_t is None:   # a call's first settle: no gap
+            self._win = _NO_POLLS
+        else:
             gap = now - self._last_poll_t
-            self._poll_ema_s = (gap if self._poll_ema_s == 0.0
-                                else 0.8 * self._poll_ema_s + 0.2 * gap)
+            self._poll_ema_s = _ema(self._poll_ema_s, gap)
+            self._wait_ema_s = _ema(self._wait_ema_s, dt)
+            self._resize(seg, dt, gap)
         self._last_poll_t = now
         self.polls += 1
-        done_tiles = tiles
-        self.tiles_swept += done_tiles
-        _TILES_C.inc(done_tiles)
+        self.tiles_swept += tiles
+        _TILES_C.inc(tiles)
         dw.WATCHDOG.beat("miner")
         return seg, found, nonce, tiles
+
+    def _resize(self, seg: _Segment, wait: float, gap: float) -> None:
+        """The sizing rule. ``gap`` is the time since the previous settle
+        of this call and ``wait`` the part of it blocked in the fetch,
+        summed over SIZING_POLLS consecutive segments of the present
+        length (one clamped to a budget or the 2^32 boundary, or still in
+        flight from before a doubling, is no evidence and starts the
+        window again). With the queue kept full the mean gap stands for
+        a segment's device time: the device cannot finish them faster.
+        While the waits are under DEVICE_PACED_SHARE of the gaps the host
+        sets the pace: double, unless a doubled segment could pass
+        SEG_CEILING_S."""
+        if not self._sized:
+            return
+        if seg.n_tiles != self.seg_tiles:
+            self._win = _NO_POLLS
+            return
+        n, gaps, waits = self._win
+        n, gaps, waits = n + 1, gaps + gap, waits + wait
+        if n < SIZING_POLLS:
+            self._win = (n, gaps, waits)
+            return
+        self._win = _NO_POLLS
+        if (waits < DEVICE_PACED_SHARE * gaps
+                and 2 * gaps / n <= SEG_CEILING_S):
+            self.seg_tiles *= 2
 
     def _confirm(self, nonce: int) -> bool:
         """Host exact-verify of a device candidate (the scalar oracle)."""
@@ -285,8 +371,7 @@ class ResidentSweep:
         while nonces_left > 0:
             n_tiles, nonces = _clamp_segment(
                 start, nonces_left, self.tile, self.seg_tiles)
-            out = self._dispatch(start, n_tiles)
-            found, cand, tiles = bool(out[0]), int(out[1]), int(out[2])
+            found, cand, tiles = self._fetch(self._dispatch(start, n_tiles))
             done = min(tiles * self.tile, nonces)
             self.tiles_swept += tiles
             self.nonces_swept += done
@@ -321,6 +406,7 @@ class ResidentSweep:
         self.segments_discarded += len(self._segments)
         self._segments.clear()
         self._cursor = start_nonce & 0xFFFFFFFF
+        self._last_poll_t = None
         budget = min(max_nonces, 1 << 32)
         swept = 0
         planned = self._pump(budget)
@@ -367,6 +453,7 @@ class ResidentSweep:
         assert self._header76 is not None, "set_template first"
         gen = self.generation
         new_hits = 0
+        self._last_poll_t = None
         planned = self._pump(nonce_budget)
         while self._segments:
             seg, found, cand, tiles = self._settle_oldest()
@@ -450,6 +537,10 @@ class ResidentSweep:
             "rollover_passes": self.passes,
             "polls": self.polls,
             "poll_cadence_s": round(self._poll_ema_s, 6),
+            # ~0: the host starves the chip; near 1: the device paces
+            "poll_wait_share": round(
+                self._wait_ema_s / self._poll_ema_s, 4)
+            if self._poll_ema_s else 0.0,
             "fifo_depth": len(self.fifo),
             "fifo_capacity": self.fifo.maxlen,
             "fifo_dropped": self.fifo_dropped,

@@ -280,12 +280,280 @@ def test_template_swaps_do_not_retrace():
         snap = dw.program(PROGRAM).snapshot()
         shapes_after_first = snap["shapes"]
         retraces_before = snap["retraces_unexpected"]
-        for fill in (0x42, 0x43, 0x44):
+        compiled = miner.sweep_jit._cache_size()
+        # a segment's length is a traced argument like the template: the
+        # lengths the sizing rule moves through are one compiled shape
+        for fill, rs.seg_tiles in ((0x42, 1), (0x43, 4), (0x44, 64)):
             rs.sweep(bytes([fill]) * 80, EASY, max_nonces=1 << 11)
+            rs.sweep(bytes([fill]) * 80, 0, max_nonces=1 << 11)
         snap = dw.program(PROGRAM).snapshot()
-        assert rs.buffer_swaps >= 4
+        assert rs.buffer_swaps >= 7
         assert snap["shapes"] == shapes_after_first
         assert snap["retraces_unexpected"] == retraces_before
+        assert miner.sweep_jit._cache_size() == compiled
+    finally:
+        rs.close()
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 26: segments sized by device time, one dispatch and one fetch each
+# ---------------------------------------------------------------------------
+
+class _Pending:
+    """What the stub kernel hands back: readable only through the fetch."""
+
+    def __init__(self, ready, value):
+        self.ready, self.value = ready, value
+
+
+class _FakeChip:
+    """A stub kernel and clock. The device runs segments in order at
+    ``per_tile`` seconds a tile; each dispatch and each fetch costs the
+    host ``host_s``, and ``stall`` maps a fetch's index to extra seconds
+    the host loses before it (a hiccup). Never finds a nonce."""
+
+    def __init__(self, per_tile, host_s, stall=None):
+        self.per_tile, self.host_s, self.stall = per_tile, host_s, stall or {}
+        self.t, self.free = 1.0, 0.0
+        self.calls, self.fetches = [], 0
+
+    def now(self):
+        return self.t
+
+    def kernel(self, mid, tail, tgt, start, n_tiles, tile):
+        self.t += self.host_s
+        self.free = max(self.t, self.free) + int(n_tiles) * self.per_tile
+        self.calls.append((int(start), int(n_tiles)))
+        return _Pending(self.free, (False, 0, int(n_tiles)))
+
+    def fetch(self, out):
+        self.t += self.stall.get(self.fetches, 0.0)
+        self.fetches += 1
+        self.t = max(self.t + self.host_s, out.ready)
+        return out.value
+
+    def drive(self, monkeypatch, **kw):
+        from bitcoincashplus_tpu.mining import resident
+
+        rs = ResidentSweep(tile=1 << 16, kernel="h7", **kw)
+        rs._jitfn = lambda: self.kernel
+        rs._fetch = self.fetch
+        monkeypatch.setattr(resident, "_now", self.now)
+        return rs
+
+    def longest_s(self):
+        return max(n for _, n in self.calls) * self.per_tile
+
+
+V5E_TILE_S = 1e-4      # 2^16 nonces at 0.6565 GH/s (ledger, PR 25)
+HOST_TRIP_S = 1.25e-3  # half of the ~2.5 ms a segment cost the host there
+CALL = 1 << 29         # the benchmark's maxtries
+
+
+def test_host_bound_loop_grows_to_the_ceiling(monkeypatch):
+    """A chip-like device under a host that needs three kernel-times a
+    segment: the length doubles until one segment is ~51 ms, every nonce
+    of the budget is dispatched once and in order, and the next call
+    starts at the learned length."""
+    from bitcoincashplus_tpu.mining.resident import (
+        SEG_CEILING_S, SEG_TILES_START)
+
+    chip = _FakeChip(V5E_TILE_S, HOST_TRIP_S)
+    rs = chip.drive(monkeypatch)
+    assert rs.seg_tiles == SEG_TILES_START
+    assert rs.sweep(b"\x71" * 80, 0, max_nonces=CALL) == (None, CALL)
+    assert rs.seg_tiles == 512
+    assert SEG_CEILING_S / 2 < chip.longest_s() <= SEG_CEILING_S
+    assert sum(n for _, n in chip.calls) == CALL >> 16
+    cursor = 0
+    for start, n in chip.calls:
+        assert start == cursor
+        cursor += n << 16
+    snap = rs.snapshot()
+    assert snap["seg_tiles"] == 512 and snap["tile"] == 1 << 16
+    assert snap["poll_wait_share"] > 0.9      # the device sets the pace
+    first_call = len(chip.calls)
+    rs.sweep(b"\x72" * 80, 0, max_nonces=CALL)
+    assert chip.calls[first_call] == (0, 512)           # no second ramp
+    assert len(chip.calls) - first_call == (CALL >> 16) // 512
+
+
+@pytest.mark.parametrize("per_tile,host_s", [
+    (48e-3, 1e-3),     # XLA:CPU at tile 2^14: 8 tiles are already 0.4 s
+    (1e-3, 1e-5),      # under the ceiling, but the host always waits
+])
+def test_device_bound_loop_holds(monkeypatch, per_tile, host_s):
+    from bitcoincashplus_tpu.mining.resident import SEG_TILES_START
+
+    chip = _FakeChip(per_tile, host_s)
+    rs = chip.drive(monkeypatch)
+    rs.sweep(b"\x73" * 80, 0, max_nonces=1 << 23)
+    rs.sweep(b"\x74" * 80, 0, max_nonces=1 << 23)
+    assert rs.seg_tiles == SEG_TILES_START
+    assert {n for _, n in chip.calls} == {SEG_TILES_START}
+    assert rs.snapshot()["poll_wait_share"] > 0.99
+
+
+@pytest.mark.parametrize("per_tile,host_s,stall", [
+    (V5E_TILE_S, HOST_TRIP_S, {}),
+    (V5E_TILE_S, 1e-4, {}),
+    (3e-5, 5e-3, {}),
+    (7e-4, 2e-3, {}),
+    # a host that loses 45-90 ms now and then settles late, and the gap
+    # after a late settle is short: that must not ratchet the length up
+    (V5E_TILE_S, HOST_TRIP_S, {k: 0.045 for k in range(40, 4000, 3)}),
+    (V5E_TILE_S, HOST_TRIP_S, {k: 0.09 for k in range(40, 4000, 5)}),
+    (V5E_TILE_S, 2e-4, {k: 0.03 for k in range(0, 4000, 2)}),
+])
+def test_segment_never_outgrows_the_ceiling(monkeypatch, per_tile, host_s,
+                                            stall):
+    """Whatever the device's and the host's speeds, no segment carries
+    more than the ceiling's device time (4/3 of it under hiccups: the
+    bound SIZING_POLLS states)."""
+    from bitcoincashplus_tpu.mining.resident import SEG_CEILING_S
+
+    chip = _FakeChip(per_tile, host_s, stall)
+    rs = chip.drive(monkeypatch)
+    for fill in (0x75, 0x76, 0x77, 0x78):
+        rs.sweep(bytes([fill]) * 80, 0, max_nonces=CALL)
+    assert chip.longest_s() <= SEG_CEILING_S * (4 / 3 if stall else 1)
+    assert sum(n for _, n in chip.calls) == 4 * (CALL >> 16)
+
+
+def test_explicit_seg_tiles_pins_the_length(monkeypatch):
+    chip = _FakeChip(V5E_TILE_S, HOST_TRIP_S)
+    rs = chip.drive(monkeypatch, seg_tiles=8)
+    rs.sweep(b"\x79" * 80, 0, max_nonces=1 << 26)
+    assert rs.seg_tiles == 8
+    assert {n for _, n in chip.calls} == {8}
+    assert rs.snapshot()["poll_wait_share"] < 0.6       # and it starves
+
+
+def test_short_budget_dispatches_what_it_did(monkeypatch):
+    """A budget under the sizing window (regtest, the default maxtries of
+    10^6) never moves the length: its segments are those of seg_tiles=8."""
+    chip = _FakeChip(V5E_TILE_S, HOST_TRIP_S)
+    rs = chip.drive(monkeypatch)
+    for fill in range(6):
+        rs.sweep(bytes([fill]) * 80, 0, max_nonces=1 << 20)
+    assert rs.seg_tiles == 8
+    assert [n for _, n in chip.calls] == [8, 8] * 6
+
+
+def test_segment_is_one_dispatch_and_one_fetch(monkeypatch):
+    """A segment costs the host one program and one blocking fetch: the
+    start and the tile count reach the kernel as numpy scalars, arguments
+    of that one call (a jax array here was a convert_element_type program
+    of its own, two a segment), and its three results come back through
+    one device_get."""
+    rs = ResidentSweep(tile=1 << 9, seg_tiles=2, inflight=2, kernel="exact")
+    real, seen, gets = rs._jitfn(), [], []
+
+    def kernel(mid, tail, tgt, start, n_tiles, tile):
+        seen.append((start, n_tiles))
+        return real(mid, tail, tgt, start, n_tiles, tile=tile)
+
+    def device_get(tree):
+        gets.append(tree)
+        return real_get(tree)
+
+    real_get = jax.device_get
+    monkeypatch.setattr(jax, "device_get", device_get)
+    rs._jitfn = lambda: kernel
+    try:
+        assert rs.sweep(b"\x7a" * 80, 0, max_nonces=1 << 13) == (None, 1 << 13)
+    finally:
+        rs.close()
+    assert len(seen) == rs.polls == 8
+    for start, n_tiles in seen:
+        assert type(start) is np.uint32 and type(n_tiles) is np.uint32
+    assert len(gets) == 8 and all(len(tree) == 3 for tree in gets)
+
+
+# one header whose double hashes are known around the scenarios below
+# (hashlib, offline): over nonces [-49152, 131072) the lowest hash is at
+# 74763, and over [-700, 48452) at 3743
+DIFF_HEADER = b"\x03" * 80
+DIFF_TILE = 1 << 9
+DIFF_BUDGET = 96 * DIFF_TILE
+LOWEST, LOWEST_PAST_WRAP = 74763, 3743
+
+
+def _hash_at(header80, nonce):
+    return int.from_bytes(
+        sha256d(header80[:76] + nonce.to_bytes(4, "little")), "little")
+
+
+@pytest.mark.parametrize("kernel", ["exact", "h7"])
+@pytest.mark.parametrize("seg_tiles", [1, 2, 64, 1 << 20])
+def test_segment_lengths_differential(seg_tiles, kernel):
+    """Segment lengths 1, 2, 64 tiles and longer than the budget, both
+    kernels, against sweep_header_cpu: which nonce wins does not depend on
+    how the budget is cut into segments, and nonces_swept takes a hit's
+    tile whole and a discarded segment not at all."""
+    tile, budget = DIFF_TILE, DIFF_BUDGET
+    seg_nonces = min(seg_tiles * tile, budget)
+    rs = ResidentSweep(tile=tile, seg_tiles=seg_tiles, inflight=2,
+                       kernel=kernel)
+
+    def run(target, start):
+        before = rs.nonces_swept, rs.segments_discarded
+        got = rs.sweep(DIFF_HEADER, target, start_nonce=start,
+                       max_nonces=budget)
+        want = miner.sweep_header_cpu(DIFF_HEADER, target,
+                                      start_nonce=start, max_nonces=budget)
+        assert got[0] == want[0]
+        counted = rs.nonces_swept - before[0]
+        assert got[1] == counted
+        if want[0] is None:
+            assert counted == budget
+        else:   # the hit's tile whole, nothing behind it
+            assert 0 <= counted - want[1] < tile
+        return want, rs.segments_discarded - before[1]
+
+    try:
+        # first hit in nonce order, of many
+        (nonce, _), _ = run(1 << 250, 0)
+        assert nonce is not None
+        # a hit in the first tile: the segment behind it is discarded
+        (nonce, tried), dropped = run(_hash_at(DIFF_HEADER, LOWEST),
+                                      LOWEST - 5)
+        assert (nonce, tried) == (LOWEST, 6)
+        assert dropped == (1 if seg_nonces < budget else 0)
+        # a hit in the last tile of the first segment
+        (nonce, tried), _ = run(_hash_at(DIFF_HEADER, LOWEST),
+                                LOWEST - (seg_nonces - 3))
+        assert nonce == LOWEST and (tried - 1) // tile == seg_nonces // tile - 1
+        # the 2^32 rollover, from an unaligned start
+        passes = rs.passes
+        (nonce, tried), _ = run(_hash_at(DIFF_HEADER, LOWEST_PAST_WRAP),
+                                (1 << 32) - 700)
+        assert (nonce, tried) == (LOWEST_PAST_WRAP, 700 + LOWEST_PAST_WRAP + 1)
+        assert rs.passes == passes + 1
+        # no hit: the whole budget, counted exactly
+        run(0, 12345)
+    finally:
+        rs.close()
+
+
+@pytest.mark.parametrize("kernel", ["exact", "h7"])
+@pytest.mark.parametrize("seg_tiles", [1, 2, 64, 1 << 20])
+def test_false_positive_resumed_inside_segment(seg_tiles, kernel):
+    """A candidate the host refuses (the h7 limb tie, planted) is resumed
+    past inside its segment, however long: the next real hit wins."""
+    target = 1 << 250
+    real = [n for n in range(1 << 11)
+            if _hash_at(DIFF_HEADER, n) <= target]
+    assert len(real) >= 2
+    rs = ResidentSweep(tile=DIFF_TILE, seg_tiles=seg_tiles, inflight=2,
+                       kernel=kernel)
+    try:
+        true_confirm = rs._confirm
+        rs._confirm = lambda n: n != real[0] and true_confirm(n)
+        nonce, tried = rs.sweep(DIFF_HEADER, target, max_nonces=DIFF_BUDGET)
+        assert nonce == real[1]
+        assert rs.false_positives == 1 and rs.hits == 1
+        assert tried == rs.nonces_swept >= real[1] + 1
     finally:
         rs.close()
 
