@@ -15,6 +15,7 @@ from __future__ import annotations
 import os
 import threading
 import time
+from collections import deque
 from contextlib import contextmanager
 from typing import Optional
 
@@ -53,6 +54,52 @@ class _NativeImportAbort(Exception):
     """A staged fast-import block's signature batch failed after commit —
     recover by rebuilding from the last flush and replaying through the
     Python engine (node.import_block_files)."""
+
+
+class _MultisigSettler:
+    """Deferred OP_CHECKMULTISIG groups of the native import, settled as
+    its dispatches settle (script/interpreter.py, module docstring).
+
+    Lanes are numbered over the whole import, in the order they entered the
+    aggregation; dispatches settle first in, first out, each a contiguous
+    slice of those numbers, so a group is whole once the slice that holds
+    its last lane has settled, whichever slice its first lane rode (a group
+    straddling two dispatches settles with the later). A group whose walk
+    fails on the verdicts goes to ``confirm(owner)``, which decides on the
+    host: the device's word alone rejects nothing."""
+
+    def __init__(self, confirm):
+        self.confirm = confirm
+        self.pending = deque()   # (first lane, MultisigGroup), lane order
+        self.slices = deque()    # (first lane, verdicts) still needed
+
+    def add(self, base: int, groups) -> None:
+        self.pending.extend((base + g.start, g) for g in groups)
+
+    def settled(self, first: int, ok) -> None:
+        import numpy as np
+
+        from ..ops import ecdsa_batch
+        from ..script.interpreter import multisig_walk
+
+        self.slices.append((first, ok))
+        end = first + len(ok)
+        with telemetry.span("import.multisig_settle", lanes=len(ok)):
+            while self.pending and (
+                    self.pending[0][0] + self.pending[0][1].lanes <= end):
+                a, g = self.pending.popleft()
+                b = a + g.lanes
+                parts = [v[max(a - f, 0):b - f] for f, v in self.slices
+                         if f < b and f + len(v) > a]
+                verdicts = parts[0] if len(parts) == 1 else (
+                    np.concatenate(parts))
+                if not multisig_walk(g.m, g.n, verdicts):
+                    ecdsa_batch.STATS.multisig_group_confirms += 1
+                    self.confirm(g.owner)
+        keep = self.pending[0][0] if self.pending else end
+        while self.slices and (
+                self.slices[0][0] + len(self.slices[0][1]) <= keep):
+            self.slices.popleft()
 
 
 class _ShadowBlockStore:
@@ -1577,6 +1624,7 @@ class Node:
             SCRIPT_VERIFY_NULLFAIL,
             DeferringSignatureChecker,
             ScriptError,
+            TransactionSignatureChecker,
             VerifyScript,
         )
         from ..script.script import script_int
@@ -1598,14 +1646,19 @@ class Node:
 
         eng = native.ConnectEngine()
         eng.set_best(cs.coins.best_block())
+        # fallback_s (the generic-script leg) is inside verify_s too
         stats = {"blocks": 0, "bytes": 0, "native_connect_s": 0.0,
-                 "sigscan_s": 0.0, "verify_s": 0.0, "flush_s": 0.0,
-                 "slow_path_blocks": 0, "fallback_inputs": 0,
-                 "fast_inputs": 0}
+                 "sigscan_s": 0.0, "verify_s": 0.0, "fallback_s": 0.0,
+                 "flush_s": 0.0, "slow_path_blocks": 0,
+                 "fallback_inputs": 0, "fast_inputs": 0}
+        multisig_keys = ("multisig_groups", "multisig_lanes",
+                         "multisig_group_confirms")
+        multisig0 = [getattr(ecdsa_batch.STATS, k) for k in multisig_keys]
         n_imported = 0
         pending: dict[bytes, list[tuple[bytes, Optional[tuple]]]] = {}
-        # in-flight signature batches: (block hash, BatchHandle)
-        inflight: list[tuple[bytes, object]] = []
+        # in-flight signature batches: (block hash, BatchHandle, number of
+        # its first lane, its candidate mask)
+        inflight: list[tuple] = []
         MAX_INFLIGHT = 3
         # cross-block record aggregation: mainnet blocks carry ~2-5k sig
         # inputs, and per-dispatch latency amortizes over wider buckets
@@ -1618,16 +1671,43 @@ class Node:
         # (ops/ecdsa_batch appends them per batch; an exact-8192 slice
         # would spill into the 10240 bucket and pay a fresh compile).
         AGG_LANES = 8190
-        agg: list[tuple] = []  # (pub, rs, msg, rn, wrap) per block
+        # (pub, rs, msg, rn, wrap, cand) per block; cand marks the
+        # candidate lanes of deferred OP_CHECKMULTISIG groups
+        agg: list[tuple] = []
         agg_count = [0]
         agg_last_hash = [b""]
+        lanes_dispatched = [0]  # lane numbers run over the whole import
+
+        def confirm_on_host(owner) -> None:
+            """A multisig group's walk failed on the batch's verdicts: the
+            input runs again with the eager checker, and only its
+            ScriptError aborts the import (the Python replay then names the
+            block)."""
+            tx, in_i, value, spk, flags, h = owner
+            try:
+                VerifyScript(tx.vin[in_i].script_sig, spk, flags,
+                             TransactionSignatureChecker(tx, in_i, value))
+            except ScriptError as e:
+                raise _NativeImportAbort(
+                    f"multisig input failed ({e.code}) in block "
+                    f"{hash_to_hex(h)[:16]}") from e
+
+        settler = _MultisigSettler(confirm_on_host)
+
+        def dispatch(arrays, sl: slice) -> None:
+            cand = arrays[5][sl]
+            handle = ecdsa_batch.dispatch_packed(
+                *(a[sl] for a in arrays[:5]), backend=self.connect_backend,
+                candidate=cand if cand.any() else None)
+            inflight.append((agg_last_hash[0], handle,
+                             lanes_dispatched[0] + sl.start, cand))
 
         def flush_agg(everything: bool = True):
             if not agg:
                 return
             t0 = time.perf_counter()
             arrays = [np.concatenate([a[i] for a in agg])
-                      for i in range(5)]
+                      for i in range(6)]
             agg.clear()
             pos = 0
             total = len(arrays[2])
@@ -1637,11 +1717,7 @@ class Node:
             # minutes-long compile); only the final sub-AGG_LANES tail
             # may hit a second bucket
             while total - pos >= AGG_LANES:
-                sl = slice(pos, pos + AGG_LANES)
-                handle = ecdsa_batch.dispatch_packed(
-                    *(a[sl] for a in arrays),
-                    backend=self.connect_backend)
-                inflight.append((agg_last_hash[0], handle))
+                dispatch(arrays, slice(pos, pos + AGG_LANES))
                 pos += AGG_LANES
             if everything:
                 # drain the tail in <=2046-lane chunks (2048-bucket minus
@@ -1650,14 +1726,12 @@ class Node:
                 # the whole import
                 while pos < total:
                     end = min(pos + 2046, total)
-                    handle = ecdsa_batch.dispatch_packed(
-                        *(a[pos:end] for a in arrays),
-                        backend=self.connect_backend)
-                    inflight.append((agg_last_hash[0], handle))
+                    dispatch(arrays, slice(pos, end))
                     pos = end
             if pos < total:
                 agg.append(tuple(a[pos:] for a in arrays))
             agg_count[0] = total - pos
+            lanes_dispatched[0] += pos
             dt = time.perf_counter() - t0
             stats["verify_s"] += dt
             cs.bench["verify_ms"] += dt * 1e3
@@ -1665,21 +1739,35 @@ class Node:
                 settle_oldest()
 
         def settle_oldest():
-            h, handle = inflight.pop(0)
+            h, handle, first, cand = inflight.pop(0)
             t0 = time.perf_counter()
-            ok = handle.result()
-            dt = time.perf_counter() - t0
-            stats["verify_s"] += dt
-            cs.bench["verify_ms"] += dt * 1e3
-            if not bool(np.all(ok)):
-                raise _NativeImportAbort(
-                    f"sig batch failed in block {hash_to_hex(h)[:16]}"
-                )
+            try:
+                ok = handle.result()
+                # a must-verify lane has to verify; a candidate lane's
+                # verdict feeds its group's walk
+                if not bool(np.all(ok | cand)):
+                    raise _NativeImportAbort(
+                        f"sig batch failed in block {hash_to_hex(h)[:16]}"
+                    )
+                settler.settled(first, ok)
+            finally:
+                dt = time.perf_counter() - t0
+                stats["verify_s"] += dt
+                cs.bench["verify_ms"] += dt * 1e3
 
         def settle_all():
             flush_agg()
             while inflight:
                 settle_oldest()
+            # a deferred OP_CHECKMULTISIG succeeded speculatively: nothing
+            # is written while one waits for verdicts that no dispatch in
+            # flight will bring (a slip in the lane numbering, not a fault
+            # of the chain: the Python engine decides)
+            if settler.pending:
+                raise _NativeImportAbort(
+                    f"{len(settler.pending)} multisig group(s) left "
+                    f"unsettled after the last dispatch, the first at lane "
+                    f"{settler.pending[0][0]} of {lanes_dispatched[0]}")
 
         def fast_flush():
             settle_all()
@@ -1742,6 +1830,43 @@ class Node:
                 return False
             n_imported += 1
             return True
+
+        def script_leg(raw: bytes, res, fb_idx, flags: int, h: bytes):
+            """The generic-script leg of one block: every input the native
+            scan did not match, through the Python interpreter. Returns
+            (records, multisig groups), or None where the block has to take
+            the Python path: a script failed, or a record is not ECDSA's
+            (a 65-byte Schnorr signature has no lane in the packed
+            batch)."""
+            records: list = []
+            groups: list = []
+            tx_cache: dict[int, tuple] = {}
+            spk_off = res.spent_spk_offsets
+            try:
+                for g in fb_idx:
+                    t_i, in_i = (int(res.sig_txin[g, 0]),
+                                 int(res.sig_txin[g, 1]))
+                    if t_i not in tx_cache:
+                        s, e_ = (int(res.tx_offsets[t_i, 0]),
+                                 int(res.tx_offsets[t_i, 1]))
+                        tx = CTransaction.from_bytes(raw[s:e_])
+                        tx_cache[t_i] = (tx, SighashCache(tx))
+                    tx, cache = tx_cache[t_i]
+                    spk = res.spent_spk_blob[
+                        int(spk_off[g]):int(spk_off[g + 1])]
+                    value = int(res.spent_values[g])
+                    seen = len(groups)
+                    checker = DeferringSignatureChecker(
+                        tx, in_i, value, records, cache, groups)
+                    VerifyScript(tx.vin[in_i].script_sig, spk, flags,
+                                 checker)
+                    for grp in groups[seen:]:
+                        grp.owner = (tx, in_i, value, spk, flags, h)
+            except ScriptError:
+                return None
+            if any(r.algo != "ecdsa" for r in records):
+                return None
+            return records, groups
 
         def fast_connect(raw: bytes, h: bytes, prev, pos_info) -> bool:
             """One linear-extension block through the native engine.
@@ -1811,44 +1936,37 @@ class Node:
                 msg = res.sig_msg[fast_idx]
                 rn = res.sig_rn[fast_idx]
                 wrap = res.sig_wrap[fast_idx]
+                cand = np.zeros(len(msg), bool)
                 fb_idx = np.nonzero(status == 1)[0]
                 if fb_idx.size:
                     # generic-script inputs: the Python interpreter is the
                     # authority; its deferred records join the same batch
                     stats["fallback_inputs"] += int(fb_idx.size)
-                    records = []
-                    tx_cache: dict[int, tuple] = {}
-                    spk_off = res.spent_spk_offsets
-                    try:
-                        for g in fb_idx:
-                            t_i, in_i = (int(res.sig_txin[g, 0]),
-                                         int(res.sig_txin[g, 1]))
-                            if t_i not in tx_cache:
-                                s, e_ = (int(res.tx_offsets[t_i, 0]),
-                                         int(res.tx_offsets[t_i, 1]))
-                                tx = CTransaction.from_bytes(raw[s:e_])
-                                tx_cache[t_i] = (tx, SighashCache(tx))
-                            tx, cache = tx_cache[t_i]
-                            spk = res.spent_spk_blob[
-                                int(spk_off[g]):int(spk_off[g + 1])]
-                            checker = DeferringSignatureChecker(
-                                tx, in_i, int(res.spent_values[g]),
-                                records, cache)
-                            VerifyScript(tx.vin[in_i].script_sig, spk,
-                                         flags, checker)
-                    except ScriptError:
+                    t_leg = time.perf_counter()
+                    with telemetry.span("import.script_leg", height=height,
+                                        inputs=int(fb_idx.size)):
+                        leg = script_leg(raw, res, fb_idx, flags, h)
+                    stats["fallback_s"] += time.perf_counter() - t_leg
+                    if leg is None:
                         eng.abort()
-                        return False  # Python path re-derives the reject
+                        return False  # Python path re-derives the verdict
+                    records, groups = leg
                     if records:
                         epub, ers, emsg, ern, ewrap = (
                             ecdsa_batch.records_to_blobs(records))
+                        ecand = np.zeros(len(records), bool)
+                        for grp in groups:
+                            ecand[grp.start:grp.start + grp.lanes] = True
+                        settler.add(lanes_dispatched[0] + agg_count[0]
+                                    + len(msg), groups)
                         pub = np.concatenate([pub, epub])
                         rs = np.concatenate([rs, ers])
                         msg = np.concatenate([msg, emsg])
                         rn = np.concatenate([rn, ern])
                         wrap = np.concatenate([wrap, ewrap])
+                        cand = np.concatenate([cand, ecand])
                 if len(msg):
-                    agg.append((pub, rs, msg, rn, wrap))
+                    agg.append((pub, rs, msg, rn, wrap, cand))
                     agg_count[0] += len(msg)
                     agg_last_hash[0] = h
                 dt = time.perf_counter() - t0
@@ -1945,7 +2063,7 @@ class Node:
             fast_flush()
         finally:
             while inflight:
-                _h, handle = inflight.pop(0)
+                handle = inflight.pop(0)[1]
                 try:
                     handle.result()
                 except Exception:  # noqa: BLE001 — abort-path drain
@@ -1954,6 +2072,8 @@ class Node:
         cs.flush()
         eng.close()
         stats["wall_s"] = time.perf_counter() - t_start
+        for key, was in zip(multisig_keys, multisig0):
+            stats[key] = getattr(ecdsa_batch.STATS, key) - was
         self.last_import_stats = stats
         log_printf(
             "native import: %d blocks (%d slow-path), %.1f MB in %.1fs "

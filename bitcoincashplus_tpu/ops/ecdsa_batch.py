@@ -29,6 +29,7 @@ the Python-int oracle — the reference's single-threaded VerifyScript path.
 
 from __future__ import annotations
 
+import gc
 import os
 import random
 import time
@@ -155,11 +156,28 @@ def _watched_kernel(pw, bucket: int, arrays, fn, jitfn=None, kwargs=None,
     dw.note_transfer("ecdsa", "h2d",
                      sum(int(a.nbytes) for a in arrays))
     sig = bucket if split is None else min(bucket, split)
+    traced = (sig,) not in pw.signatures
     t0 = time.monotonic()
     with pw.dispatch(sig, jitfn=jitfn, args=arrays, kwargs=kwargs):
         out = fn()
     dw.note_phase("ecdsa", "execute", time.monotonic() - t0)
+    if traced:
+        _freeze_traced_heap()
     return out
+
+
+def _freeze_traced_heap() -> None:
+    """Tracing and lowering a verify program leaves millions of objects
+    that live as long as the process (jaxprs, the lowering's caches), and
+    every full pass of Python's cyclic collector walks all of them: 0.55 s
+    a pass behind ``_glv_dev_program``, three or four passes in a 30 s
+    import (PERF.md, PR 28). Once a shape has been traced, what is alive
+    moves to the permanent generation, which no pass visits. Reference
+    counts still free what dies; only a cycle alive at this moment is kept
+    for good, and this runs once a compiled shape (the program's call is
+    already on the device, so the chip does not wait for it)."""
+    gc.collect()
+    gc.freeze()
 
 # ---- kernel selection (-ecdsakernel=glv|w4|msm) ----------------------------
 # "glv": the λ-endomorphism split verifier (ops/secp256k1 GLV core — 32
@@ -279,6 +297,14 @@ class BatchStats:
     # sigchecks that never reach the batch at all (gettpuinfo honesty:
     # what fraction of a block's sigops actually ran on the chip):
     eager_multisig_sigs: int = 0   # CHECKMULTISIG trials, verified inline
+    # OP_CHECKMULTISIG operations whose key trials joined the batch as
+    # candidate lanes (script/interpreter.py, module docstring): groups
+    # deferred, lanes they took (m(n-m+1) a group, inside sigs_verified
+    # too), and groups whose walk failed on the device's verdicts and went
+    # back to the host's eager checker for the verdict
+    multisig_groups: int = 0
+    multisig_lanes: int = 0
+    multisig_group_confirms: int = 0
     inline_legacy_sigs: int = 0    # pre-NULLFAIL blocks, deferral unsound
     sigcache_hits: int = 0         # records dropped by the sigcache probe
     p2pkh_fast_path: int = 0       # inputs that skipped the generic EvalScript
@@ -1069,11 +1095,12 @@ class BatchHandle:
     fabricated mask."""
 
     __slots__ = ("_n", "_bucket", "_device_ok", "_cpu_ok", "_degen",
-                 "_records", "_breaker", "_kat", "_recover", "_ctx")
+                 "_records", "_breaker", "_kat", "_recover", "_ctx",
+                 "_candidate")
 
     def __init__(self, n, bucket=0, device_ok=None, cpu_ok=None,
                  degen=None, records=None, breaker=None, kat=False,
-                 recover=None, ctx=None):
+                 recover=None, ctx=None, candidate=None):
         self._n = n
         self._bucket = bucket
         self._device_ok = device_ok
@@ -1087,6 +1114,9 @@ class BatchHandle:
         # thread, possibly many blocks later) links back to the span that
         # dispatched this batch
         self._ctx = ctx
+        # multisig candidate lanes (bool mask over the n real lanes, or
+        # None): a False there is an answer, not an alarm
+        self._candidate = candidate
 
     def _device_failed(self, err: BaseException) -> np.ndarray:
         """Settle-time device failure: breaker bookkeeping + CPU re-verify
@@ -1172,7 +1202,15 @@ class BatchHandle:
             # blocks have zero False lanes, so this is free in the common
             # case; an invalid-sig block pays one oracle verify per bad
             # lane, which the pure-CPU reference paid anyway.
-            bad = np.nonzero(~out)[0]
+            # A multisig candidate lane is exempt from this per-lane step
+            # and from nothing else: most of a group's trials fail by
+            # design. The rule holds for the group as a whole instead: a
+            # walk that fails on these verdicts goes back to the host's
+            # eager checker (the caller that passed ``candidate``).
+            bad = ~out
+            if self._candidate is not None:
+                bad &= ~self._candidate
+            bad = np.nonzero(bad)[0]
             if bad.size:
                 STATS.reject_confirm_sigs += int(bad.size)
                 out[bad] = _verify_cpu([self._records[i] for i in bad])
@@ -1781,8 +1819,11 @@ class _LazyRecords:
 def records_to_blobs(records: Sequence):
     """Pack script-interpreter SigCheckRecords into the blob layout so the
     occasional generic-path record can join a packed dispatch. Also emits
-    rn/wrap (the x-wraparound candidate gate)."""
+    rn/wrap (the x-wraparound candidate gate). ECDSA only: a Schnorr
+    record packed here would ride an ECDSA lane and read False."""
     n = len(records)
+    if any(r.algo != "ecdsa" for r in records):
+        raise ValueError("records_to_blobs packs ECDSA records only")
     pub = np.frombuffer(
         b"".join(r.pubkey[0].to_bytes(32, "big") + r.pubkey[1].to_bytes(32, "big")
                  for r in records), np.uint8).reshape(n, 64)
@@ -1807,12 +1848,14 @@ PACKED_DEVICE_FLOOR = 512
 
 def dispatch_packed(pub: np.ndarray, rs: np.ndarray, msg: np.ndarray,
                     rn: np.ndarray, wrap: np.ndarray,
-                    backend: str = "auto") -> BatchHandle:
+                    backend: str = "auto",
+                    candidate: Optional[np.ndarray] = None) -> BatchHandle:
     """Enqueue a packed verify batch: pub (n,64), rs (n,64), msg (n,32),
     rn (n,32), wrap (n,) — all uint8, big-endian fields, caller-validated
     ranges (1 <= r,s < N; pubkey on-curve affine). Device leg is breaker-
     supervised like dispatch_batch (same KAT lanes, same CPU re-verify on
-    failure)."""
+    failure). ``candidate`` (n,) bool marks multisig candidate lanes, whose
+    False the caller settles by group (BatchHandle.result)."""
     from .. import native
 
     _check_backend(backend)
@@ -1842,7 +1885,8 @@ def dispatch_packed(pub: np.ndarray, rs: np.ndarray, msg: np.ndarray,
         br.note_fallback(n)
         STATS.fault_fallback_sigs += n
         return _packed_cpu_handle(pub, rs, msg, n)
-    handle = _dispatch_packed_device(pub, rs, msg, rn, wrap, n, br)
+    handle = _dispatch_packed_device(pub, rs, msg, rn, wrap, n, br,
+                                     candidate)
     if handle is None:
         STATS.fault_fallback_sigs += n
         return _packed_cpu_handle(pub, rs, msg, n)
@@ -1863,8 +1907,8 @@ def _packed_cpu_handle(pub, rs, msg, n: int) -> BatchHandle:
     return BatchHandle(n, cpu_ok=_verify_cpu([recs[i] for i in range(n)]))
 
 
-def _dispatch_packed_device(pub, rs, msg, rn, wrap, n: int,
-                            br) -> Optional[BatchHandle]:
+def _dispatch_packed_device(pub, rs, msg, rn, wrap, n: int, br,
+                            candidate=None) -> Optional[BatchHandle]:
     """Supervised packed enqueue (retries + KAT lanes); None when every
     attempt failed."""
     from .. import native
@@ -2017,7 +2061,7 @@ def _dispatch_packed_device(pub, rs, msg, rn, wrap, n: int,
             return BatchHandle(n, bucket, device_ok, degen=degen,
                                records=_LazyRecords(pub2, rs2, msg2),
                                breaker=br, kat=True, recover=recover,
-                               ctx=ctx)
+                               ctx=ctx, candidate=candidate)
         except (KeyboardInterrupt, SystemExit):
             raise
         except SURFACE_ERRORS:

@@ -13,9 +13,27 @@ per-block batch then runs in ONE TPU dispatch (ops/ecdsa_batch). This is
 sound iff SCRIPT_VERIFY_NULLFAIL is active: a failing check with a
 non-empty signature then always invalidates the script, so "all deferred
 records verify" ⇔ "all scripts that reported success actually succeed".
-The checker asserts that precondition. CHECKMULTISIG trials are verified
-eagerly (sig→pubkey assignment is outcome-dependent, so deferral is
-unsound there); multisig is rare and stays on the CPU fallback path.
+The checker asserts that precondition.
+
+OP_CHECKMULTISIG defers too, where the caller gave the checker a list to
+record groups in (the native import does; every other caller stays eager).
+Which key a signature belongs to depends on the verdicts, so a trial may
+legitimately fail and one lane cannot stand for the operation. But
+upstream's walk over signatures s_0..s_{m-1} and keys k_0..k_{n-1} (in the
+order it visits them) only ever tries pairs (s_i, k_j) with
+i <= j <= i + n - m: every one of those m(n-m+1) pairs becomes a
+*candidate lane* of the batch (a function of the script alone, not of who
+signed), and the caller replays the walk over their verdicts
+(``multisig_walk``). That is sound because (1) acceptance needs upstream's
+own walk to succeed on verdicts trusted exactly as OP_CHECKSIG lanes are;
+(2) the candidate band contains every pair any walk can visit; (3) an
+operation defers only if no trial could raise or be decided without
+arithmetic (every signature and every key passed its encoding check and
+parsed), so which pairs the walk visits no longer matters to the error
+path; and (4) a walk that fails on the device's verdicts rejects nothing:
+the caller runs that input again with the eager checker and only *its*
+ScriptError counts. Anything that does not meet (3) takes the eager walk,
+unchanged and counted (``eager_multisig_sigs``).
 """
 
 from __future__ import annotations
@@ -244,6 +262,30 @@ def _ecdsa_verify_scalar(pt, r: int, s: int, e: int) -> bool:
 
 # ---- signature checkers (interpreter.h BaseSignatureChecker) ----
 
+def _signature_scalars(sig: bytes):
+    """(r, s, algo) of a non-empty signature with its hashtype byte still
+    on, or None where DER does not decode. ``algo`` is "schnorr" for
+    65-byte signatures (BCH length discrimination), "ecdsa" for DER: both
+    run over the SAME sighash digests."""
+    if is_schnorr_signature(sig):
+        return (int.from_bytes(sig[0:32], "big"),
+                int.from_bytes(sig[32:64], "big"), "schnorr")
+    rs = secp.sig_der_decode(sig[:-1])
+    if rs is None:
+        return None
+    return rs[0], rs[1], "ecdsa"
+
+
+def _lane_can_carry(r: int, s: int, algo: str) -> bool:
+    """Whether a batch lane can stand for the check: out-of-range scalars
+    never verify, so such a check is decided at once, not deferred.
+    Schnorr: r is a field element, s a scalar (spec: fail if r >= p or
+    s >= n)."""
+    if algo == "schnorr":
+        return r < secp.P and s < secp.N
+    return 1 <= r < secp.N and 1 <= s < secp.N
+
+
 @dataclass
 class SigCheckRecord:
     """One deferred signature verification — the unit the TPU batch
@@ -261,11 +303,52 @@ class SigCheckRecord:
     algo: str = "ecdsa"
 
 
+@dataclass
+class MultisigGroup:
+    """One deferred OP_CHECKMULTISIG: its m(n-m+1) candidate lanes are
+    ``records[start:start + lanes]``, signature-major (lane
+    ``i * (n-m+1) + (j-i)`` is signature i against key j, both counted in
+    the order the walk visits them). ``owner`` is the caller's: whatever it
+    needs to run the input again on the host if the walk fails."""
+
+    start: int
+    m: int
+    n: int
+    owner: object = None
+
+    @property
+    def lanes(self) -> int:
+        return self.m * (self.n - self.m + 1)
+
+
+def multisig_walk(m: int, n: int, verdicts) -> bool:
+    """Upstream's key-trial walk (the loop in EvalScript below) replayed
+    over a group's candidate verdicts: ok moves to the next signature,
+    every trial to the next key, and it fails as soon as more signatures
+    than keys are left. The walk enters a trial only while
+    m - si <= n - ki, so 0 <= ki - si <= n - m: inside the band."""
+    width = n - m + 1
+    si = ki = 0
+    while si < m:
+        if verdicts[si * width + ki - si]:
+            si += 1
+        ki += 1
+        if m - si > n - ki:
+            return False
+    return True
+
+
 class BaseSignatureChecker:
     """No-transaction-context checker: every check fails (interpreter.h)."""
 
     def check_sig(self, sig: bytes, pubkey: bytes, script_code: bytes,
                   flags: int, defer_ok: bool = True) -> bool:
+        return False
+
+    def defer_multisig(self, sigs: list, keys: list, script_code: bytes,
+                       flags: int) -> bool:
+        """True where a whole OP_CHECKMULTISIG was recorded for the batch
+        (DeferringSignatureChecker); False: walk it now."""
         return False
 
     def check_locktime(self, locktime: int) -> bool:
@@ -286,35 +369,30 @@ class TransactionSignatureChecker(BaseSignatureChecker):
         self.amount = amount
         self.cache = cache
 
+    def _sighash(self, sig: bytes, script_code: bytes, flags: int) -> int:
+        """The digest ``sig`` (hashtype byte last) commits to, as the
+        integer both verifiers take."""
+        return int.from_bytes(signature_hash(
+            script_code, self.tx, self.in_idx, sig[-1], self.amount,
+            enable_forkid=bool(flags & SCRIPT_ENABLE_SIGHASH_FORKID),
+            cache=self.cache,
+            strip_sig=S.push_data_raw(sig),
+        ), "big")
+
     def _sighash_and_parse(self, sig: bytes, pubkey: bytes, script_code: bytes,
                            flags: int):
         """Shared parse path: returns (point, r, s, e, algo) or None if any
-        parse fails (pubkey off-curve, empty/garbled sig). ``algo`` is
-        "schnorr" for 65-byte signatures (BCH length discrimination),
-        "ecdsa" for DER — both run over the SAME sighash digests."""
+        parse fails (pubkey off-curve, empty/garbled sig)."""
         if not sig:
             return None
         pt = _pubkey_parse_fast(pubkey)
         if pt is None:
             return None
-        hashtype = sig[-1]
-        if is_schnorr_signature(sig):
-            algo = "schnorr"
-            r = int.from_bytes(sig[0:32], "big")
-            s = int.from_bytes(sig[32:64], "big")
-        else:
-            algo = "ecdsa"
-            rs = secp.sig_der_decode(sig[:-1])
-            if rs is None:
-                return None
-            r, s = rs
-        ehash = signature_hash(
-            script_code, self.tx, self.in_idx, hashtype, self.amount,
-            enable_forkid=bool(flags & SCRIPT_ENABLE_SIGHASH_FORKID),
-            cache=self.cache,
-            strip_sig=S.push_data_raw(sig),
-        )
-        return pt, r, s, int.from_bytes(ehash, "big"), algo
+        scalars = _signature_scalars(sig)
+        if scalars is None:
+            return None
+        r, s, algo = scalars
+        return pt, r, s, self._sighash(sig, script_code, flags), algo
 
     def check_sig(self, sig: bytes, pubkey: bytes, script_code: bytes,
                   flags: int, defer_ok: bool = True) -> bool:
@@ -365,14 +443,55 @@ class TransactionSignatureChecker(BaseSignatureChecker):
 class DeferringSignatureChecker(TransactionSignatureChecker):
     """Records CHECKSIG verifications for the per-block TPU batch instead
     of running them. Requires NULLFAIL in flags (see module docstring);
-    VerifyScript enforces this. Multisig trials (defer_ok=False) verify
-    eagerly via the parent."""
+    VerifyScript enforces this. A caller that passes ``groups`` settles
+    multisig groups itself (``multisig_walk`` over the candidate lanes'
+    verdicts, the eager checker where it fails); without it every multisig
+    trial (defer_ok=False) verifies eagerly via the parent."""
 
     def __init__(self, tx: CTransaction, in_idx: int, amount: int,
                  records: list[SigCheckRecord],
-                 cache: Optional[SighashCache] = None):
+                 cache: Optional[SighashCache] = None,
+                 groups: Optional[list[MultisigGroup]] = None):
         super().__init__(tx, in_idx, amount, cache)
         self.records = records
+        self.groups = groups
+
+    def defer_multisig(self, sigs: list, keys: list, script_code: bytes,
+                       flags: int) -> bool:
+        """Record the candidate lanes of one OP_CHECKMULTISIG, if no trial
+        of its walk could raise or be decided without arithmetic (module
+        docstring, (3)); one sighash a signature, one parse a key."""
+        if (self.groups is None or not sigs
+                or not flags & SCRIPT_VERIFY_NULLFAIL):
+            return False
+        try:
+            for sig in sigs:
+                if not sig or is_schnorr_signature(sig):
+                    return False
+                check_signature_encoding(sig, flags)
+            for key in keys:
+                check_pubkey_encoding(key, flags)
+        except ScriptError:
+            return False  # the eager walk raises it, if it gets there
+        scalars = [_signature_scalars(sig) for sig in sigs]
+        if not all(rs is not None and _lane_can_carry(*rs) for rs in scalars):
+            return False
+        points = [_pubkey_parse_fast(key) for key in keys]
+        if None in points:
+            return False
+        m, n = len(sigs), len(keys)
+        group = MultisigGroup(len(self.records), m, n)
+        for i, (sig, (r, s, _)) in enumerate(zip(sigs, scalars)):
+            e = self._sighash(sig, script_code, flags)
+            for j in range(i, i + n - m + 1):
+                self.records.append(SigCheckRecord(
+                    points[j], r, s, e, self.tx.txid, self.in_idx))
+        self.groups.append(group)
+        from ..ops.ecdsa_batch import STATS
+
+        STATS.multisig_groups += 1
+        STATS.multisig_lanes += group.lanes
+        return True
 
     def check_sig(self, sig: bytes, pubkey: bytes, script_code: bytes,
                   flags: int, defer_ok: bool = True) -> bool:
@@ -385,13 +504,8 @@ class DeferringSignatureChecker(TransactionSignatureChecker):
         if parsed is None:
             return False
         pt, r, s, e, algo = parsed
-        if algo == "schnorr":
-            # Schnorr ranges: r is a field element, s a scalar (spec:
-            # fail if r >= p or s >= n) — out-of-range never verifies
-            if not (r < secp.P and s < secp.N):
-                return False
-        elif not (1 <= r < secp.N and 1 <= s < secp.N):
-            return False  # out-of-range scalars never verify; don't defer
+        if not _lane_can_carry(r, s, algo):
+            return False
         self.records.append(
             SigCheckRecord(pt, r, s, e, self.tx.txid, self.in_idx, algo)
         )
@@ -749,8 +863,15 @@ def EvalScript(stack: list[bytes], script: bytes, flags: int,
                                 script_code, S.push_data_raw(sig)
                             )
 
+                    # the whole operation joins the batch as candidate
+                    # lanes where the checker takes it (it then succeeds
+                    # speculatively, as OP_CHECKSIG does: with every
+                    # signature non-empty under NULLFAIL, failure is a
+                    # script error, never a pushed false)
                     success = True
                     si, ki = 0, 0
+                    if checker.defer_multisig(sigs, keys, script_code, flags):
+                        si = sigs_count
                     while success and sigs_count - si > 0:
                         sig = sigs[si]
                         pubkey = keys[ki]

@@ -1,0 +1,131 @@
+"""BENCHMARK.json against the limits the driver refuses a PR for before any
+run (PR 27 was: a configuration's ``source`` of 201 characters), and against
+the files it names."""
+
+import json
+import os
+import re
+import string
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
+    MANIFEST = json.load(_f)
+NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}")
+UNIT = re.compile(r"[A-Za-z0-9_/%.-]{1,16}")
+PRINTABLE = set(string.printable) - set("\t\n\r\x0b\x0c")
+CONFIGS = {c["name"]: c for c in MANIFEST["configs"]}
+CELLS = {w["name"]: w for w in MANIFEST["workloads"]}
+END_TO_END = {m["name"]: m for m in MANIFEST["end_to_end"]}
+PER_LAYER = {m["name"]: m for m in MANIFEST["per_layer"]}
+
+
+def line_ok(text) -> bool:
+    """1 to 200 printable ASCII characters, on one line, no tab."""
+    return (isinstance(text, str) and 1 <= len(text) <= 200
+            and set(text) <= PRINTABLE)
+
+
+def test_line_ok_knows_the_limit():
+    assert line_ok("x" * 200) and not line_ok("x" * 201)
+    assert not line_ok("") and not line_ok("a\tb") and not line_ok("a\nb")
+    assert not line_ok("café")
+
+
+def test_the_manifest_is_small_and_its_names_are_unique():
+    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) <= 64 * 1024
+    for key in ("configs", "workloads"):
+        names = [e["name"] for e in MANIFEST[key]]
+        assert len(set(names)) == len(names) and 1 <= len(names) <= 24
+    metrics = [m["name"] for m in MANIFEST["end_to_end"] + MANIFEST["per_layer"]]
+    assert len(set(metrics)) == len(metrics)
+    assert 1 <= len(MANIFEST["per_layer"]) <= 128
+    assert all(line_ok(word) for word in MANIFEST["command"])
+    pairs = [(w["config"], w["traffic"]) for w in MANIFEST["workloads"]]
+    assert len(set(pairs)) == len(pairs)
+
+
+@pytest.mark.parametrize("name", sorted(CONFIGS))
+def test_configuration_entry_and_its_file(name):
+    entry = CONFIGS[name]
+    assert set(entry) == {"name", "source", "file", "reduced", "why"}
+    assert NAME.fullmatch(name)
+    assert line_ok(entry["source"]) and line_ok(entry["why"])
+    assert len(entry["reduced"]) <= 16
+    assert all(NAME.fullmatch(key) for key in entry["reduced"])
+    assert any(entry["file"].startswith(p + "/") for p in MANIFEST["paths"])
+    assert re.fullmatch(r"[A-Za-z0-9_.\-/]+", entry["file"])
+    with open(os.path.join(ROOT, entry["file"])) as f:
+        config = json.load(f)
+    # the configuration as it is run carries the source it was taken from,
+    # letter for letter, and says which of its sizes were cut
+    assert config["source"] == entry["source"]
+    assert config["name"] == name
+    assert config["reduced"] == entry["reduced"]
+    assert set(config.get("reductions", {})) == set(entry["reduced"])
+    assert any(w["config"] == name for w in MANIFEST["workloads"])
+    files = [c["file"] for c in MANIFEST["configs"]]
+    assert files.count(entry["file"]) == 1
+
+
+@pytest.mark.parametrize("name", sorted(CELLS))
+def test_cell_entry_and_its_files(name):
+    cell = CELLS[name]
+    assert set(cell) == {"name", "config", "traffic", "chips", "why"}
+    assert NAME.fullmatch(name) and NAME.fullmatch(cell["traffic"])
+    assert cell["config"] in CONFIGS
+    assert cell["chips"] in (1, 4)
+    assert line_ok(cell["why"])
+    bench = MANIFEST["paths"][0]
+    assert os.path.isfile(os.path.join(
+        ROOT, bench, "traffic", cell["traffic"] + ".json"))
+    with open(os.path.join(ROOT, CONFIGS[cell["config"]]["file"])) as f:
+        driver = json.load(f)["driver"]
+    assert os.path.isfile(os.path.join(ROOT, bench, "drivers",
+                                       driver + ".py"))
+    # every cell reports setup_s, one more end-to-end metric and a
+    # per-layer metric of its own
+    felt = [m for m in MANIFEST["end_to_end"]
+            if name in m.get("workloads", [name])]
+    assert "setup_s" in {m["name"] for m in felt} and len(felt) >= 2
+    assert any(name in m.get("workloads", [name])
+               for m in MANIFEST["per_layer"])
+
+
+def test_at_most_half_the_cells_ask_for_four_chips():
+    four = sum(1 for w in MANIFEST["workloads"] if w["chips"] == 4)
+    assert four <= max(1, len(MANIFEST["workloads"]) // 2)
+
+
+@pytest.mark.parametrize("name", sorted(END_TO_END))
+def test_end_to_end_metric(name):
+    metric = END_TO_END[name]
+    assert set(metric) - {"workloads"} == {
+        "name", "unit", "better", "bound", "source"}
+    assert NAME.fullmatch(name) and UNIT.fullmatch(metric["unit"])
+    assert metric["better"] in ("lower", "higher")
+    assert metric["source"] in ("host_clock", "device_trace")
+    assert 0 < metric["bound"] < 1
+    assert set(metric.get("workloads", [])) <= set(CELLS)
+
+
+@pytest.mark.parametrize("name", sorted(PER_LAYER))
+def test_per_layer_metric_moves_an_end_to_end_metric_its_cells_report(name):
+    metric = PER_LAYER[name]
+    assert set(metric) - {"workloads"} == {
+        "name", "unit", "better", "source", "layer", "moves"}
+    assert NAME.fullmatch(name) and UNIT.fullmatch(metric["unit"])
+    assert metric["better"] in ("lower", "higher")
+    assert metric["source"] in ("device_trace", "program_span",
+                                "program_counter", "host_clock")
+    assert line_ok(metric["layer"])
+    moved = END_TO_END[metric["moves"]]  # KeyError: moves nothing felt
+    for cell in metric.get("workloads", []):
+        assert cell in CELLS
+        assert cell in moved.get("workloads", [cell])
+    if "roofline" in name or "mfu" in name:
+        assert metric["unit"] == "%"
+    assert os.path.isfile(os.path.join(
+        ROOT, MANIFEST["paths"][0], "layer_metrics", name + ".py"))

@@ -152,6 +152,44 @@ def test_ecdsa_programs_declare_budgets():
     assert isinstance(progs, dict)
 
 
+@pytest.mark.parametrize("calls, froze", [(1, 1), (3, 1)])
+def test_a_newly_traced_verify_shape_freezes_the_heap_once(
+        monkeypatch, calls, froze):
+    """What tracing a verify program leaves lives as long as the process:
+    it moves out of the cyclic collector's reach when the shape is first
+    called, once a shape and not once a dispatch; a call that raised froze
+    nothing."""
+    from bitcoincashplus_tpu.ops import ecdsa_batch as eb
+
+    frozen = []
+    monkeypatch.setattr(eb.gc, "freeze", lambda: frozen.append(1))
+    pw = dw.program("test_freeze_once", shape_budget=2)
+    arrays = (np.zeros((4, 32), np.uint8),)
+
+    def boom():
+        raise RuntimeError("lowering refused")
+
+    with pytest.raises(RuntimeError):
+        eb._watched_kernel(pw, 2048, arrays, boom)
+    assert frozen == []
+    for _ in range(calls):
+        assert eb._watched_kernel(pw, 8192, arrays, lambda: "ok") == "ok"
+    assert len(frozen) == froze
+
+
+def test_frozen_heap_is_out_of_the_collectors_reach():
+    import gc
+
+    from bitcoincashplus_tpu.ops import ecdsa_batch as eb
+
+    before = gc.get_freeze_count()
+    try:
+        eb._freeze_traced_heap()
+        assert gc.get_freeze_count() > before
+    finally:
+        gc.unfreeze()
+
+
 # ---------------------------------------------------------------------------
 # transfer & memory accounting
 # ---------------------------------------------------------------------------

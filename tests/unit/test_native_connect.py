@@ -617,6 +617,83 @@ def test_fast_import_falls_back_on_invalid_block(tmp_path):
         node.close()
 
 
+def test_fast_import_sends_a_schnorr_signature_to_the_python_engine(tmp_path):
+    """A 65-byte Schnorr signature under a pay-to-pubkey output reaches the
+    generic-script leg of the native import (the P2PKH scan never matches
+    it). Its record is no ECDSA lane: the block takes the slow path, where
+    the Python engine verifies it under its own scheme, and the import goes
+    on through the native engine."""
+    from bitcoincashplus_tpu.crypto import secp256k1 as secp
+    from bitcoincashplus_tpu.node.config import Config
+    from bitcoincashplus_tpu.node.node import Node
+    from bitcoincashplus_tpu.script.script import p2pk_script, push_data_raw
+    from bitcoincashplus_tpu.script.sighash import signature_hash
+    from bitcoincashplus_tpu.store.blockstore import BlockStore
+    from bitcoincashplus_tpu.store.chainstatedb import BlockIndexDB, CoinsDB
+    from bitcoincashplus_tpu.store.kvstore import KVStore
+
+    net_dir = os.path.join(tmp_path, "regtest")
+    blocks_dir = os.path.join(net_dir, "blocks")
+    os.makedirs(blocks_dir, exist_ok=True)
+    index_kv = KVStore(os.path.join(blocks_dir, "index.sqlite"))
+    coins_kv = KVStore(os.path.join(net_dir, "chainstate.sqlite"))
+    store = BlockStore(net_dir, PARAMS.netmagic)
+    cs = ChainstateManager(PARAMS, CoinsDB(coins_kv), store,
+                           script_verifier=None,
+                           index_db=BlockIndexDB(index_kv))
+    t = PARAMS.genesis.header.time
+
+    def push(txs=()):
+        nonlocal t
+        t += 60
+        tip = cs.tip()
+        blk = _block(tip.hash, tip.height + 1, t, txs)
+        cs.process_new_block(blk)
+        return blk
+
+    coinbases = [push().vtx[0] for _ in range(102)]
+    # a pay-to-pubkey output, then its spend under a Schnorr signature
+    value = coinbases[0].vout[0].value
+    fund = _spend([COutPoint(coinbases[0].txid, 0)], [value])
+    pk_spk = p2pk_script(KEY.pubkey)
+    fund = sign_transaction(
+        CTransaction(1, tuple(CTxIn(i.prevout, b"", i.sequence)
+                              for i in fund.vin),
+                     (CTxOut(value - 10_000, pk_spk),)),
+        [(SPK, value)], _key_for, enable_forkid=True)
+    push((fund,))
+    unsigned = CTransaction(
+        1, (CTxIn(COutPoint(fund.txid, 0), b"", 0xFFFFFFFE),),
+        (CTxOut(value - 20_000, SPK),))
+    digest = signature_hash(pk_spk, unsigned, 0, 0x41, value - 10_000,
+                            enable_forkid=True)
+    r, s = secp.schnorr_sign(KEY.secret, int.from_bytes(digest, "big"))
+    sig = r.to_bytes(32, "big") + s.to_bytes(32, "big") + b"\x41"
+    spend = CTransaction(
+        1, (CTxIn(unsigned.vin[0].prevout, push_data_raw(sig), 0xFFFFFFFE),),
+        unsigned.vout)
+    push((spend,))
+    last = push((_spend([COutPoint(coinbases[1].txid, 0)],
+                        [coinbases[1].vout[0].value]),))
+    cs.flush()
+    store.close()
+    index_kv.close()
+    coins_kv.close()
+
+    cfg = Config()
+    cfg.args["datadir"] = [str(tmp_path)]
+    cfg.args["regtest"] = ["1"]
+    cfg.args["reindex"] = ["1"]
+    node = Node(config=cfg)
+    try:
+        assert node.chainstate.tip().hash == last.get_hash()
+        stats = node.last_import_stats
+        assert stats["slow_path_blocks"] == 1
+        assert stats["fallback_inputs"] == 1  # the Schnorr input, once
+    finally:
+        node.close()
+
+
 @pytest.mark.skipif(not os.environ.get("BCP_SLOW_TESTS"),
                     reason="slow randomized campaign (BCP_SLOW_TESTS=1)")
 def test_randomized_differential_campaign():
