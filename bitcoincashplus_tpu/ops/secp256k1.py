@@ -1011,12 +1011,14 @@ def ecdsa_verify_batch_pallas_w4(u1w, u2w, qx, qy, q_inf, r0, rn, wrap_ok):
 # constant-time discipline is required — lane-varying table gathers leak
 # nothing an observer does not already have.
 #
-# This core is plain XLA (jnp + gather), not Pallas: the comb tables are
-# captured numpy constants, which Mosaic forbids and in-kernel synthesis
-# cannot afford at 16×512 entries (the w4 Pallas kernels remain the
-# Mosaic-tuned path and the dispatch fallback; `-ecdsakernel=w4` forces
-# them). Completeness contract is identical to w4: the cheap adds flag
-# H == 0 collisions (degen plane) and the host re-verifies flagged lanes.
+# This core is plain XLA (jnp; the comb reads its constant tables with
+# gathers, the ladder its per-lane tables with selects: _glv_tab_read),
+# not Pallas: the comb tables are captured numpy constants, which Mosaic
+# forbids and in-kernel synthesis cannot afford at 16×512 entries (the w4
+# Pallas kernels remain the Mosaic-tuned path and the dispatch fallback;
+# `-ecdsakernel=w4` forces them). Completeness contract is identical to
+# w4: the cheap adds flag H == 0 collisions (degen plane) and the host
+# re-verifies flagged lanes.
 
 LAMBDA = 0x5363AD4CC05C30E0A5261C028812645A122E22EA20816678DF02967C1B23BD72
 BETA = 0x7AE96A2B657C07106E64479EAC3434E99CF0497512F58995C1396C28719501EE
@@ -1156,7 +1158,7 @@ def _f_neg(y):
 
 
 def _glv_q_tables(qx, qy, ydiff_u, q_inf_u, one):
-    """Per-lane Q-stream tables, stacked for gather. Returns two
+    """Per-lane Q-stream tables, stacked (_glv_tab_read). Returns two
     (X, Y, Z) tuples of (16, 20, B) arrays: T1[j] = j·Q' (Q' is Q with
     the first Q-stream sign already folded into qy by the packer) and
     T2[j] = j·(±φ(Q')) — the λQ stream, derived from T1 by the
@@ -1197,15 +1199,23 @@ def _glv_q_tables(qx, qy, ydiff_u, q_inf_u, one):
     return t1, t2
 
 
-def _glv_tab_gather(t, w):
-    """Gather one Jacobian entry per lane from a stacked (16, 20, B)
-    table: w is the (1, B) int32 window value (0..15). One gather per
-    coordinate — the XLA core's cheaper analogue of the w4 kernel's
-    15-way select chain."""
-    idx = jnp.broadcast_to(w[:, None, :], (1,) + t[0].shape[1:]).astype(
-        jnp.int32
-    )
-    return tuple(jnp.take_along_axis(c, idx, axis=0)[0] for c in t)
+def _glv_tab_read(t, w):
+    """Read one Jacobian entry per lane from a stacked (16, 20, B) table:
+    w is the (1, B) int32 window value (0..15; 0 reads the dummy entry 0
+    and the caller masks the add). A where-chain over the sixteen entries,
+    its fifteen compares shared by X, Y and Z. Not take_along_axis: on the
+    chip that per-lane gather fetches one word at a time (2.0 ms a
+    coordinate at B = 8192, six a window, three quarters of the verify
+    program) where the chain streams the table at HBM speed (18 us at
+    most; PERF.md §6, PR 29)."""
+    hits = [w == j for j in range(1, 16)]
+    out = []
+    for c in t:
+        sel = c[0]
+        for j, hit in enumerate(hits, start=1):
+            sel = jnp.where(hit, c[j], sel)
+        out.append(sel)
+    return tuple(out)
 
 
 def _glv_window_step(carry, w1, w2, t1, t2, q_inf_u):
@@ -1214,7 +1224,7 @@ def _glv_window_step(carry, w1, w2, t1, t2, q_inf_u):
     acc, degen = carry
     acc = pt_double(pt_double(pt_double(pt_double(acc))))
     for t, w in ((t1, w1), (t2, w2)):
-        x, y, z = _glv_tab_gather(t, w)
+        x, y, z = _glv_tab_read(t, w)
         q_sel = {"X": x, "Y": y, "Z": z, "inf": q_inf_u}
         act = jnp.where(w != 0, 1, 0) * (1 - q_inf_u)
         added, hz = _pt_add_full_cheap_u(acc, q_sel)

@@ -139,6 +139,30 @@ def test_w4_looped_form_is_refused_by_mosaic(one_chip, chip_forms,
         dev._w4_bytes_program.lower(*_verify_args(one_chip, 1024)).compile()
 
 
+def test_glv_window_step_lowers_without_gather_for_v5e(one_chip, chip_forms):
+    """One ladder window in the chip's form reads its per-lane Q and
+    lambda-Q tables with selects: on the chip a per-lane gather out of a
+    stacked (16, 20, B) table fetches a word at a time, 2.0 ms a
+    coordinate at B = 8192, six a window, three quarters of the program
+    (PR 29). Lowered only, not compiled: the whole program is the slow
+    test below."""
+    from bitcoincashplus_tpu.ops import secp256k1 as dev
+
+    lanes = 8192
+
+    def s(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    limbs = s(jnp.uint32, dev.N_LIMBS, lanes)
+    mask = s(jnp.int32, 1, lanes)
+    acc = {"X": limbs, "Y": limbs, "Z": limbs, "inf": mask}
+    table = (s(jnp.uint32, 16, dev.N_LIMBS, lanes),) * 3
+    text = jax.jit(dev._glv_window_step).lower(
+        (acc, mask), mask, mask, table, table, mask).as_text()
+    assert "stablehlo.select" in text
+    assert "stablehlo.gather" not in text
+
+
 # the node's reindex buckets (node.py _import_block_files_native); compile
 # seconds measured on this 8-core sandbox for PR 22: 215 / 228 / 242 s
 @pytest.mark.slow(reason="AOT compile 215-242 s per bucket (PR 22, sandbox)")

@@ -81,6 +81,26 @@ def test_glv_comb_tables():
     assert dev._glv_comb() is dev._glv_comb()
 
 
+@pytest.mark.parametrize("lanes", [16, 200, 1024])
+def test_glv_table_read_matches_numpy(lanes):
+    """The ladder's per-lane table read alone (one form, chip and CPU):
+    out of random stacked (16, 20, B) tables, with every window value
+    0..15 among the lanes, it returns the three coordinates numpy
+    indexing does."""
+    import jax
+
+    nprng = np.random.default_rng(lanes)
+    table = tuple(
+        nprng.integers(0, 1 << 32, (16, dev.N_LIMBS, lanes), dtype=np.uint32)
+        for _ in range(3))
+    w = np.arange(lanes, dtype=np.int32) % 16
+    nprng.shuffle(w)
+    got = jax.jit(dev._glv_tab_read)(table, w[None])
+    for coord, read in zip(table, got):
+        assert np.array_equal(np.asarray(read),
+                              coord[w, :, np.arange(lanes)].T)
+
+
 def test_kernel_selection_knob():
     old = ecdsa_batch._KERNEL
     try:

@@ -500,13 +500,14 @@ DRIFT_BUDGET = 0.10
 # run lowers differently and reports without flagging until a baseline
 # for that arrangement is recorded here.
 COST_BASELINES = {
-    "cpu": {"ecdsa_glv": 2_370_312.0, "ecdsa_w4_bytes": 1_618_602.0,
+    # the two GLV twins: recorded with the §7 census of PR 29 (jax 0.9.0)
+    "cpu": {"ecdsa_glv": 2_442_480.0, "ecdsa_w4_bytes": 1_618_602.0,
             # the fused decompose+verify program (ISSUE 11) — the
             # parallel-form lowering's whole-program flop accounting
             # weighs the unrolled carry rounds far above their census
             # primitive count (+12.6k census vs +1.19M flops), which is
             # exactly why drift is per kernel against its OWN twin
-            "ecdsa_glv_decompose": 3_562_004.0,
+            "ecdsa_glv_decompose": 4_052_615.0,
             # Schnorr MSM batch check (ISSUE 19): compiled flops per
             # TERM-SLOT at bucket 64 (the whole batch-equation program's
             # flop count / 64 slots — the smallest, unit-test-priced
