@@ -3,28 +3,29 @@
 Reference: this layer replaces CCheckQueue (src/checkqueue.h:~30) +
 ThreadScriptCheck (src/validation.cpp): instead of fanning CScriptCheck
 closures to worker threads, the block's deferred sigcheck records are
-packed SoA (scalar decomposition on host, bit-planes + 13-bit limbs) and
-verified in ONE device dispatch via ops/secp256k1.ecdsa_verify_batch_jit
+packed into byte matrices and verified in ONE device dispatch
 (SURVEY.md §3.2 P1, §8.4 "ECDSA batch").
 
-Pipeline per batch:
-  1. host: w = s⁻¹ mod n, u1 = e·w, u2 = r·w  (native C++/Python ints,
-     µs per sig). The GLV lattice split (k = k1 + λ·k2, |k1|,|k2| <
-     2^128) rides the DEVICE program since ISSUE 11 (_glv_dev_program —
-     raw scalar bytes in, exact in-kernel rounding); the host split
-     (pack_records_glv, numpy limb batches) is the retained fallback
-  2. pack: u1/u2 → (256, B) MSB-first bit planes (ladder kernels), raw
-     (B, 32) byte matrices (w4 bytes AND device-decompose GLV), or
-     split-scalar byte matrices + sign flags (host-decompose GLV);
-     qx/qy/r/rn → (20, B) 13-bit limbs or bytes; wrap_ok = (r + n < p)
-     per lane (the kernel gates the x-wraparound candidate on it — see
-     ecdsa_verify_batch_device)
-  3. pad B up to a bucket size (bounds XLA recompiles to len(BUCKETS))
-  4. one jit dispatch; padded lanes are poisoned (q_inf) and ignored
-  5. device returns a (B,) validity mask; caller attributes failures
+One arrow: dispatch_batch(records) -> records_to_blobs ->
+_dispatch_packed_device <- dispatch_packed(blobs). Pipeline per batch:
+  1. host: w = s⁻¹ mod n, u1 = e·w, u2 = r·w (native C++, threaded;
+     Python ints without the library). The GLV lattice split
+     (k = k1 + λ·k2, |k1|,|k2| < 2^128) runs inside the device program
+  2. pack (pack_lanes, the one packer): u1/u2/qx/qy/r/rn as (B, 32)
+     big-endian byte matrices, q_inf/wrap_ok as (B,) uint8; wrap_ok =
+     (r + n < p) per lane (the kernel gates the x-wraparound candidate
+     on it); window, digit and limb expansion happen on the device
+  3. pad B up to a bucket size (bounds XLA recompiles); padded lanes are
+     poisoned (q_inf) and ignored
+  4. one jit dispatch under the ecdsa breaker: the selected kernel
+     (_glv_dev_program, or _w4_bytes_program under -ecdsakernel=w4), then
+     _w4_bytes_program if GLV failed, then retries, then the breaker
+  5. device returns (B,) validity + degenerate masks; BatchHandle.result
+     checks the two known-answer lanes and host-confirms every False
 
-CPU fallback (``backend="cpu"`` or batches below the dispatch floor) runs
-the Python-int oracle — the reference's single-threaded VerifyScript path.
+CPU fallback (``backend="cpu"``, batches below the dispatch floor, an
+open breaker, a failed dispatch) is the threaded native verify, else the
+Python-int oracle — the reference's single-threaded VerifyScript path.
 """
 
 from __future__ import annotations
@@ -52,8 +53,8 @@ from . import dispatch
 # and gettpuinfo's `batch` section read the same counters.
 _STAGE_H = tm.histogram(
     "bcp_ecdsa_stage_seconds",
-    "Host pack-stage latency per dispatch (decompose = GLV lattice split, "
-    "pack = byte-matrix emit)", labels=("stage",),
+    "Host pack-stage latency per dispatch (emit = byte-matrix padding)",
+    labels=("stage",),
     buckets=(0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
              1.0, 2.5, 5.0))
 _SETTLE_H = tm.histogram(
@@ -99,9 +100,6 @@ def _collect_ecdsa_stats():
 
 tm.register_collector("ecdsa_stats", _collect_ecdsa_stats)
 
-# Pad-to-bucket sizes (SURVEY.md §8.4 dispatch layer). One compiled
-# executable per bucket; persistent across blocks via jit cache.
-BUCKETS = (32, 128, 512, 2048, 8192, 16384, 32768)
 # Below this lane count a device round-trip costs more than host verify.
 CPU_FLOOR = 8
 
@@ -111,17 +109,12 @@ CPU_FLOOR = 8
 # that mints a shape beyond its program's budget fires
 # bcp_xla_retrace_unexpected_total + a log warning + a trace instant).
 # The byte-pipeline ladder is {1024, 2048, 4096} then 2048-granular to
-# 16384 = 9 shapes (_bucket_for pallas=True; >16384 splits per program
-# call, so no extra shapes); the plane/ladder programs pad to BUCKETS.
+# 16384 = 9 shapes (_bucket_for; >16384 splits per program call, so no
+# extra shapes), and both verify programs share it.
 PALLAS_SHAPE_BUDGET = 9
-_PW_GLV = dw.program("ecdsa_glv", shape_budget=PALLAS_SHAPE_BUDGET)
-# the fused decompose+verify program (ISSUE 11): same bucket ladder as
-# the other byte pipelines, so the same 9-shape budget applies
 _PW_GLV_DEV = dw.program("ecdsa_glv_decompose",
                          shape_budget=PALLAS_SHAPE_BUDGET)
 _PW_W4_BYTES = dw.program("ecdsa_w4_bytes", shape_budget=PALLAS_SHAPE_BUDGET)
-_PW_W4 = dw.program("ecdsa_w4", shape_budget=len(BUCKETS))
-_PW_XLA = dw.program("ecdsa_xla", shape_budget=len(BUCKETS))
 # Pippenger MSM batch-verification program (ISSUE 19): term counts pad to
 # the _MSM_BUCKETS ladder, and the canary batches reuse the smallest
 # bucket, so the compiled-shape set is exactly that ladder.
@@ -152,7 +145,7 @@ def _watched_kernel(pw, bucket: int, arrays, fn, jitfn=None, kwargs=None,
     sentinel must see — is min(bucket, split), never the raw bucket (an
     unclamped 32768 would read as a fresh shape and fire a false
     invariant alarm). Pass split=None for programs that do not slice
-    (the XLA ladder compiles the padded bucket as-is)."""
+    (the MSM program compiles the padded bucket as-is)."""
     dw.note_transfer("ecdsa", "h2d",
                      sum(int(a.nbytes) for a in arrays))
     sig = bucket if split is None else min(bucket, split)
@@ -182,26 +175,22 @@ def _freeze_traced_heap() -> None:
 # ---- kernel selection (-ecdsakernel=glv|w4|msm) ----------------------------
 # "glv": the λ-endomorphism split verifier (ops/secp256k1 GLV core — 32
 # windows / 128 doublings over four addition streams + the fixed-base G
-# comb). "w4": the previous-generation 64-window kernel, kept in-tree as
-# the differential oracle and the breaker/dispatch fallback. The GLV path
-# degrades w4 -> XLA ladder -> CPU on failure; selection is validated at
-# node startup (node.py rejects unknown values before the first batch).
+# comb, lattice split in the program). "w4": the 64-window Pallas kernel,
+# the rung a failed GLV dispatch falls to and the differential oracle.
+# Selection is validated at node startup (node.py rejects unknown values
+# before the first batch).
 # "msm": the Pippenger batch-verification rung (ISSUE 19) — it applies to
 # SCHNORR records only (the batch equation needs Schnorr's linear verify
 # relation); ECDSA records under -ecdsakernel=msm ride the GLV ladder,
 # and a failed/rejected MSM batch bisects down to the per-lane oracle.
 ECDSA_KERNELS = ("glv", "w4", "msm")
-# Fault-injection site for the GLV leg specifically (explicit opt-in only,
-# like util/faults' "net" site: BCP_FAULT_OPS=all keeps meaning the four
+# Fault-injection sites for the GLV rung (explicit opt-in only, like
+# util/faults' "net" site: BCP_FAULT_OPS=all keeps meaning the four
 # accelerator subsystems, so existing dead-backend drills are unchanged).
 # fail-* modes prove the glv -> w4 dispatch fallback; poison-output proves
 # the KAT gate catches a lying GLV mask and settles on the CPU engine.
+# Two names, one rung: operators' drills arm either.
 GLV_SITE = "ecdsa_glv"
-# Device-decompose leg of the GLV path (ISSUE 11), likewise explicit-only:
-# fail-* proves the device-decompose -> host-decompose fallback (the
-# degradation ladder's first rung); poison-output proves the KAT gate.
-# GLV_SITE stays armed across the WHOLE GLV family (both legs consult
-# it), so the pre-existing glv -> w4 drills keep their meaning.
 GLV_DEV_SITE = "ecdsa_glv_dev"
 # MSM batch-verification site (ISSUE 19), explicit-only like the GLV
 # legs: fail-* proves the msm -> per-lane fallback rung (a dead MSM
@@ -210,26 +199,12 @@ GLV_DEV_SITE = "ecdsa_glv_dev"
 # (the per-lane KAT gate cannot ride a ONE-bit batch result, so the MSM
 # path carries its own known-answer batches — see _msm_verify_records).
 MSM_SITE = "ecdsa_msm"
-_KERNEL = None  # set_kernel() override; None = BCP_ECDSA_KERNEL or "glv"
-_BAD_ENV_WARNED = False
+_KERNEL = "glv"  # set_kernel() (-ecdsakernel) replaces it
 
 
 def active_kernel() -> str:
-    """The kernel the next device dispatch will try first. An invalid
-    BCP_ECDSA_KERNEL value falls back to the default with a one-time
-    warning (this runs on the dispatch hot path, so it must not raise —
-    the -ecdsakernel flag is the validated front door)."""
-    global _BAD_ENV_WARNED
-    if _KERNEL is not None:
-        return _KERNEL
-    env = os.environ.get("BCP_ECDSA_KERNEL", "glv")
-    if env in ECDSA_KERNELS:
-        return env
-    if not _BAD_ENV_WARNED:
-        _BAD_ENV_WARNED = True
-        log_printf("BCP_ECDSA_KERNEL=%r is not one of %s — using 'glv'",
-                   env, "/".join(ECDSA_KERNELS))
-    return "glv"
+    """The kernel the next device dispatch will try first."""
+    return _KERNEL
 
 
 def set_kernel(name: str) -> str:
@@ -248,11 +223,11 @@ def set_kernel(name: str) -> str:
 
 def kernel_info() -> dict:
     """gettpuinfo's ``ecdsa`` section: the active kernel, GLV health, the
-    one-time fixed-base-table build cost, and the pack-stage split —
-    decompose (host lattice split; ~0 while the device-decompose leg is
-    healthy), emit (numpy byte emission) and dispatch (host-side program
-    enqueue) reported SEPARATELY since ISSUE 11 (decompose_s/pack_s keep
-    their PR-8 meanings, so the section stays a key-for-key superset)."""
+    one-time fixed-base-table build cost, and the host's two stages a
+    dispatch: emit (numpy byte emission) and dispatch (program enqueue).
+    There is one GLV rung, so ``glv_broken``/``glv_fallbacks`` and
+    ``dev_decompose.broken``/``fallbacks`` read the same latch and the
+    same counter (chipbench/checks.py and chip_smoke.py read both)."""
     from . import secp256k1 as dev_mod
 
     return {
@@ -262,15 +237,13 @@ def kernel_info() -> dict:
         "glv_dispatches": STATS.glv_dispatches,
         "glv_fallbacks": STATS.glv_fallbacks,
         "table_build_s": round(dev_mod.GLV_TABLE_BUILD_S, 4),
-        "decompose_s": round(STATS.glv_decompose_s, 4),
-        "pack_s": round(STATS.glv_pack_s, 4),
         "emit_s": round(STATS.glv_emit_s, 4),
         "dispatch_s": round(STATS.glv_dispatch_s, 4),
         "dev_decompose": {
-            "enabled": glv_dev_enabled(),
-            "broken": _GLV_DEV_BROKEN,
-            "dispatches": STATS.glv_dev_dispatches,
-            "fallbacks": STATS.glv_dev_fallbacks,
+            "enabled": glv_enabled(),
+            "broken": _GLV_BROKEN,
+            "dispatches": STATS.glv_dispatches,
+            "fallbacks": STATS.glv_fallbacks,
         },
         "msm": {
             "schnorr_sigs": STATS.schnorr_sigs,
@@ -313,26 +286,16 @@ class BatchStats:
     # P3 pipeline overlap: dispatches currently in flight / high-water mark
     in_flight: int = 0
     max_in_flight: int = 0
-    pallas_fallbacks: int = 0  # Mosaic compile failures -> XLA kernel
+    pallas_fallbacks: int = 0  # w4 kernel failures (Mosaic refusals latch)
     # w4/glv kernel lanes flagged degenerate (adversarially-crafted H == 0
     # collisions) and re-verified on the CPU path — see ops/secp256k1.py
     degenerate_rechecks: int = 0
-    # GLV kernel accounting: dispatches that ran the GLV program, GLV-leg
-    # failures that degraded to the w4 kernel, and the host-side pack
-    # stage split (lattice decomposition vs byte packing) for the
-    # per-stage bench timings (gettpuinfo `ecdsa` section)
+    # GLV kernel accounting (gettpuinfo `ecdsa` section): dispatches that
+    # ran the GLV program, GLV failures that degraded to the w4 kernel,
+    # and the host's two stages a dispatch: emit_s is the numpy byte
+    # emission (pack_lanes), dispatch_s the enqueue of the GLV program
     glv_dispatches: int = 0
     glv_fallbacks: int = 0
-    glv_decompose_s: float = 0.0
-    glv_pack_s: float = 0.0
-    # device-decompose leg (ISSUE 11): dispatches that ran the fused
-    # decompose+verify program, failures that degraded to the host
-    # lattice split, and the decompose/emit/dispatch stage separation
-    # (decompose_s above stays HOST decompose only — ~0 when the device
-    # leg is healthy; emit_s is the numpy byte emission across BOTH GLV
-    # legs; dispatch_s is the host-side enqueue of the glv programs)
-    glv_dev_dispatches: int = 0
-    glv_dev_fallbacks: int = 0
     glv_emit_s: float = 0.0
     glv_dispatch_s: float = 0.0
     # supervised-dispatch accounting (ops/dispatch breaker layer): sigs
@@ -387,33 +350,29 @@ def _note_device_dispatch(n: int, bucket: int) -> None:
     _IN_FLIGHT_G.set(STATS.in_flight)
 
 
-def _bucket_for(n: int, pallas: bool = False) -> int:
-    if pallas and n > 128:
-        # w4-bytes program buckets: {1024, 2048, 4096} then 2048-granular
-        # up to 16384, then 16384-granular (the program splits at 16384
-        # per call) — the jit bakes B into shapes and grid, so bucket
-        # sizes ARE compiled-program shapes and must stay a small bounded
-        # set (a fresh compile is minutes per shape; at most 9 shapes
-        # exist, and only the ones actually hit compile).
-        # 2048-granularity bounds worst-case padding waste at ~33%
-        # (n=4097 -> 6144) and ~20% at the 10k scale — a pure pow2 ladder
-        # padded the bench's 10k batch to 16384 (39% wasted grid steps).
-        # Batches <= 128 lanes use the 2D kernel's small buckets.
-        if n <= 1024:
-            return 1024
-        if n <= 4096:
-            return 2048 if n <= 2048 else 4096
-        if n <= 16384:
-            return ((n + 2047) // 2048) * 2048
-        return ((n + 16383) // 16384) * 16384
-    for b in BUCKETS:
-        if n <= b:
-            return b
-    return ((n + BUCKETS[-1] - 1) // BUCKETS[-1]) * BUCKETS[-1]
+def _bucket_for(n: int) -> int:
+    """Lanes -> the padded size both byte programs compile for: {1024,
+    2048, 4096}, then 2048-granular up to 16384, then 16384-granular (the
+    programs split at 16384 per call). The jit bakes B into shapes and
+    grid, so bucket sizes ARE compiled-program shapes and must stay a
+    small bounded set (a fresh compile is minutes per shape; at most 9
+    shapes exist, and only the ones actually hit compile).
+    2048-granularity bounds worst-case padding waste at ~33% (n=4097 ->
+    6144) and ~20% at the 10k scale. The floor is 1024 for every batch:
+    a smaller shape would be one more program to compile, on the chip as
+    on the CPU backend."""
+    if n <= 1024:
+        return 1024
+    if n <= 4096:
+        return 2048 if n <= 2048 else 4096
+    if n <= 16384:
+        return ((n + 2047) // 2048) * 2048
+    return ((n + 16383) // 16384) * 16384
 
 
 def decompose_scalars(records: Sequence) -> list[tuple[int, int]]:
-    """Step 1: (u1, u2) per record. Records carry (r, s, msg_hash)."""
+    """(u1, u2) per record, Python ints: the no-native form of the
+    precompute. Records carry (r, s, msg_hash)."""
     out = []
     for rec in records:
         w = pow(rec.s, oracle.N - 2, oracle.N)
@@ -421,235 +380,56 @@ def decompose_scalars(records: Sequence) -> list[tuple[int, int]]:
     return out
 
 
-def _scalar_bitplanes(records: Sequence, n: int) -> tuple:
-    """(u1, u2) for all records as (n, 32) big-endian byte matrices, ready
-    for unpackbits. Native C++ when available (bcp_ecdsa_precompute — the
-    Python pow() loop was ~40% of host pack time at 10k sigs), else the
-    Python-int path. Range-invalid records (never produced by the deferral
-    layer, which pre-checks) come back flagged; callers poison those lanes."""
+def _pad(mat: np.ndarray, bucket: int) -> np.ndarray:
+    """(m, w) uint8 rows -> (bucket, w), the rows past m zero."""
+    out = np.zeros((bucket, mat.shape[1]), np.uint8)
+    out[:len(mat)] = mat
+    return out
+
+
+def pack_lanes(pub: np.ndarray, rs: np.ndarray, msg: np.ndarray,
+               rn: np.ndarray, wrap: np.ndarray, bucket: int) -> list:
+    """The one packer: m blob rows (records_to_blobs' layout, which is the
+    native scan's) -> the eight arrays both byte programs take, padded to
+    ``bucket`` lanes: u1, u2, qx, qy, r, rn as (bucket, 32) big-endian
+    uint8 matrices, q_inf and wrap_ok as (bucket,) uint8. Padding lanes
+    and lanes whose r or s the precompute range-flags are poisoned
+    (q_inf = 1: the kernel reports False), so they can never turn a bad
+    batch good or a good batch bad. u1/u2 come from the threaded native
+    modular-inverse leg; the Python-int loop only if the library is
+    missing."""
     from .. import native
 
+    m = len(msg)
     if native.available():
-        u1_blob, u2_blob, ok = native.ecdsa_precompute(records)
-        u1 = np.frombuffer(u1_blob, np.uint8).reshape(n, 32)
-        u2 = np.frombuffer(u2_blob, np.uint8).reshape(n, 32)
-        return u1, u2, ok
-    scalars = decompose_scalars(records)
-    u1 = np.frombuffer(
-        b"".join(u1.to_bytes(32, "big") for u1, _ in scalars), np.uint8
-    ).reshape(n, 32)
-    u2 = np.frombuffer(
-        b"".join(u2.to_bytes(32, "big") for _, u2 in scalars), np.uint8
-    ).reshape(n, 32)
-    return u1, u2, None
-
-
-_LIMB_WEIGHTS = (1 << np.arange(13)).astype(np.uint32)
-
-
-def _limb_cols(blob: bytes, n: int, bucket: int) -> np.ndarray:
-    """n concatenated 32-byte big-endian values -> (20, bucket) 13-bit limb
-    columns (padding lanes zero). Fully vectorized — the per-record
-    to_limbs_np loop was ~60% of host pack time at 10k sigs."""
-    from . import secp256k1 as dev
-
-    mat = np.frombuffer(blob, np.uint8).reshape(n, 32)
-    bits = np.unpackbits(mat, axis=1)[:, ::-1]  # LSB-first bit order
-    bits = np.concatenate(
-        [bits, np.zeros((n, 13 * dev.N_LIMBS - 256), np.uint8)], axis=1
-    )
-    limbs = (
-        bits.reshape(n, dev.N_LIMBS, 13).astype(np.uint32) * _LIMB_WEIGHTS
-    ).sum(axis=2)
-    out = np.zeros((dev.N_LIMBS, bucket), np.uint32)
-    out[:, :n] = limbs.T
-    return out
-
-
-def _pack_limbs(records: Sequence, bucket: int):
-    """Shared SoA limb packing: pubkey/r limbs + poison masks, padded to
-    ``bucket`` lanes. Padded lanes get q_inf=True (poisoned: kernel reports
-    False) and are masked out by the caller — they can never turn a bad
-    batch good or a good batch bad. Returns the (n, 32) u1/u2 scalar byte
-    matrices alongside (the caller picks bit planes or window planes)."""
-    n = len(records)
-    u1_bytes, u2_bytes, range_ok = _scalar_bitplanes(records, n)
-    wraps = [rec.r + oracle.N < oracle.P for rec in records]
-    qx = _limb_cols(
-        b"".join(rec.pubkey[0].to_bytes(32, "big") for rec in records),
-        n, bucket)
-    qy = _limb_cols(
-        b"".join(rec.pubkey[1].to_bytes(32, "big") for rec in records),
-        n, bucket)
-    r0 = _limb_cols(
-        b"".join(rec.r.to_bytes(32, "big") for rec in records), n, bucket)
-    rn = _limb_cols(
-        b"".join(
-            (rec.r + oracle.N if w else rec.r).to_bytes(32, "big")
-            for rec, w in zip(records, wraps)
-        ), n, bucket)
-    q_inf = np.ones(bucket, bool)  # default poisoned (padding)
-    wrap_ok = np.zeros(bucket, bool)
-    wrap_ok[:n] = wraps
-    # real lanes un-poisoned, except any the precompute range-flagged
-    q_inf[:n] = False if range_ok is None else ~np.asarray(range_ok, bool)
-    return u1_bytes, u2_bytes, qx, qy, q_inf, r0, rn, wrap_ok
-
-
-def pack_records(records: Sequence, bucket: int):
-    """Step 2+3 for the bit-ladder kernels: SoA arrays padded to ``bucket``
-    lanes with (256, B) MSB-first bit planes. unpackbits on the 32-byte
-    big-endian scalars — vectorized, not a 256·B Python loop (host packing
-    must stay negligible next to the device dispatch)."""
-    n = len(records)
-    u1_bytes, u2_bytes, qx, qy, q_inf, r0, rn, wrap_ok = _pack_limbs(
-        records, bucket
-    )
-    u1b = np.zeros((256, bucket), np.uint32)
-    u2b = np.zeros((256, bucket), np.uint32)
-    u1b[:, :n] = np.unpackbits(u1_bytes, axis=1).T
-    u2b[:, :n] = np.unpackbits(u2_bytes, axis=1).T
-    return u1b, u2b, qx, qy, q_inf, r0, rn, wrap_ok
-
-
-def pack_records_w4(records: Sequence, bucket: int):
-    """pack_records for the w=4 windowed Pallas kernel: (64, B) 4-bit
-    window planes instead of bit planes."""
-    from . import secp256k1 as dev
-
-    u1_bytes, u2_bytes, qx, qy, q_inf, r0, rn, wrap_ok = _pack_limbs(
-        records, bucket
-    )
-    u1w = dev.bits_to_windows_np(u1_bytes, bucket)
-    u2w = dev.bits_to_windows_np(u2_bytes, bucket)
-    return u1w, u2w, qx, qy, q_inf, r0, rn, wrap_ok
-
-
-def pack_records_w4_bytes(records: Sequence, bucket: int):
-    """Byte-matrix pack for the single-dispatch w4 pipeline: every 256-bit
-    field as a (bucket, 32) big-endian uint8 matrix (window/limb expansion
-    happens ON DEVICE — ops/secp256k1._w4_bytes_program), masks as uint8
-    vectors. ~5x less host->device traffic than the expanded planes."""
-    n = len(records)
-    u1_bytes, u2_bytes, range_ok = _scalar_bitplanes(records, n)
-    wraps = [rec.r + oracle.N < oracle.P for rec in records]
-
-    def mat(blob: bytes) -> np.ndarray:
-        out = np.zeros((bucket, 32), np.uint8)
-        out[:n] = np.frombuffer(blob, np.uint8).reshape(n, 32)
-        return out
-
-    u1m = np.zeros((bucket, 32), np.uint8)
-    u1m[:n] = u1_bytes
-    u2m = np.zeros((bucket, 32), np.uint8)
-    u2m[:n] = u2_bytes
-    qxb = mat(b"".join(rec.pubkey[0].to_bytes(32, "big") for rec in records))
-    qyb = mat(b"".join(rec.pubkey[1].to_bytes(32, "big") for rec in records))
-    r0b = mat(b"".join(rec.r.to_bytes(32, "big") for rec in records))
-    rnb = mat(b"".join(
-        (rec.r + oracle.N if w else rec.r).to_bytes(32, "big")
-        for rec, w in zip(records, wraps)
-    ))
+        u1_blob, u2_blob, ok = native.ecdsa_precompute_blobs(
+            rs.tobytes(), msg.tobytes(), m)
+        u1 = np.frombuffer(u1_blob, np.uint8).reshape(m, 32)
+        u2 = np.frombuffer(u2_blob, np.uint8).reshape(m, 32)
+        range_bad = ~np.asarray(ok, bool)
+    else:
+        recs = _LazyRecords(pub, rs, msg)
+        scalars = decompose_scalars([recs[i] for i in range(m)])
+        u1 = np.frombuffer(
+            b"".join(a.to_bytes(32, "big") for a, _ in scalars),
+            np.uint8).reshape(m, 32)
+        u2 = np.frombuffer(
+            b"".join(b.to_bytes(32, "big") for _, b in scalars),
+            np.uint8).reshape(m, 32)
+        range_bad = np.zeros(m, bool)
     q_inf = np.ones(bucket, np.uint8)
-    q_inf[:n] = 0 if range_ok is None else \
-        (~np.asarray(range_ok, bool)).astype(np.uint8)
+    q_inf[:m] = range_bad.astype(np.uint8)
     wrap8 = np.zeros(bucket, np.uint8)
-    wrap8[:n] = np.asarray(wraps, np.uint8)
-    return u1m, u2m, qxb, qyb, q_inf, r0b, rnb, wrap8
-
-
-def _glv_pack_parts(u1_bytes, u2_bytes, qx_bytes, qy_bytes, r_bytes,
-                    rn_bytes, wraps, range_bad, bucket: int):
-    """Shared HOST-decompose GLV pack (the device-decompose leg's
-    fallback): lattice-decompose the (u1, u2) scalars with the numpy
-    limb-batch split (ops/secp256k1.glv_split_batch_np — vectorized
-    since ISSUE 11; the per-record Python-bigint loop it replaced was
-    the BENCH_r08 host_share 0.56 leg) and emit the GLV program's byte
-    matrices. u1/u2/qx/qy/r/rn: (n, 32) uint8 big-endian. range_bad:
-    (n,) bool poison mask or None. Decompose and emit stages are timed
-    into STATS for the bench's per-stage split."""
-    from . import secp256k1 as dev
-
-    n = len(qy_bytes)
+    wrap8[:m] = wrap
     t0 = time.monotonic()
-    if n:
-        a1m, na1, a2m, na2 = dev.glv_decompose_batch_np(u1_bytes)
-        b1m, nb1, b2m, nb2 = dev.glv_decompose_batch_np(u2_bytes)
+    with dw.phase("ecdsa", "pack"):
+        arrays = [_pad(u1, bucket), _pad(u2, bucket),
+                  _pad(pub[:, :32], bucket), _pad(pub[:, 32:], bucket),
+                  q_inf, _pad(rs[:, :32], bucket), _pad(rn, bucket), wrap8]
     dt = time.monotonic() - t0
-    STATS.glv_decompose_s += dt
-    _STAGE_H.labels(stage="decompose").observe(dt)
-    dw.note_phase("ecdsa", "decompose", dt)
-
-    t0 = time.monotonic()
-    d1m = np.zeros((bucket, 16), np.uint8)
-    d2m = np.zeros((bucket, 16), np.uint8)
-    s1m = np.zeros((bucket, 16), np.uint8)
-    s2m = np.zeros((bucket, 16), np.uint8)
-    sg1 = np.zeros(bucket, np.uint8)
-    sg2 = np.zeros(bucket, np.uint8)
-    ydiff = np.zeros(bucket, np.uint8)
-    qyb = np.zeros((bucket, 32), np.uint8)
-    if n:
-        # comb digits little-endian (position i = weight 256^i); ladder
-        # scalars big-endian (MSB-first nibble windows on device)
-        d1m[:n] = a1m
-        d2m[:n] = a2m
-        s1m[:n] = b1m[:, ::-1]
-        s2m[:n] = b2m[:, ::-1]
-        sg1[:n] = na1
-        sg2[:n] = na2
-        ydiff[:n] = nb1 ^ nb2
-        # first Q-stream sign folds into qy (device never negates Q)
-        fold = nb1.astype(bool)
-        qyb[:n] = qy_bytes
-        if fold.any():
-            qyb[:n][fold] = dev.field_neg_bytes_np(qy_bytes[fold])
-
-    def pad(mat: np.ndarray) -> np.ndarray:
-        out = np.zeros((bucket, 32), np.uint8)
-        out[:n] = mat
-        return out
-
-    q_inf = np.ones(bucket, np.uint8)
-    q_inf[:n] = (np.asarray(range_bad, bool).astype(np.uint8)
-                 if range_bad is not None else 0)
-    wrap8 = np.zeros(bucket, np.uint8)
-    wrap8[:n] = np.asarray(wraps, np.uint8)
-    out = (d1m, d2m, sg1, sg2, s1m, s2m, ydiff, pad(qx_bytes), qyb,
-           q_inf, pad(r_bytes), pad(rn_bytes), wrap8)
-    dt = time.monotonic() - t0
-    STATS.glv_pack_s += dt
     STATS.glv_emit_s += dt
-    _STAGE_H.labels(stage="pack").observe(dt)
-    dw.note_phase("ecdsa", "pack", dt)
-    return out
-
-
-def pack_records_glv(records: Sequence, bucket: int):
-    """pack_records for the GLV kernel: split scalars + signs (the packer
-    emits the λ-decomposition; LanePacker buckets are unchanged). Padded
-    lanes are poisoned exactly like the w4 packers."""
-    n = len(records)
-    u1_bytes, u2_bytes, range_ok = _scalar_bitplanes(records, n)
-    wraps = [rec.r + oracle.N < oracle.P for rec in records]
-    qx_bytes = np.frombuffer(
-        b"".join(rec.pubkey[0].to_bytes(32, "big") for rec in records),
-        np.uint8).reshape(n, 32) if n else np.zeros((0, 32), np.uint8)
-    r_bytes = np.frombuffer(
-        b"".join(rec.r.to_bytes(32, "big") for rec in records),
-        np.uint8).reshape(n, 32) if n else np.zeros((0, 32), np.uint8)
-    rn_bytes = np.frombuffer(
-        b"".join((rec.r + oracle.N if w else rec.r).to_bytes(32, "big")
-                 for rec, w in zip(records, wraps)),
-        np.uint8).reshape(n, 32) if n else np.zeros((0, 32), np.uint8)
-    qy_bytes = np.frombuffer(
-        b"".join(rec.pubkey[1].to_bytes(32, "big") for rec in records),
-        np.uint8).reshape(n, 32) if n else np.zeros((0, 32), np.uint8)
-    range_bad = None if range_ok is None else ~np.asarray(range_ok, bool)
-    return _glv_pack_parts(
-        u1_bytes, u2_bytes, qx_bytes, qy_bytes, r_bytes, rn_bytes, wraps,
-        range_bad, bucket,
-    )
+    _STAGE_H.labels(stage="emit").observe(dt)
+    return arrays
 
 
 def _verify_cpu_ecdsa(records: Sequence) -> np.ndarray:
@@ -924,9 +704,10 @@ def _dispatch_msm(records: Sequence, br) -> Optional[BatchHandle]:
     (synchronous settle): the bisection ladder is verdict-driven, so
     there is nothing to pipeline — the returned handle already carries
     the final verdicts. Returns None when every attempt failed (caller
-    owns the per-lane fallback). Mirrors _dispatch_device's supervision:
-    breaker retries with backoff, programming errors re-raise, canary
-    trips are PoisonedOutput and retried like any device fault."""
+    owns the per-lane fallback). Mirrors _dispatch_packed_device's
+    supervision: breaker retries with backoff, programming errors
+    re-raise, canary trips are PoisonedOutput and retried like any
+    device fault."""
     boff = Backoff(base=br.cfg.backoff_base, maximum=1.0)
     last: Optional[BaseException] = None
     for attempt in range(br.cfg.retries + 1):
@@ -1109,7 +890,7 @@ class BatchHandle:
         self._records = records
         self._breaker = breaker
         self._kat = kat
-        self._recover = recover  # fast whole-batch CPU verdict (packed)
+        self._recover = recover  # whole-batch CPU verdict over the blobs
         # enqueue-side trace context: the settle span (possibly another
         # thread, possibly many blocks later) links back to the span that
         # dispatched this batch
@@ -1130,14 +911,9 @@ class BatchHandle:
         log_printf("ecdsa device batch failed at settle (%s: %s) — CPU "
                    "re-verify of %d sig(s)",
                    type(err).__name__, str(err)[:120], self._n)
-        if self._recover is not None:
-            # packed batches carry a fast whole-batch CPU path (native
-            # threaded verify over the original blobs)
-            out = self._recover()
-        else:
-            out = _verify_cpu([self._records[i] for i in range(self._n)])
+        out = self._recover()
         self._degen = None
-        self._records = None
+        self._records = self._recover = None
         self._cpu_ok = np.asarray(out, dtype=bool)
         return self._cpu_ok
 
@@ -1216,7 +992,7 @@ class BatchHandle:
                 out[bad] = _verify_cpu([self._records[i] for i in bad])
         if self._breaker is not None:
             self._breaker.record_success()
-        self._records = None
+        self._records = self._recover = None  # let the blobs go
         self._cpu_ok = out
         return self._cpu_ok
 
@@ -1264,7 +1040,10 @@ def dispatch_batch(records: Sequence, backend: str = "auto",
     if use_device:
         br = dispatch.breaker("ecdsa")
         if br.allow():
-            handle = _dispatch_device(records, br, kernel=kernel)
+            handle = _dispatch_packed_device(
+                *records_to_blobs(records), n, br,
+                kernel if kernel in ECDSA_KERNELS else active_kernel(),
+                records=records)
             if handle is not None:
                 return handle
             # device leg failed after retries (breaker already charged):
@@ -1278,210 +1057,35 @@ def dispatch_batch(records: Sequence, backend: str = "auto",
 
 
 def _interpret_kernels() -> bool:
-    """True when the Pallas w4 kernels must run in interpret mode: CPU
-    backends have no Mosaic, and WITHOUT this the dispatch path silently
-    degraded every CPU "device" batch to the 256-step XLA bit ladder
-    (pallas_call raises "Only interpret mode is supported on CPU
-    backend"). Interpret mode lowers the real w4 kernel through XLA — the
-    same arrangement parallel/sig_shard uses on virtual CPU meshes."""
+    """True when the Pallas w4 kernel must run in interpret mode: CPU
+    backends have no Mosaic (pallas_call raises "Only interpret mode is
+    supported on CPU backend"). Interpret mode lowers the real w4 kernel
+    through XLA — the same arrangement parallel/sig_shard uses on virtual
+    CPU meshes."""
     from .sha256 import backend_is_cpu
 
     return backend_is_cpu()
 
 
-def _dispatch_device(records: Sequence, br,
-                     kernel: str | None = None) -> Optional[BatchHandle]:
-    """One supervised device enqueue attempt (with retries). Returns None
-    when every attempt failed — the caller owns the CPU fallback. Two
-    known-answer lanes (good + bad signature) ride after the real records
-    so BatchHandle.result can detect a lying validity mask (the KAT lanes
-    ride — and therefore exercise — whichever kernel actually ran,
-    GLV included).
-
-    Kernel chain: GLV (when selected and not latched broken) -> w4 Pallas
-    -> XLA bit ladder; a GLV-leg failure is metered (STATS.glv_fallbacks)
-    and degrades to w4 within the same attempt."""
-    from . import secp256k1 as dev
-
-    wire = list(records) + list(_kat_records())
-    boff = Backoff(base=br.cfg.backoff_base, maximum=1.0)
-    last: Optional[BaseException] = None
-    kern = kernel if kernel in ECDSA_KERNELS else active_kernel()
-    if kern == "msm":
-        # the MSM batch equation verifies Schnorr sigs only; ECDSA lanes
-        # under -ecdsakernel=msm keep the strongest per-lane ladder
-        kern = "glv"
-    # the enqueuing span (block.scan during the pipelined import) is the
-    # settle span's parent — settle may run threads/blocks away
-    ctx = tm.trace_context()
-    for attempt in range(br.cfg.retries + 1):
-        try:
-            INJECTOR.on_call("ecdsa")
-            device_ok = degen = None
-            if kern == "glv" and glv_enabled():
-                # floor 1024: the GLV program shapes stay the packed-path
-                # bucket set {1024, 2048, ...} — sub-128 record batches
-                # would otherwise each compile a tiny one-off shape
-                # (~minutes per shape on a CPU backend, and every shape is
-                # a fresh XLA program on the chip too)
-                bucket = max(1024, _bucket_for(len(wire), pallas=True))
-                if glv_dev_enabled():
-                    # device-decompose leg (ISSUE 11): the host pack is
-                    # the w4 byte emit ONLY — the lattice split runs
-                    # inside the fused program
-                    try:
-                        INJECTOR.on_call(GLV_DEV_SITE)
-                        INJECTOR.on_call(GLV_SITE)
-                        t0 = time.monotonic()
-                        with dw.phase("ecdsa", "pack"):
-                            arrays = pack_records_w4_bytes(wire, bucket)
-                        dt = time.monotonic() - t0
-                        STATS.glv_emit_s += dt
-                        _STAGE_H.labels(stage="emit").observe(dt)
-                        t0 = time.monotonic()
-                        device_ok, degen = _watched_kernel(
-                            _PW_GLV_DEV, bucket, arrays,
-                            lambda: dev.ecdsa_verify_batch_glv_dev(*arrays),
-                            jitfn=(dev._glv_dev_program
-                                   if bucket <= 16384 else None))
-                        STATS.glv_dispatch_s += time.monotonic() - t0
-                        if (INJECTOR.should_poison(GLV_DEV_SITE)
-                                or INJECTOR.should_poison(GLV_SITE)):
-                            device_ok = ~device_ok
-                        STATS.glv_dispatches += 1
-                        STATS.glv_dev_dispatches += 1
-                    except (KeyboardInterrupt, SystemExit):
-                        raise
-                    except Exception as e:
-                        _note_glv_dev_failure(e)
-                        device_ok = degen = None
-                if device_ok is None and glv_enabled():
-                    # host-decompose fallback (the pre-ISSUE-11 path,
-                    # itself numpy-vectorized now)
-                    try:
-                        INJECTOR.on_call(GLV_SITE)
-                        arrays = pack_records_glv(wire, bucket)
-                        t0 = time.monotonic()
-                        device_ok, degen = _watched_kernel(
-                            _PW_GLV, bucket, arrays,
-                            lambda: dev.ecdsa_verify_batch_glv(*arrays),
-                            jitfn=(dev._glv_program
-                                   if bucket <= 16384 else None))
-                        STATS.glv_dispatch_s += time.monotonic() - t0
-                        if INJECTOR.should_poison(GLV_SITE):
-                            device_ok = ~device_ok
-                        STATS.glv_dispatches += 1
-                    except (KeyboardInterrupt, SystemExit):
-                        raise
-                    except Exception as e:
-                        _note_glv_failure(e)
-                        device_ok = degen = None
-            if device_ok is None and pallas_enabled():
-                bucket = _bucket_for(len(wire), pallas=True)
-                try:
-                    if bucket % 1024 == 0:
-                        # single-dispatch byte pipeline: (rows, 8, 128)
-                        # exact-vreg tiles over a grid, device-side
-                        # expansion — the whole batch is one program/round
-                        # trip (ops/secp256k1.py)
-                        with dw.phase("ecdsa", "pack"):
-                            arrays = pack_records_w4_bytes(wire, bucket)
-                        interp = _interpret_kernels()
-                        device_ok, degen = _watched_kernel(
-                            _PW_W4_BYTES, bucket, arrays,
-                            lambda: dev.ecdsa_verify_batch_pallas_w4_bytes(
-                                *arrays, interpret=interp),
-                            jitfn=(dev._w4_bytes_program
-                                   if bucket <= 16384 else None),
-                            kwargs={"interpret": interp})
-                    else:
-                        with dw.phase("ecdsa", "pack"):
-                            arrays = [np.asarray(a) for a in
-                                      pack_records_w4(wire, bucket)]
-                        device_ok, degen = _watched_kernel(
-                            _PW_W4, bucket, arrays,
-                            lambda: dev.ecdsa_verify_batch_pallas_w4(
-                                *arrays),
-                            split=None)
-                except Exception as e:
-                    _note_pallas_failure(e)
-                    device_ok = None
-            if device_ok is None:
-                bucket = _bucket_for(len(wire), pallas=False)
-                with dw.phase("ecdsa", "pack"):
-                    arrays = [np.asarray(a) for a in
-                              pack_records(wire, bucket)]
-                device_ok = _watched_kernel(
-                    _PW_XLA, bucket, arrays,
-                    lambda: dev.ecdsa_verify_batch_jit(*arrays),
-                    jitfn=dev.ecdsa_verify_batch_jit, split=None)
-            _note_device_dispatch(len(records), bucket)
-            return BatchHandle(len(records), bucket, device_ok, degen=degen,
-                               records=wire, breaker=br, kat=True, ctx=ctx)
-        except (KeyboardInterrupt, SystemExit):
-            raise
-        except SURFACE_ERRORS:
-            # programming errors must not degrade silently to the CPU
-            # engine forever — same invariant as _note_pallas_failure
-            raise
-        except Exception as e:  # noqa: BLE001 — supervised boundary
-            last = e
-            if attempt < br.cfg.retries:
-                time.sleep(boff.next())
-    br.record_failure(last)
-    br.note_fallback(len(records))
-    log_printf("ecdsa device dispatch failed (%s: %s) — CPU fallback for "
-               "%d sig(s)", type(last).__name__, str(last)[:120],
-               len(records))
-    return None
-
-
+# One latch a rung: set by a deterministic compiler refusal only, so a
+# toolchain that cannot compile a program stops trying once, not per
+# dispatch. Transient errors (injected drill faults among them) do not
+# latch, and under -tpu=1 a refusal raises KernelRefused instead.
 _PALLAS_BROKEN = False
 _GLV_BROKEN = False
-_GLV_DEV_BROKEN = False
-
-
-def glv_dev_enabled() -> bool:
-    """Gate for the device-decompose GLV leg (ISSUE 11) — the first rung
-    of the degradation ladder (device-decompose -> host decompose -> w4
-    -> XLA -> CPU); latched off on deterministic lowering failures only."""
-    return not _GLV_DEV_BROKEN
-
-
-def _note_glv_dev_failure(e: Exception) -> None:
-    """Device-decompose-leg failure bookkeeping: the dispatch degrades to
-    the host-decompose GLV pack (same supervised attempt). Deterministic
-    lowering failures latch _GLV_DEV_BROKEN; transient errors (including
-    injected drill faults) do not. Programming errors re-raise — the
-    _note_pallas_failure invariant: a NameError in the decompose kernel
-    must not hide behind a green host fallback forever."""
-    global _GLV_DEV_BROKEN
-    if isinstance(e, SURFACE_ERRORS):
-        raise e
-    STATS.glv_dev_fallbacks += 1
-    text = f"{type(e).__name__}: {e}"
-    if _compiler_refused(e):
-        _GLV_DEV_BROKEN = True
-    log_printf("glv device-decompose leg failed (%s) — host decompose "
-               "fallback%s", text[:200],
-               " (latched)" if _GLV_DEV_BROKEN else "")
 
 
 def glv_enabled() -> bool:
-    """Gate for the GLV device leg (kernel selection happens separately —
-    see active_kernel); latched off on deterministic lowering failures so
-    a toolchain that can't compile the GLV program degrades to w4 once,
-    not per dispatch."""
+    """Gate for the GLV rung (kernel selection happens separately — see
+    active_kernel)."""
     return not _GLV_BROKEN
 
 
 def _note_glv_failure(e: Exception) -> None:
-    """GLV-leg failure bookkeeping: the dispatch degrades to the w4 kernel
-    (same supervised attempt). Deterministic lowering failures latch
-    _GLV_BROKEN; transient errors (including injected drill faults) do
-    not. Programming errors re-raise — same invariant as
-    _note_pallas_failure: a NameError in the GLV core must not hide
-    behind a green w4 fallback forever."""
+    """GLV-rung failure bookkeeping: the dispatch degrades to the w4
+    kernel (same supervised attempt). Programming errors re-raise — same
+    invariant as _note_pallas_failure: a NameError in the GLV core must
+    not hide behind a green w4 fallback forever."""
     global _GLV_BROKEN
     if isinstance(e, SURFACE_ERRORS):
         raise e
@@ -1495,26 +1099,18 @@ def _note_glv_failure(e: Exception) -> None:
 
 
 def pallas_enabled() -> bool:
-    """Single source of truth for the Pallas-vs-XLA kernel choice — bucket
-    granularity (dispatch_batch) and kernel selection (_dispatch_device)
-    must agree or big batches get Pallas-sized buckets on the XLA kernel,
-    defeating the bounded-recompile bucket design."""
-    return (
-        not _PALLAS_BROKEN
-        and os.environ.get("BCP_SECP_PALLAS", "1") not in ("0", "false")
-    )
+    """Gate for the w4 rung."""
+    return not _PALLAS_BROKEN
 
 
 def _note_pallas_failure(e: Exception) -> None:
-    """Pallas compile failure bookkeeping (jit compilation is synchronous,
-    so failures surface at the dispatch call). Deterministic Mosaic/
-    lowering failures latch _PALLAS_BROKEN (under -tpu=1 they raise
-    KernelRefused instead); transient errors do NOT — the next dispatch
-    retries.
+    """w4-rung failure bookkeeping (jit compilation is synchronous, so
+    failures surface at the dispatch call); the attempt fails and the
+    retry loop, then the breaker, take over.
 
     Programming errors are NOT toolchain failures: a NameError inside the
-    kernel code would otherwise degrade silently to the XLA fallback
-    forever (it happened — a refactor deleted _PALLAS_SUPER and every
+    kernel code would otherwise degrade silently to the CPU engine
+    forever (it happened — a refactor deleted a module constant and every
     test stayed green on the fallback). Those re-raise."""
     global _PALLAS_BROKEN
     if isinstance(e, SURFACE_ERRORS):
@@ -1523,10 +1119,7 @@ def _note_pallas_failure(e: Exception) -> None:
     text = f"{type(e).__name__}: {e}"
     if _compiler_refused(e):
         _PALLAS_BROKEN = True  # this toolchain can't compile it
-    from ..util.log import log_printf
-
-    log_printf("pallas ECDSA kernel failed (%s) — XLA fallback%s",
-               text[:200],
+    log_printf("pallas ECDSA kernel failed (%s)%s", text[:200],
                " (latched)" if _PALLAS_BROKEN else "")
 
 
@@ -1787,10 +1380,11 @@ class LanePacker:
 
 # ---------------------------------------------------------------------------
 # Blob-level dispatch — the native connect engine's sigscan
-# (native/connect.cpp) emits (pub64, r||s, msg, rn, wrap) byte blobs; this
-# entry feeds them straight into the w4-bytes device program (or the native
-# threaded CPU verify) with zero per-record Python-int work. The record-level
-# dispatch_batch above remains the generic path (script interpreter output).
+# (native/connect.cpp) emits (pub64, r||s, msg, rn, wrap) byte blobs;
+# dispatch_packed feeds them straight into the device program (or the
+# native threaded CPU verify) with zero per-record Python-int work. The
+# record-level dispatch_batch above (script interpreter output) packs its
+# records into the same blobs and takes the same device leg.
 # ---------------------------------------------------------------------------
 
 class _LazyRecords:
@@ -1817,26 +1411,29 @@ class _LazyRecords:
 
 
 def records_to_blobs(records: Sequence):
-    """Pack script-interpreter SigCheckRecords into the blob layout so the
-    occasional generic-path record can join a packed dispatch. Also emits
-    rn/wrap (the x-wraparound candidate gate). ECDSA only: a Schnorr
-    record packed here would ride an ECDSA lane and read False."""
+    """Pack script-interpreter SigCheckRecords into the blob layout (the
+    one place that turns a record into bytes): pub (n,64), r||s (n,64),
+    msg (n,32), rn (n,32), wrap (n,) — the x-wraparound candidate
+    rn = r + n and its gate wrap = (r + n < p). r, s and msg go in mod
+    2^256; the precompute range-rejects them (pack_lanes). ECDSA only: a
+    Schnorr record packed here would ride an ECDSA lane and read False."""
     n = len(records)
-    if any(r.algo != "ecdsa" for r in records):
+    if any(getattr(r, "algo", "ecdsa") != "ecdsa" for r in records):
         raise ValueError("records_to_blobs packs ECDSA records only")
+    top = 1 << 256
     pub = np.frombuffer(
         b"".join(r.pubkey[0].to_bytes(32, "big") + r.pubkey[1].to_bytes(32, "big")
                  for r in records), np.uint8).reshape(n, 64)
     rs = np.frombuffer(
-        b"".join((r.r % (1 << 256)).to_bytes(32, "big")
-                 + (r.s % (1 << 256)).to_bytes(32, "big")
+        b"".join((r.r % top).to_bytes(32, "big")
+                 + (r.s % top).to_bytes(32, "big")
                  for r in records), np.uint8).reshape(n, 64)
     msg = np.frombuffer(
-        b"".join((r.msg_hash % (1 << 256)).to_bytes(32, "big")
+        b"".join((r.msg_hash % top).to_bytes(32, "big")
                  for r in records), np.uint8).reshape(n, 32)
-    wraps = [r.r + oracle.N < oracle.P for r in records]
+    wraps = [0 <= r.r and r.r + oracle.N < oracle.P for r in records]
     rn = np.frombuffer(
-        b"".join((r.r + oracle.N if w else r.r).to_bytes(32, "big")
+        b"".join((r.r + oracle.N if w else r.r % top).to_bytes(32, "big")
                  for r, w in zip(records, wraps)), np.uint8).reshape(n, 32)
     return pub, rs, msg, rn, np.asarray(wraps, np.uint8)
 
@@ -1856,8 +1453,6 @@ def dispatch_packed(pub: np.ndarray, rs: np.ndarray, msg: np.ndarray,
     supervised like dispatch_batch (same KAT lanes, same CPU re-verify on
     failure). ``candidate`` (n,) bool marks multisig candidate lanes, whose
     False the caller settles by group (BatchHandle.result)."""
-    from .. import native
-
     _check_backend(backend)
     n = len(msg)
     if n == 0:
@@ -1865,55 +1460,67 @@ def dispatch_packed(pub: np.ndarray, rs: np.ndarray, msg: np.ndarray,
     use_device = backend == "device" or (
         backend == "auto" and n >= PACKED_DEVICE_FLOOR and _device_available()
     )
-    if not use_device and native.available():
+    if not use_device:
         return _packed_cpu_handle(pub, rs, msg, n)
-    # the packed device leg is viable when EITHER byte-pipeline kernel can
-    # run: the GLV program is plain XLA and does not need Pallas, so a
-    # latched-broken Mosaic toolchain must not push the hottest import
-    # path through the per-record Python repack below
-    packed_ok = pallas_enabled() or (
-        active_kernel() == "glv" and glv_enabled()
-    )
-    if not (use_device and packed_ok):
-        # XLA fallback (both kernels broken / no native lib): go through
-        # the record-level path — rare, and it keeps one source of truth
-        recs = _LazyRecords(pub, rs, msg)
-        return dispatch_batch([recs[i] for i in range(n)], backend=backend)
-
     br = dispatch.breaker("ecdsa")
     if not br.allow():
         br.note_fallback(n)
         STATS.fault_fallback_sigs += n
         return _packed_cpu_handle(pub, rs, msg, n)
     handle = _dispatch_packed_device(pub, rs, msg, rn, wrap, n, br,
-                                     candidate)
+                                     active_kernel(), candidate=candidate)
     if handle is None:
         STATS.fault_fallback_sigs += n
         return _packed_cpu_handle(pub, rs, msg, n)
     return handle
 
 
-def _packed_cpu_handle(pub, rs, msg, n: int) -> BatchHandle:
-    """CPU verdict for a packed batch (native threaded verify when the
-    library loaded, Python-int oracle otherwise)."""
+def _verify_cpu_blobs(pub, rs, msg, n: int) -> np.ndarray:
+    """CPU verdicts for n blob rows: the native threaded verify when the
+    library loaded (orders of magnitude ahead of walking _LazyRecords
+    through the Python-int oracle at reindex batch sizes), else that
+    oracle."""
     from .. import native
 
-    STATS.cpu_fallback_sigs += n
     if native.available():
-        ok = native.ecdsa_verify_batch_blobs(
-            pub.tobytes(), rs.tobytes(), msg.tobytes(), n)
-        return BatchHandle(n, cpu_ok=np.asarray(ok, bool))
+        return np.asarray(native.ecdsa_verify_batch_blobs(
+            pub.tobytes(), rs.tobytes(), msg.tobytes(), n), bool)
     recs = _LazyRecords(pub, rs, msg)
-    return BatchHandle(n, cpu_ok=_verify_cpu([recs[i] for i in range(n)]))
+    return _verify_cpu([recs[i] for i in range(n)])
 
 
-def _dispatch_packed_device(pub, rs, msg, rn, wrap, n: int, br,
-                            candidate=None) -> Optional[BatchHandle]:
-    """Supervised packed enqueue (retries + KAT lanes); None when every
-    attempt failed."""
-    from .. import native
+def _packed_cpu_handle(pub, rs, msg, n: int) -> BatchHandle:
+    STATS.cpu_fallback_sigs += n
+    return BatchHandle(n, cpu_ok=_verify_cpu_blobs(pub, rs, msg, n))
+
+
+def _dispatch_packed_device(pub, rs, msg, rn, wrap, n: int, br, kern: str,
+                            candidate=None,
+                            records=None) -> Optional[BatchHandle]:
+    """The one supervised device enqueue (retries + KAT lanes), fed by
+    blobs; None when every attempt failed or no rung is left — the caller
+    owns the CPU fallback. Two known-answer lanes (good + bad signature)
+    ride after the real lanes so BatchHandle.result can detect a lying
+    validity mask (they ride — and therefore exercise — whichever kernel
+    actually ran).
+
+    Rungs: ``kern`` ("glv": _glv_dev_program, unless latched broken; "w4":
+    _w4_bytes_program), then _w4_bytes_program within the same attempt if
+    GLV failed (metered in STATS.glv_fallbacks). A w4 failure fails the
+    attempt: retries, then the breaker, then the caller's CPU verify.
+    ``records`` are the caller's SigCheckRecords where it has them
+    (dispatch_batch), for the degenerate-lane and reject-side rechecks;
+    the packed entry has none and gets a lazy view over the blobs."""
     from . import secp256k1 as dev
 
+    if kern == "msm":
+        # the MSM batch equation verifies Schnorr sigs only; ECDSA lanes
+        # under -ecdsakernel=msm keep the strongest per-lane ladder
+        kern = "glv"
+    if not ((kern == "glv" and glv_enabled()) or pallas_enabled()):
+        # the compiler refused every kernel this process could run
+        br.note_fallback(n)
+        return None
     # KAT probe lanes appended after the real records (blob layout)
     kpub, krs, kmsg, krn, kwrap = records_to_blobs(list(_kat_records()))
     pub2 = np.concatenate([pub, kpub])
@@ -1921,63 +1528,22 @@ def _dispatch_packed_device(pub, rs, msg, rn, wrap, n: int, br,
     msg2 = np.concatenate([msg, kmsg])
     rn2 = np.concatenate([rn, krn])
     wrap2 = np.concatenate([np.asarray(wrap, np.uint8), kwrap])
-    m = n + 2
-    bucket = max(1024, _bucket_for(m, pallas=True))
-
-    def pad(mat: np.ndarray, width: int) -> np.ndarray:
-        out = np.zeros((bucket, width), np.uint8)
-        out[:m] = mat
-        return out
+    bucket = _bucket_for(n + 2)
 
     boff = Backoff(base=br.cfg.backoff_base, maximum=1.0)
     last: Optional[BaseException] = None
-    ctx = tm.trace_context()  # settle-span parent (see _dispatch_device)
+    # the enqueuing span (block.scan during the pipelined import) is the
+    # settle span's parent — settle may run threads/blocks away
+    ctx = tm.trace_context()
     for attempt in range(br.cfg.retries + 1):
         try:
             INJECTOR.on_call("ecdsa")
-            # u1/u2 via the threaded native modular-inverse leg;
-            # Python-int loop only if the native library is missing
-            if native.available():
-                u1_blob, u2_blob, ok = native.ecdsa_precompute_blobs(
-                    rs2.tobytes(), msg2.tobytes(), m)
-                u1 = np.frombuffer(u1_blob, np.uint8).reshape(m, 32)
-                u2 = np.frombuffer(u2_blob, np.uint8).reshape(m, 32)
-                range_bad = ~np.asarray(ok, bool)
-            else:
-                recs = _LazyRecords(pub2, rs2, msg2)
-                scalars = decompose_scalars([recs[i] for i in range(m)])
-                u1 = np.frombuffer(
-                    b"".join(a.to_bytes(32, "big") for a, _ in scalars),
-                    np.uint8).reshape(m, 32)
-                u2 = np.frombuffer(
-                    b"".join(b.to_bytes(32, "big") for _, b in scalars),
-                    np.uint8).reshape(m, 32)
-                range_bad = np.zeros(m, bool)
-            q_inf = np.ones(bucket, np.uint8)
-            q_inf[:m] = range_bad.astype(np.uint8)
-            wrap8 = np.zeros(bucket, np.uint8)
-            wrap8[:m] = wrap2
+            arrays = pack_lanes(pub2, rs2, msg2, rn2, wrap2, bucket)
             device_ok = degen = None
-            if (active_kernel() == "glv" and glv_enabled()
-                    and glv_dev_enabled()):
-                # device-decompose GLV leg for the packed path (ISSUE
-                # 11): the blobs pad straight into the fused program's
-                # byte matrices — zero per-record host work beyond the
-                # precompute above; failure degrades to the host lattice
-                # split below, then the w4 kernel
+            if kern == "glv" and glv_enabled():
                 try:
                     INJECTOR.on_call(GLV_DEV_SITE)
                     INJECTOR.on_call(GLV_SITE)
-                    t0 = time.monotonic()
-                    with dw.phase("ecdsa", "pack"):
-                        arrays = [pad(u1, 32), pad(u2, 32),
-                                  pad(pub2[:, :32], 32),
-                                  pad(pub2[:, 32:], 32), q_inf,
-                                  pad(rs2[:, :32], 32), pad(rn2, 32),
-                                  wrap8]
-                    dt = time.monotonic() - t0
-                    STATS.glv_emit_s += dt
-                    _STAGE_H.labels(stage="emit").observe(dt)
                     t0 = time.monotonic()
                     device_ok, degen = _watched_kernel(
                         _PW_GLV_DEV, bucket, arrays,
@@ -1989,44 +1555,15 @@ def _dispatch_packed_device(pub, rs, msg, rn, wrap, n: int, br,
                             or INJECTOR.should_poison(GLV_SITE)):
                         device_ok = ~device_ok
                     STATS.glv_dispatches += 1
-                    STATS.glv_dev_dispatches += 1
-                except (KeyboardInterrupt, SystemExit):
-                    raise
-                except Exception as e:
-                    _note_glv_dev_failure(e)
-                    device_ok = degen = None
-            if (device_ok is None and active_kernel() == "glv"
-                    and glv_enabled()):
-                # host-decompose GLV leg: same lattice split as
-                # pack_records_glv (numpy limb batches), fed from the
-                # blobs; failure degrades to the w4 kernel below
-                try:
-                    INJECTOR.on_call(GLV_SITE)
-                    arrays = _glv_pack_parts(
-                        u1, u2, pub2[:, :32], pub2[:, 32:], rs2[:, :32],
-                        rn2, wrap2.astype(bool), range_bad, bucket)
-                    t0 = time.monotonic()
-                    device_ok, degen = _watched_kernel(
-                        _PW_GLV, bucket, arrays,
-                        lambda: dev.ecdsa_verify_batch_glv(*arrays),
-                        jitfn=dev._glv_program if bucket <= 16384 else None)
-                    STATS.glv_dispatch_s += time.monotonic() - t0
-                    if INJECTOR.should_poison(GLV_SITE):
-                        device_ok = ~device_ok
-                    STATS.glv_dispatches += 1
                 except (KeyboardInterrupt, SystemExit):
                     raise
                 except Exception as e:
                     _note_glv_failure(e)
                     device_ok = degen = None
+                    if not pallas_enabled():
+                        raise
             if device_ok is None:
                 try:
-                    with dw.phase("ecdsa", "pack"):
-                        arrays = [pad(u1, 32), pad(u2, 32),
-                                  pad(pub2[:, :32], 32),
-                                  pad(pub2[:, 32:], 32), q_inf,
-                                  pad(rs2[:, :32], 32), pad(rn2, 32),
-                                  wrap8]
                     interp = _interpret_kernels()
                     device_ok, degen = _watched_kernel(
                         _PW_W4_BYTES, bucket, arrays,
@@ -2045,23 +1582,12 @@ def _dispatch_packed_device(pub, rs, msg, rn, wrap, n: int, br,
                     _note_pallas_failure(e)
                     raise
             _note_device_dispatch(n, bucket)
-
-            def recover() -> np.ndarray:
-                # settle-time failure on a packed batch: the native
-                # threaded verify over the original blobs beats walking
-                # _LazyRecords through the Python-int oracle by orders of
-                # magnitude at reindex batch sizes
-                if native.available():
-                    return np.asarray(native.ecdsa_verify_batch_blobs(
-                        pub.tobytes(), rs.tobytes(), msg.tobytes(), n),
-                        bool)
-                recs = _LazyRecords(pub, rs, msg)
-                return _verify_cpu([recs[i] for i in range(n)])
-
-            return BatchHandle(n, bucket, device_ok, degen=degen,
-                               records=_LazyRecords(pub2, rs2, msg2),
-                               breaker=br, kat=True, recover=recover,
-                               ctx=ctx, candidate=candidate)
+            return BatchHandle(
+                n, bucket, device_ok, degen=degen,
+                records=(records if records is not None
+                         else _LazyRecords(pub, rs, msg)),
+                breaker=br, kat=True, ctx=ctx, candidate=candidate,
+                recover=lambda: _verify_cpu_blobs(pub, rs, msg, n))
         except (KeyboardInterrupt, SystemExit):
             raise
         except SURFACE_ERRORS:
@@ -2072,7 +1598,6 @@ def _dispatch_packed_device(pub, rs, msg, rn, wrap, n: int, br,
                 time.sleep(boff.next())
     br.record_failure(last)
     br.note_fallback(n)
-    log_printf("ecdsa packed device dispatch failed (%s: %s) — CPU "
-               "fallback for %d sig(s)", type(last).__name__,
-               str(last)[:120], n)
+    log_printf("ecdsa device dispatch failed (%s: %s) — CPU fallback for "
+               "%d sig(s)", type(last).__name__, str(last)[:120], n)
     return None

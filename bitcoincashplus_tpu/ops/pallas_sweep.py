@@ -15,7 +15,8 @@ Mosaic kernel:
     linear lane indices (u32), avoiding 1D reshapes Mosaic dislikes.
 
 The XLA and Pallas paths are differential-tested against each other and the
-hashlib oracle; bench.py picks whichever is faster on the real chip.
+hashlib oracle. Nothing under the node selects this one: ROADMAP S1/D2
+decide with a chip run whether it goes under the resident loop or goes.
 """
 
 from __future__ import annotations

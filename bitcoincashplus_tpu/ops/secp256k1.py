@@ -22,8 +22,9 @@ Design (SURVEY.md §8.4 "ECDSA batch"):
   - Jacobian points, branchless-complete add/double via jnp.where selects.
   - Verify needs NO field inversion: u1*G + u2*Q is compared via
     X_R == (r + k*n) * Z_R^2 for k in {0,1} (x-wraparound case included).
-  - Scalar work mod n (w = s^-1, u1 = e*w, u2 = r*w) runs on the HOST with
-    Python ints (ops/ecdsa_batch.py) — O(batch) microseconds.
+  - Scalar work mod n (w = s^-1, u1 = e*w, u2 = r*w) runs on the HOST
+    (ops/ecdsa_batch.pack_lanes: native threads, else Python ints) —
+    O(batch) microseconds.
 
 Differentially tested against crypto/secp256k1.py (the Python-int oracle).
 """
@@ -38,7 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..crypto.secp256k1 import GX, GY, N, P
+from ..crypto.secp256k1 import N, P
 
 LIMB_BITS = 13
 N_LIMBS = 20  # 20*13 = 260 bits
@@ -454,64 +455,6 @@ def pt_add_mixed(pt: dict, qx, qy, q_inf, mask2d: bool = False) -> dict:
     return out
 
 
-# ---- batched u1*G + u2*Q and the verify equation ----
-
-_GX_CONST = _const(GX)
-_GY_CONST = _const(GY)
-
-
-def ecdsa_verify_batch_device(u1_bits, u2_bits, qx, qy, q_inf, r0, rn,
-                              wrap_ok):
-    """u1_bits/u2_bits: (256, B) uint32 in {0,1}, MSB first. qx/qy/r0/rn:
-    (20, B) weak limbs. q_inf: (B,) poison mask (malformed pubkey lanes).
-    wrap_ok: (B,) bool — True iff r + n < p, i.e. the x-coordinate
-    wraparound candidate rn = r + n is admissible. The reference
-    (secp256k1_ecdsa_sig_verify, ecdsa_impl.h) only retries the +n
-    candidate under that bound; accepting X == rn·Z² without the gate
-    would falsely accept signatures with x_R = r + n - p. The gate is
-    enforced HERE, in-kernel, so a host layer cannot mis-use rn.
-    Returns (B,) bool validity.
-
-    MSB-first joint double-and-add: 256 x (double + 2 select-merged mixed
-    adds) — no data-dependent control flow; poisoned lanes compute garbage
-    and report False."""
-    batch = qx.shape[1]
-    gx = jnp.broadcast_to(_GX_CONST, (N_LIMBS, batch)).astype(jnp.uint32)
-    gy = jnp.broadcast_to(_GY_CONST, (N_LIMBS, batch)).astype(jnp.uint32)
-    never_inf = jnp.zeros((batch,), bool)
-
-    def step(i, acc):
-        acc = pt_double(acc)
-        with_g = pt_add_mixed(acc, gx, gy, never_inf)
-        acc = pt_select(u1_bits[i].astype(bool), with_g, acc)
-        with_q = pt_add_mixed(acc, qx, qy, q_inf)
-        acc = pt_select(u2_bits[i].astype(bool) & ~q_inf, with_q, acc)
-        return acc
-
-    # infinity init derived from qx/q_inf so the fori_loop carry stays
-    # chip-varying under shard_map (parallel/sig_shard)
-    zero_v = qx * U32_0
-    acc0 = {
-        "X": zero_v + _const(1),
-        "Y": zero_v + _const(1),
-        "Z": zero_v,
-        "inf": q_inf | (q_inf == q_inf),  # all True, varying
-    }
-    acc = jax.lax.fori_loop(0, 256, step, acc0)
-
-    ZZ = f_sqr(acc["Z"])
-    ok0 = f_eq(acc["X"], f_mul(r0, ZZ))
-    ok1 = f_eq(acc["X"], f_mul(rn, ZZ)) & wrap_ok
-    return ~acc["inf"] & ~q_inf & (ok0 | ok1)
-
-
-@jax.jit
-def ecdsa_verify_batch_jit(u1_bits, u2_bits, qx, qy, q_inf, r0, rn, wrap_ok):
-    return ecdsa_verify_batch_device(
-        u1_bits, u2_bits, qx, qy, q_inf, r0, rn, wrap_ok
-    )
-
-
 # ---- Pallas verify kernel ---------------------------------------------------
 
 def _build_const_limbs(value_limbs, shape):
@@ -534,10 +477,10 @@ class _KernelConsts:
     while the Pallas kernel traces (f_sub reads _BIAS_2P, f_is_zero reads
     _P_CONST as module globals). Built at full (20, *lanes) width — lane-1
     arrays trip Mosaic layout assertions on multi-step grids. ``lanes`` is
-    an int (2D tile width) or a shape tuple (the 3D kernel's (8, T))."""
+    the kernel block's lane shape, (8, T)."""
 
     def __init__(self, lanes):
-        self.lanes = (lanes,) if isinstance(lanes, int) else tuple(lanes)
+        self.lanes = tuple(lanes)
 
     def __enter__(self):
         global _BIAS_2P, _P_CONST, _ONE_CONST
@@ -583,26 +526,13 @@ def _pt_select_u(mask_u, t: dict, f: dict) -> dict:
     }
 
 
-# (The round-3 bit-at-a-time Pallas ladder — _verify_core_2d /
-# ecdsa_verify_batch_pallas — was removed in round 4: the w=4 windowed
-# kernels below replaced it in dispatch and nothing else consumed it.
-# The XLA bit-ladder form ecdsa_verify_batch_jit above remains as the
-# compile-failure fallback and the mesh-sharded path.)
-
-# Mosaic on this toolchain rejects >128-LANE tiles; small (<=128-lane)
-# batches run the 2D kernel in one 128-lane tile, and the 2D wrapper
-# splits anything larger into <=4096-lane jit programs (the 3D byte
-# pipeline below is the production path for those).
-_PALLAS_TILE = 128
-_PALLAS_SUPER = 4096
-
 # ---- w=4 windowed Pallas verify kernel (round 4) --------------------------
 #
-# The bit-at-a-time ladder above costs, per scalar bit, 1 explicit double +
-# 2 complete mixed adds — and each COMPLETE add internally computes another
-# pt_double for its `same` select plus two exact-norm zero tests. The
-# windowed form replaces that with, per 4 bits: 4 doubles + ONE add from a
-# 15-entry G table (affine, compile-time constants) + ONE add from a
+# A bit-at-a-time ladder costs, per scalar bit, 1 explicit double + 2
+# complete mixed adds — and each COMPLETE add (pt_add_mixed) internally
+# computes another pt_double for its `same` select plus two exact-norm
+# zero tests. The windowed form costs, per 4 bits: 4 doubles + ONE add
+# from a 15-entry G table (affine, compile-time constants) + ONE add from a
 # 15-entry per-lane Q table (Jacobian, built per batch) — ~3x fewer
 # field-mul-equivalents.
 #
@@ -765,8 +695,8 @@ def _verify_final(acc, degen, q_inf_u, r0, rn, wrap2):
 
 def _verify_core_w4(get_w1, get_w2, qx, qy, q_inf2, r0, rn, wrap2):
     """Windowed ecdsa verify core: window planes are (64, *lanes) int32
-    values in 0..15, MSB-first. Lane axes are generic: (B,) for the 2D
-    kernel, (8, T) for the aligned 3D kernel. Returns (ok, degen) as
+    values in 0..15, MSB-first. Lane axes are generic ((8, T) in the
+    aligned 3D kernel, flat in the op census). Returns (ok, degen) as
     (1, *lanes) int32 0/1 planes — degen lanes carry garbage and MUST be
     re-verified by the caller."""
     lanes = qx.shape[1:]
@@ -794,52 +724,6 @@ def _verify_core_w4(get_w1, get_w2, qx, qy, q_inf2, r0, rn, wrap2):
 
     acc, degen = jax.lax.fori_loop(0, 64, wstep, (acc0, degen0))
     return _verify_final(acc, degen, q_inf_u, r0, rn, wrap2)
-
-
-def _verify_kernel_w4(u1w_ref, u2w_ref, qx_ref, qy_ref, qinf_ref, r0_ref,
-                      rn_ref, wrap_ref, out_ref):
-    from jax.experimental import pallas as pl
-
-    with _KernelConsts(u1w_ref.shape[1]):
-        ok, degen = _verify_core_w4(
-            lambda i: u1w_ref[pl.ds(i, 1), :],
-            lambda i: u2w_ref[pl.ds(i, 1), :],
-            qx_ref[...], qy_ref[...], qinf_ref[0:1, :],
-            r0_ref[...], rn_ref[...], wrap_ref[0:1, :],
-        )
-    plane = jnp.concatenate(
-        [ok.astype(jnp.uint32), degen.astype(jnp.uint32)]
-        + [jnp.zeros_like(ok, jnp.uint32)] * 6,
-        axis=0,
-    )
-    out_ref[...] = plane
-
-
-@jax.jit
-def _pallas_verify_w4_program(u1w, u2w, qx, qy, q2, r0, rn, w2):
-    """<=4096-lane slice -> (8, S) plane: row 0 = ok, row 1 = degenerate."""
-    from jax.experimental import pallas as pl
-
-    S = qx.shape[1]
-    tile = min(_PALLAS_TILE, S)
-    assert S % tile == 0, (S, tile)
-    bs = lambda r: pl.BlockSpec((r, tile), lambda i: (0, 0))  # noqa: E731
-    call = pl.pallas_call(
-        _verify_kernel_w4,
-        grid=(1,),
-        in_specs=[bs(64), bs(64), bs(N_LIMBS), bs(N_LIMBS), bs(8),
-                  bs(N_LIMBS), bs(N_LIMBS), bs(8)],
-        out_specs=bs(8),
-        out_shape=jax.ShapeDtypeStruct((8, tile), jnp.uint32),
-    )
-    outs = []
-    for c in range(S // tile):
-        sl = slice(c * tile, (c + 1) * tile)
-        outs.append(call(
-            u1w[:, sl], u2w[:, sl], qx[:, sl], qy[:, sl],
-            q2[:, sl], r0[:, sl], rn[:, sl], w2[:, sl],
-        ))
-    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
 
 
 def _verify_kernel_w4_3d(u1w_ref, u2w_ref, qx_ref, qy_ref, qinf_ref, r0_ref,
@@ -953,50 +837,16 @@ def ecdsa_verify_batch_pallas_w4_bytes(u1m, u2m, qxb, qyb, q_inf8, r0b,
             jnp.concatenate(dgs).astype(bool))
 
 
-def bits_to_windows_np(scalar_bytes: np.ndarray, bucket: int) -> np.ndarray:
-    """(n, 32) big-endian scalar bytes -> (64, bucket) uint32 4-bit window
-    planes, MSB-first (window 0 = bits 255..252)."""
-    n = scalar_bytes.shape[0]
-    hi = (scalar_bytes >> 4).astype(np.uint32)
-    lo = (scalar_bytes & 0xF).astype(np.uint32)
-    inter = np.stack([hi, lo], axis=2).reshape(n, 64)
-    out = np.zeros((64, bucket), np.uint32)
-    out[:, :n] = inter.T
-    return out
-
-
-def ecdsa_verify_batch_pallas_w4(u1w, u2w, qx, qy, q_inf, r0, rn, wrap_ok):
-    """Windowed Pallas verify. Returns (ok, degen) bool arrays of shape
-    (B,); degen lanes MUST be re-verified on the CPU path (their ok value
-    is garbage by design — see the module notes above)."""
-    B = qx.shape[1]
-    q2 = jnp.broadcast_to(
-        jnp.asarray(q_inf).astype(jnp.uint32).reshape(1, B), (8, B)
-    )
-    w2 = jnp.broadcast_to(
-        jnp.asarray(wrap_ok).astype(jnp.uint32).reshape(1, B), (8, B)
-    )
-    pieces = []
-    for s in range(0, B, _PALLAS_SUPER):
-        sl = slice(s, min(s + _PALLAS_SUPER, B))
-        pieces.append(_pallas_verify_w4_program(
-            u1w[:, sl], u2w[:, sl], qx[:, sl], qy[:, sl],
-            q2[:, sl], r0[:, sl], rn[:, sl], w2[:, sl],
-        )[0:2])
-    out = pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces, axis=1)
-    return out[0].astype(bool), out[1].astype(bool)
-
-
 # ---- GLV endomorphism verify kernel (round 6) ------------------------------
 #
 # secp256k1 admits the efficient endomorphism φ(x, y) = (βx, y) = λ·(x, y)
 # (β³ = 1 mod p, λ³ = 1 mod n — the GLV construction, and the same split
 # libsecp256k1 ships in secp256k1_scalar_split_lambda). Each verify scalar
 # decomposes as k = k1 + λ·k2 (mod n) with |k1|, |k2| < 2^128 via lattice
-# rounding against the basis (a1, b1), (a2, b2) — done on the HOST in the
-# packer with exact Python ints (ops/ecdsa_batch.pack_records_glv), signs
-# folded into table/comb selection. The joint ladder then runs 32 4-bit
-# windows / 128 doublings over FOUR addition streams (Q, λQ, G, λG)
+# rounding against the basis (a1, b1), (a2, b2) — done in the program
+# itself, exactly (_glv_split_device; glv_decompose is its Python-int
+# oracle), signs folded into table/comb selection. The joint ladder then
+# runs 32 4-bit windows / 128 doublings over FOUR addition streams (Q, λQ, G, λG)
 # instead of the w4 kernel's 64 windows / 256 doublings over two:
 #
 #   u1·G + u2·Q = s11·(±G) + s12·(±λG) + s21·(±Q) + s22·(±λQ)
@@ -1015,8 +865,8 @@ def ecdsa_verify_batch_pallas_w4(u1w, u2w, qx, qy, q_inf, r0, rn, wrap_ok):
 # gathers, the ladder its per-lane tables with selects: _glv_tab_read),
 # not Pallas: the comb tables are captured numpy constants, which Mosaic
 # forbids and in-kernel synthesis cannot afford at 16×512 entries (the w4
-# Pallas kernels remain the Mosaic-tuned path and the dispatch fallback;
-# `-ecdsakernel=w4` forces them). Completeness contract is identical to
+# Pallas kernel remains the Mosaic-tuned path and the dispatch fallback;
+# `-ecdsakernel=w4` forces it). Completeness contract is identical to
 # w4: the cheap adds flag H == 0 collisions (degen plane) and the host
 # re-verifies flagged lanes.
 
@@ -1056,8 +906,9 @@ def glv_split(k: int) -> tuple[int, int]:
 
 def glv_decompose(k: int) -> tuple[int, int, int, int]:
     """glv_split with the signs folded out: (|k1|, neg1, |k2|, neg2),
-    neg in {0, 1}. The packer ships magnitudes; signs select negated
-    table/comb entries on device."""
+    neg in {0, 1}: the Python-int oracle of _glv_split_device, whose
+    magnitudes feed the windows and digits and whose signs select negated
+    table/comb entries."""
     k1, k2 = glv_split(k)
     n1, n2 = int(k1 < 0), int(k2 < 0)
     s1, s2 = abs(k1), abs(k2)
@@ -1160,7 +1011,7 @@ def _f_neg(y):
 def _glv_q_tables(qx, qy, ydiff_u, q_inf_u, one):
     """Per-lane Q-stream tables, stacked (_glv_tab_read). Returns two
     (X, Y, Z) tuples of (16, 20, B) arrays: T1[j] = j·Q' (Q' is Q with
-    the first Q-stream sign already folded into qy by the packer) and
+    the first Q-stream sign already folded into qy by the caller) and
     T2[j] = j·(±φ(Q')) — the λQ stream, derived from T1 by the
     endomorphism (X → βX; Y negated where ydiff_u says the two Q-stream
     signs differ). Entry 0 is a dummy (= entry 1, callers mask)."""
@@ -1307,68 +1158,16 @@ def _verify_core_glv(w1, w2, d1, sg1, d2, sg2, qx, qy, ydiff2, q_inf2,
     return _verify_final(acc, degen, q_inf_u, r0, rn, wrap2)
 
 
-@jax.jit
-def _glv_program(d1m, d2m, sg1v, sg2v, s1m, s2m, ydiff8, qxb, qyb, qinf8,
-                 r0b, rnb, wrap8):
-    """The GLV pipeline, ONE dispatch end-to-end: byte-matrix inputs
-    (16-byte scalar halves, 32-byte field elements), device-side
-    expansion to window/digit planes and 13-bit limbs, then the GLV core.
-    Returns (2, B) uint32: row 0 ok, row 1 degenerate."""
-    B = qxb.shape[0]
-    nib_windows = _expand_nibble_windows  # (B, 16) -> (32, B)
-    limbs = _expand_limb_cols             # (B, 32) -> (20, B)
-
-    ok, degen = _verify_core_glv(
-        nib_windows(s1m), nib_windows(s2m),
-        d1m.astype(jnp.int32).T, sg1v.astype(jnp.int32),
-        d2m.astype(jnp.int32).T, sg2v.astype(jnp.int32),
-        limbs(qxb), limbs(qyb),
-        ydiff8.astype(jnp.uint32).reshape(1, B),
-        qinf8.astype(jnp.uint32).reshape(1, B),
-        limbs(r0b), limbs(rnb),
-        wrap8.astype(jnp.uint32).reshape(1, B),
-    )
-    return jnp.concatenate(
-        [ok.astype(jnp.uint32), degen.astype(jnp.uint32)], axis=0
-    )
-
-
-def ecdsa_verify_batch_glv(d1m, d2m, sg1v, sg2v, s1m, s2m, ydiff8, qxb,
-                           qyb, qinf8, r0b, rnb, wrap8):
-    """Byte-matrix GLV verify (see _glv_program). Batches beyond 16384
-    lanes split into 16384-lane program calls so compiled shapes stay the
-    same bounded bucket set as the w4 pipeline. Returns (ok, degen) bool
-    (B,) arrays — device futures until materialized."""
-    B = qxb.shape[0]
-    SPLIT = 16384
-    if B <= SPLIT:
-        out = _glv_program(d1m, d2m, sg1v, sg2v, s1m, s2m, ydiff8, qxb,
-                           qyb, qinf8, r0b, rnb, wrap8)
-        return out[0].astype(bool), out[1].astype(bool)
-    oks, dgs = [], []
-    for s in range(0, B, SPLIT):
-        sl = slice(s, s + SPLIT)
-        out = _glv_program(d1m[sl], d2m[sl], sg1v[sl], sg2v[sl], s1m[sl],
-                           s2m[sl], ydiff8[sl], qxb[sl], qyb[sl],
-                           qinf8[sl], r0b[sl], rnb[sl], wrap8[sl])
-        n = min(SPLIT, B - s)
-        oks.append(out[0].reshape(n))
-        dgs.append(out[1].reshape(n))
-    return (jnp.concatenate(oks).astype(bool),
-            jnp.concatenate(dgs).astype(bool))
-
-
 # ---- device-side GLV decomposition (round 11) ------------------------------
 #
-# BENCH_r08's dispatch breakdown showed the GLV HOST pack dominating the
-# verify path: 3.37 s of per-record Python-bigint lattice rounding +
-# byte emit against 2.64 s of device execute (host_share 0.56). The split
-# is exact integer arithmetic, so it moves on-device: the program below
-# takes the SAME raw byte matrices as the w4 byte pipeline ((B, 32) uint8
-# per 256-bit field — the host pack collapses to pack_records_w4_bytes'
-# numpy byte emission) and computes the lattice rounding per lane with
-# multi-limb integer arithmetic in the same 13-bit-limb discipline as the
-# field core.
+# A host lattice split is per-record bigint rounding, and it dominated the
+# verify path when the packer did it (more host seconds than device
+# seconds a batch). The split is exact integer arithmetic, so it runs
+# on-device: the program below takes the SAME raw byte matrices as the w4
+# byte pipeline ((B, 32) uint8 per 256-bit field — the host pack is
+# ops/ecdsa_batch.pack_lanes' numpy byte emission) and computes the
+# lattice rounding per lane with multi-limb integer arithmetic in the
+# same 13-bit-limb discipline as the field core.
 #
 # Rounding is EXACT, not estimate-grade: c̃K = floor(k·gK / 2^384) (the
 # libsecp g1/g2 Barrett constants, re-derived from the basis at import)
@@ -1574,7 +1373,7 @@ def glv_decompose_device_batch(scalars) -> tuple:
 def _glv_dev_program(u1m, u2m, qxb, qyb, qinf8, r0b, rnb, wrap8):
     """The device-decompose GLV pipeline (round 11), ONE dispatch end to
     end: byte-matrix inputs IDENTICAL to the w4 byte pipeline (so the
-    host pack is pack_records_w4_bytes' pure numpy byte emission),
+    host pack is ops/ecdsa_batch.pack_lanes' pure numpy byte emission),
     device-side exact lattice decomposition of u1/u2, window/digit/limb
     expansion, the sign-folded λQ y-select, then the GLV verify core.
     Returns (2, B) uint32: row 0 ok, row 1 degenerate."""
@@ -1594,9 +1393,9 @@ def _glv_dev_program(u1m, u2m, qxb, qyb, qinf8, r0b, rnb, wrap8):
     w2 = _bits_to_nibble_windows(b2)   # λQ-stream windows
     qy = _expand_limb_cols(qyb)
     nb1r = nb1.reshape(1, B)
-    # the first Q-stream sign folds into qy (the host packer's P − qy
-    # leg, done in the field here); the second folds into the λQ table's
-    # y-select via ydiff — exactly pack_records_glv's emission contract
+    # the first Q-stream sign folds into qy (P − qy, in the field); the
+    # second folds into the λQ table's y-select via ydiff, which is what
+    # _verify_core_glv's qy/ydiff2 arguments mean
     qy = jnp.where(nb1r, _f_neg(qy), qy)
     ydiff = (nb1r ^ nb2.reshape(1, B)).astype(jnp.uint32)
     ok, degen = _verify_core_glv(
@@ -1631,151 +1430,6 @@ def ecdsa_verify_batch_glv_dev(u1m, u2m, qxb, qyb, qinf8, r0b, rnb, wrap8):
     return (jnp.concatenate(oks).astype(bool),
             jnp.concatenate(dgs).astype(bool))
 
-
-# ---- numpy-vectorized host decomposition (fallback + reference) ------------
-#
-# The retained host-decompose path (device-decompose latched broken, or
-# the explicit drill) must still beat the old per-record Python-bigint
-# loop: the same estimate-plus-exact-correction algorithm as the device
-# kernel, vectorized over records in 16-bit limbs on uint64 (products
-# < 2^32, <= 16-term column sums < 2^37 — u64-safe). Also the
-# differential reference the unit suite runs against glv_decompose.
-
-_NP16_MASK = np.uint64(0xFFFF)
-
-
-def _np_limbs16(mat: np.ndarray, width: int) -> np.ndarray:
-    """(n, nb) uint8 big-endian -> (n, width) uint64 16-bit LE limbs."""
-    rev = mat[:, ::-1].astype(np.uint64)
-    out = np.zeros((mat.shape[0], width), np.uint64)
-    half = mat.shape[1] // 2
-    out[:, :half] = rev[:, 0::2] | (rev[:, 1::2] << np.uint64(8))
-    return out
-
-
-def _np_const16(value: int, width: int) -> np.ndarray:
-    assert 0 <= value < (1 << (16 * width)), (value, width)
-    return np.array([(value >> (16 * i)) & 0xFFFF for i in range(width)],
-                    np.uint64)
-
-
-def _np_mul(a: np.ndarray, c: np.ndarray) -> np.ndarray:
-    """(n, La) exact 16-bit limbs x (Lc,) const -> (n, La + Lc) raw
-    columns (u64-safe, un-normalized)."""
-    n, La = a.shape
-    cols = np.zeros((n, La + len(c)), np.uint64)
-    for i, ci in enumerate(c):
-        if int(ci):
-            cols[:, i:i + La] += a * ci
-    return cols
-
-
-def _np_norm(cols: np.ndarray) -> np.ndarray:
-    """Raw columns -> exact 16-bit limbs, same width (value must fit).
-    Three rounds collapse any < 2^37 magnitudes to <= 2^16 + 1; the
-    residual single-carry ripple is data-dependent on host, so loop
-    until quiescent (typically 1-2 more passes) instead of the device
-    kernel's fixed worst-case `width` rounds."""
-    v = cols
-    for _ in range(3):
-        carry = v >> np.uint64(16)
-        v = v & _NP16_MASK
-        v[:, 1:] += carry[:, :-1]
-    while True:
-        carry = v >> np.uint64(16)
-        if not carry.any():
-            return v
-        v = v & _NP16_MASK
-        v[:, 1:] += carry[:, :-1]
-
-
-def _np_sub(a: np.ndarray, b: np.ndarray) -> tuple:
-    """Limbwise a - b with borrow ripple; returns (diff, underflow).
-    underflow True where a < b (diff is then the wrapped complement)."""
-    n, width = a.shape
-    out = np.empty((n, width), np.uint64)
-    borrow = np.zeros(n, np.uint64)
-    for i in range(width):
-        v = a[:, i] - b[:, i] - borrow
-        borrow = v >> np.uint64(63)
-        out[:, i] = v + (borrow << np.uint64(16))
-    return out, borrow.astype(bool)
-
-
-def _np_ge(a: np.ndarray, b: np.ndarray) -> np.ndarray:
-    return ~_np_sub(a, b)[1]
-
-
-def _np_dbl(v: np.ndarray) -> np.ndarray:
-    out = np.zeros((v.shape[0], v.shape[1] + 1), np.uint64)
-    out[:, :-1] = (v << np.uint64(1)) & _NP16_MASK
-    out[:, 1:] += v >> np.uint64(15)
-    return out
-
-
-def _np_bytes_le(limbs: np.ndarray) -> np.ndarray:
-    """(n, L) 16-bit limbs -> (n, 2L) uint8 little-endian bytes."""
-    out = np.empty((limbs.shape[0], 2 * limbs.shape[1]), np.uint8)
-    out[:, 0::2] = (limbs & np.uint64(0xFF)).astype(np.uint8)
-    out[:, 1::2] = ((limbs >> np.uint64(8)) & np.uint64(0xFF)).astype(
-        np.uint8)
-    return out
-
-
-def glv_split_batch_np(scalars: np.ndarray) -> tuple:
-    """Numpy-vectorized exact lattice split: (n, 32) big-endian scalar
-    bytes (each < n) -> (m1 (n, 8) u64 16-bit LE limbs, n1 (n,) bool,
-    m2, n2), rounding identical to glv_split (asserted differentially
-    by the unit suite)."""
-    k = _np_limbs16(np.asarray(scalars, np.uint8), 16)
-    n_16 = _np_const16(N, 16)
-
-    def round_quot(g_int: int, m_int: int) -> np.ndarray:
-        prod = _np_norm(_np_mul(k, _np_const16(g_int, 16)))    # (n, 32)
-        c_est = prod[:, 24:].copy()     # floor(· / 2^384): 24 limbs off
-        t = _np_norm(_np_mul(k, _np_const16(m_int, 8)))        # (n, 24)
-        cn = _np_norm(_np_mul(c_est, n_16))                    # (n, 24)
-        diff, under = _np_sub(t, cn)
-        two = _np_dbl(diff)
-        plus = (~under) & _np_ge(
-            two, np.broadcast_to(_np_const16(N, two.shape[1]), two.shape))
-        c_est[:, 0] += plus
-        return _np_norm(c_est)
-
-    c1 = round_quot(_GLV_G1_INT, _GLV_B2)
-    c2 = round_quot(_GLV_G2_INT, _GLV_MINUS_B1)
-    s_cols = _np_mul(c2, _np_const16(_GLV_A2, 9))              # (n, 17)
-    s_cols[:, :16] += _np_mul(c1, _np_const16(_GLV_A1, 8))
-    s = _np_norm(s_cols)
-    k_pad = np.zeros_like(s)
-    k_pad[:, :16] = k
-    d_ks, n1 = _np_sub(k_pad, s)
-    d_sk, _ = _np_sub(s, k_pad)
-    m1 = np.where(n1[:, None], d_sk, d_ks)[:, :8]
-    p1 = _np_norm(_np_mul(c1, _np_const16(_GLV_MINUS_B1, 8)))  # (n, 16)
-    p2 = _np_norm(_np_mul(c2, _np_const16(_GLV_B2, 8)))
-    d12, n2 = _np_sub(p1, p2)
-    d21, _ = _np_sub(p2, p1)
-    m2 = np.where(n2[:, None], d21, d12)[:, :8]
-    return m1, n1, m2, n2
-
-
-def glv_decompose_batch_np(scalars: np.ndarray) -> tuple:
-    """glv_decompose, vectorized: (n, 32) big-endian scalar bytes ->
-    (|k1| (n, 16) LE bytes, n1 (n,) uint8, |k2| (n, 16) LE bytes, n2)."""
-    m1, n1, m2, n2 = glv_split_batch_np(scalars)
-    return (_np_bytes_le(m1), n1.astype(np.uint8),
-            _np_bytes_le(m2), n2.astype(np.uint8))
-
-
-def field_neg_bytes_np(yb: np.ndarray) -> np.ndarray:
-    """(n, 32) big-endian y (< p) -> (n, 32) big-endian p − y, vectorized
-    (the host packer's Q-stream sign fold; y = 0 is never on the curve,
-    so the p − 0 = p edge is unreachable from parsed pubkeys)."""
-    yl = _np_limbs16(np.asarray(yb, np.uint8), 16)
-    d, under = _np_sub(
-        np.broadcast_to(_np_const16(P, 16), yl.shape).copy(), yl)
-    return _np_bytes_le(d)[:, ::-1]
 
 # ---- Pippenger/bucket MSM — Schnorr batch verification (round 19) ----------
 #

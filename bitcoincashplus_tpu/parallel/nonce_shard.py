@@ -68,7 +68,7 @@ def _shard_body(midstate, tail, target_limbs, start_nonce, n_tiles, tile: int):
     any_found = jax.lax.pmax(found.astype(jnp.uint32), CHIP_AXIS) > 0
     total_tiles = jax.lax.psum(tiles, CHIP_AXIS)
     # per-chip tiles-done, gathered over the chip axis (shard imbalance
-    # observability — SURVEY §6.5; bench config 5 reports the vector)
+    # observability — SURVEY §6.5)
     per_chip = tiles.reshape(1)
     return any_found, best, total_tiles, per_chip
 

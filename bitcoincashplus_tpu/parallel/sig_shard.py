@@ -1,9 +1,9 @@
 """Multi-chip ECDSA batch sharding (P1 in SURVEY.md §3.2).
 
 The signature-batch axis is embarrassingly parallel: shard the B lanes of
-the PRODUCTION w=4 windowed Pallas pipeline (ops/secp256k1._w4_bytes_program
-— the same kernel behind bench config 4) across the ('chip',) mesh with
-shard_map. Inputs are the byte matrices ((B, 32) uint8 per field) sharded on
+the w=4 windowed Pallas pipeline (ops/secp256k1._w4_bytes_program) across
+the ('chip',) mesh with shard_map. Inputs are the byte matrices ((B, 32)
+uint8 per field, ops/ecdsa_batch.pack_lanes) sharded on
 the batch axis; each chip expands its shard to window planes / 13-bit limbs
 on device and runs the Pallas grid locally; the per-lane validity mask
 gathers back over ICI, and a psum'd failure count gives the block-level
@@ -11,21 +11,15 @@ verdict without a host round trip. This is the 8-chip scale-out of the
 CCheckQueue replacement: the reference's `-par=N` worker threads become mesh
 shards.
 
-On CPU meshes (the virtual-8 dryrun/bench — no Mosaic backend) the same
+On CPU meshes (the virtual-8 dryrun — no Mosaic backend) the same
 kernel runs in pallas interpret mode, so the sharded program is the real
-w4 pipeline everywhere, not a stand-in ladder (VERDICT r4 #3/weak-3).
+w4 pipeline everywhere, not a stand-in ladder.
 
-The GLV kernel (ops/secp256k1._glv_program, -ecdsakernel=glv, the
-default) shards the same way via _sharded_glv_jit — plain XLA end to
+The GLV kernel (ops/secp256k1._glv_dev_program, -ecdsakernel=glv, the
+default) shards the same way via _sharded_glv_dev_jit — plain XLA end to
 end, so no interpret split: the fixed-base comb constants replicate per
-chip and the split-scalar byte matrices shard on the batch axis.
-
-Since ISSUE 11 the GLV path shards the FUSED device-decompose program
-(ops/secp256k1._glv_dev_program) by default: inputs are the same raw
-byte matrices as the w4 pipeline (u1/u2 NOT host-split), and each chip
-lattice-decomposes its own shard on device — the mesh-native shape the
-multi-chip roadmap item needs, with the host-decompose _sharded_glv_jit
-kept as the fallback when the fused leg is latched broken.
+chip, the inputs are the same raw byte matrices as the w4 pipeline, and
+each chip lattice-decomposes its own shard on device.
 """
 
 from __future__ import annotations
@@ -63,48 +57,14 @@ def _use_interpret(n_chips: int) -> bool:
 
 
 @partial(jax.jit, static_argnames=("n_chips",))
-def _sharded_glv_jit(d1m, d2m, sg1, sg2, s1m, s2m, ydiff8, qxb, qyb,
-                     qinf8, r0b, rnb, wrap8, n_chips: int):
-    """GLV analogue of _sharded_w4_jit: the plain-XLA GLV program
-    (ops/secp256k1._glv_program) sharded on the batch axis — no
-    interpret-mode split needed because the GLV core never enters Mosaic
-    (its fixed-base comb rides as captured XLA constants, replicated per
-    chip by the partitioner)."""
-    from ..ops.secp256k1 import _glv_program
-
-    mesh = chip_mesh(n_chips)
-    row = P(CHIP_AXIS)
-
-    def body(d1m, d2m, sg1, sg2, s1m, s2m, ydiff8, qxb, qyb, qinf8, r0b,
-             rnb, wrap8):
-        out = _glv_program(d1m, d2m, sg1, sg2, s1m, s2m, ydiff8, qxb, qyb,
-                           qinf8, r0b, rnb, wrap8)
-        b_local = qxb.shape[0]
-        ok = out[0].reshape(b_local).astype(bool)
-        degen = out[1].reshape(b_local).astype(bool)
-        fails = jax.lax.psum(
-            jnp.sum(((~ok | degen) & (qinf8 == 0)).astype(jnp.uint32)),
-            CHIP_AXIS,
-        )
-        return ok, degen, fails
-
-    fn = shard_map_nocheck(
-        body,
-        mesh,
-        in_specs=(row,) * 13,
-        out_specs=(P(CHIP_AXIS), P(CHIP_AXIS), P()),
-    )
-    return fn(d1m, d2m, sg1, sg2, s1m, s2m, ydiff8, qxb, qyb, qinf8, r0b,
-              rnb, wrap8)
-
-
-@partial(jax.jit, static_argnames=("n_chips",))
 def _sharded_glv_dev_jit(u1m, u2m, qxb, qyb, qinf8, r0b, rnb, wrap8,
                          n_chips: int):
-    """Sharded FUSED decompose+verify GLV program (ISSUE 11): raw scalar
-    byte matrices shard on the batch axis and every chip runs the exact
-    in-kernel lattice split over its own lanes — the host ships bytes,
-    never split scalars. Plain XLA end to end (no interpret split)."""
+    """Sharded decompose+verify GLV program: raw scalar byte matrices
+    shard on the batch axis and every chip runs the exact in-kernel
+    lattice split over its own lanes — the host ships bytes, never split
+    scalars. Plain XLA end to end (no interpret-mode split: the GLV core
+    never enters Mosaic, and its fixed-base comb rides as captured XLA
+    constants the partitioner replicates per chip)."""
     from ..ops.secp256k1 import _glv_dev_program
 
     mesh = chip_mesh(n_chips)
@@ -239,11 +199,8 @@ def verify_batch_sharded(records, n_chips: int,
     like the single-chip dispatch (ops/ecdsa_batch.BatchHandle). ``kernel``
     overrides the -ecdsakernel selection for this call (None = active)."""
     from ..ops import ecdsa_batch
-    from ..ops.ecdsa_batch import (
-        _verify_cpu,
-        pack_records_glv,
-        pack_records_w4_bytes,
-    )
+    from ..ops.ecdsa_batch import _verify_cpu
+    from ..util import devicewatch as dw
 
     n = len(records)
     per_chip = max(
@@ -252,37 +209,20 @@ def verify_batch_sharded(records, n_chips: int,
         // _CHIP_BUCKET * _CHIP_BUCKET,
     )
     bucket = per_chip * n_chips
-    from ..util import devicewatch as dw
-
+    arrays = ecdsa_batch.pack_lanes(
+        *ecdsa_batch.records_to_blobs(records), bucket)
+    dw.note_transfer("sig_shard", "h2d",
+                     sum(int(a.nbytes) for a in arrays))
     kern = kernel if kernel in ecdsa_batch.ECDSA_KERNELS \
         else ecdsa_batch.active_kernel()
-    if (kern == "glv" and ecdsa_batch.glv_enabled()
-            and ecdsa_batch.glv_dev_enabled()):
-        # fused device-decompose program: the host pack is the w4 byte
-        # emit, each chip splits its own scalar shard in-kernel
-        arrays = [np.asarray(a)
-                  for a in pack_records_w4_bytes(records, bucket)]
-        dw.note_transfer("sig_shard", "h2d",
-                         sum(int(a.nbytes) for a in arrays))
-        # mesh-width x bucket is the compiled-shape signature; no budget —
-        # virtual meshes legitimately sweep 1/2/4/8
+    # mesh-width x bucket is the compiled-shape signature; no budget —
+    # virtual meshes legitimately sweep 1/2/4/8
+    if kern == "glv" and ecdsa_batch.glv_enabled():
         with dw.program("sig_shard_glv_dev").dispatch((bucket, n_chips)):
             ok, degen, _fails = jax.block_until_ready(
                 _sharded_glv_dev_jit(*arrays, n_chips=n_chips)
             )
-    elif kern == "glv" and ecdsa_batch.glv_enabled():
-        arrays = [np.asarray(a) for a in pack_records_glv(records, bucket)]
-        dw.note_transfer("sig_shard", "h2d",
-                         sum(int(a.nbytes) for a in arrays))
-        with dw.program("sig_shard_glv").dispatch((bucket, n_chips)):
-            ok, degen, _fails = jax.block_until_ready(
-                _sharded_glv_jit(*arrays, n_chips=n_chips)
-            )
     else:
-        arrays = [np.asarray(a)
-                  for a in pack_records_w4_bytes(records, bucket)]
-        dw.note_transfer("sig_shard", "h2d",
-                         sum(int(a.nbytes) for a in arrays))
         with dw.program("sig_shard_w4").dispatch((bucket, n_chips)):
             ok, degen, _fails = jax.block_until_ready(
                 _sharded_w4_jit(*arrays, n_chips=n_chips,

@@ -40,10 +40,11 @@ construction (the callers run the unchanged synchronous path).
 Since ISSUE 11 the GLV lattice split rides the device program, so the
 host half of a flush (_dispatch_flush) is numpy byte emission only: with
 ``-sigservicebuffers`` >= 2 the residual emit of flush N+1 overlaps the
-device decompose+verify of flush N. (The BENCH_r11 re-measure of the
-closed-loop ``concurrent`` level still favors sync — 0.33x — which
+device decompose+verify of flush N. (A CPU-backend re-measure of the
+closed-loop ``concurrent`` level still favored sync — 0.33x — which
 rules pack cost OUT as the cause: bounded concurrency simply cannot
-fill buckets, so the batching tax is structural there, not a host leg.)
+fill buckets, so the batching tax is structural there, not a host leg;
+ROADMAP S4 prices it on the chip.)
 
 Block-import priority: while a block is being connected
 (ChainstateManager wraps process_new_block* in ``import_priority()``),
@@ -552,14 +553,14 @@ class SigService:
         out["buffers"] = self.buffers
         out["running"] = self.running()
         out["backend"] = self.backend
-        # which decompose the GLV flushes ride (ISSUE 11): "device" =
-        # the fused in-kernel lattice split, "host" = the numpy-batch
-        # fallback, "n/a" = a non-GLV kernel is selected
+        # which decompose the GLV flushes ride: "device" = the in-kernel
+        # lattice split (the only one), "n/a" = a non-GLV kernel is
+        # selected
         from ..ops import ecdsa_batch as _eb
 
         out["glv_decompose"] = (
-            "n/a" if (self.kernel or _eb.active_kernel()) != "glv"
-            else ("device" if _eb.glv_dev_enabled() else "host"))
+            "device" if (self.kernel or _eb.active_kernel()) == "glv"
+            else "n/a")
         out["deadline_ms"] = round(self.deadline_s * 1e3, 3)
         out["lanes"] = self.lanes
         out["wait_ms"] = {

@@ -187,8 +187,8 @@ def _batch_product_limbs(values: list[int]) -> int:
 
 
 # Opt-in to the limb backend for the live accumulator. Default off: the
-# int path measured faster at every batch size on the bench host (see
-# module docstring; BENCH_r12.json records the commit-path numbers).
+# int path measured faster at every batch size on a one-core CPU host
+# (see module docstring).
 _USE_LIMBS = os.environ.get("BCP_MUHASH_LIMBS") == "1"
 
 

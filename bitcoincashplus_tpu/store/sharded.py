@@ -95,7 +95,7 @@ def shard_of(key36: bytes, n_shards: int) -> int:
 
 class _KeyBloom:
     """Write-side membership filter over a shard's coin keys (ISSUE 20
-    satellite, BENCH_r12 follow-up).
+    satellite).
 
     The accumulator delta must divide out every changed row's PERSISTED
     old value — which costs a point lookup per changed key even when the

@@ -5,8 +5,8 @@ measurable; every number below the dispatch boundary was still dark:
 nothing verified the bounded-recompile bucket invariant at runtime
 (ops/ecdsa_batch pads batches to a small compiled-shape set precisely so
 XLA retraces stay bounded), nothing accounted for host<->device bytes,
-and the "mining loses ~15x to host dispatch" claim (BENCH_r05) had no
-per-phase decomposition behind it. This module is the device-lane
+and the "mining loses ~15x to host dispatch" claim had no per-phase
+decomposition behind it. This module is the device-lane
 monitor registered around every jit entrypoint:
 
 - **Compile/retrace sentinel** (``program()``/``ProgramWatch.dispatch``):

@@ -29,9 +29,9 @@ settle horizon. This module is the single aggregation surface:
 
 Gating: ``-telemetry=off|counters|trace`` (env ``BCP_TELEMETRY`` seeds the
 default for subprocesses). ``off`` turns every record call into a cheap
-flag check; ``counters`` (default) enables the registry with a
-bench-proven overhead budget (< 2 % on the import_pipeline corpus —
-bench.py telemetry_overhead / BENCH_r06.json); ``trace`` additionally
+flag check; ``counters`` (default) enables the registry, whose budget
+is under 2 % of a block import's wall time (held on the CPU backend
+when it was set; not measured on the chip); ``trace`` additionally
 records spans.
 
 Metric naming scheme: ``bcp_<subsystem>_<what>[_<unit>]`` — e.g.

@@ -659,7 +659,7 @@ static long scan_input(Engine& e, const PTx& tx, const TxMidstates& m,
     memcpy(e.sig_rs.data() + 64 * g + 32, s32, 32);
     memcpy(e.sig_pub.data() + 64 * g, pub64, 64);
     // rn = r + N if r + N < P else r; wrap flag for the kernel's
-    // x-wraparound candidate (ops/ecdsa_batch._pack_limbs semantics)
+    // x-wraparound candidate (ops/ecdsa_batch.records_to_blobs semantics)
     uint8_t rn[32];
     int carry = add_n256(r32, rn);
     bool wrap = (carry == 0) && (cmp256(rn, SECP_P) < 0);

@@ -167,3 +167,65 @@ def fault_harness(monkeypatch):
         os.environ.pop(key, None)
     faults.INJECTOR.reload()
     dispatch.reset()
+
+
+class StubVerifyKernels:
+    """What ``stub_verify_kernels`` hands a test: ``calls`` lists every
+    kernel call as (rung, arrays) in order, rung "glv" or "w4"; ``fail``
+    maps a rung to the exception its next calls raise; ``verdicts``, when
+    set, replaces the oracle: arrays -> (bucket,) bool."""
+
+    def __init__(self):
+        self.calls: list = []
+        self.fail: dict = {}
+        self.verdicts = None
+
+    def rungs(self) -> list:
+        return [rung for rung, _ in self.calls]
+
+
+@pytest.fixture
+def stub_verify_kernels(monkeypatch):
+    """Stand-ins for the two device verify programs, at the two calls the
+    one dispatch function (ops/ecdsa_batch._dispatch_packed_device) makes:
+    the real ones cost minutes of compile on the CPU test backend, and the
+    supervision around them (KAT lanes, retries, breaker, settle-time
+    detection, CPU re-verify) is identical. Verdicts come from the packed
+    arrays alone, with Python ints: R = u1·G + u2·Q, R.x against r and,
+    where wrap_ok, r + n; so the KAT lanes get honest answers."""
+    import numpy as np
+
+    import bitcoincashplus_tpu.ops.secp256k1 as dev
+    from bitcoincashplus_tpu.crypto import secp256k1 as oracle
+
+    # a stubbed first dispatch "compiles" in no time, and devicewatch
+    # would then lower and compile the real program for its cost analysis
+    monkeypatch.setenv("BCP_DEVICEWATCH_COST", "off")
+    stub = StubVerifyKernels()
+
+    def oracle_verdicts(arrays):
+        u1m, u2m, qxb, qyb, q_inf, r0b, rnb, wrap8 = arrays
+        ok = np.zeros(len(q_inf), bool)
+        for i in np.nonzero(np.asarray(q_inf) == 0)[0]:
+            u1, u2, qx, qy, r0, rn = (
+                int.from_bytes(m[i].tobytes(), "big")
+                for m in (u1m, u2m, qxb, qyb, r0b, rnb))
+            pt = oracle.point_add(oracle.point_mul(u1, oracle.G),
+                                  oracle.point_mul(u2, (qx, qy)))
+            ok[i] = pt is not None and (
+                pt[0] == r0 or bool(wrap8[i]) and pt[0] == rn)
+        return ok
+
+    def kernel(rung):
+        def call(*arrays, interpret=False):
+            stub.calls.append((rung, arrays))
+            if rung in stub.fail:
+                raise stub.fail[rung]
+            ok = (stub.verdicts or oracle_verdicts)(arrays)
+            return np.asarray(ok, bool), np.zeros(len(ok), bool)
+        return call
+
+    monkeypatch.setattr(dev, "ecdsa_verify_batch_glv_dev", kernel("glv"))
+    monkeypatch.setattr(dev, "ecdsa_verify_batch_pallas_w4_bytes",
+                        kernel("w4"))
+    return stub

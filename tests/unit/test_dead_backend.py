@@ -5,7 +5,6 @@ complete with verdicts and a final coin set byte-identical to the pure-CPU
 reference engine, while every circuit breaker reports open with nonzero
 fallback counts — the whole robustness tentpole in one scenario."""
 
-import numpy as np
 import pytest
 
 from bitcoincashplus_tpu.consensus.tx import COutPoint, CTransaction, CTxIn, CTxOut
@@ -59,35 +58,13 @@ def _coin_set(cs) -> dict:
 
 
 @pytest.fixture
-def fake_ecdsa_kernel(monkeypatch):
-    """Oracle-backed stand-in for the XLA ECDSA kernel (the real one costs
-    minutes of compile on the CPU test backend; the supervision plumbing
-    under test is identical). Only reachable through half-open probes —
-    with fail-always armed the injector kills the dispatch first."""
-    import bitcoincashplus_tpu.ops.secp256k1 as dev
-    from bitcoincashplus_tpu.crypto import secp256k1 as oracle
-
-    monkeypatch.setenv("BCP_SECP_PALLAS", "0")
-    # pin the w4/XLA kernel so a half-open probe hits this stub, not the
-    # real GLV program (which would pay a real kernel compile here)
-    monkeypatch.setenv("BCP_ECDSA_KERNEL", "w4")
-    state: dict = {"mask": []}
-    real_pack = ecdsa_batch.pack_records
-
-    def spy_pack(records, bucket):
-        state["mask"] = [
-            oracle.ecdsa_verify(r.pubkey, r.r, r.s, r.msg_hash)
-            for r in records
-        ]
-        return real_pack(records, bucket)
-
-    def fake_jit(u1b, u2b, qx, qy, q_inf, r0, rn, wrap_ok):
-        out = np.zeros(q_inf.shape[0], bool)
-        out[: len(state["mask"])] = state["mask"]
-        return out
-
-    monkeypatch.setattr(ecdsa_batch, "pack_records", spy_pack)
-    monkeypatch.setattr(dev, "ecdsa_verify_batch_jit", fake_jit)
+def fake_ecdsa_kernel(stub_verify_kernels):
+    """Oracle-backed stand-in for the device verify programs (the real
+    ones cost minutes of compile on the CPU test backend; the supervision
+    plumbing under test is identical). Only reachable through half-open
+    probes — with fail-always armed the injector kills the dispatch
+    first."""
+    return stub_verify_kernels
 
 
 def test_dead_backend_end_to_end(fault_harness, fake_ecdsa_kernel,

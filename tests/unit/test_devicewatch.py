@@ -145,10 +145,9 @@ def test_ecdsa_programs_declare_budgets():
     progs = dw.snapshot()["programs"]
     # ops/ecdsa_batch was imported (and thus registered) by other suites;
     # after dw.reset() re-derive the handles the module holds
-    assert eb._PW_GLV.shape_budget == eb.PALLAS_SHAPE_BUDGET
     assert eb._PW_GLV_DEV.shape_budget == eb.PALLAS_SHAPE_BUDGET
     assert eb._PW_W4_BYTES.shape_budget == eb.PALLAS_SHAPE_BUDGET
-    assert eb._PW_XLA.shape_budget == len(eb.BUCKETS)
+    assert eb._PW_MSM.shape_budget == len(eb._MSM_BUCKETS)
     assert isinstance(progs, dict)
 
 

@@ -7,8 +7,8 @@ engine — a dead or lying backend can never change consensus — and (b) the
 subsystem's circuit breaker trips on hard failures and recovers through a
 half-open probe once the fault clears.
 
-The ECDSA device kernel is stubbed (oracle-backed fake for the XLA entry)
-so the harness logic — KAT lanes, settle-time detection, CPU re-verify —
+The ECDSA device kernels are stubbed (oracle-backed fakes at the dispatch
+function's two kernel calls) so the harness logic — KAT lanes, settle-time detection, CPU re-verify —
 is exercised without the minutes-long kernel compile; everything else runs
 the real jitted paths on the CPU backend. All tests here are tier-1 fast
 and run by default (pytest -m faults for the smoke subset alone).
@@ -213,36 +213,12 @@ def _make_records(n_good=3, n_bad=1):
 
 
 @pytest.fixture
-def fake_kernel(monkeypatch):
-    """Stand-in for the XLA verify kernel: evaluates the packed batch's
-    verdicts with the Python-int oracle at dispatch time (so KAT lanes get
-    honest answers) — the dispatch/KAT/fallback plumbing under test is
-    identical to the real kernel's."""
-    import bitcoincashplus_tpu.ops.secp256k1 as dev
-
-    monkeypatch.setenv("BCP_SECP_PALLAS", "0")
-    # pin the w4/XLA kernel: the GLV leg (default) would bypass this stub
-    # and pay a real kernel compile — the GLV drill has its own suite
-    # (tests/unit/test_glv.py)
-    monkeypatch.setenv("BCP_ECDSA_KERNEL", "w4")
-    state: dict = {"mask": None}
-    real_pack = ecdsa_batch.pack_records
-
-    def spy_pack(records, bucket):
-        state["mask"] = [
-            oracle.ecdsa_verify(r.pubkey, r.r, r.s, r.msg_hash)
-            for r in records
-        ]
-        return real_pack(records, bucket)
-
-    def fake_jit(u1b, u2b, qx, qy, q_inf, r0, rn, wrap_ok):
-        out = np.zeros(q_inf.shape[0], bool)
-        out[: len(state["mask"])] = state["mask"]
-        return out
-
-    monkeypatch.setattr(ecdsa_batch, "pack_records", spy_pack)
-    monkeypatch.setattr(dev, "ecdsa_verify_batch_jit", fake_jit)
-    return state
+def fake_kernel(stub_verify_kernels):
+    """The oracle-backed stand-in for the device verify programs
+    (tests/conftest.py) at the one dispatch function's kernel calls — the
+    dispatch/KAT/fallback plumbing under test is identical to the real
+    kernel's, without its compile."""
+    return stub_verify_kernels
 
 
 class TestEcdsaFaults:

@@ -1,10 +1,10 @@
 """GLV verification kernel tests (ISSUE 5).
 
-Host-side suite (decomposition lattice, fixed-base comb tables, packer
-shapes) is plain-fast. Kernel differentials run the GLV program at its
-floor bucket (1024) — one XLA compile, persistent-cached (conftest) —
-against both the w4 oracle kernel and the pure-CPU verifier, including
-the adversarial edge corpus (wrap-claim lanes, k2=0 splits, λ-boundary
+Host-side suite (decomposition lattice, fixed-base comb tables) is
+plain-fast. Kernel differentials run the GLV program at its floor bucket
+(1024) — one XLA compile, persistent-cached (conftest) — against both
+the w4 oracle kernel and the pure-CPU verifier, including the
+adversarial edge corpus (wrap-claim lanes, k2=0 splits, λ-boundary
 scalars, negative-half decompositions, u1=0, poisoned lanes). The 10k
 random corpus differential is `slow`-marked like the other full kernel
 differentials; the `glv` marker selects this suite (ordered with the
@@ -131,43 +131,32 @@ def test_node_rejects_unknown_kernel_at_startup(tmp_path):
 
 
 def test_glv_failure_bookkeeping():
-    """Programming errors in the GLV leg re-raise (no silent w4 green);
+    """Programming errors in the GLV rung re-raise (no silent w4 green);
     toolchain errors latch, transients don't — mirror of the pallas
-    bookkeeping invariant."""
+    bookkeeping invariant. One latch and one counter for the one rung."""
     before = ecdsa_batch.STATS.glv_fallbacks
     with pytest.raises(NameError):
         ecdsa_batch._note_glv_failure(NameError("name '_GONE' is not defined"))
+    with pytest.raises(AttributeError):
+        ecdsa_batch._note_glv_failure(
+            AttributeError("module has no attribute '_GONE'"))
     old = ecdsa_batch._GLV_BROKEN
     try:
         ecdsa_batch._note_glv_failure(RuntimeError("transient sneeze"))
         assert ecdsa_batch.STATS.glv_fallbacks == before + 1
-        assert not ecdsa_batch._GLV_BROKEN
+        assert not ecdsa_batch._GLV_BROKEN and ecdsa_batch.glv_enabled()
         ecdsa_batch._note_glv_failure(RuntimeError("Mosaic lowering died"))
+        assert ecdsa_batch._GLV_BROKEN and not ecdsa_batch.glv_enabled()
+        ecdsa_batch._GLV_BROKEN = False
+        ecdsa_batch._note_glv_failure(
+            RuntimeError("NotImplementedError: no lowering"))
         assert ecdsa_batch._GLV_BROKEN
+        info = ecdsa_batch.kernel_info()
+        assert info["glv_broken"] and info["dev_decompose"]["broken"]
+        assert (info["glv_fallbacks"] == info["dev_decompose"]["fallbacks"]
+                == before + 3)
     finally:
         ecdsa_batch._GLV_BROKEN = old
-
-
-def test_pack_records_glv_shapes_and_poison():
-    recs = _records_with_scalars([(rng.randrange(oracle.N),
-                                   rng.randrange(1, oracle.N),
-                                   rng.randrange(1, oracle.N))
-                                  for _ in range(3)])
-    arrays = ecdsa_batch.pack_records_glv([r for r, _ in recs], 8)
-    (d1m, d2m, sg1, sg2, s1m, s2m, ydiff, qxb, qyb, qinf, r0b, rnb,
-     wrap8) = arrays
-    assert d1m.shape == (8, 16) and s1m.shape == (8, 16)
-    assert qxb.shape == (8, 32)
-    assert qinf.tolist() == [0, 0, 0, 1, 1, 1, 1, 1]  # padding poisoned
-    assert not wrap8[3:].any()
-    # digit planes reconstruct the lattice split of u1
-    rec = recs[0][0]
-    w = pow(rec.s, oracle.N - 2, oracle.N)
-    u1 = rec.msg_hash * w % oracle.N
-    s11, n11, s12, _n12 = dev.glv_decompose(u1)
-    assert int.from_bytes(d1m[0].tobytes(), "little") == s11
-    assert int.from_bytes(d2m[0].tobytes(), "little") == s12
-    assert sg1[0] == n11
 
 
 def _records_with_scalars(triples):
@@ -220,6 +209,18 @@ def _edge_corpus():
     base = recs[0][0]
     bad.append((SigCheckRecord(base.pubkey, 5, base.s, base.msg_hash),
                 False))
+    # a corrupt r, another key's signature, and two lanes the packer
+    # poisons (s = 0 and s = n are out of range: q_inf, never a verdict
+    # of the ladder's)
+    other = recs[1][0]
+    bad.append((SigCheckRecord(base.pubkey, (base.r + 1) % n or 1, base.s,
+                               base.msg_hash), False))
+    bad.append((SigCheckRecord(other.pubkey, base.r, base.s,
+                               base.msg_hash), False))
+    bad.append((SigCheckRecord(base.pubkey, base.r, 0, base.msg_hash),
+                False))
+    bad.append((SigCheckRecord(base.pubkey, base.r, n, base.msg_hash),
+                False))
     return recs + bad
 
 
@@ -241,29 +242,38 @@ def test_glv_kernel_edge_differential():
     assert ecdsa_batch.STATS.glv_dispatches >= 1
 
 
-def test_glv_fallback_drill(fault_harness):
-    """Dispatch-breaker drill: a poisoned/failed GLV kernel must degrade
-    glv -> w4 -> CPU with verdict parity and metered fallbacks."""
+@pytest.mark.parametrize("site", [ecdsa_batch.GLV_SITE,
+                                  ecdsa_batch.GLV_DEV_SITE])
+@pytest.mark.parametrize("mode", ["fail-always", "poison-output"])
+def test_glv_fallback_drill(fault_harness, site, mode):
+    """Dispatch-breaker drill, either site name aimed at the one GLV rung:
+    a failed GLV kernel degrades glv -> w4 in the same attempt with
+    verdict parity and a metered fallback; a poisoned one is caught by the
+    riding KAT lanes at settle and the verdict is a fresh CPU
+    re-verification."""
     pairs = _edge_corpus()[:10]
     records = [r for r, _ in pairs]
     expected = _cpu_verdicts(records)
-
-    # leg 1: GLV dispatch fails outright -> same-attempt w4 fallback
-    fault_harness("fail-always", ops=ecdsa_batch.GLV_SITE)
-    before = ecdsa_batch.STATS.glv_fallbacks
-    got = ecdsa_batch.verify_batch(records, backend="device", kernel="glv")
-    assert got.tolist() == expected
-    assert ecdsa_batch.STATS.glv_fallbacks == before + 1
-
-    # leg 2: GLV output poisoned -> the riding KAT lanes catch the lie at
-    # settle and the verdict is a fresh CPU re-verification
-    fault_harness("poison-output", ops=ecdsa_batch.GLV_SITE)
+    fault_harness(mode, ops=site)
+    fb0 = ecdsa_batch.STATS.glv_fallbacks
+    glv0 = ecdsa_batch.STATS.glv_dispatches
     kat0 = ecdsa_batch.STATS.kat_failures
     ff0 = ecdsa_batch.STATS.fault_fallback_sigs
+    w4_0 = ecdsa_batch._PW_W4_BYTES.snapshot()["dispatches"]
     got = ecdsa_batch.verify_batch(records, backend="device", kernel="glv")
     assert got.tolist() == expected
-    assert ecdsa_batch.STATS.kat_failures == kat0 + 1
-    assert ecdsa_batch.STATS.fault_fallback_sigs >= ff0 + len(records)
+    w4_calls = ecdsa_batch._PW_W4_BYTES.snapshot()["dispatches"] - w4_0
+    if mode == "fail-always":
+        assert ecdsa_batch.STATS.glv_fallbacks == fb0 + 1
+        assert ecdsa_batch.STATS.glv_dispatches == glv0
+        assert w4_calls == 1
+        assert ecdsa_batch.STATS.fault_fallback_sigs == ff0
+    else:
+        assert ecdsa_batch.STATS.glv_fallbacks == fb0
+        assert ecdsa_batch.STATS.glv_dispatches == glv0 + 1
+        assert w4_calls == 0
+        assert ecdsa_batch.STATS.kat_failures == kat0 + 1
+        assert ecdsa_batch.STATS.fault_fallback_sigs >= ff0 + len(records)
 
 
 @pytest.mark.slow
@@ -332,25 +342,10 @@ def _scalar_bytes(ks):
     ).reshape(len(ks), 32)
 
 
-def test_host_decompose_batch_np_differential():
-    """The numpy limb-batch host split (the retained fallback AND the
-    packer's vectorized decompose) is bit-identical to glv_decompose."""
-    ks = _decompose_edge_scalars()
-    m1, n1, m2, n2 = dev.glv_decompose_batch_np(_scalar_bytes(ks))
-    quadrants = set()
-    for i, k in enumerate(ks):
-        s1, e1, s2, e2 = dev.glv_decompose(k)
-        got = (int.from_bytes(m1[i].tobytes(), "little"), int(n1[i]),
-               int.from_bytes(m2[i].tobytes(), "little"), int(n2[i]))
-        assert got == (s1, e1, s2, e2), hex(k)
-        quadrants.add((e1, e2))
-    assert quadrants == {(0, 0), (0, 1), (1, 0), (1, 1)}
-
-
 def test_device_decompose_differential():
-    """The in-kernel device split (the production hot path since ISSUE
-    11) is bit-identical to the glv_decompose host oracle over the
-    crafted edge corpus — exact rounding, not estimate-grade."""
+    """The in-kernel device split is bit-identical to the glv_decompose
+    Python-int oracle over the crafted edge corpus — exact rounding, not
+    estimate-grade."""
     ks = _decompose_edge_scalars()[:32]
     m1, n1, m2, n2 = dev.glv_decompose_device_batch(_scalar_bytes(ks))
     for i, k in enumerate(ks):
@@ -360,77 +355,20 @@ def test_device_decompose_differential():
         assert got == (s1, e1, s2, e2), hex(k)
 
 
-def test_field_neg_bytes_np():
-    ys = [rng.randrange(oracle.P) for _ in range(16)] + [1, oracle.P - 1]
-    got = dev.field_neg_bytes_np(_scalar_bytes(ys))
-    for i, y in enumerate(ys):
-        assert int.from_bytes(got[i].tobytes(), "big") == oracle.P - y
-
-
-def test_glv_dev_failure_bookkeeping():
-    """Mirror of the GLV/pallas invariant for the device-decompose leg:
-    programming errors re-raise, toolchain errors latch, transients
-    don't."""
-    before = ecdsa_batch.STATS.glv_dev_fallbacks
-    with pytest.raises(AttributeError):
-        ecdsa_batch._note_glv_dev_failure(
-            AttributeError("module has no attribute '_GONE'"))
-    old = ecdsa_batch._GLV_DEV_BROKEN
-    try:
-        ecdsa_batch._note_glv_dev_failure(RuntimeError("transient sneeze"))
-        assert ecdsa_batch.STATS.glv_dev_fallbacks == before + 1
-        assert not ecdsa_batch._GLV_DEV_BROKEN
-        ecdsa_batch._note_glv_dev_failure(
-            RuntimeError("NotImplementedError: no lowering"))
-        assert ecdsa_batch._GLV_DEV_BROKEN
-        assert not ecdsa_batch.glv_dev_enabled()
-    finally:
-        ecdsa_batch._GLV_DEV_BROKEN = old
-
-
-def test_glv_dev_fallback_drill(fault_harness):
-    """Degradation-ladder drill for the new leg: a failed device-decompose
-    dispatch degrades to the HOST-decompose GLV pack (same supervised
-    attempt, verdict parity); a poisoned one is caught by the riding KAT
-    lanes and settles on the CPU engine."""
-    pairs = _edge_corpus()[:10]
-    records = [r for r, _ in pairs]
-    expected = _cpu_verdicts(records)
-
-    # leg 1: device-decompose fails -> host-decompose GLV (not w4)
-    fault_harness("fail-always", ops=ecdsa_batch.GLV_DEV_SITE)
-    dev_fb0 = ecdsa_batch.STATS.glv_dev_fallbacks
-    glv0 = ecdsa_batch.STATS.glv_dispatches
-    w4_fb0 = ecdsa_batch.STATS.glv_fallbacks
-    got = ecdsa_batch.verify_batch(records, backend="device", kernel="glv")
-    assert got.tolist() == expected
-    assert ecdsa_batch.STATS.glv_dev_fallbacks == dev_fb0 + 1
-    assert ecdsa_batch.STATS.glv_dispatches == glv0 + 1  # host leg ran
-    assert ecdsa_batch.STATS.glv_fallbacks == w4_fb0     # w4 NOT needed
-
-    # leg 2: device-decompose output poisoned -> KAT gate -> CPU engine
-    fault_harness("poison-output", ops=ecdsa_batch.GLV_DEV_SITE)
-    kat0 = ecdsa_batch.STATS.kat_failures
-    got = ecdsa_batch.verify_batch(records, backend="device", kernel="glv")
-    assert got.tolist() == expected
-    assert ecdsa_batch.STATS.kat_failures == kat0 + 1
-
-
 def test_glv_dev_retrace_sentinel_and_packer():
     """devicewatch acceptance: >= 3 decompose-program dispatches at
     DISTINCT batch fills stay inside the declared shape budget with
     retraces_unexpected == 0 (the fills share the 1024 bucket — that IS
     the bounded-shape design); one of them rides the cross-block
     LanePacker so the aggregation layer provably feeds the fused
-    program; host decompose stays untouched the whole time."""
+    program."""
     # the handle the dispatch leg holds, not dw.program(name): a
     # dw.reset() by a suite earlier in this worker mints a fresh watch
     # under the same name, and the leg's counts stay on the old one
     pw = ecdsa_batch._PW_GLV_DEV
     assert pw.name == "ecdsa_glv_decompose"
     d0 = pw.snapshot()["dispatches"]
-    dev0 = ecdsa_batch.STATS.glv_dev_dispatches
-    host_dec0 = ecdsa_batch.STATS.glv_decompose_s
+    dev0 = ecdsa_batch.STATS.glv_dispatches
     emit0 = ecdsa_batch.STATS.glv_emit_s
 
     fills = (6, 40, 90)
@@ -456,12 +394,11 @@ def test_glv_dev_retrace_sentinel_and_packer():
     assert snap["retraces_unexpected"] == 0
     assert snap["shape_budget"] == ecdsa_batch.PALLAS_SHAPE_BUDGET
     assert snap["shapes"] <= snap["shape_budget"]
-    assert ecdsa_batch.STATS.glv_dev_dispatches >= dev0 + 3
-    # the device path pays byte EMISSION, never host decompose
-    assert ecdsa_batch.STATS.glv_decompose_s == host_dec0
+    assert ecdsa_batch.STATS.glv_dispatches >= dev0 + 3
+    # the host pays byte EMISSION only
     assert ecdsa_batch.STATS.glv_emit_s > emit0
     info = ecdsa_batch.kernel_info()
     assert info["dev_decompose"]["enabled"]
     assert info["dev_decompose"]["dispatches"] >= 3
-    for key in ("decompose_s", "pack_s", "emit_s", "dispatch_s"):
+    for key in ("emit_s", "dispatch_s"):
         assert key in info

@@ -138,17 +138,14 @@ def test_require_device_makes_refusal_fatal():
     eb.require_device(True)
     try:
         assert eb._compiler_refused(RuntimeError("socket closed")) is False
-        for note in (eb._note_glv_dev_failure, eb._note_glv_failure,
-                     eb._note_pallas_failure):
+        for note in (eb._note_glv_failure, eb._note_pallas_failure):
             with pytest.raises(eb.KernelRefused, match="dynamic_slice"):
                 note(refusal)
-        assert not (eb._GLV_DEV_BROKEN or eb._GLV_BROKEN
-                    or eb._PALLAS_BROKEN)
+        assert not (eb._GLV_BROKEN or eb._PALLAS_BROKEN)
         assert issubclass(eb.KernelRefused, eb.SURFACE_ERRORS)
     finally:
         eb.require_device(False)
         s = eb.STATS
-        s.glv_dev_fallbacks -= 1
         s.glv_fallbacks -= 1
         s.pallas_fallbacks -= 1
 

@@ -136,66 +136,3 @@ class TestPointOps:
         a, b = rng.randrange(oracle.N), rng.randrange(oracle.N)
         got = _scalar_mult_device([a, b, (a + b) % oracle.N], [oracle.G] * 3)
         assert oracle.point_add(got[0], got[1]) == got[2]
-
-
-def _make_sig_batch(n_valid, n_invalid):
-    """Returns (u1b, u2b, qx, qy, qinf, r0, rn, wrap_ok, expected)."""
-    entries = []
-    for i in range(n_valid + n_invalid):
-        d = rng.randrange(1, oracle.N)
-        pub = oracle.point_mul(d, oracle.G)
-        e = rng.randrange(1 << 256)
-        r, s = oracle.ecdsa_sign(d, e)
-        valid = i < n_valid
-        if not valid:
-            kind = i % 3
-            if kind == 0:
-                e = (e + 1) % (1 << 256)  # wrong message
-            elif kind == 1:
-                r = (r + 1) % oracle.N or 1  # corrupt r
-            else:
-                pub = oracle.point_mul(d + 1, oracle.G)  # wrong key
-        assert oracle.ecdsa_verify(pub, r, s, e) == valid
-        entries.append((pub, r, s, e, valid))
-
-    B = len(entries)
-    u1b = np.zeros((256, B), np.uint32)
-    u2b = np.zeros((256, B), np.uint32)
-    r0v, rnv, qxv, qyv, expected = [], [], [], [], []
-    for j, (pub, r, s, e, valid) in enumerate(entries):
-        w = pow(s, oracle.N - 2, oracle.N)
-        u1, u2 = e * w % oracle.N, r * w % oracle.N
-        for i in range(256):
-            u1b[i, j] = (u1 >> (255 - i)) & 1
-            u2b[i, j] = (u2 >> (255 - i)) & 1
-        r0v.append(r)
-        rnv.append(r + oracle.N)  # kernel's wrap_ok mask gates admissibility
-        qxv.append(pub[0])
-        qyv.append(pub[1])
-        expected.append(valid)
-    qinf = jnp.zeros((B,), bool)
-    wrap_ok = jnp.asarray(
-        np.array([r + oracle.N < oracle.P for r in r0v])
-    )
-    return (
-        jnp.asarray(u1b), jnp.asarray(u2b), limbs(qxv), limbs(qyv), qinf,
-        limbs(r0v), limbs(rnv), wrap_ok, expected,
-    )
-
-
-@pytest.mark.slow
-class TestVerifyBatch:
-    def test_valid_and_invalid_lanes(self):
-        u1b, u2b, qx, qy, qinf, r0, rn, wrap, expected = _make_sig_batch(5, 4)
-        got = np.asarray(
-            S.ecdsa_verify_batch_jit(u1b, u2b, qx, qy, qinf, r0, rn, wrap)
-        )
-        assert got.tolist() == expected
-
-    def test_poisoned_lane_reports_false(self):
-        u1b, u2b, qx, qy, _, r0, rn, wrap, expected = _make_sig_batch(2, 0)
-        qinf = jnp.asarray(np.array([False, True]))
-        got = np.asarray(
-            S.ecdsa_verify_batch_jit(u1b, u2b, qx, qy, qinf, r0, rn, wrap)
-        )
-        assert got.tolist() == [True, False]

@@ -341,8 +341,7 @@ def run_ecdsa_census():
     oh = glv['decompose_total'] / glv['total']
     print(f"{'fused verify total':<28}"
           f"{glv['total_with_decompose']:>12,}  "
-          f"(+{oh * 100:.2f}% over the ladder — the host leg it "
-          "replaces was 56% of wall)")
+          f"(+{oh * 100:.2f}% over the ladder)")
     return parts
 
 
@@ -489,8 +488,9 @@ def run_msm_census():
 #
 # This drives one real dispatch per kernel through the util/devicewatch
 # program registry (BCP_DEVICEWATCH_COST=always captures cost_analysis
-# at first compile into the SAME "ecdsa_glv"/"ecdsa_w4_bytes" programs a
-# running node populates — the live registry, not a side channel).
+# at first compile into the SAME "ecdsa_glv_decompose"/"ecdsa_w4_bytes"
+# programs a running node populates — the live registry, not a side
+# channel).
 
 DRIFT_BUDGET = 0.10
 
@@ -500,12 +500,11 @@ DRIFT_BUDGET = 0.10
 # run lowers differently and reports without flagging until a baseline
 # for that arrangement is recorded here.
 COST_BASELINES = {
-    # the two GLV twins: recorded with the §7 census of PR 29 (jax 0.9.0)
-    "cpu": {"ecdsa_glv": 2_442_480.0, "ecdsa_w4_bytes": 1_618_602.0,
-            # the fused decompose+verify program (ISSUE 11) — the
-            # parallel-form lowering's whole-program flop accounting
-            # weighs the unrolled carry rounds far above their census
-            # primitive count (+12.6k census vs +1.19M flops), which is
+    "cpu": {"ecdsa_w4_bytes": 1_618_602.0,
+            # the GLV decompose+verify program, recorded with the §7
+            # census of PR 29 (jax 0.9.0) — the parallel-form lowering's
+            # whole-program flop accounting weighs the unrolled carry
+            # rounds far above their census primitive count, which is
             # exactly why drift is per kernel against its OWN twin
             "ecdsa_glv_decompose": 4_052_615.0,
             # Schnorr MSM batch check (ISSUE 19): compiled flops per
@@ -551,21 +550,16 @@ def run_ecdsa_live_drift(parts, bucket: int = 1024):
 
     print(f"\nlive cost-analysis drift check (bucket {bucket}, one real "
           "dispatch per kernel through the devicewatch registry)...")
-    glv_args = eb.pack_records_glv(records, bucket)
-    with dwatch.program("ecdsa_glv").dispatch(
-            bucket, jitfn=S._glv_program, args=glv_args):
-        jax.block_until_ready(S._glv_program(*glv_args))
-    dev_args = eb.pack_records_w4_bytes(records, bucket)
+    args = eb.pack_lanes(*eb.records_to_blobs(records), bucket)
     with dwatch.program("ecdsa_glv_decompose").dispatch(
-            bucket, jitfn=S._glv_dev_program, args=dev_args):
-        jax.block_until_ready(S._glv_dev_program(*dev_args))
+            bucket, jitfn=S._glv_dev_program, args=args):
+        jax.block_until_ready(S._glv_dev_program(*args))
     interp = backend_is_cpu()
-    w4_args = eb.pack_records_w4_bytes(records, bucket)
     with dwatch.program("ecdsa_w4_bytes").dispatch(
-            bucket, jitfn=S._w4_bytes_program, args=w4_args,
+            bucket, jitfn=S._w4_bytes_program, args=args,
             kwargs={"interpret": interp}):
         jax.block_until_ready(
-            S._w4_bytes_program(*w4_args, interpret=interp))
+            S._w4_bytes_program(*args, interpret=interp))
     # Schnorr MSM batch-equation program (ISSUE 19) at ITS census rung —
     # bucket 64, the smallest _MSM_BUCKETS shape (1024 is a many-minute
     # XLA compile on a CPU backend; the flops/term-slot unit is bucket-
@@ -578,7 +572,7 @@ def run_ecdsa_live_drift(parts, bucket: int = 1024):
         random.Random(17))
 
     progs = dwatch.snapshot()["programs"]
-    per_name_bucket = {"ecdsa_glv": bucket, "ecdsa_glv_decompose": bucket,
+    per_name_bucket = {"ecdsa_glv_decompose": bucket,
                        "ecdsa_w4_bytes": bucket, "ecdsa_msm": msm_bucket}
     live = {}
     for name, bkt in per_name_bucket.items():
@@ -592,12 +586,10 @@ def run_ecdsa_live_drift(parts, bucket: int = 1024):
     arrangement = "cpu" if interp else "mosaic"
     baselines = COST_BASELINES.get(arrangement)
     census_ratio = parts["glv"]["total"] / parts["w4"]["total"]
-    print(f"{'':<28}{'w4':>14}{'glv':>14}{'glv+dec':>14}")
+    print(f"{'':<28}{'w4':>14}{'glv+dec':>14}")
     print(f"{'census ops/lane':<28}{parts['w4']['total']:>14,}"
-          f"{parts['glv']['total']:>14,}"
           f"{parts['glv']['total_with_decompose']:>14,}")
     print(f"{'compiled flops/lane':<28}{live['ecdsa_w4_bytes']:>14,.0f}"
-          f"{live['ecdsa_glv']:>14,.0f}"
           f"{live['ecdsa_glv_decompose']:>14,.0f}")
     print(f"census glv/w4 ratio: {census_ratio:.4f} "
           "(primitive counts of the kernel cores — see §7)")
