@@ -139,6 +139,10 @@ def _bind(lib: ctypes.CDLL) -> None:
         ctypes.c_void_p, ctypes.POINTER(ctypes.c_size_t)
     ]
     lib.bcp_engine_spent_spk_blob.restype = u8p
+    lib.bcp_engine_leg_blob.argtypes = [
+        ctypes.c_void_p, ctypes.c_int, ctypes.POINTER(ctypes.c_size_t)
+    ]
+    lib.bcp_engine_leg_blob.restype = u8p
     lib.bcp_engine_connect_block.argtypes = [
         ctypes.c_void_p, ctypes.c_char_p, ctypes.c_size_t,
         ctypes.c_uint32, ctypes.c_int64, ctypes.c_uint32, ctypes.c_uint32,
@@ -419,14 +423,23 @@ ENGINE_ERRORS = {
 class NativeConnectResult:
     """Successful native connect: everything the Python orchestration layer
     needs, copied out of the engine's scratch buffers (which the next engine
-    call reuses). Sig arrays are numpy for vectorized compaction."""
+    call reuses). Sig arrays are numpy for vectorized compaction.
+
+    ``sig_status`` per input: 0 = the P2PKH scan's record is in the input's
+    slot of ``sig_pub`` .. ``sig_wrap``; 1 = the Python interpreter decides
+    it; 2 = a script template (P2PK, bare or P2SH CHECKMULTISIG) wrote its
+    lanes into ``leg_lanes``, the arrays (pub, rs, msg, rn, wrap, cand) in
+    input order, cand marking a multisig group's candidate lanes.
+    ``leg_table`` has one row a template input: (input number, first lane,
+    m, n) of its OP_CHECKMULTISIG, m = 0 for the one lane of an
+    OP_CHECKSIG."""
 
     __slots__ = ("block_hash", "n_tx", "n_inputs", "undo", "txids_blob",
                  "sigscan_s",
                  "tx_offsets", "tx_out_counts", "sig_status", "sig_msg",
                  "sig_rs", "sig_pub", "sig_rn", "sig_wrap", "sig_txin",
                  "spent_values", "spent_heightcodes", "spent_spk_offsets",
-                 "spent_spk_blob")
+                 "spent_spk_blob", "leg_lanes", "leg_table")
 
     def txid(self, i: int) -> bytes:
         return self.txids_blob[32 * i:32 * i + 32]
@@ -612,6 +625,18 @@ class ConnectEngine:
             sptr = lib.bcp_engine_spent_spk_blob(self._h,
                                                  ctypes.byref(slen))
             res.spent_spk_blob = ctypes.string_at(sptr, slen.value)
+
+        def leg_blob(which: int, dtype, width: int):
+            ln = ctypes.c_size_t()
+            ptr = lib.bcp_engine_leg_blob(self._h, which, ctypes.byref(ln))
+            blob = np.frombuffer(
+                ctypes.string_at(ptr, ln.value) if ln.value else b"", dtype)
+            return blob.reshape(-1, width) if width > 1 else blob
+
+        res.leg_lanes = tuple(
+            leg_blob(which, np.uint8, width)
+            for which, width in enumerate((64, 64, 32, 32, 1, 1)))
+        res.leg_table = leg_blob(6, np.uint32, 4)
         return res
 
     def commit(self) -> None:

@@ -1623,6 +1623,7 @@ class Node:
         from ..script.interpreter import (
             SCRIPT_VERIFY_NULLFAIL,
             DeferringSignatureChecker,
+            MultisigGroup,
             ScriptError,
             TransactionSignatureChecker,
             VerifyScript,
@@ -1646,11 +1647,16 @@ class Node:
 
         eng = native.ConnectEngine()
         eng.set_best(cs.coins.best_block())
-        # fallback_s (the generic-script leg) is inside verify_s too
+        # fallback_s (the generic-script leg) is inside verify_s too.
+        # fallback_inputs: every input the P2PKH scan did not take =
+        # template_inputs (a native script template wrote their lanes, its
+        # time inside native_connect_s / sigscan_s) + interp_inputs (the
+        # ones that went on to VerifyScript)
         stats = {"blocks": 0, "bytes": 0, "native_connect_s": 0.0,
                  "sigscan_s": 0.0, "verify_s": 0.0, "fallback_s": 0.0,
                  "flush_s": 0.0, "slow_path_blocks": 0,
-                 "fallback_inputs": 0, "fast_inputs": 0}
+                 "fallback_inputs": 0, "template_inputs": 0,
+                 "interp_inputs": 0, "fast_inputs": 0}
         multisig_keys = ("multisig_groups", "multisig_lanes",
                          "multisig_group_confirms")
         multisig0 = [getattr(ecdsa_batch.STATS, k) for k in multisig_keys]
@@ -1682,11 +1688,19 @@ class Node:
             """A multisig group's walk failed on the batch's verdicts: the
             input runs again with the eager checker, and only its
             ScriptError aborts the import (the Python replay then names the
-            block)."""
-            tx, in_i, value, spk, flags, h = owner
+            block). ``owner`` is (block bytes, its connect result, input
+            number, flags, block hash): the transaction is parsed only
+            here."""
+            raw, res, g, flags, h = owner
+            t_i, in_i = (int(v) for v in res.sig_txin[g])
+            tx = CTransaction.from_bytes(
+                raw[int(res.tx_offsets[t_i, 0]):int(res.tx_offsets[t_i, 1])])
+            spk = res.spent_spk_blob[int(res.spent_spk_offsets[g]):
+                                     int(res.spent_spk_offsets[g + 1])]
             try:
                 VerifyScript(tx.vin[in_i].script_sig, spk, flags,
-                             TransactionSignatureChecker(tx, in_i, value))
+                             TransactionSignatureChecker(
+                                 tx, in_i, int(res.spent_values[g])))
             except ScriptError as e:
                 raise _NativeImportAbort(
                     f"multisig input failed ({e.code}) in block "
@@ -1831,19 +1845,20 @@ class Node:
             n_imported += 1
             return True
 
-        def script_leg(raw: bytes, res, fb_idx, flags: int, h: bytes):
-            """The generic-script leg of one block: every input the native
-            scan did not match, through the Python interpreter. Returns
-            (records, multisig groups), or None where the block has to take
-            the Python path: a script failed, or a record is not ECDSA's
-            (a 65-byte Schnorr signature has no lane in the packed
-            batch)."""
+        def script_leg(raw: bytes, res, interp_idx, flags: int, h: bytes):
+            """The generic-script leg of one block: the lanes the native
+            scan's templates wrote for the inputs they fit, then every
+            input they declined (``interp_idx``) through the Python
+            interpreter. Returns (the six lane arrays, multisig groups), or
+            None where the block has to take the Python path: a script
+            failed, or a record is not ECDSA's (a 65-byte Schnorr signature
+            has no lane in the packed batch)."""
             records: list = []
             groups: list = []
             tx_cache: dict[int, tuple] = {}
             spk_off = res.spent_spk_offsets
             try:
-                for g in fb_idx:
+                for g in interp_idx:
                     t_i, in_i = (int(res.sig_txin[g, 0]),
                                  int(res.sig_txin[g, 1]))
                     if t_i not in tx_cache:
@@ -1854,19 +1869,36 @@ class Node:
                     tx, cache = tx_cache[t_i]
                     spk = res.spent_spk_blob[
                         int(spk_off[g]):int(spk_off[g + 1])]
-                    value = int(res.spent_values[g])
                     seen = len(groups)
                     checker = DeferringSignatureChecker(
-                        tx, in_i, value, records, cache, groups)
+                        tx, in_i, int(res.spent_values[g]), records, cache,
+                        groups)
                     VerifyScript(tx.vin[in_i].script_sig, spk, flags,
                                  checker)
                     for grp in groups[seen:]:
-                        grp.owner = (tx, in_i, value, spk, flags, h)
+                        grp.owner = (raw, res, int(g), flags, h)
             except ScriptError:
                 return None
             if any(r.algo != "ecdsa" for r in records):
                 return None
-            return records, groups
+            *lanes, cand = res.leg_lanes
+            cand = cand.view(bool)
+            # the templates' groups, counted as defer_multisig counts its own
+            table = res.leg_table
+            native_groups = [
+                MultisigGroup(first, m, n, (raw, res, g, flags, h))
+                for g, first, m, n in table[table[:, 2] > 0].tolist()]
+            ecdsa_batch.STATS.multisig_groups += len(native_groups)
+            ecdsa_batch.STATS.multisig_lanes += int(cand.sum())
+            if records:
+                ecand = np.zeros(len(records), bool)
+                for grp in groups:
+                    ecand[grp.start:grp.start + grp.lanes] = True
+                    grp.start += len(cand)  # behind the templates' lanes
+                lanes = [np.concatenate(pair) for pair in zip(
+                    lanes, ecdsa_batch.records_to_blobs(records))]
+                cand = np.concatenate([cand, ecand])
+            return (*lanes, cand), native_groups + groups
 
         def fast_connect(raw: bytes, h: bytes, prev, pos_info) -> bool:
             """One linear-extension block through the native engine.
@@ -1937,34 +1969,29 @@ class Node:
                 rn = res.sig_rn[fast_idx]
                 wrap = res.sig_wrap[fast_idx]
                 cand = np.zeros(len(msg), bool)
-                fb_idx = np.nonzero(status == 1)[0]
-                if fb_idx.size:
-                    # generic-script inputs: the Python interpreter is the
-                    # authority; its deferred records join the same batch
-                    stats["fallback_inputs"] += int(fb_idx.size)
+                n_leg = res.n_inputs - int(fast_idx.size)
+                if n_leg:
+                    # generic-script inputs: the lanes of those a native
+                    # template fits, the Python interpreter the authority
+                    # for the rest; both join the same batch
+                    interp_idx = np.nonzero(status == 1)[0]
+                    stats["fallback_inputs"] += n_leg
+                    stats["template_inputs"] += len(res.leg_table)
+                    stats["interp_inputs"] += int(interp_idx.size)
                     t_leg = time.perf_counter()
                     with telemetry.span("import.script_leg", height=height,
-                                        inputs=int(fb_idx.size)):
-                        leg = script_leg(raw, res, fb_idx, flags, h)
+                                        inputs=n_leg):
+                        leg = script_leg(raw, res, interp_idx, flags, h)
                     stats["fallback_s"] += time.perf_counter() - t_leg
                     if leg is None:
                         eng.abort()
                         return False  # Python path re-derives the verdict
-                    records, groups = leg
-                    if records:
-                        epub, ers, emsg, ern, ewrap = (
-                            ecdsa_batch.records_to_blobs(records))
-                        ecand = np.zeros(len(records), bool)
-                        for grp in groups:
-                            ecand[grp.start:grp.start + grp.lanes] = True
-                        settler.add(lanes_dispatched[0] + agg_count[0]
-                                    + len(msg), groups)
-                        pub = np.concatenate([pub, epub])
-                        rs = np.concatenate([rs, ers])
-                        msg = np.concatenate([msg, emsg])
-                        rn = np.concatenate([rn, ern])
-                        wrap = np.concatenate([wrap, ewrap])
-                        cand = np.concatenate([cand, ecand])
+                    leg_lanes, groups = leg
+                    settler.add(lanes_dispatched[0] + agg_count[0]
+                                + len(msg), groups)
+                    pub, rs, msg, rn, wrap, cand = (
+                        np.concatenate(pair) for pair in zip(
+                            (pub, rs, msg, rn, wrap, cand), leg_lanes))
                 if len(msg):
                     agg.append((pub, rs, msg, rn, wrap, cand))
                     agg_count[0] += len(msg)

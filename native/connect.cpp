@@ -28,6 +28,7 @@
 #include <thread>
 #include <atomic>
 #include <chrono>
+#include <functional>
 
 #include "common.h"
 
@@ -60,6 +61,7 @@ static const uint8_t SECP_N_HALF[32] = {
     0x5D,0x57,0x6E,0x73,0x57,0xA4,0x50,0x1D,0xDF,0xE9,0x2F,0x46,0x68,0x1B,0x20,0xA0};
 
 // script flag bits (script/interpreter.py)
+constexpr uint32_t F_P2SH = 1 << 0;
 constexpr uint32_t F_DERSIG = 1 << 2;
 constexpr uint32_t F_LOW_S = 1 << 3;
 constexpr uint32_t F_STRICTENC = 1 << 1;
@@ -191,6 +193,55 @@ struct CoinEnt {
     std::vector<uint8_t> spk;
 };
 
+// The lanes the script templates wrote for inputs of the generic-script
+// leg (P2PK, bare and P2SH CHECKMULTISIG), in input order, in the format of
+// sig_pub .. sig_wrap; `cand` marks the candidate lanes of multisig groups.
+// One table row an input: its number g, its first lane, and m and n of its
+// OP_CHECKMULTISIG (m(n-m+1) lanes), or m = 0 for the one lane of an
+// OP_CHECKSIG.
+struct LegLanes {
+    enum { PUB, RS, MSG, RN, WRAP, CAND, N_BLOBS };
+    std::vector<uint8_t> blob[N_BLOBS];
+    std::vector<uint32_t> table;
+
+    uint32_t lanes() const { return uint32_t(blob[WRAP].size()); }
+
+    void clear() {
+        for (auto& b : blob) b.clear();
+        table.clear();
+    }
+
+    void row(uint32_t g, uint32_t m, uint32_t n) {
+        const uint32_t r[4] = {g, lanes(), m, n};
+        table.insert(table.end(), r, r + 4);
+    }
+
+    void lane(const uint8_t pub64[64], const uint8_t r32[32],
+              const uint8_t s32[32], const uint8_t msg32[32],
+              bool candidate) {
+        blob[PUB].insert(blob[PUB].end(), pub64, pub64 + 64);
+        blob[RS].insert(blob[RS].end(), r32, r32 + 32);
+        blob[RS].insert(blob[RS].end(), s32, s32 + 32);
+        blob[MSG].insert(blob[MSG].end(), msg32, msg32 + 32);
+        // rn = r + N if r + N < P else r, as scan_input writes it
+        uint8_t sum[32];
+        bool wrapped = add_n256(r32, sum) == 0 && cmp256(sum, SECP_P) < 0;
+        const uint8_t* x = wrapped ? sum : r32;
+        blob[RN].insert(blob[RN].end(), x, x + 32);
+        blob[WRAP].push_back(wrapped ? 1 : 0);
+        blob[CAND].push_back(candidate ? 1 : 0);
+    }
+
+    // a later thread's lanes behind this one's
+    void append(const LegLanes& o) {
+        uint32_t base = lanes();
+        for (int i = 0; i < N_BLOBS; i++)
+            blob[i].insert(blob[i].end(), o.blob[i].begin(), o.blob[i].end());
+        for (size_t i = 0; i < o.table.size(); i++)
+            table.push_back(o.table[i] + (i % 4 == 1 ? base : 0));
+    }
+};
+
 struct Engine {
     std::unordered_map<Key36, CoinEnt, KeyHash> map;
     uint8_t best[32] = {0};
@@ -209,13 +260,17 @@ struct Engine {
     std::vector<uint32_t> spent_spk_off;  // n_inputs + 1
     std::vector<uint8_t> spent_spk;
     // sig-scan export, one slot per non-coinbase input
-    std::vector<uint8_t> sig_status;  // 0 = fast record, 1 = python fallback
+    // 0 = fast P2PKH record in this input's slot, 1 = the Python
+    // interpreter decides, 2 = a script template wrote the input's lanes
+    // into `leg`
+    std::vector<uint8_t> sig_status;
     std::vector<uint8_t> sig_msg;     // n * 32
     std::vector<uint8_t> sig_rs;      // n * 64
     std::vector<uint8_t> sig_pub;     // n * 64
     std::vector<uint8_t> sig_rn;      // n * 32
     std::vector<uint8_t> sig_wrap;    // n
     std::vector<uint32_t> sig_txin;   // n * 2 (tx index, input index)
+    LegLanes leg;                     // the template inputs' lanes
 
     long err_code = 0;
     long err_tx = -1;
@@ -574,12 +629,185 @@ static void sighash_forkid(const PTx& tx, const TxMidstates& m,
     bcpn::sha256(mid, 32, out);
 }
 
+// ---------------------------------------------------------------------------
+// Script templates of the generic-script leg: P2PK, bare and P2SH
+// OP_CHECKMULTISIG. The specification is script/interpreter.py:
+// DeferringSignatureChecker.check_sig and defer_multisig under VerifyScript.
+// A template writes the lanes the Python leg would have written for an input
+// it can prove the interpreter would pass speculatively, or declines and
+// leaves the input to the interpreter: it never gives a verdict. Where the
+// flags would let the interpreter defer more than the scan models (a hybrid
+// key or an undefined hashtype without STRICTENC, loose DER without DERSIG,
+// a non-null dummy without NULLDUMMY, a non-minimal push), it declines.
+// ---------------------------------------------------------------------------
+
+constexpr uint8_t OP_PUSHDATA1 = 0x4C;
+constexpr uint8_t OP_PUSHDATA2 = 0x4D;
+constexpr uint8_t OP_1 = 0x51;
+constexpr uint8_t OP_16 = 0x60;
+constexpr uint8_t OP_EQUAL = 0x87;
+constexpr uint8_t OP_HASH160 = 0xA9;
+constexpr uint8_t OP_CHECKSIG = 0xAC;
+constexpr uint8_t OP_CHECKMULTISIG = 0xAE;
+constexpr uint32_t MAX_SCRIPT_ELEMENT_SIZE = 520;
+constexpr int MAX_TEMPLATE_KEYS = 16;  // a count is OP_1 .. OP_16
+
+struct Push {
+    const uint8_t* p;
+    uint32_t len;
+};
+
+// the direct push (opcodes 1..75) at *pos
+static bool direct_push(const uint8_t* sc, uint32_t sc_len, uint32_t* pos,
+                        Push* out) {
+    if (*pos >= sc_len) return false;
+    uint8_t op = sc[*pos];
+    if (op < 1 || op > 75 || *pos + 1 + op > sc_len) return false;
+    *out = Push{sc + *pos + 1, op};
+    *pos += 1 + op;
+    return true;
+}
+
+// A signature a lane can carry: what check_sig / defer_multisig accept
+// under `flags` (non-empty, not Schnorr's 65 bytes, its encoding checks,
+// scalars in 1..N-1), signed over the FORKID digest.
+static bool template_sig(Push sig, uint32_t flags, uint8_t r32[32],
+                         uint8_t s32[32]) {
+    if (sig.len == 65 || !valid_sig_encoding(sig.p, sig.len)) return false;
+    uint32_t len_r = sig.p[3];
+    uint32_t len_s = sig.p[5 + len_r];
+    if (!der_int_to_32(sig.p + 4, len_r, r32) ||
+        !der_int_to_32(sig.p + 6 + len_r, len_s, s32))
+        return false;
+    if (is_zero256(r32) || is_zero256(s32) ||
+        cmp256(r32, SECP_N) >= 0 || cmp256(s32, SECP_N) >= 0)
+        return false;
+    if ((flags & F_LOW_S) && cmp256(s32, SECP_N_HALF) > 0) return false;
+    uint8_t ht = sig.p[sig.len - 1];
+    uint8_t base = ht & uint8_t(~(SIGHASH_ANYONECANPAY | SIGHASH_FORKID));
+    return (ht & SIGHASH_FORKID) && base >= 1 && base <= SIGHASH_SINGLE;
+}
+
+// a key in one of STRICTENC's two forms that is a point of the curve
+static bool template_key(Push key, uint8_t pub64[64]) {
+    bool form = (key.len == 33 && (key.p[0] == 2 || key.p[0] == 3)) ||
+                (key.len == 65 && key.p[0] == 4);
+    return form && bcp_pubkey_parse(key.p, long(key.len), pub64);
+}
+
+// OP_m <key>*n OP_n OP_CHECKMULTISIG, 1 <= m <= n, and nothing else
+static bool multisig_template(const uint8_t* sc, uint32_t sc_len,
+                              uint32_t* m, uint32_t* n, Push* keys) {
+    if (sc_len < 3 || sc[0] < OP_1 || sc[0] > OP_16) return false;
+    *m = sc[0] - (OP_1 - 1);
+    uint32_t pos = 1, k = 0;
+    while (k < MAX_TEMPLATE_KEYS && direct_push(sc, sc_len, &pos, &keys[k]))
+        k++;
+    if (pos + 2 != sc_len || sc[pos] != OP_1 - 1 + k ||
+        sc[pos + 1] != OP_CHECKMULTISIG)
+        return false;
+    *n = k;
+    return k >= 1 && *m <= k;
+}
+
+// The lanes of one input that fits a template, appended to `out` with its
+// table row; false (and nothing written) where no template fits.
+static bool scan_templates(const Engine& e, const PTx& tx,
+                           const TxMidstates& mid, uint32_t in_idx,
+                           uint32_t g, uint32_t flags, const uint8_t* spk,
+                           uint32_t spk_len, LegLanes& out) {
+    if (!(flags & F_NULLFAIL) || !(flags & F_FORKID)) return false;
+    const PIn& in = tx.vin[in_idx];
+    int64_t amount = e.spent_values[g];
+    uint8_t r32[MAX_TEMPLATE_KEYS][32], s32[MAX_TEMPLATE_KEYS][32];
+    uint8_t msg[32], pub[MAX_TEMPLATE_KEYS][64];
+    uint32_t pos = 0;
+    Push sig;
+
+    // P2PK: <sig> | <key> OP_CHECKSIG
+    if ((spk_len == 35 || spk_len == 67) && spk[0] == spk_len - 2 &&
+        spk[spk_len - 1] == OP_CHECKSIG) {
+        if (!direct_push(in.ss, in.ss_len, &pos, &sig) || pos != in.ss_len ||
+            !template_sig(sig, flags, r32[0], s32[0]) ||
+            !template_key(Push{spk + 1, spk_len - 2}, pub[0]))
+            return false;
+        sighash_forkid(tx, mid, in_idx, sig.p[sig.len - 1], spk, spk_len,
+                       amount, msg);
+        out.row(g, 0, 0);
+        out.lane(pub[0], r32[0], s32[0], msg, false);
+        return true;
+    }
+
+    // OP_0 <sig>*m | the bare script, or OP_0 <sig>*m <redeem> |
+    // OP_HASH160 <20> OP_EQUAL with the bare script as the redeem script
+    if (in.ss_len < 1 || in.ss[0] != 0) return false;
+    pos = 1;
+    Push sigs[MAX_TEMPLATE_KEYS], keys[MAX_TEMPLATE_KEYS];
+    uint32_t n_sigs = 0, m, n;
+    while (n_sigs < MAX_TEMPLATE_KEYS &&
+           direct_push(in.ss, in.ss_len, &pos, &sigs[n_sigs]))
+        n_sigs++;
+    const uint8_t* code = spk;
+    uint32_t code_len = spk_len;
+    if (spk_len == 23 && spk[0] == OP_HASH160 && spk[1] == 20 &&
+        spk[22] == OP_EQUAL) {
+        if (!(flags & F_P2SH)) return false;
+        // the redeem script is the last push, in its minimal form
+        if (pos == in.ss_len) {
+            if (n_sigs < 2) return false;
+            n_sigs--;
+            code = sigs[n_sigs].p;
+            code_len = sigs[n_sigs].len;
+        } else {
+            uint32_t left = in.ss_len - pos, head;
+            if (in.ss[pos] == OP_PUSHDATA1 && left >= 2) {
+                head = 2;
+                code_len = in.ss[pos + 1];
+                if (code_len <= 75) return false;
+            } else if (in.ss[pos] == OP_PUSHDATA2 && left >= 3) {
+                head = 3;
+                code_len = in.ss[pos + 1] | uint32_t(in.ss[pos + 2]) << 8;
+                if (code_len <= 255 || code_len > MAX_SCRIPT_ELEMENT_SIZE)
+                    return false;
+            } else {
+                return false;
+            }
+            if (left != head + code_len) return false;
+            code = in.ss + pos + head;
+        }
+        uint8_t h20[20];
+        bcpn::hash160(code, code_len, h20);
+        if (memcmp(h20, spk + 2, 20) != 0) return false;
+    } else if (pos != in.ss_len) {
+        return false;
+    }
+    if (!multisig_template(code, code_len, &m, &n, keys) || n_sigs != m)
+        return false;
+    // sigs and keys in the order EvalScript hands them to defer_multisig:
+    // from the top of the stack down, so the walk starts at the last key
+    for (uint32_t i = 0; i < m; i++)
+        if (!template_sig(sigs[m - 1 - i], flags, r32[i], s32[i]))
+            return false;
+    for (uint32_t j = 0; j < n; j++)
+        if (!template_key(keys[n - 1 - j], pub[j])) return false;
+    out.row(g, m, n);
+    for (uint32_t i = 0; i < m; i++) {
+        const Push& s = sigs[m - 1 - i];
+        sighash_forkid(tx, mid, in_idx, s.p[s.len - 1], code, code_len,
+                       amount, msg);
+        for (uint32_t j = i; j <= i + n - m; j++)
+            out.lane(pub[j], r32[i], s32[i], msg, true);
+    }
+    return true;
+}
+
 // One input's fast-path scan. Returns OK and fills the record slot, a
 // script error code (block-fatal), or sets *fallback for the Python
 // interpreter. Mirrors scriptcheck._p2pkh_fast_verify +
 // DeferringSignatureChecker.check_sig exactly.
 static long scan_input(Engine& e, const PTx& tx, const TxMidstates& m,
-                       uint32_t in_idx, uint32_t g, uint32_t flags) {
+                       uint32_t in_idx, uint32_t g, uint32_t flags,
+                       LegLanes& leg) {
     const PIn& in = tx.vin[in_idx];
     const uint8_t* spk = e.spent_spk.data() + e.spent_spk_off[g];
     uint32_t spk_len = e.spent_spk_off[g + 1] - e.spent_spk_off[g];
@@ -587,7 +815,10 @@ static long scan_input(Engine& e, const PTx& tx, const TxMidstates& m,
     uint32_t sig_len, pub_len;
     if (!p2pkh_template(in.ss, in.ss_len, spk, spk_len,
                         &sig, &sig_len, &pub, &pub_len)) {
-        e.sig_status[g] = 1;  // generic interpreter (Python) handles it
+        // another template's lanes, or the generic interpreter (Python)
+        e.sig_status[g] =
+            scan_templates(e, tx, m, in_idx, g, flags, spk, spk_len, leg)
+                ? 2 : 1;
         return OK;
     }
     // DUP HASH160 <h20> EQUALVERIFY collapse
@@ -855,6 +1086,19 @@ const uint32_t* bcp_engine_sig_txin(void* ep) {
     return static_cast<Engine*>(ep)->sig_txin.data();
 }
 
+// The template lanes of the last connect (LegLanes): blob `which` in the
+// order pub, rs, msg, rn, wrap, cand, then 6 = the table's uint32 rows; its
+// length in bytes.
+const uint8_t* bcp_engine_leg_blob(void* ep, int which, size_t* len) {
+    LegLanes& leg = static_cast<Engine*>(ep)->leg;
+    if (which == LegLanes::N_BLOBS) {
+        *len = leg.table.size() * sizeof(uint32_t);
+        return reinterpret_cast<const uint8_t*>(leg.table.data());
+    }
+    *len = leg.blob[which].size();
+    return leg.blob[which].data();
+}
+
 // The connect itself. See the ABI sketch in native.py for argument docs.
 long bcp_engine_connect_block(
     void* ep, const uint8_t* raw, size_t raw_len,
@@ -1118,6 +1362,7 @@ long bcp_engine_connect_block(
         e.sig_rn.resize(size_t(n_inputs) * 32);
         e.sig_wrap.assign(size_t(n_inputs), 0);
         e.sig_txin.resize(size_t(n_inputs) * 2);
+        e.leg.clear();
         unsigned hw = nthreads > 0 ? unsigned(nthreads)
                                    : std::thread::hardware_concurrency();
         if (hw == 0) hw = 1;
@@ -1125,7 +1370,7 @@ long bcp_engine_connect_block(
         // first error by (tx, input) order wins, deterministically
         std::atomic<long> first_err_pos{-1};
         std::vector<long> err_codes(size_t(n_inputs), 0);
-        auto work = [&](long t_begin, long t_end) {
+        auto work = [&](long t_begin, long t_end, LegLanes& leg) {
             TxMidstates m;
             for (long i = t_begin; i < t_end; i++) {
                 PTx& tx = txs[i];
@@ -1138,7 +1383,8 @@ long bcp_engine_connect_block(
                         compute_midstates(tx, m);
                         have_mid = true;
                     }
-                    long rc = scan_input(e, tx, m, vi, gg, script_flags);
+                    long rc = scan_input(e, tx, m, vi, gg, script_flags,
+                                         leg);
                     if (rc != OK) {
                         err_codes[gg] = rc;
                         long cur = first_err_pos.load();
@@ -1150,7 +1396,7 @@ long bcp_engine_connect_block(
             }
         };
         if (nt <= 1) {
-            work(1, n_tx);
+            work(1, n_tx, e.leg);
         } else {
             // static partition by input count for balance
             std::vector<std::thread> th;
@@ -1166,9 +1412,13 @@ long bcp_engine_connect_block(
                 }
             }
             bounds.push_back(n_tx);
+            // each thread's template lanes, joined in input order
+            std::vector<LegLanes> legs(bounds.size() - 1);
             for (size_t t = 0; t + 1 < bounds.size(); t++)
-                th.emplace_back(work, bounds[t], bounds[t + 1]);
+                th.emplace_back(work, bounds[t], bounds[t + 1],
+                                std::ref(legs[t]));
             for (auto& t : th) t.join();
+            for (const LegLanes& leg : legs) e.leg.append(leg);
         }
         e.sigscan_ns = uint64_t(
             std::chrono::duration_cast<std::chrono::nanoseconds>(
@@ -1182,6 +1432,7 @@ long bcp_engine_connect_block(
     } else {
         e.sig_status.assign(size_t(n_inputs), 1);
         e.sig_txin.resize(size_t(n_inputs) * 2);
+        e.leg.clear();
         g = 0;
         for (long i = 1; i < n_tx; i++)
             for (uint32_t vi = 0; vi < txs[i].vin.size(); vi++, g++) {

@@ -11,12 +11,14 @@ import itertools
 import json
 import os
 import shutil
+import struct
 import subprocess
 import sys
 
 import numpy as np
 import pytest
 
+from bitcoincashplus_tpu import native
 from bitcoincashplus_tpu.consensus.tx import (
     COutPoint,
     CTransaction,
@@ -318,6 +320,242 @@ def test_two_operations_in_one_script_are_two_groups():
     assert deferred(script_sig, spk) == ("OK", 2, 0, 0)
 
 
+# -- the native scan's templates against the Python leg ------------------------
+# native/connect.cpp scan_templates: an input that fits P2PK, bare or P2SH
+# CHECKMULTISIG gets the lanes the Python leg would have written, byte for
+# byte; anything else is declined (sig_status 1, no lane) and the interpreter
+# decides it as before.
+
+needs_engine = pytest.mark.skipif(
+    not native.engine_available(), reason="native connect engine unavailable")
+
+
+def native_scan(script_sig: bytes, spk: bytes, flags: int = FLAGS) -> tuple:
+    """One input through the native scan: a block of a coinbase and the
+    spend, the spent coin put into the engine by hand. Returns (sig_status
+    of the input, the six lane arrays, the table's rows)."""
+    spend = _with_script_sig(script_sig)
+    coinbase = CTransaction(
+        1, (CTxIn(COutPoint(), b"\x01\x01", 0xFFFFFFFF),),
+        (CTxOut(50 * 10**8, b"\x51"),))
+    raw = bytes(80) + b"\x02" + coinbase.serialize() + spend.serialize()
+    prevout = spend.vin[0].prevout
+    eng = native.ConnectEngine()
+    try:
+        eng.insert(prevout.hash + struct.pack("<I", prevout.n), 2, AMOUNT,
+                   spk)
+        res = eng.connect_block(raw, 5, 50 * 10**8, 32_000_000, 100, 0, None,
+                                flags, want_sigs=True, check_merkle=False,
+                                commit=False)
+    finally:
+        eng.close()
+    return int(res.sig_status[0]), res.leg_lanes, res.leg_table.tolist()
+
+
+def python_leg(script_sig: bytes, spk: bytes, flags: int = FLAGS) -> tuple:
+    """What node.py's script leg makes of the input through the
+    interpreter: (the six lane arrays, the table's rows)."""
+    tx = _with_script_sig(script_sig)
+    records, groups = [], []
+    VerifyScript(script_sig, spk, flags, DeferringSignatureChecker(
+        tx, 0, AMOUNT, records, groups=groups))
+    cand = np.zeros(len(records), np.uint8)
+    for g in groups:
+        cand[g.start:g.start + g.lanes] = 1
+    rows = [[0, g.start, g.m, g.n] for g in groups] or [[0, 0, 0, 0]]
+    return (*ecdsa_batch.records_to_blobs(records), cand), rows
+
+
+def assert_lanes_equal(got: tuple, want: tuple) -> None:
+    for name, a, b in zip(("pub", "rs", "msg", "rn", "wrap", "cand"),
+                          got[0], want[0]):
+        assert a.dtype == b.dtype and a.tobytes() == b.tobytes(), name
+    assert got[1] == want[1]
+
+
+def _p2pk_spend(key: CKey, sig: bytes = None) -> tuple:
+    spk = S.p2pk_script(key.pubkey)
+    return S.push_data_raw(_sign(key, spk) if sig is None else sig), spk
+
+
+def settled_verdict(script_sig: bytes, spk: bytes, lanes: tuple,
+                    rows: list) -> str:
+    """Where the native lanes end, as the import settles them: the batch's
+    verdicts (the CPU's here), each group's walk, and the eager checker on
+    the host where a walk or a must-verify lane fails."""
+    *blobs, cand = lanes
+    ok = ecdsa_batch.dispatch_packed(
+        *blobs, backend="cpu", candidate=cand.astype(bool)).result()
+    walks = all(multisig_walk(m, n, ok[first:first + m * (n - m + 1)])
+                for _, first, m, n in rows if m)
+    if walks and bool(np.all(ok | cand.astype(bool))):
+        return "OK"
+    try:
+        VerifyScript(script_sig, spk, FLAGS, TransactionSignatureChecker(
+            _with_script_sig(script_sig), 0, AMOUNT))
+    except ScriptError as e:
+        return e.code
+    return "OK"
+
+
+def _fitting_cases() -> dict:
+    """name -> (spend, the verdict it ends with)."""
+    cases = {}
+    for m, n, subset in SUBSETS:
+        for p2sh in (True, False):
+            name = f"{'p2sh' if p2sh else 'bare'}-{m}of{n}-" + "".join(
+                map(str, subset))
+            cases[name] = (multisig_spend(
+                m, [k.pubkey for k in KEYS[:n]], [KEYS[i] for i in subset],
+                p2sh=p2sh), "OK")
+    cases["p2pk-33-byte-key"] = (_p2pk_spend(KEYS[0]), "OK")
+    cases["p2pk-65-byte-key"] = (_p2pk_spend(LONG_KEY), "OK")
+    cases["p2pk-signed-by-another-key"] = (_p2pk_spend(
+        KEYS[0], _sign(OUTSIDER, S.p2pk_script(KEYS[0].pubkey))),
+        "sig-nullfail")
+    cases["bare-16of16"] = (multisig_spend(
+        16, [k.pubkey for k in KEYS[:16]], KEYS[:16], p2sh=False), "OK")
+    cases["p2sh-1of15"] = (multisig_spend(
+        1, [k.pubkey for k in KEYS[:15]], [KEYS[9]]), "OK")
+    # what defer_multisig takes, whatever the walk will say of it
+    for name, (spend, want, groups, _, _) in EDGE_CASES.items():
+        if groups == 1 and want != "sig-nulldummy":
+            cases[name] = (spend, want)
+    return cases
+
+
+FITTING = _fitting_cases()
+
+
+@needs_engine
+@pytest.mark.parametrize("name", sorted(FITTING))
+def test_native_template_writes_the_python_legs_lanes(name):
+    (script_sig, spk), want = FITTING[name]
+    status, lanes, rows = native_scan(script_sig, spk)
+    assert status == 2
+    assert_lanes_equal((lanes, rows), python_leg(script_sig, spk))
+    m, n = rows[0][2:]
+    assert len(lanes[4]) == (m * (n - m + 1) if m else 1)
+    # a template emits, it gives no verdict: that is the batch's, the
+    # walk's and the host's, and it is the one of today
+    assert settled_verdict(script_sig, spk, lanes, rows) == want
+    assert deferred(script_sig, spk)[0] == want
+
+
+def _raw_multisig(m_op: bytes, pubkeys: list, n_op: bytes) -> bytes:
+    return (m_op + b"".join(S.push_data_raw(k) for k in pubkeys) + n_op
+            + bytes([S.OP_CHECKMULTISIG]))
+
+
+def _declined_cases() -> dict:
+    """name -> (spend, the verdict the interpreter gives it today)."""
+    cases = {name: (spend, want)
+             for name, (spend, want, groups, _, _) in EDGE_CASES.items()
+             if groups == 0 or want == "sig-nulldummy"}
+    pubkeys = [k.pubkey for k in KEYS[:3]]
+    good_ss, good_spk = _two_of_three([KEYS[0], KEYS[2]])
+    cases["wrong_redeem_hash"] = (
+        (good_ss, S.p2sh_script(bytes(20))), "eval-false")
+    # FLAGS has no MINIMALDATA: the interpreter takes these pushes, the
+    # template names direct pushes only
+    sigs = [_sign(KEYS[0], CODE_2OF3), _sign(KEYS[2], CODE_2OF3)]
+    pushdata1 = b"".join(bytes([S.OP_PUSHDATA1, len(x)]) + x for x in sigs)
+    cases["signatures_pushed_with_pushdata1"] = (
+        (b"\x00" + pushdata1 + S.push_data_raw(CODE_2OF3), good_spk), "OK")
+    code = (b"\x52" + b"".join(bytes([S.OP_PUSHDATA1, len(k)]) + k
+                               for k in pubkeys)
+            + b"\x53" + bytes([S.OP_CHECKMULTISIG]))
+    cases["keys_pushed_with_pushdata1"] = (multisig_spend(
+        2, pubkeys, None, p2sh=False,
+        sigs=[_sign(KEYS[0], code), _sign(KEYS[2], code)])[:1] + (code,),
+        "OK")
+    cases["redeem_script_pushed_with_pushdata2"] = (
+        (b"\x00" + b"".join(S.push_data_raw(x) for x in sigs)
+         + bytes([S.OP_PUSHDATA2]) + struct.pack("<H", len(CODE_2OF3))
+         + CODE_2OF3, good_spk), "OK")
+    code = _raw_multisig(b"\x53", pubkeys[:2], b"\x52")  # 3-of-2
+    cases["m_above_n"] = ((b"\x00" + b"".join(
+        S.push_data_raw(_sign(k, code)) for k in KEYS[:3]), code),
+        "sig-count")
+    cases["one_of_twenty"] = (multisig_spend(
+        1, [k.pubkey for k in KEYS], [KEYS[7]], p2sh=False), "OK")
+    code = _raw_multisig(b"\x01\x01", pubkeys[:2], b"\x01\x02")
+    cases["counts_pushed_as_bytes"] = (
+        (b"\x00" + S.push_data_raw(_sign(KEYS[1], code)), code), "OK")
+    cases["a_signature_short"] = (
+        (b"\x00" + S.push_data_raw(sigs[0]) + S.push_data_raw(CODE_2OF3),
+         good_spk), "invalid-stack-operation")
+    cases["a_signature_too_many"] = (
+        (b"\x00" + b"".join(S.push_data_raw(x) for x in sigs[:1] + sigs),
+         CODE_2OF3), "sig-nulldummy")  # the first one is where the dummy is
+    cases["a_push_below_the_dummy"] = (  # no CLEANSTACK in a block's flags
+        (b"\x00\x00" + b"".join(S.push_data_raw(x) for x in sigs),
+         CODE_2OF3), "OK")
+    code = CODE_2OF3 + bytes([S.OP_NOP])
+    cases["an_opcode_after_checkmultisig"] = ((b"\x00" + b"".join(
+        S.push_data_raw(_sign(k, code)) for k in (KEYS[0], KEYS[2])), code),
+        "OK")
+    code = bytes([S.OP_CODESEPARATOR]) + CODE_2OF3
+    cases["codeseparator_in_the_redeem_script"] = ((b"\x00" + b"".join(
+        S.push_data_raw(_sign(k, CODE_2OF3)) for k in (KEYS[0], KEYS[2]))
+        + S.push_data_raw(code), S.p2sh_script_for_redeem(code)), "OK")
+    code = S.p2pk_script(KEYS[0].pubkey)
+    cases["p2sh_of_pay_to_pubkey"] = (
+        (S.push_data_raw(_sign(KEYS[0], code)) + S.push_data_raw(code),
+         S.p2sh_script_for_redeem(code)), "OK")
+    code = b"\x51" + bytes([S.OP_VERIFY]) + S.p2pk_script(KEYS[0].pubkey)
+    cases["verify_before_checksig"] = (
+        (S.push_data_raw(_sign(KEYS[0], code)), code), "OK")
+    spk = S.p2pk_script(KEYS[0].pubkey)
+    cases["p2pk_schnorr_sized_signature"] = (
+        _p2pk_spend(KEYS[0], b"\x01" * 64 + b"\x41"), "sig-nullfail")
+    cases["p2pk_empty_signature"] = ((b"\x00", spk), "eval-false")
+    cases["p2pk_non_forkid_hashtype"] = (
+        _p2pk_spend(KEYS[0], _sign(KEYS[0], spk, forkid=False)),
+        "must-use-forkid")
+    cases["p2pk_high_s_signature"] = (
+        _p2pk_spend(KEYS[0], _high_s(_sign(KEYS[0], spk))), "sig-high-s")
+    cases["p2pk_a_push_below_the_signature"] = (
+        (b"\x51" + S.push_data_raw(_sign(KEYS[0], spk)), spk), "OK")
+    off_curve = b"\x02" + (5).to_bytes(32, "big")
+    cases["p2pk_key_off_the_curve"] = (
+        (S.push_data_raw(_sign(KEYS[0], S.p2pk_script(off_curve))),
+         S.p2pk_script(off_curve)), "sig-nullfail")
+    hybrid = b"\x06" + LONG_KEY.pubkey[1:]
+    cases["p2pk_hybrid_key"] = (
+        (S.push_data_raw(_sign(LONG_KEY, S.p2pk_script(hybrid))),
+         S.p2pk_script(hybrid)), "pubkeytype")
+    return cases
+
+
+DECLINED = _declined_cases()
+
+
+@needs_engine
+@pytest.mark.parametrize("name", sorted(DECLINED))
+def test_native_template_declines_and_the_interpreter_decides(name):
+    (script_sig, spk), want = DECLINED[name]
+    status, lanes, rows = native_scan(script_sig, spk)
+    assert status == 1 and rows == []
+    assert [len(a) for a in lanes] == [0] * 6
+    # the verdict and error code of today, on both of the leg's paths
+    assert eager(script_sig, spk)[0] == want
+    assert deferred(script_sig, spk)[0] == want
+
+
+@needs_engine
+@pytest.mark.parametrize("without", [
+    SCRIPT_VERIFY_NULLFAIL, SCRIPT_ENABLE_SIGHASH_FORKID, SCRIPT_VERIFY_P2SH],
+    ids=["nullfail", "forkid", "p2sh"])
+def test_native_templates_need_the_flags_that_let_the_leg_defer(without):
+    """Without NULLFAIL nothing may defer; without FORKID the digest is not
+    the one the scan computes; without P2SH a redeem script is not run."""
+    script_sig, spk = _two_of_three([KEYS[0], KEYS[2]])
+    assert native_scan(script_sig, spk)[0] == 2
+    status, lanes, rows = native_scan(script_sig, spk, FLAGS & ~without)
+    assert status == 1 and rows == [] and len(lanes[4]) == 0
+
+
 # -- the walk -----------------------------------------------------------------
 
 def _upstream_walk(m: int, n: int, matches) -> tuple:
@@ -485,11 +723,12 @@ def test_interpreter_and_plain_reference_agree(kind, sound):
 LANES = 2600  # one 2,046-lane slice and a tail: some group straddles them
 
 
-def _generate(datadir, *extra) -> dict:
+def _generate(datadir, *extra, seed: int = 2**31 + 2800,
+              lanes: int = LANES) -> dict:
     out = subprocess.run(
         [sys.executable, os.path.join(BENCH, "gen", "mixedchain.py"),
-         "--datadir", str(datadir), "--seed", str(2**31 + 2800), "--lanes",
-         str(LANES), "--traffic",
+         "--datadir", str(datadir), "--seed", str(seed), "--lanes",
+         str(lanes), "--traffic",
          os.path.join(BENCH, "traffic", "mixed_era.json"), "--rehearse",
          "--workers", "2", *extra],
         check=True, capture_output=True, text=True,
@@ -571,6 +810,135 @@ def test_generated_chain_reindexes_to_the_references_answer(
     # 4 lanes a 2-of-3, 2 a 1-of-2, whoever signed
     assert gen["multisig_lanes"] == (4 * gen["inputs_by_kind"]["p2sh_multisig"]
                                      + 2 * gen["inputs_by_kind"]["bare_multisig"])
+
+
+def _blocks_of(chain_dir) -> list:
+    """The raw blocks of the chain's block files, genesis left out."""
+    import glob
+
+    blocks = []
+    for path in sorted(glob.glob(os.path.join(
+            chain_dir, "regtest", "blocks", "blk?????.dat"))):
+        with open(path, "rb") as f:
+            data = f.read()
+        pos = 0
+        while pos + 8 <= len(data) and data[pos:pos + 4] == data[:4]:
+            (size,) = struct.unpack_from("<I", data, pos + 4)
+            blocks.append(data[pos + 8:pos + 8 + size])
+            pos += 8 + size
+    return blocks[1:]
+
+
+def _leg_differential(chain_dir) -> dict:
+    """Every block of a generated chain through the native engine, and
+    every input its P2PKH scan did not take through the interpreter as
+    node.py's script leg ran it before the templates: the native lanes,
+    candidate mask and table equal the records' and groups', byte for byte
+    and in order. Returns what it counted."""
+    from bitcoincashplus_tpu.consensus.params import (
+        get_block_subsidy,
+        regtest_params,
+    )
+    from bitcoincashplus_tpu.script.sighash import SighashCache
+    from bitcoincashplus_tpu.validation.scriptcheck import block_script_flags
+
+    params = regtest_params()
+    eng = native.ConnectEngine()
+    eng.set_best(params.genesis.get_hash())
+    times = [params.genesis.header.time]
+    seen = {"template_inputs": 0, "lanes": 0, "groups": 0, "threaded": 0}
+    try:
+        for height, raw in enumerate(_blocks_of(chain_dir), start=1):
+            (when,) = struct.unpack_from("<I", raw, 68)
+            flags = block_script_flags(height, when, params)
+            res = eng.connect_block(
+                raw, height, get_block_subsidy(height, params.consensus),
+                params.max_block_size, params.consensus.coinbase_maturity,
+                sorted(times[-11:])[len(times[-11:]) // 2], None, flags,
+                want_sigs=True, nthreads=4)
+            times.append(when)
+            status = (res.sig_status if res.n_inputs
+                      else np.zeros(0, np.uint8))  # a coinbase-only block
+            assert not (status == 1).any()
+            leg_idx = np.nonzero(status == 2)[0]
+            records, groups, rows, txs = [], [], [], {}
+            for g in leg_idx:
+                t_i, in_i = (int(v) for v in res.sig_txin[g])
+                if t_i not in txs:
+                    tx = CTransaction.from_bytes(raw[
+                        int(res.tx_offsets[t_i, 0]):
+                        int(res.tx_offsets[t_i, 1])])
+                    txs[t_i] = (tx, SighashCache(tx))
+                tx, cache = txs[t_i]
+                spk = res.spent_spk_blob[int(res.spent_spk_offsets[g]):
+                                         int(res.spent_spk_offsets[g + 1])]
+                first, had = len(records), len(groups)
+                VerifyScript(tx.vin[in_i].script_sig, spk, flags,
+                             DeferringSignatureChecker(
+                                 tx, in_i, int(res.spent_values[g]), records,
+                                 cache, groups))
+                rows.append([int(g), first, *(
+                    (groups[-1].m, groups[-1].n) if len(groups) > had
+                    else (0, 0))])
+            cand = np.zeros(len(records), np.uint8)
+            for grp in groups:
+                cand[grp.start:grp.start + grp.lanes] = 1
+            assert_lanes_equal(
+                (res.leg_lanes, res.leg_table.tolist()),
+                ((*ecdsa_batch.records_to_blobs(records), cand), rows))
+            seen["template_inputs"] += len(rows)
+            seen["lanes"] += len(records)
+            seen["groups"] += len(groups)
+            seen["threaded"] += bool(res.n_inputs >= 64 and len(rows))
+    finally:
+        eng.close()
+    return seen
+
+
+@needs_engine
+@pytest.mark.parametrize("seed", [2**31 + 2800, 2**31 + 3111, 31, 987654321])
+def test_native_leg_equals_the_python_leg_over_a_generated_chain(
+        seed, sound_chain, tmp_path):
+    """A seeded campaign of mixed blocks in the generator's shapes (P2SH
+    2-of-3, P2PK, bare 1-of-2 among P2PKH, 1 to 250 inputs a transaction):
+    no input is left to the interpreter, and some blocks are wide enough
+    for the scan's threads, whose lanes are joined in input order."""
+    if seed == 2**31 + 2800:
+        chain_dir, gen = sound_chain
+    else:
+        chain_dir, gen = tmp_path, _generate(tmp_path, seed=seed, lanes=1300)
+    seen = _leg_differential(chain_dir)
+    assert seen["template_inputs"] == gen["non_p2pkh_inputs"]
+    assert seen["groups"] == gen["multisig_groups"]
+    assert seen["lanes"] == (gen["multisig_lanes"]
+                             + gen["inputs_by_kind"]["p2pk"])
+    assert seen["threaded"] >= 1
+
+
+def test_reindex_settles_every_template_input_in_the_native_scan(
+        sound_chain, tmp_path):
+    """The leg's counters over the generated chain: every input the P2PKH
+    scan did not take fits a native template, none reaches VerifyScript,
+    and the chain is the reference's."""
+    chain_dir, gen = sound_chain
+    node, moved = _reindex(chain_dir, tmp_path)
+    try:
+        stats = node.last_import_stats
+        tip = _tip(node)
+    finally:
+        node.close()
+    ref = reference_mixed.scan_chain(
+        os.path.join(chain_dir, "regtest", "blocks"), 28, 6)
+    assert tip == (ref["height"], ref["tip_hash"], ref["utxos"])
+    assert stats["fallback_inputs"] == (stats["template_inputs"]
+                                        + stats["interp_inputs"])
+    assert stats["template_inputs"] == gen["non_p2pkh_inputs"]
+    assert stats["interp_inputs"] == 0
+    assert stats["multisig_lanes"] == gen["multisig_lanes"]
+    assert stats["slow_path_blocks"] == 0
+    assert (moved["multisig_groups"], moved["multisig_lanes"]) == (
+        gen["multisig_groups"], gen["multisig_lanes"])
+    assert moved["eager_multisig_sigs"] == 0
 
 
 class _Flipping:
@@ -740,3 +1108,24 @@ def test_schnorr_record_takes_no_ecdsa_lane():
     with pytest.raises(ValueError, match="ECDSA"):
         ecdsa_batch.records_to_blobs(
             [good, SigCheckRecord(pt, 5, 7, 11, algo="schnorr")])
+
+
+@pytest.mark.parametrize("stats,want", [
+    ({"fallback_inputs": 8, "template_inputs": 6, "interp_inputs": 2}, 75.0),
+    ({"fallback_inputs": 8, "template_inputs": 8, "interp_inputs": 0}, 100.0),
+    ({"fallback_inputs": 8}, None),               # a program before PR 31
+    ({"fallback_inputs": 0, "template_inputs": 0}, None),  # no leg at all
+    (None, None),                                 # the import aborted
+], ids=["some", "all", "no-counter", "no-leg", "no-stopwatch"])
+def test_template_share_reader_reads_the_legs_counters(stats, want):
+    """chipbench/layer_metrics/script_leg.template_share.py: the share of
+    the leg's inputs a native template settled; nothing (and no raise)
+    where the program has no such counter."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "template_share", os.path.join(
+            BENCH, "layer_metrics", "script_leg.template_share.py"))
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    assert reader.read({"after": {"import": stats}}) == want

@@ -617,81 +617,160 @@ def test_fast_import_falls_back_on_invalid_block(tmp_path):
         node.close()
 
 
+class _DiskChain:
+    """A regtest chain written to a datadir's block files through the
+    Python engine with no script verifier (it stores whatever spends it is
+    given), for Node(-reindex) to import: 102 coinbase blocks to start."""
+
+    def __init__(self, tmp_path):
+        from bitcoincashplus_tpu.store.blockstore import BlockStore
+        from bitcoincashplus_tpu.store.chainstatedb import (
+            BlockIndexDB,
+            CoinsDB,
+        )
+        from bitcoincashplus_tpu.store.kvstore import KVStore
+
+        self.datadir = str(tmp_path)
+        net_dir = os.path.join(tmp_path, "regtest")
+        blocks_dir = os.path.join(net_dir, "blocks")
+        os.makedirs(blocks_dir, exist_ok=True)
+        self.index_kv = KVStore(os.path.join(blocks_dir, "index.sqlite"))
+        self.coins_kv = KVStore(os.path.join(net_dir, "chainstate.sqlite"))
+        self.store = BlockStore(net_dir, PARAMS.netmagic)
+        self.cs = ChainstateManager(
+            PARAMS, CoinsDB(self.coins_kv), self.store, script_verifier=None,
+            index_db=BlockIndexDB(self.index_kv))
+        self.t = PARAMS.genesis.header.time
+        self.coinbases = [self.push().vtx[0] for _ in range(102)]
+
+    def push(self, txs=()):
+        self.t += 60
+        tip = self.cs.tip()
+        blk = _block(tip.hash, tip.height + 1, self.t, txs)
+        self.cs.process_new_block(blk)
+        return blk
+
+    def fund(self, i: int, spks) -> CTransaction:
+        """Coinbase ``i`` paid out to ``spks`` in equal shares."""
+        value = self.coinbases[i].vout[0].value
+        each = (value - 10_000) // len(spks)
+        unsigned = CTransaction(
+            1, (CTxIn(COutPoint(self.coinbases[i].txid, 0), b"",
+                      0xFFFFFFFE),),
+            tuple(CTxOut(each, spk) for spk in spks))
+        return sign_transaction(unsigned, [(SPK, value)], _key_for,
+                                enable_forkid=True)
+
+    def reindex(self):
+        """Close the stores and import the block files with a new Node."""
+        from bitcoincashplus_tpu.node.config import Config
+        from bitcoincashplus_tpu.node.node import Node
+
+        self.cs.flush()
+        self.store.close()
+        self.index_kv.close()
+        self.coins_kv.close()
+        cfg = Config()
+        cfg.args["datadir"] = [self.datadir]
+        cfg.args["regtest"] = ["1"]
+        cfg.args["reindex"] = ["1"]
+        return Node(config=cfg)
+
+
 def test_fast_import_sends_a_schnorr_signature_to_the_python_engine(tmp_path):
     """A 65-byte Schnorr signature under a pay-to-pubkey output reaches the
     generic-script leg of the native import (the P2PKH scan never matches
-    it). Its record is no ECDSA lane: the block takes the slow path, where
-    the Python engine verifies it under its own scheme, and the import goes
-    on through the native engine."""
+    it, the P2PK template declines it). Its record is no ECDSA lane: the
+    block takes the slow path, where the Python engine verifies it under
+    its own scheme, and the import goes on through the native engine."""
     from bitcoincashplus_tpu.crypto import secp256k1 as secp
-    from bitcoincashplus_tpu.node.config import Config
-    from bitcoincashplus_tpu.node.node import Node
     from bitcoincashplus_tpu.script.script import p2pk_script, push_data_raw
     from bitcoincashplus_tpu.script.sighash import signature_hash
-    from bitcoincashplus_tpu.store.blockstore import BlockStore
-    from bitcoincashplus_tpu.store.chainstatedb import BlockIndexDB, CoinsDB
-    from bitcoincashplus_tpu.store.kvstore import KVStore
 
-    net_dir = os.path.join(tmp_path, "regtest")
-    blocks_dir = os.path.join(net_dir, "blocks")
-    os.makedirs(blocks_dir, exist_ok=True)
-    index_kv = KVStore(os.path.join(blocks_dir, "index.sqlite"))
-    coins_kv = KVStore(os.path.join(net_dir, "chainstate.sqlite"))
-    store = BlockStore(net_dir, PARAMS.netmagic)
-    cs = ChainstateManager(PARAMS, CoinsDB(coins_kv), store,
-                           script_verifier=None,
-                           index_db=BlockIndexDB(index_kv))
-    t = PARAMS.genesis.header.time
-
-    def push(txs=()):
-        nonlocal t
-        t += 60
-        tip = cs.tip()
-        blk = _block(tip.hash, tip.height + 1, t, txs)
-        cs.process_new_block(blk)
-        return blk
-
-    coinbases = [push().vtx[0] for _ in range(102)]
+    chain = _DiskChain(tmp_path)
     # a pay-to-pubkey output, then its spend under a Schnorr signature
-    value = coinbases[0].vout[0].value
-    fund = _spend([COutPoint(coinbases[0].txid, 0)], [value])
     pk_spk = p2pk_script(KEY.pubkey)
-    fund = sign_transaction(
-        CTransaction(1, tuple(CTxIn(i.prevout, b"", i.sequence)
-                              for i in fund.vin),
-                     (CTxOut(value - 10_000, pk_spk),)),
-        [(SPK, value)], _key_for, enable_forkid=True)
-    push((fund,))
+    fund = chain.fund(0, [pk_spk])
+    chain.push((fund,))
+    value = fund.vout[0].value
     unsigned = CTransaction(
         1, (CTxIn(COutPoint(fund.txid, 0), b"", 0xFFFFFFFE),),
-        (CTxOut(value - 20_000, SPK),))
-    digest = signature_hash(pk_spk, unsigned, 0, 0x41, value - 10_000,
+        (CTxOut(value - 10_000, SPK),))
+    digest = signature_hash(pk_spk, unsigned, 0, 0x41, value,
                             enable_forkid=True)
     r, s = secp.schnorr_sign(KEY.secret, int.from_bytes(digest, "big"))
     sig = r.to_bytes(32, "big") + s.to_bytes(32, "big") + b"\x41"
     spend = CTransaction(
         1, (CTxIn(unsigned.vin[0].prevout, push_data_raw(sig), 0xFFFFFFFE),),
         unsigned.vout)
-    push((spend,))
-    last = push((_spend([COutPoint(coinbases[1].txid, 0)],
-                        [coinbases[1].vout[0].value]),))
-    cs.flush()
-    store.close()
-    index_kv.close()
-    coins_kv.close()
-
-    cfg = Config()
-    cfg.args["datadir"] = [str(tmp_path)]
-    cfg.args["regtest"] = ["1"]
-    cfg.args["reindex"] = ["1"]
-    node = Node(config=cfg)
+    chain.push((spend,))
+    last = chain.push((_spend([COutPoint(chain.coinbases[1].txid, 0)],
+                              [chain.coinbases[1].vout[0].value]),))
+    node = chain.reindex()
     try:
         assert node.chainstate.tip().hash == last.get_hash()
         stats = node.last_import_stats
         assert stats["slow_path_blocks"] == 1
         assert stats["fallback_inputs"] == 1  # the Schnorr input, once
+        assert (stats["template_inputs"], stats["interp_inputs"]) == (0, 1)
     finally:
         node.close()
+
+
+def test_fast_import_joins_template_lanes_and_the_interpreters(tmp_path):
+    """One block whose generic-script leg is half the templates' and half
+    the interpreter's: a bare 1-of-2 and a pay-to-pubkey input fit, the same
+    two scripts behind ``OP_1 OP_VERIFY`` fit no template and go through
+    VerifyScript. The block connects through the native engine, the
+    interpreter's group lies behind the templates' lanes, and every walk
+    succeeds on the batch's verdicts."""
+    from bitcoincashplus_tpu.ops import ecdsa_batch
+    from bitcoincashplus_tpu.script.script import (
+        multisig_script,
+        p2pk_script,
+        push_data_raw,
+    )
+    from bitcoincashplus_tpu.wallet.signing import make_signature
+
+    other = CKey(0xFACADE, compressed=True)
+    prefix = b"\x51\x69"  # OP_1 OP_VERIFY
+    one_of_two = multisig_script(1, [other.pubkey, KEY.pubkey])
+    spks = [one_of_two, prefix + p2pk_script(KEY.pubkey),
+            prefix + one_of_two, p2pk_script(other.pubkey)]
+    signers = [KEY, KEY, other, other]
+    chain = _DiskChain(tmp_path)
+    fund = chain.fund(0, spks)
+    chain.push((fund,))
+    each = fund.vout[0].value
+    unsigned = CTransaction(
+        1, tuple(CTxIn(COutPoint(fund.txid, i), b"", 0xFFFFFFFE)
+                 for i in range(4)),
+        (CTxOut(4 * each - 10_000, SPK),))
+    sigs = [push_data_raw(make_signature(key, spk, unsigned, i, each,
+                                         enable_forkid=True))
+            for i, (key, spk) in enumerate(zip(signers, spks))]
+    script_sigs = [b"\x00" + sigs[0], sigs[1], b"\x00" + sigs[2], sigs[3]]
+    spend = CTransaction(
+        1, tuple(CTxIn(txin.prevout, ss, txin.sequence)
+                 for txin, ss in zip(unsigned.vin, script_sigs)),
+        unsigned.vout)
+    last = chain.push((spend,))
+    before = ecdsa_batch.STATS.snapshot()
+    node = chain.reindex()
+    try:
+        assert node.chainstate.tip().hash == last.get_hash()
+        stats = node.last_import_stats
+    finally:
+        node.close()
+    after = ecdsa_batch.STATS.snapshot()
+    assert stats["slow_path_blocks"] == 0
+    assert stats["fast_inputs"] == 1  # the funding transaction's
+    assert (stats["fallback_inputs"], stats["template_inputs"],
+            stats["interp_inputs"]) == (4, 2, 2)
+    assert (stats["multisig_groups"], stats["multisig_lanes"]) == (2, 4)
+    assert stats["multisig_group_confirms"] == 0
+    assert after["eager_multisig_sigs"] == before["eager_multisig_sigs"]
+    assert after["reject_confirm_sigs"] == before["reject_confirm_sigs"]
 
 
 @pytest.mark.skipif(not os.environ.get("BCP_SLOW_TESTS"),
