@@ -198,7 +198,7 @@ def regtest_params() -> ChainParams:
         bip65_height=0,
         bip66_height=0,
         csv_height=0,
-        uahf_height=0,
+        uahf_height=0,  # per-run via -uahfheight (node/node.py), regtest only
         rule_change_activation_threshold=108,  # 75% of 144 (regtest)
         miner_confirmation_window=144,
         deployments=(

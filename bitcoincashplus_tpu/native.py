@@ -16,6 +16,7 @@ and returns None when unavailable — callers must keep the Python path.
 from __future__ import annotations
 
 import ctypes
+import itertools
 import os
 import struct
 import subprocess
@@ -155,6 +156,13 @@ def _bind(lib: ctypes.CDLL) -> None:
     lib.bcp_engine_commit.restype = None
     lib.bcp_engine_sigscan_ns.argtypes = [ctypes.c_void_p]
     lib.bcp_engine_sigscan_ns.restype = ctypes.c_uint64
+    lib.bcp_engine_scan_counters.argtypes = [
+        ctypes.c_void_p, ctypes.POINTER(ctypes.c_uint64)]
+    lib.bcp_engine_scan_counters.restype = None
+    lib.bcp_sighash_legacy.argtypes = [
+        ctypes.c_char_p, ctypes.c_size_t, ctypes.c_uint32, ctypes.c_char_p,
+        ctypes.c_uint32, ctypes.c_uint32, ctypes.c_char_p]
+    lib.bcp_sighash_legacy.restype = ctypes.c_long
     lib.bcp_engine_abort.argtypes = [ctypes.c_void_p]
     lib.bcp_engine_abort.restype = None
     lib.bcp_engine_flush.argtypes = [
@@ -195,6 +203,13 @@ def _bind(lib: ctypes.CDLL) -> None:
     lib.bcp_pubkey_parse.argtypes = [ctypes.c_char_p, ctypes.c_long,
                                      ctypes.c_char_p]
     lib.bcp_pubkey_parse.restype = ctypes.c_int
+    lib.bcp_muhash_product.argtypes = [
+        ctypes.c_char_p, ctypes.c_size_t, ctypes.c_int, ctypes.c_char_p]
+    lib.bcp_muhash_product.restype = None
+    lib.bcp_muhash_element_product.argtypes = [
+        ctypes.c_char_p, ctypes.POINTER(ctypes.c_uint64), ctypes.c_size_t,
+        ctypes.c_int, ctypes.c_char_p]
+    lib.bcp_muhash_element_product.restype = None
 
 
 def available() -> bool:
@@ -432,10 +447,17 @@ class NativeConnectResult:
     input order, cand marking a multisig group's candidate lanes.
     ``leg_table`` has one row a template input: (input number, first lane,
     m, n) of its OP_CHECKMULTISIG, m = 0 for the one lane of an
-    OP_CHECKSIG."""
+    OP_CHECKSIG.
+
+    The scan's threads count for themselves and the engine sums them:
+    ``sigscan_thread_s`` is their seconds in the scan, ``legacy_sighash_s``
+    those inside the legacy SignatureHash, which made ``legacy_digests``
+    digests over ``legacy_sighash_bytes`` bytes of serialised
+    transaction."""
 
     __slots__ = ("block_hash", "n_tx", "n_inputs", "undo", "txids_blob",
-                 "sigscan_s",
+                 "sigscan_s", "sigscan_thread_s", "legacy_digests",
+                 "legacy_sighash_bytes", "legacy_sighash_s",
                  "tx_offsets", "tx_out_counts", "sig_status", "sig_msg",
                  "sig_rs", "sig_pub", "sig_rn", "sig_wrap", "sig_txin",
                  "spent_values", "spent_heightcodes", "spent_spk_offsets",
@@ -575,6 +597,11 @@ class ConnectEngine:
         res = NativeConnectResult()
         res.block_hash = hash_out.raw
         res.sigscan_s = lib.bcp_engine_sigscan_ns(self._h) / 1e9
+        scan = (ctypes.c_uint64 * 4)()
+        lib.bcp_engine_scan_counters(self._h, scan)
+        res.legacy_digests, res.legacy_sighash_bytes = scan[0], scan[1]
+        res.legacy_sighash_s, res.sigscan_thread_s = (scan[2] / 1e9,
+                                                      scan[3] / 1e9)
         res.n_tx = lib.bcp_engine_n_tx(self._h)
         res.n_inputs = lib.bcp_engine_n_inputs(self._h)
         ulen = ctypes.c_size_t()
@@ -675,6 +702,46 @@ class ConnectEngine:
 
     def clear(self) -> None:
         self._lib.bcp_engine_clear(self._h)
+
+
+def sighash_legacy(raw_tx: bytes, in_idx: int, script_code: bytes,
+                   hashtype: int) -> tuple[bytes, int]:
+    """The native scan's legacy SignatureHash over one serialised
+    transaction: (digest, bytes of serialisation hashed). ``script_code``
+    is hashed as it is given: the scan's templates hold no
+    OP_CODESEPARATOR and no push of their signature."""
+    lib = load()
+    out = ctypes.create_string_buffer(32)
+    n = lib.bcp_sighash_legacy(raw_tx, len(raw_tx), in_idx, script_code,
+                               len(script_code), hashtype & 0xFFFFFFFF, out)
+    if n < 0:
+        raise ValueError("transaction does not parse")
+    return out.raw, n
+
+
+def muhash_product(values: list[int]) -> int:
+    """prod(values) mod the MuHash prime (store/muhash.batch_product_ref is
+    the specification); values below 2^3072."""
+    lib = load()
+    assert lib is not None, "native library unavailable"
+    out = ctypes.create_string_buffer(384)
+    lib.bcp_muhash_product(
+        b"".join(v.to_bytes(384, "little") for v in values), len(values),
+        0, out)
+    return int.from_bytes(out.raw, "little")
+
+
+def muhash_element_product(rows: list[bytes]) -> int:
+    """prod(store/muhash.element(row) for row in rows) mod the MuHash
+    prime: SHAKE256 and the product both on the library's threads."""
+    lib = load()
+    assert lib is not None, "native library unavailable"
+    offsets = (ctypes.c_uint64 * (len(rows) + 1))(
+        0, *itertools.accumulate(map(len, rows)))
+    out = ctypes.create_string_buffer(384)
+    lib.bcp_muhash_element_product(b"".join(rows), offsets, len(rows), 0,
+                                   out)
+    return int.from_bytes(out.raw, "little")
 
 
 def engine_available() -> bool:

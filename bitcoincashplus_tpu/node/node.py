@@ -16,7 +16,7 @@ import os
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Optional
 
 from ..consensus.block import CBlock
@@ -425,6 +425,26 @@ class Node:
                 consensus=_dc.replace(self.params.consensus,
                                       use_cash_daa=True,
                                       daa_height=daa_height))
+        # -uahfheight=<n>: the height from which blocks carry the fork's
+        # flag bundle (FORKID, STRICTENC, LOW_S, NULLFAIL, NULLDUMMY), on
+        # regtest only, where it is 0 otherwise: the door to a chain with
+        # history below the fork height (ABC's -uahfstarttime was this
+        # door). block_script_flags reads it from the parameters.
+        if config.has("uahfheight"):
+            import dataclasses as _dc
+
+            if self.params.network != "regtest":
+                raise ConfigError(
+                    "-uahfheight is a regtest option: the fork height of "
+                    f"{self.params.network} is part of its consensus")
+            uahf_height = config.get_int("uahfheight", 0)
+            if uahf_height < 0:
+                raise ConfigError(
+                    f"-uahfheight={uahf_height}: must be >= 0")
+            self.params = _dc.replace(
+                self.params,
+                consensus=_dc.replace(self.params.consensus,
+                                      uahf_height=uahf_height))
         verifier = BlockScriptVerifier(self.params,
                                        backend=self.connect_backend,
                                        sigcache=self.sigcache,
@@ -1631,7 +1651,10 @@ class Node:
         from ..script.script import script_int
         from ..script.sighash import SighashCache
         from ..validation.chain import BlockStatus, CBlockIndex
-        from ..validation.scriptcheck import block_script_flags
+        from ..validation.scriptcheck import (
+            _InlineCountingChecker,
+            block_script_flags,
+        )
 
         cs = self.chainstate
         params = self.params
@@ -1651,15 +1674,26 @@ class Node:
         # fallback_inputs: every input the P2PKH scan did not take =
         # template_inputs (a native script template wrote their lanes, its
         # time inside native_connect_s / sigscan_s) + interp_inputs (the
-        # ones that went on to VerifyScript)
+        # ones that went on to VerifyScript).
+        # prefork_blocks: blocks connected here under flags without
+        # NULLFAIL (history below the fork height); the scan's threads'
+        # seconds (sigscan_thread_s; sigscan_s is its wall) and, of them,
+        # those inside the legacy SignatureHash, with its digests and the
+        # bytes of serialised transaction they hashed
         stats = {"blocks": 0, "bytes": 0, "native_connect_s": 0.0,
                  "sigscan_s": 0.0, "verify_s": 0.0, "fallback_s": 0.0,
                  "flush_s": 0.0, "slow_path_blocks": 0,
                  "fallback_inputs": 0, "template_inputs": 0,
-                 "interp_inputs": 0, "fast_inputs": 0}
-        multisig_keys = ("multisig_groups", "multisig_lanes",
-                         "multisig_group_confirms")
-        multisig0 = [getattr(ecdsa_batch.STATS, k) for k in multisig_keys]
+                 "interp_inputs": 0, "fast_inputs": 0,
+                 "prefork_blocks": 0, "sigscan_thread_s": 0.0,
+                 "legacy_digests": 0, "legacy_sighash_bytes": 0,
+                 "legacy_sighash_s": 0.0}
+        scan_keys = ("sigscan_s", "sigscan_thread_s", "legacy_digests",
+                     "legacy_sighash_bytes", "legacy_sighash_s")
+        # counters of gettpuinfo.batch reported as the import's own deltas
+        delta_keys = ("multisig_groups", "multisig_lanes",
+                      "multisig_group_confirms", "inline_legacy_sigs")
+        delta0 = [getattr(ecdsa_batch.STATS, k) for k in delta_keys]
         n_imported = 0
         pending: dict[bytes, list[tuple[bytes, Optional[tuple]]]] = {}
         # in-flight signature batches: (block hash, BatchHandle, number of
@@ -1845,6 +1879,37 @@ class Node:
             n_imported += 1
             return True
 
+        def interpret(raw: bytes, res, interp_idx, flags: int, h: bytes,
+                      records: list, groups: list) -> None:
+            """The inputs the templates declined, through VerifyScript.
+            Under NULLFAIL their checks defer into ``records`` and
+            ``groups``. Without it (history below the fork height) a failed
+            check may push false into a script that goes on, so they are
+            verified here and now, on this thread (inline_legacy_sigs counts
+            the checks). Raises ScriptError."""
+            defer = bool(flags & SCRIPT_VERIFY_NULLFAIL)
+            tx_cache: dict[int, tuple] = {}
+            spk_off = res.spent_spk_offsets
+            for g in interp_idx:
+                t_i, in_i = int(res.sig_txin[g, 0]), int(res.sig_txin[g, 1])
+                if t_i not in tx_cache:
+                    s, e_ = (int(res.tx_offsets[t_i, 0]),
+                             int(res.tx_offsets[t_i, 1]))
+                    tx = CTransaction.from_bytes(raw[s:e_])
+                    tx_cache[t_i] = (tx, SighashCache(tx))
+                tx, cache = tx_cache[t_i]
+                spk = res.spent_spk_blob[int(spk_off[g]):int(spk_off[g + 1])]
+                amount = int(res.spent_values[g])
+                seen = len(groups)
+                if defer:
+                    checker = DeferringSignatureChecker(
+                        tx, in_i, amount, records, cache, groups)
+                else:
+                    checker = _InlineCountingChecker(tx, in_i, amount, cache)
+                VerifyScript(tx.vin[in_i].script_sig, spk, flags, checker)
+                for grp in groups[seen:]:
+                    grp.owner = (raw, res, int(g), flags, h)
+
         def script_leg(raw: bytes, res, interp_idx, flags: int, h: bytes):
             """The generic-script leg of one block: the lanes the native
             scan's templates wrote for the inputs they fit, then every
@@ -1855,28 +1920,12 @@ class Node:
             has no lane in the packed batch)."""
             records: list = []
             groups: list = []
-            tx_cache: dict[int, tuple] = {}
-            spk_off = res.spent_spk_offsets
+            inline = len(interp_idx) and not flags & SCRIPT_VERIFY_NULLFAIL
             try:
-                for g in interp_idx:
-                    t_i, in_i = (int(res.sig_txin[g, 0]),
-                                 int(res.sig_txin[g, 1]))
-                    if t_i not in tx_cache:
-                        s, e_ = (int(res.tx_offsets[t_i, 0]),
-                                 int(res.tx_offsets[t_i, 1]))
-                        tx = CTransaction.from_bytes(raw[s:e_])
-                        tx_cache[t_i] = (tx, SighashCache(tx))
-                    tx, cache = tx_cache[t_i]
-                    spk = res.spent_spk_blob[
-                        int(spk_off[g]):int(spk_off[g + 1])]
-                    seen = len(groups)
-                    checker = DeferringSignatureChecker(
-                        tx, in_i, int(res.spent_values[g]), records, cache,
-                        groups)
-                    VerifyScript(tx.vin[in_i].script_sig, spk, flags,
-                                 checker)
-                    for grp in groups[seen:]:
-                        grp.owner = (raw, res, int(g), flags, h)
+                with (telemetry.span("import.inline_legacy",
+                                     inputs=len(interp_idx))
+                      if inline else nullcontext()):
+                    interpret(raw, res, interp_idx, flags, h, records, groups)
             except ScriptError:
                 return None
             if any(r.algo != "ecdsa" for r in records):
@@ -1915,8 +1964,7 @@ class Node:
             check_scripts = (cs.script_checks_needed(idx)
                              and cs.script_verifier is not None)
             flags = block_script_flags(height, header.time, params)
-            if check_scripts and not (flags & SCRIPT_VERIFY_NULLFAIL):
-                return False  # pre-NULLFAIL: inline-verify via Python
+            prefork = check_scripts and not flags & SCRIPT_VERIFY_NULLFAIL
             bip34 = (script_int(height)
                      if height >= consensus.bip34_height else None)
             mtp = prev.get_median_time_past()
@@ -1941,7 +1989,8 @@ class Node:
                 eng.abort()
                 return False
             stats["native_connect_s"] += time.perf_counter() - t0
-            stats["sigscan_s"] += res.sigscan_s
+            for key in scan_keys:
+                stats[key] += getattr(res, key)
             cs.bench["connect_ms"] += (time.perf_counter() - t0) * 1e3
 
             # BIP30 base-store leg: only pre-BIP34 heights can mint
@@ -1996,6 +2045,8 @@ class Node:
                     agg.append((pub, rs, msg, rn, wrap, cand))
                     agg_count[0] += len(msg)
                     agg_last_hash[0] = h
+                    if prefork:
+                        ecdsa_batch.STATS.prefork_lanes += len(msg)
                 dt = time.perf_counter() - t0
                 stats["verify_s"] += dt
                 cs.bench["verify_ms"] += dt * 1e3
@@ -2021,6 +2072,7 @@ class Node:
                 flush_agg(everything=False)
             n_imported += 1
             stats["blocks"] += 1
+            stats["prefork_blocks"] += prefork
             return True
 
         def process_raw(raw: bytes, pos_info: Optional[tuple]) -> bool:
@@ -2099,7 +2151,7 @@ class Node:
         cs.flush()
         eng.close()
         stats["wall_s"] = time.perf_counter() - t_start
-        for key, was in zip(multisig_keys, multisig0):
+        for key, was in zip(delta_keys, delta0):
             stats[key] = getattr(ecdsa_batch.STATS, key) - was
         self.last_import_stats = stats
         log_printf(

@@ -278,7 +278,13 @@ class BatchStats:
     multisig_groups: int = 0
     multisig_lanes: int = 0
     multisig_group_confirms: int = 0
-    inline_legacy_sigs: int = 0    # pre-NULLFAIL blocks, deferral unsound
+    # checks below the fork height (flags without NULLFAIL) that ran on the
+    # host at once: every check of the Python engines, and in the native
+    # import those of the scripts its templates declined
+    inline_legacy_sigs: int = 0
+    # lanes the native import took for blocks below the fork height (their
+    # signature check is the script's last operation: interpreter.py)
+    prefork_lanes: int = 0
     sigcache_hits: int = 0         # records dropped by the sigcache probe
     p2pkh_fast_path: int = 0       # inputs that skipped the generic EvalScript
     device_seconds: float = 0.0

@@ -10,10 +10,25 @@ interpreter is branchy host code, but OP_CHECKSIG's expensive
 secp256k1_ecdsa_verify is *deferred* — ``DeferringSignatureChecker``
 records (pubkey, r, s, msghash) and speculatively reports success; the
 per-block batch then runs in ONE TPU dispatch (ops/ecdsa_batch). This is
-sound iff SCRIPT_VERIFY_NULLFAIL is active: a failing check with a
-non-empty signature then always invalidates the script, so "all deferred
-records verify" ⇔ "all scripts that reported success actually succeed".
-The checker asserts that precondition.
+sound where a failing check with a non-empty signature always invalidates
+the script, so "all deferred records verify" ⇔ "all scripts that reported
+success actually succeed". Two things make it so:
+
+* SCRIPT_VERIFY_NULLFAIL in the flags (blocks from the fork height on):
+  the failing check raises, whatever the script does next.
+* The check is the script's *last operation* (``last_check_form``):
+  pay-to-pubkey-hash, pay-to-pubkey, and bare or pay-to-script-hash
+  ``OP_m <key>*n OP_n OP_CHECKMULTISIG``. Without NULLFAIL a failing check
+  pushes false, and NULLFAIL matters to deferral only where the rest of the
+  script can *consume* that false (``OP_CHECKSIG OP_NOT``, an ``OP_IF`` on
+  it). In these forms nothing follows it: the false is the final stack's
+  top, the script's verdict is the check's verdict, and a multisig walk
+  over candidate-lane verdicts decides exactly as upstream's loop does. So
+  history below the fork height rides lanes in these forms exactly as the
+  chain above it does; every other script there is verified eagerly and
+  inline (``validation/scriptcheck._InlineCountingChecker``).
+
+The checker asserts one of the two (VerifyScript).
 
 OP_CHECKMULTISIG defers too, where the caller gave the checker a list to
 record groups in (the native import does; every other caller stays eager).
@@ -442,19 +457,23 @@ class TransactionSignatureChecker(BaseSignatureChecker):
 
 class DeferringSignatureChecker(TransactionSignatureChecker):
     """Records CHECKSIG verifications for the per-block TPU batch instead
-    of running them. Requires NULLFAIL in flags (see module docstring);
-    VerifyScript enforces this. A caller that passes ``groups`` settles
-    multisig groups itself (``multisig_walk`` over the candidate lanes'
-    verdicts, the eager checker where it fails); without it every multisig
-    trial (defer_ok=False) verifies eagerly via the parent."""
+    of running them. Requires NULLFAIL in flags, or ``last_operation``: the
+    caller's word that ``last_check_form`` names this input's scripts (see
+    module docstring); VerifyScript enforces one of the two. A caller that
+    passes ``groups`` settles multisig groups itself (``multisig_walk`` over
+    the candidate lanes' verdicts, the eager checker where it fails);
+    without it every multisig trial (defer_ok=False) verifies eagerly via
+    the parent."""
 
     def __init__(self, tx: CTransaction, in_idx: int, amount: int,
                  records: list[SigCheckRecord],
                  cache: Optional[SighashCache] = None,
-                 groups: Optional[list[MultisigGroup]] = None):
+                 groups: Optional[list[MultisigGroup]] = None,
+                 last_operation: bool = False):
         super().__init__(tx, in_idx, amount, cache)
         self.records = records
         self.groups = groups
+        self.last_operation = last_operation
 
     def defer_multisig(self, sigs: list, keys: list, script_code: bytes,
                        flags: int) -> bool:
@@ -462,7 +481,8 @@ class DeferringSignatureChecker(TransactionSignatureChecker):
         of its walk could raise or be decided without arithmetic (module
         docstring, (3)); one sighash a signature, one parse a key."""
         if (self.groups is None or not sigs
-                or not flags & SCRIPT_VERIFY_NULLFAIL):
+                or not (flags & SCRIPT_VERIFY_NULLFAIL
+                        or self.last_operation)):
             return False
         try:
             for sig in sigs:
@@ -510,6 +530,87 @@ class DeferringSignatureChecker(TransactionSignatureChecker):
             SigCheckRecord(pt, r, s, e, self.tx.txid, self.in_idx, algo)
         )
         return True  # speculative success — batch settles it
+
+
+# ---- scripts whose signature check is their last operation ----
+
+def _direct_pushes(script: bytes, pos: int = 0) -> tuple[list[bytes], int]:
+    """The run of direct pushes (opcodes 1..75) from ``pos`` on, and where
+    it ends."""
+    items = []
+    while pos < len(script) and 1 <= script[pos] <= 75:
+        end = pos + 1 + script[pos]
+        if end > len(script):
+            break
+        items.append(script[pos + 1:end])
+        pos = end
+    return items, pos
+
+
+def _multisig_form(script: bytes) -> Optional[tuple[int, int]]:
+    """(m, n) of ``OP_m <key>*n OP_n OP_CHECKMULTISIG`` with directly
+    pushed keys, 1 <= m <= n <= 16, and nothing else; or None."""
+    if len(script) < 3 or not S.OP_1 <= script[0] <= S.OP_16:
+        return None
+    keys, pos = _direct_pushes(script, 1)
+    m, n = script[0] - (S.OP_1 - 1), len(keys)
+    if (pos + 2 != len(script) or script[pos] != S.OP_1 - 1 + n
+            or script[pos + 1] != S.OP_CHECKMULTISIG
+            or not 1 <= m <= n <= 16):
+        return None
+    return m, n
+
+
+def last_check_form(script_sig: bytes, script_pubkey: bytes,
+                    flags: int) -> Optional[str]:
+    """The name of the form, where the input's scripts are one of those
+    whose signature check is the last operation they run (module docstring),
+    else None. The specification of the shapes native/connect.cpp's
+    templates match (``p2pkh_template``, ``scan_templates``): every push of
+    the scriptSig direct (the multisig dummy OP_0, a redeem script in its
+    minimal push), nothing above or below what the script consumes. The
+    native templates decline more, by encoding (a hybrid key, an undefined
+    hashtype, loose DER, a non-minimal push); what they emit for an input is
+    what this checker records for it under ``last_operation``."""
+    spk = script_pubkey
+    if (len(spk) == 25 and spk[:3] == bytes([S.OP_DUP, S.OP_HASH160, 20])
+            and spk[23:] == bytes([S.OP_EQUALVERIFY, S.OP_CHECKSIG])):
+        # <sig> <key>, either of them possibly OP_0's empty item
+        items, pos = 0, 0
+        while pos < len(script_sig) and script_sig[pos] <= 75:
+            pos += 1 + script_sig[pos]
+            items += 1
+        return "p2pkh" if items == 2 and pos == len(script_sig) else None
+    if (len(spk) in (35, 67) and spk[0] == len(spk) - 2
+            and spk[-1] == S.OP_CHECKSIG):
+        items, pos = _direct_pushes(script_sig)
+        return "p2pk" if len(items) == 1 and pos == len(script_sig) else None
+    if not script_sig or script_sig[0] != S.OP_0:
+        return None
+    sigs, pos = _direct_pushes(script_sig, 1)
+    if S.is_p2sh(spk):
+        if not flags & SCRIPT_VERIFY_P2SH:
+            return None
+        try:
+            ops = list(S.get_script_ops(script_sig[pos:]))
+        except ScriptParseError:
+            return None
+        if pos == len(script_sig) and len(sigs) >= 2:
+            code = sigs.pop()
+        elif (len(ops) == 1 and ops[0][1] is not None
+                and check_minimal_push(ops[0][1], ops[0][0])):
+            code = ops[0][1]
+        else:
+            return None
+        form = _multisig_form(code)
+        if (form is None or len(sigs) != form[0]
+                or hash160(code) != spk[2:22]):
+            return None
+        return "p2sh-multisig"
+    form = _multisig_form(spk)
+    if form is None or pos != len(script_sig) or len(sigs) != form[0]:
+        return None
+    return "multisig"
 
 
 # ---- EvalScript (interpreter.cpp:~250) ----
@@ -928,8 +1029,11 @@ def VerifyScript(script_sig: bytes, script_pubkey: bytes, flags: int,
     scriptPubKey (+ P2SH redeem script), enforce final-stack truth.
     Raises ScriptError; returns None on success."""
     if isinstance(checker, DeferringSignatureChecker):
-        assert flags & SCRIPT_VERIFY_NULLFAIL, (
-            "deferred sig batching requires NULLFAIL for soundness"
+        assert flags & SCRIPT_VERIFY_NULLFAIL or (
+            checker.last_operation
+            and last_check_form(script_sig, script_pubkey, flags)), (
+            "deferred sig batching requires NULLFAIL, or a script whose "
+            "signature check is its last operation, for soundness"
         )
     if (flags & SCRIPT_VERIFY_SIGPUSHONLY) and not S.is_push_only(script_sig):
         raise ScriptError("sig-pushonly")

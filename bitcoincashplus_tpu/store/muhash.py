@@ -20,21 +20,24 @@ group is (Z/pZ)*, so:
     one accumulator per shard and the global digest is the product of the
     shard accumulators, identical for every shard count.
 
-Two batch-product backends, differential-tested against each other:
+Three batch-product backends, differential-tested against each other:
 
   - `batch_product_ref`: plain python ints (CPython's native big-int
-    multiply);
+    multiply), the specification;
+  - `native/muhash.cpp` (through `..native`): 48 64-bit limbs, a schoolbook
+    multiply and the fold 2^3072 ≡ 1103717 mod p, on host threads, with
+    the hash-to-group (`coin_product`) beside it. A flush of the coins
+    store is one element a changed row: ~27 µs of python-int division an
+    element, ~1 µs here;
   - `_batch_product_limbs`: numpy 16-bit-limb rows (192 limbs, pairwise
     tree reduction with a shift-add schoolbook multiply — partial sums
     bounded by 192 * (2^16-1)^2 < 2^40, far under uint64 — a sequential
-    carry sweep, and a fold-based reduction using 2^3072 ≡ 1103717
-    mod p). The limb layout is the vector-unit-friendly form.
+    carry sweep, and the same fold). The limb layout is the
+    vector-unit-friendly form; 22 µs against ~270 µs an element at 50k
+    elements on one core, so it is opt-in (BCP_MUHASH_LIMBS=1).
 
-`batch_product` dispatches between them. Measured on the bench host
-(single core), CPython's int multiply wins at every batch size — 22 µs
-vs ~270 µs per element at 50k elements; the limb path's per-level python
-loop over 192 limb positions dominates — so the int path is the default
-and BCP_MUHASH_LIMBS=1 opts in to the limb backend. stdlib+numpy only —
+`batch_product` and `coin_product` take the native library from 64 values
+on where it is built, else the python ints. stdlib+numpy only at import —
 importable from the jax-free crash-test workers.
 """
 
@@ -190,15 +193,45 @@ def _batch_product_limbs(values: list[int]) -> int:
 # int path measured faster at every batch size on a one-core CPU host
 # (see module docstring).
 _USE_LIMBS = os.environ.get("BCP_MUHASH_LIMBS") == "1"
+# below this many values a call into the native library saves nothing
+_NATIVE_FLOOR = 64
+
+
+def _native():
+    """The native library where it is built (native/muhash.cpp: 64-bit
+    limbs, the fold 2^3072 = c mod p, host threads), else None. Imported
+    late: this module stays importable with the stdlib alone."""
+    from .. import native
+
+    return native if native.available() else None
 
 
 def batch_product(values: list[int]) -> int:
-    """prod(values) mod p. Dispatches to the measured-faster python-int
-    path unless BCP_MUHASH_LIMBS=1 forces the numpy limb backend (which
-    also needs numpy present and a non-tiny batch)."""
+    """prod(values) mod p: the native library from _NATIVE_FLOOR values on
+    (~1 us a value and threaded, against ~27 us of python-int division),
+    else the python-int path; BCP_MUHASH_LIMBS=1 forces the numpy limb
+    backend (which also needs numpy present and a non-tiny batch). All
+    equal :func:`batch_product_ref`: the unit suite asserts it."""
     if _USE_LIMBS and _np is not None and len(values) >= 8:
         return _batch_product_limbs(values)
+    if len(values) >= _NATIVE_FLOOR:
+        native = _native()
+        if native is not None:
+            return native.muhash_product(values)
     return batch_product_ref(values)
+
+
+def coin_product(rows: Iterable[tuple[bytes, bytes]]) -> int:
+    """prod(coin_element(key36, coin_ser)) mod p over (key36, coin_ser)
+    rows: what a flush multiplies into (or divides out of) a shard's
+    accumulator. From _NATIVE_FLOOR rows on the hash-to-group runs in the
+    native library too, so no row becomes a python int."""
+    rows = [k + ser for k, ser in rows]
+    if len(rows) >= _NATIVE_FLOOR:
+        native = _native()
+        if native is not None:
+            return native.muhash_element_product(rows)
+    return batch_product_ref(map(element, rows))
 
 
 class MuHash:

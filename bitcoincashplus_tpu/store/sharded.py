@@ -304,12 +304,11 @@ class ShardedCoinsDB(CoinsView):
                 maybe = changed
             old = self.shards[i].get_serialized_many(maybe) if maybe \
                 else {}
-            removed = [muhash.coin_element(k, old[k])
-                       for k in changed if k in old]
-            added = [muhash.coin_element(k, ser)
-                     for k, ser in per_puts[i].items()]
+            removed = [(k, old[k]) for k in changed if k in old]
             acc = muhash.MuHash(self._accs[i].state)
-            acc.apply(added, removed)
+            acc.apply(
+                [muhash.coin_product(per_puts[i].items())],
+                [muhash.coin_product(removed)] if removed else [])
             new_accs.append(acc)
             if self.bloom_enabled and per_puts[i]:
                 # the new puts become persisted rows below — future

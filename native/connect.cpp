@@ -242,6 +242,28 @@ struct LegLanes {
     }
 };
 
+static uint64_t ns_since(std::chrono::steady_clock::time_point t0) {
+    return uint64_t(std::chrono::duration_cast<std::chrono::nanoseconds>(
+        std::chrono::steady_clock::now() - t0).count());
+}
+
+// What the legacy digest cost one scan thread (summed after the join):
+// digests made, bytes of serialised transaction they hashed, nanoseconds
+// inside sighash_legacy; and the thread's nanoseconds in the scan as a whole.
+struct ScanCounters {
+    uint64_t legacy_digests = 0;
+    uint64_t legacy_bytes = 0;
+    uint64_t legacy_ns = 0;
+    uint64_t thread_ns = 0;
+
+    void add(const ScanCounters& o) {
+        legacy_digests += o.legacy_digests;
+        legacy_bytes += o.legacy_bytes;
+        legacy_ns += o.legacy_ns;
+        thread_ns += o.thread_ns;
+    }
+};
+
 struct Engine {
     std::unordered_map<Key36, CoinEnt, KeyHash> map;
     uint8_t best[32] = {0};
@@ -276,6 +298,7 @@ struct Engine {
     long err_tx = -1;
     long err_in = -1;
     uint64_t sigscan_ns = 0;  // last connect's signature-scan wall time
+    ScanCounters scan;        // and its threads' counters, summed
 
     // deferred-commit overlay: connect(commit=0) validates and stages the
     // block's UTXO edits here; bcp_engine_commit applies them (or
@@ -469,6 +492,12 @@ static bool is_final(const PTx& tx, uint32_t height, int64_t mtp) {
 // P2PKH fast-path signature scan (validation/scriptcheck.py semantics)
 // ---------------------------------------------------------------------------
 
+// the two forms check_pubkey_encoding takes under STRICTENC
+static bool strict_key_form(const uint8_t* pub, uint32_t len) {
+    return (len == 33 && (pub[0] == 2 || pub[0] == 3)) ||
+           (len == 65 && pub[0] == 4);
+}
+
 // strict DER + hashtype tail (interpreter.py is_valid_signature_encoding)
 static bool valid_sig_encoding(const uint8_t* sig, uint32_t len) {
     if (len < 9 || len > 73) return false;
@@ -629,6 +658,165 @@ static void sighash_forkid(const PTx& tx, const TxMidstates& m,
     bcpn::sha256(mid, 32, out);
 }
 
+// What the digests of one transaction share, each made when the first
+// input asks for it: the FORKID digest's three midstates, and for the
+// legacy digest the serialisation with every scriptSig empty.
+struct TxDigests {
+    const PTx& tx;
+    ScanCounters& counters;
+    TxMidstates m;
+    bool have_mid = false;
+    // version | compact(n_in), then 41 bytes an input: prevout | 0x00 |
+    // sequence. compact(n_out) | outputs | locktime are the transaction's
+    // own bytes from the end of its last input on.
+    std::vector<uint8_t> head, blank;
+
+    TxDigests(const PTx& t, ScanCounters& c) : tx(t), counters(c) {}
+
+    const TxMidstates& mid() {
+        if (!have_mid) {
+            compute_midstates(tx, m);
+            have_mid = true;
+        }
+        return m;
+    }
+
+    void skeleton() {
+        if (!head.empty()) return;
+        uint32_t ver = uint32_t(tx.version);
+        head.resize(4);
+        memcpy(head.data(), &ver, 4);
+        put_compact(head, tx.vin.size());
+        blank.resize(tx.vin.size() * 41);
+        uint8_t* p = blank.data();
+        for (const PIn& in : tx.vin) {
+            memcpy(p, in.prevout, 36);
+            p[36] = 0;
+            memcpy(p + 37, &in.sequence, 4);
+            p += 41;
+        }
+    }
+};
+
+// signature_hash_legacy (script/sighash.py; upstream interpreter.cpp
+// SignatureHash over CTransactionSignatureSerializer): the transaction
+// serialised again for this input, with `code` as the input's script and
+// every other script empty; SIGHASH_NONE and SIGHASH_SINGLE zero the other
+// inputs' sequences and cut the outputs, ANYONECANPAY keeps this input
+// alone; the 32-bit hashtype behind it; SHA-256 twice. SIGHASH_SINGLE
+// without an output of the input's number signs the number 1. `code` holds
+// no OP_CODESEPARATOR and no push of the signature (the callers' scripts
+// cannot), so nothing is deleted from it.
+static void sighash_legacy(TxDigests& d, uint32_t in_idx, uint32_t hashtype,
+                           const uint8_t* code, uint32_t code_len,
+                           uint8_t out[32]) {
+    auto t0 = std::chrono::steady_clock::now();
+    const PTx& tx = d.tx;
+    uint32_t base = hashtype & 0x1F;
+    d.counters.legacy_digests++;
+    if (in_idx >= tx.vin.size() ||
+        (base == SIGHASH_SINGLE && in_idx >= tx.vout.size())) {
+        memset(out, 0, 32);
+        out[0] = 1;
+        return;
+    }
+    d.skeleton();
+    const PIn& in = tx.vin[in_idx];
+    bcpn::Sha256 a;
+    auto signed_input = [&]() {  // prevout | compact(len) code | sequence
+        std::vector<uint8_t> len;
+        put_compact(len, code_len);
+        a.update(in.prevout, 36);
+        a.update(len.data(), len.size());
+        a.update(code, code_len);
+        a.update(reinterpret_cast<const uint8_t*>(&in.sequence), 4);
+    };
+    bool cut = base == SIGHASH_NONE || base == SIGHASH_SINGLE;
+    if (hashtype & SIGHASH_ANYONECANPAY) {
+        a.update(d.head.data(), 4);
+        const uint8_t one = 1;
+        a.update(&one, 1);
+        signed_input();
+    } else if (cut) {
+        a.update(d.head.data(), d.head.size());
+        uint8_t other[41];
+        memset(other + 36, 0, 5);
+        for (uint32_t j = 0; j < tx.vin.size(); j++) {
+            if (j == in_idx) {
+                signed_input();
+            } else {
+                memcpy(other, tx.vin[j].prevout, 36);
+                a.update(other, 41);
+            }
+        }
+    } else {
+        a.update(d.head.data(), d.head.size());
+        a.update(d.blank.data(), size_t(in_idx) * 41);
+        signed_input();
+        a.update(d.blank.data() + size_t(in_idx + 1) * 41,
+                 d.blank.size() - size_t(in_idx + 1) * 41);
+    }
+    const PIn& last = tx.vin.back();
+    const uint8_t* tail = last.ss + last.ss_len + 4;
+    const uint8_t* end = tx.start + tx.size;
+    if (!cut) {
+        a.update(tail, size_t(end - tail));  // outputs and locktime
+    } else {
+        std::vector<uint8_t> outs;
+        if (base == SIGHASH_NONE) {
+            outs.push_back(0);
+        } else {
+            // the outputs before this input's are CTxOut(): -1, no script
+            put_compact(outs, uint64_t(in_idx) + 1);
+            for (uint32_t j = 0; j < in_idx; j++) {
+                outs.insert(outs.end(), 8, 0xFF);
+                outs.push_back(0);
+            }
+            const POut& o = tx.vout[in_idx];
+            const uint8_t* v = reinterpret_cast<const uint8_t*>(&o.value);
+            outs.insert(outs.end(), v, v + 8);
+            put_compact(outs, o.spk_len);
+            outs.insert(outs.end(), o.spk, o.spk + o.spk_len);
+        }
+        a.update(outs.data(), outs.size());
+        a.update(end - 4, 4);
+    }
+    uint8_t ht[4];
+    memcpy(ht, &hashtype, 4);
+    a.update(ht, 4);
+    d.counters.legacy_bytes += a.total;
+    uint8_t mid[32];
+    a.final(mid);
+    bcpn::sha256(mid, 32, out);
+    d.counters.legacy_ns += ns_since(t0);
+}
+
+// The hashtypes the scan models under `flags`: ALL, NONE and SINGLE, each
+// with or without ANYONECANPAY, with the FORKID bit where and only where
+// STRICTENC wants it (_check_hashtype_encoding). Without STRICTENC the
+// interpreter hashes any byte; the scan leaves the undefined ones to it.
+static bool hashtype_modelled(uint8_t ht, uint32_t flags) {
+    bool uses_forkid = (ht & SIGHASH_FORKID) != 0;
+    bool forkid_on = (flags & F_FORKID) != 0;
+    if (uses_forkid && !forkid_on) return false;
+    if ((flags & F_STRICTENC) && forkid_on && !uses_forkid) return false;
+    uint8_t base = ht & uint8_t(~(SIGHASH_ANYONECANPAY | SIGHASH_FORKID));
+    return base >= 1 && base <= SIGHASH_SINGLE;
+}
+
+// The digest a signature of hashtype `ht` commits to under `flags`
+// (script/sighash.py signature_hash): FORKID's where the flags enable it
+// and the hashtype asks for it, else the legacy one. No option chooses.
+static void sighash(TxDigests& d, uint32_t in_idx, uint8_t ht, uint32_t flags,
+                    const uint8_t* code, uint32_t code_len, int64_t amount,
+                    uint8_t out[32]) {
+    if ((flags & F_FORKID) && (ht & SIGHASH_FORKID))
+        sighash_forkid(d.tx, d.mid(), in_idx, ht, code, code_len, amount,
+                       out);
+    else
+        sighash_legacy(d, in_idx, ht, code, code_len, out);
+}
+
 // ---------------------------------------------------------------------------
 // Script templates of the generic-script leg: P2PK, bare and P2SH
 // OP_CHECKMULTISIG. The specification is script/interpreter.py:
@@ -639,6 +827,21 @@ static void sighash_forkid(const PTx& tx, const TxMidstates& m,
 // flags would let the interpreter defer more than the scan models (a hybrid
 // key or an undefined hashtype without STRICTENC, loose DER without DERSIG,
 // a non-null dummy without NULLDUMMY, a non-minimal push), it declines.
+//
+// In each of these forms, as in P2PKH, the signature check is the script's
+// last operation: the script's verdict is the check's, whether a failed
+// check raises (NULLFAIL) or pushes false. So the templates hold below the
+// fork height as above it; what changes with the block's flags is the
+// digest (sighash) and the encoding rules (no STRICTENC: a key is a lane in
+// STRICTENC's two forms only; no LOW_S: a high S is a lane).
+//
+// The legacy digest deletes each signature's push from the script code
+// first (FindAndDelete). A push of a strict-DER signature is its length
+// (9..73) and then 0x30; in a template's script code an opcode boundary
+// holds OP_1..OP_16, OP_CHECKSIG, OP_CHECKMULTISIG or a key's push (0x21
+// then 0x02 / 0x03, 0x41 then 0x04): nothing to delete, ever. The P2PKH
+// body's script code holds the push of a 20-byte hash besides, so a
+// signature of 20 bytes under the legacy digest goes to the interpreter.
 // ---------------------------------------------------------------------------
 
 constexpr uint8_t OP_PUSHDATA1 = 0x4C;
@@ -670,7 +873,7 @@ static bool direct_push(const uint8_t* sc, uint32_t sc_len, uint32_t* pos,
 
 // A signature a lane can carry: what check_sig / defer_multisig accept
 // under `flags` (non-empty, not Schnorr's 65 bytes, its encoding checks,
-// scalars in 1..N-1), signed over the FORKID digest.
+// scalars in 1..N-1) in strict DER, with a hashtype the scan models.
 static bool template_sig(Push sig, uint32_t flags, uint8_t r32[32],
                          uint8_t s32[32]) {
     if (sig.len == 65 || !valid_sig_encoding(sig.p, sig.len)) return false;
@@ -683,16 +886,13 @@ static bool template_sig(Push sig, uint32_t flags, uint8_t r32[32],
         cmp256(r32, SECP_N) >= 0 || cmp256(s32, SECP_N) >= 0)
         return false;
     if ((flags & F_LOW_S) && cmp256(s32, SECP_N_HALF) > 0) return false;
-    uint8_t ht = sig.p[sig.len - 1];
-    uint8_t base = ht & uint8_t(~(SIGHASH_ANYONECANPAY | SIGHASH_FORKID));
-    return (ht & SIGHASH_FORKID) && base >= 1 && base <= SIGHASH_SINGLE;
+    return hashtype_modelled(sig.p[sig.len - 1], flags);
 }
 
 // a key in one of STRICTENC's two forms that is a point of the curve
 static bool template_key(Push key, uint8_t pub64[64]) {
-    bool form = (key.len == 33 && (key.p[0] == 2 || key.p[0] == 3)) ||
-                (key.len == 65 && key.p[0] == 4);
-    return form && bcp_pubkey_parse(key.p, long(key.len), pub64);
+    return strict_key_form(key.p, key.len) &&
+           bcp_pubkey_parse(key.p, long(key.len), pub64);
 }
 
 // OP_m <key>*n OP_n OP_CHECKMULTISIG, 1 <= m <= n, and nothing else
@@ -712,12 +912,10 @@ static bool multisig_template(const uint8_t* sc, uint32_t sc_len,
 
 // The lanes of one input that fits a template, appended to `out` with its
 // table row; false (and nothing written) where no template fits.
-static bool scan_templates(const Engine& e, const PTx& tx,
-                           const TxMidstates& mid, uint32_t in_idx,
+static bool scan_templates(const Engine& e, TxDigests& d, uint32_t in_idx,
                            uint32_t g, uint32_t flags, const uint8_t* spk,
                            uint32_t spk_len, LegLanes& out) {
-    if (!(flags & F_NULLFAIL) || !(flags & F_FORKID)) return false;
-    const PIn& in = tx.vin[in_idx];
+    const PIn& in = d.tx.vin[in_idx];
     int64_t amount = e.spent_values[g];
     uint8_t r32[MAX_TEMPLATE_KEYS][32], s32[MAX_TEMPLATE_KEYS][32];
     uint8_t msg[32], pub[MAX_TEMPLATE_KEYS][64];
@@ -731,8 +929,8 @@ static bool scan_templates(const Engine& e, const PTx& tx,
             !template_sig(sig, flags, r32[0], s32[0]) ||
             !template_key(Push{spk + 1, spk_len - 2}, pub[0]))
             return false;
-        sighash_forkid(tx, mid, in_idx, sig.p[sig.len - 1], spk, spk_len,
-                       amount, msg);
+        sighash(d, in_idx, sig.p[sig.len - 1], flags, spk, spk_len, amount,
+                msg);
         out.row(g, 0, 0);
         out.lane(pub[0], r32[0], s32[0], msg, false);
         return true;
@@ -791,12 +989,20 @@ static bool scan_templates(const Engine& e, const PTx& tx,
     for (uint32_t j = 0; j < n; j++)
         if (!template_key(keys[n - 1 - j], pub[j])) return false;
     out.row(g, m, n);
+    // one digest a hashtype: signatures of one hashtype share theirs
+    uint8_t msgs[MAX_TEMPLATE_KEYS][32], hts[MAX_TEMPLATE_KEYS];
     for (uint32_t i = 0; i < m; i++) {
         const Push& s = sigs[m - 1 - i];
-        sighash_forkid(tx, mid, in_idx, s.p[s.len - 1], code, code_len,
-                       amount, msg);
+        hts[i] = s.p[s.len - 1];
+        uint32_t same = 0;
+        while (hts[same] != hts[i]) same++;
+        if (same < i)
+            memcpy(msgs[i], msgs[same], 32);
+        else
+            sighash(d, in_idx, hts[i], flags, code, code_len, amount,
+                    msgs[i]);
         for (uint32_t j = i; j <= i + n - m; j++)
-            out.lane(pub[j], r32[i], s32[i], msg, true);
+            out.lane(pub[j], r32[i], s32[i], msgs[i], true);
     }
     return true;
 }
@@ -805,10 +1011,9 @@ static bool scan_templates(const Engine& e, const PTx& tx,
 // script error code (block-fatal), or sets *fallback for the Python
 // interpreter. Mirrors scriptcheck._p2pkh_fast_verify +
 // DeferringSignatureChecker.check_sig exactly.
-static long scan_input(Engine& e, const PTx& tx, const TxMidstates& m,
-                       uint32_t in_idx, uint32_t g, uint32_t flags,
-                       LegLanes& leg) {
-    const PIn& in = tx.vin[in_idx];
+static long scan_input(Engine& e, TxDigests& d, uint32_t in_idx, uint32_t g,
+                       uint32_t flags, LegLanes& leg) {
+    const PIn& in = d.tx.vin[in_idx];
     const uint8_t* spk = e.spent_spk.data() + e.spent_spk_off[g];
     uint32_t spk_len = e.spent_spk_off[g + 1] - e.spent_spk_off[g];
     const uint8_t *sig, *pub;
@@ -817,8 +1022,7 @@ static long scan_input(Engine& e, const PTx& tx, const TxMidstates& m,
                         &sig, &sig_len, &pub, &pub_len)) {
         // another template's lanes, or the generic interpreter (Python)
         e.sig_status[g] =
-            scan_templates(e, tx, m, in_idx, g, flags, spk, spk_len, leg)
-                ? 2 : 1;
+            scan_templates(e, d, in_idx, g, flags, spk, spk_len, leg) ? 2 : 1;
         return OK;
     }
     // DUP HASH160 <h20> EQUALVERIFY collapse
@@ -849,42 +1053,53 @@ static long scan_input(Engine& e, const PTx& tx, const TxMidstates& m,
         }
     }
     // check_pubkey_encoding
-    if (flags & F_STRICTENC) {
-        bool ok = (pub_len == 33 && (pub[0] == 2 || pub[0] == 3)) ||
-                  (pub_len == 65 && pub[0] == 4);
-        if (!ok) return E_S_PUBKEYTYPE;
-    }
+    if ((flags & F_STRICTENC) && !strict_key_form(pub, pub_len))
+        return E_S_PUBKEYTYPE;
     // check_sig: empty sig -> parse fails -> False -> eval-false (empty sig
     // is exempt from NULLFAIL's nullfail code, scriptcheck.py:110-113)
     if (sig_len == 0) return E_S_EVAL_FALSE;
-    // non-forkid hashtype without STRICTENC would take the legacy sighash;
-    // the fast scan only models the forkid digest — send it to Python
     uint8_t ht = sig[sig_len - 1];
-    if (!(flags & F_FORKID) || !(ht & SIGHASH_FORKID)) {
+    // what a failed check is: a script error under NULLFAIL, else the false
+    // that OP_CHECKSIG leaves as the script's last word
+    const long failed =
+        (flags & F_NULLFAIL) ? E_S_SIG_NULLFAIL : E_S_EVAL_FALSE;
+    if (!(flags & F_STRICTENC)) {
+        // what the era's interpreter takes and the scan does not model goes
+        // to it: an undefined hashtype, a key in another form than
+        // STRICTENC's two, loose DER (and 65 bytes, Schnorr's by length)
+        if (!hashtype_modelled(ht, flags) ||
+            !strict_key_form(pub, pub_len) || sig_len == 65 ||
+            !valid_sig_encoding(sig, sig_len)) {
+            e.sig_status[g] = 1;
+            return OK;
+        }
+    }
+    bool legacy = !((flags & F_FORKID) && (ht & SIGHASH_FORKID));
+    // the one push FindAndDelete could cut out of this script code is the
+    // 20 bytes of its key hash: a signature of that length is the
+    // interpreter's
+    if (legacy && sig_len == 20) {
         e.sig_status[g] = 1;
         return OK;
     }
-    // pubkey parse (decompress): failure -> check_sig False -> NULLFAIL
+    // pubkey parse (decompress): failure -> check_sig False
     uint8_t pub64[64];
-    if (!bcp_pubkey_parse(pub, long(pub_len), pub64))
-        return E_S_SIG_NULLFAIL;
-    // DER decode r, s (structure already validated if STRICTENC/DERSIG;
-    // without those flags a malformed DER fails decode -> NULLFAIL)
-    if (!valid_sig_encoding(sig, sig_len)) return E_S_SIG_NULLFAIL;
+    if (!bcp_pubkey_parse(pub, long(pub_len), pub64)) return failed;
+    // DER decode r, s (structure validated above: under STRICTENC by
+    // check_signature_encoding's rule, else by the scan's own)
     uint32_t len_r = sig[3];
     uint32_t len_s = sig[5 + len_r];
     uint8_t r32[32], s32[32];
     if (!der_int_to_32(sig + 4, len_r, r32) ||
         !der_int_to_32(sig + 6 + len_r, len_s, s32))
-        return E_S_SIG_NULLFAIL;
+        return failed;
     // range: 1 <= r, s < N (DeferringSignatureChecker.check_sig)
     if (is_zero256(r32) || is_zero256(s32) ||
         cmp256(r32, SECP_N) >= 0 || cmp256(s32, SECP_N) >= 0)
-        return E_S_SIG_NULLFAIL;
+        return failed;
     // sighash + record emit
     uint8_t msg[32];
-    sighash_forkid(tx, m, in_idx, ht, spk, spk_len,
-                   e.spent_values[g], msg);
+    sighash(d, in_idx, ht, flags, spk, spk_len, e.spent_values[g], msg);
     memcpy(e.sig_msg.data() + 32 * g, msg, 32);
     memcpy(e.sig_rs.data() + 64 * g, r32, 32);
     memcpy(e.sig_rs.data() + 64 * g + 32, s32, 32);
@@ -1353,6 +1568,7 @@ long bcp_engine_connect_block(
     // ---- signature scan (before commit: a script error must leave the
     // map untouched, exactly like the Python path's scratch view) ----
     e.sigscan_ns = 0;
+    e.scan = ScanCounters();
     if (want_sigs && n_inputs > 0) {
         auto scan_t0 = std::chrono::steady_clock::now();
         e.sig_status.assign(size_t(n_inputs), 1);
@@ -1370,33 +1586,32 @@ long bcp_engine_connect_block(
         // first error by (tx, input) order wins, deterministically
         std::atomic<long> first_err_pos{-1};
         std::vector<long> err_codes(size_t(n_inputs), 0);
-        auto work = [&](long t_begin, long t_end, LegLanes& leg) {
-            TxMidstates m;
-            for (long i = t_begin; i < t_end; i++) {
+        // each thread counts for itself; the sums are taken after the join
+        auto work = [&](long t_begin, long t_end, LegLanes& leg,
+                        ScanCounters& counters) {
+            auto w0 = std::chrono::steady_clock::now();
+            bool stop = false;  // a thread stops at its first error
+            for (long i = t_begin; i < t_end && !stop; i++) {
                 PTx& tx = txs[i];
-                bool have_mid = false;
-                for (uint32_t vi = 0; vi < tx.vin.size(); vi++) {
+                TxDigests d(tx, counters);
+                for (uint32_t vi = 0; vi < tx.vin.size() && !stop; vi++) {
                     uint32_t gg = tx.in_base + vi;
                     e.sig_txin[2 * gg] = uint32_t(i);
                     e.sig_txin[2 * gg + 1] = vi;
-                    if (!have_mid) {
-                        compute_midstates(tx, m);
-                        have_mid = true;
-                    }
-                    long rc = scan_input(e, tx, m, vi, gg, script_flags,
-                                         leg);
+                    long rc = scan_input(e, d, vi, gg, script_flags, leg);
                     if (rc != OK) {
                         err_codes[gg] = rc;
                         long cur = first_err_pos.load();
                         while ((cur == -1 || long(gg) < cur) &&
                                !first_err_pos.compare_exchange_weak(cur, long(gg))) {}
-                        return;  // this thread stops at its first error
+                        stop = true;
                     }
                 }
             }
+            counters.thread_ns += ns_since(w0);
         };
         if (nt <= 1) {
-            work(1, n_tx, e.leg);
+            work(1, n_tx, e.leg, e.scan);
         } else {
             // static partition by input count for balance
             std::vector<std::thread> th;
@@ -1414,15 +1629,15 @@ long bcp_engine_connect_block(
             bounds.push_back(n_tx);
             // each thread's template lanes, joined in input order
             std::vector<LegLanes> legs(bounds.size() - 1);
+            std::vector<ScanCounters> counters(bounds.size() - 1);
             for (size_t t = 0; t + 1 < bounds.size(); t++)
                 th.emplace_back(work, bounds[t], bounds[t + 1],
-                                std::ref(legs[t]));
+                                std::ref(legs[t]), std::ref(counters[t]));
             for (auto& t : th) t.join();
             for (const LegLanes& leg : legs) e.leg.append(leg);
+            for (const ScanCounters& c : counters) e.scan.add(c);
         }
-        e.sigscan_ns = uint64_t(
-            std::chrono::duration_cast<std::chrono::nanoseconds>(
-                std::chrono::steady_clock::now() - scan_t0).count());
+        e.sigscan_ns = ns_since(scan_t0);
         long fe = first_err_pos.load();
         if (fe >= 0) {
             long code = err_codes[size_t(fe)];
@@ -1453,6 +1668,32 @@ long bcp_engine_connect_block(
 // the bench attributes this to the sig leg, not the byte leg.
 uint64_t bcp_engine_sigscan_ns(void* ep) {
     return static_cast<Engine*>(ep)->sigscan_ns;
+}
+
+// The last successful connect's scan counters (ScanCounters): legacy
+// digests, the bytes they hashed, nanoseconds of the scan's threads inside
+// them, and the threads' nanoseconds in the scan as a whole.
+void bcp_engine_scan_counters(void* ep, uint64_t out[4]) {
+    const ScanCounters& c = static_cast<Engine*>(ep)->scan;
+    out[0] = c.legacy_digests;
+    out[1] = c.legacy_bytes;
+    out[2] = c.legacy_ns;
+    out[3] = c.thread_ns;
+}
+
+// sighash_legacy over one serialised transaction, for the differential
+// tests (tests/unit/test_prefork_lanes.py): the bytes it hashed, or -1 where
+// the transaction does not parse.
+long bcp_sighash_legacy(const uint8_t* raw, size_t raw_len, uint32_t in_idx,
+                        const uint8_t* code, uint32_t code_len,
+                        uint32_t hashtype, uint8_t* out32) {
+    WireReader r{raw, raw_len};
+    PTx tx;
+    if (!parse_tx(r, tx) || r.pos != raw_len || tx.vin.empty()) return -1;
+    ScanCounters counters;
+    TxDigests d(tx, counters);
+    sighash_legacy(d, in_idx, hashtype, code, code_len, out32);
+    return long(counters.legacy_bytes);
 }
 
 // Apply / discard a connect(commit=0)'s staged overlay.

@@ -126,3 +126,77 @@ class TestBatchProduct:
         vals = _rand_elems(rng, 8)
         limbs = muhash._to_limbs(vals)
         assert [muhash._from_limbs(limbs[i]) for i in range(8)] == vals
+
+
+class TestNativeBackend:
+    """native/muhash.cpp against the python-int specification: the product
+    (48 limbs, the fold, threads) and the hash-to-group beside it."""
+
+    @pytest.fixture(autouse=True)
+    def _needs_library(self):
+        from bitcoincashplus_tpu import native
+
+        if not native.available():
+            pytest.skip("native library unavailable")
+        self.native = native
+
+    @pytest.mark.parametrize("n", [0, 1, 2, 63, 64, 255, 256, 257, 3000])
+    def test_product_matches_reference(self, n):
+        rng = random.Random(200 + n)
+        vals = _rand_elems(rng, n)
+        assert self.native.muhash_product(vals) == \
+            muhash.batch_product_ref(vals)
+
+    def test_product_of_values_at_the_fold(self):
+        """Operands at and above p (any value below 2^3072 is taken),
+        partial products that straddle it, and the one bit the fold's own
+        addition can carry out."""
+        top = (1 << 3072) - 1
+        vals = [muhash.MUHASH_P - 1, muhash.MUHASH_P - 2, muhash.MUHASH_P,
+                muhash.MUHASH_P - muhash.MUHASH_C, top, top - 1, 1, 2, 3]
+        for k in range(1, len(vals) + 1):
+            assert self.native.muhash_product(vals[:k]) == \
+                muhash.batch_product_ref(vals[:k]), k
+        assert self.native.muhash_product([top] * 300) == \
+            muhash.batch_product_ref([top] * 300)
+
+    # SHAKE256's rate is 136 bytes: an empty row, one short of a block,
+    # a whole block, one over, and rows of several blocks
+    @pytest.mark.parametrize("size", [0, 1, 36, 61, 135, 136, 137, 271,
+                                      272, 273, 1000, 10000])
+    def test_element_product_matches_reference(self, size):
+        rng = random.Random(300 + size)
+        rows = [rng.randbytes(size) for _ in range(5)]
+        assert self.native.muhash_element_product(rows[:1]) == \
+            muhash.element(rows[0])
+        assert self.native.muhash_element_product(rows) == \
+            muhash.batch_product_ref(map(muhash.element, rows))
+
+    @pytest.mark.parametrize("n", [0, 1, 63, 64, 1000])
+    def test_coin_product_matches_coin_elements(self, n):
+        """Both sides of coin_product's floor, rows of mixed lengths."""
+        rng = random.Random(400 + n)
+        rows = [(rng.randbytes(36), rng.randbytes(rng.randint(4, 300)))
+                for _ in range(n)]
+        assert muhash.coin_product(iter(rows)) == muhash.batch_product_ref(
+            muhash.coin_element(k, ser) for k, ser in rows)
+
+    @pytest.mark.parametrize("n", [64, 500])
+    def test_dispatch_takes_the_library_and_matches(self, n, monkeypatch):
+        rng = random.Random(500 + n)
+        vals = _rand_elems(rng, n)
+        calls = []
+        real = self.native.muhash_product
+        monkeypatch.setattr(self.native, "muhash_product",
+                            lambda v: calls.append(len(v)) or real(v))
+        assert muhash.batch_product(vals) == muhash.batch_product_ref(vals)
+        assert calls == [n]
+
+    def test_without_the_library_the_python_ints_answer(self, monkeypatch):
+        monkeypatch.setattr(muhash, "_native", lambda: None)
+        rng = random.Random(6)
+        rows = [(rng.randbytes(36), rng.randbytes(30)) for _ in range(80)]
+        assert muhash.coin_product(rows) == muhash.batch_product_ref(
+            muhash.coin_element(k, ser) for k, ser in rows)
+        vals = _rand_elems(rng, 80)
+        assert muhash.batch_product(vals) == muhash.batch_product_ref(vals)

@@ -544,16 +544,25 @@ def test_native_template_declines_and_the_interpreter_decides(name):
 
 
 @needs_engine
-@pytest.mark.parametrize("without", [
-    SCRIPT_VERIFY_NULLFAIL, SCRIPT_ENABLE_SIGHASH_FORKID, SCRIPT_VERIFY_P2SH],
-    ids=["nullfail", "forkid", "p2sh"])
-def test_native_templates_need_the_flags_that_let_the_leg_defer(without):
-    """Without NULLFAIL nothing may defer; without FORKID the digest is not
-    the one the scan computes; without P2SH a redeem script is not run."""
+@pytest.mark.parametrize("without,fits", [
+    (SCRIPT_VERIFY_NULLFAIL, True), (SCRIPT_ENABLE_SIGHASH_FORKID, False),
+    (SCRIPT_VERIFY_P2SH, False)], ids=["nullfail", "forkid", "p2sh"])
+def test_native_templates_need_the_flags_that_let_the_leg_defer(without,
+                                                                fits):
+    """NULLFAIL is not among them: the check is the script's last
+    operation, so its false is the script's (tests/unit/
+    test_prefork_lanes.py). Without FORKID under STRICTENC the hashtype is
+    illegal and the interpreter's to refuse; without P2SH a redeem script
+    is not run."""
     script_sig, spk = _two_of_three([KEYS[0], KEYS[2]])
-    assert native_scan(script_sig, spk)[0] == 2
+    with_all = native_scan(script_sig, spk)
+    assert with_all[0] == 2
     status, lanes, rows = native_scan(script_sig, spk, FLAGS & ~without)
-    assert status == 1 and rows == [] and len(lanes[4]) == 0
+    if fits:
+        assert status == 2
+        assert_lanes_equal((lanes, rows), with_all[1:])
+    else:
+        assert status == 1 and rows == [] and len(lanes[4]) == 0
 
 
 # -- the walk -----------------------------------------------------------------
@@ -748,7 +757,7 @@ def faulted_chain(tmp_path_factory):
     return datadir, _generate(datadir, "--fault", "wrong-key-multisig")
 
 
-def _reindex(chain_dir, tmp_path):
+def _reindex(chain_dir, tmp_path, *extra):
     """Node(-regtest -tpu=0 -reindex) over a copy of the chain's block
     files; returns (node, what gettpuinfo.batch moved by)."""
     from bitcoincashplus_tpu.node.config import Config
@@ -762,14 +771,15 @@ def _reindex(chain_dir, tmp_path):
             shutil.copy(os.path.join(src, leaf), blocks)
     config = Config()
     config.parse_args(["-regtest", "-tpu=0", "-reindex", "-listen=0",
-                       "-flushinterval=1000000", f"-datadir={tmp_path}"])
+                       "-flushinterval=1000000", f"-datadir={tmp_path}",
+                       *extra])
     before = ecdsa_batch.STATS.snapshot()
     node = Node(config)
     after = ecdsa_batch.STATS.snapshot()
     return node, {k: after[k] - before[k] for k in (
         "eager_multisig_sigs", "multisig_groups", "multisig_lanes",
         "multisig_group_confirms", "reject_confirm_sigs",
-        "cpu_fallback_sigs")}
+        "cpu_fallback_sigs", "inline_legacy_sigs", "prefork_lanes")}
 
 
 def _tip(node) -> tuple:
@@ -800,7 +810,8 @@ def test_generated_chain_reindexes_to_the_references_answer(
         "eager_multisig_sigs": 0, "multisig_groups": gen["multisig_groups"],
         "multisig_lanes": gen["multisig_lanes"],
         "multisig_group_confirms": 0, "reject_confirm_sigs": 0,
-        "cpu_fallback_sigs": LANES}  # -tpu=0: the batch is the CPU's
+        "cpu_fallback_sigs": LANES,  # -tpu=0: the batch is the CPU's
+        "inline_legacy_sigs": 0, "prefork_lanes": 0}
     assert stats["slow_path_blocks"] == 0
     assert stats["fallback_inputs"] == gen["non_p2pkh_inputs"]
     assert stats["fast_inputs"] == gen["inputs_by_kind"]["p2pkh"]
@@ -1035,40 +1046,38 @@ def test_reindex_aborts_on_a_group_no_dispatch_settles(
     assert moved["eager_multisig_sigs"] > 0
 
 
-def test_history_below_the_fork_height_never_reaches_the_batch(
-        tmp_path, monkeypatch):
+def test_history_below_the_fork_height_stays_on_the_native_engine(tmp_path):
     """The same chain signed as history from before the fork has it
-    (SIGHASH_ALL, no FORKID), under parameters whose fork height it never
-    reaches: its blocks carry no NULLFAIL, a failed check may push false
-    there, so nothing may defer. The native engine declines every block,
-    the Python engine verifies every signature inline on the host, and no
-    multisig group and no lane is made."""
-    import dataclasses
-
-    from bitcoincashplus_tpu.consensus import params
-
-    regtest = params.regtest_params()
-    before_fork = dataclasses.replace(regtest, consensus=dataclasses.replace(
-        regtest.consensus, uahf_height=10**9))
-    monkeypatch.setitem(params._NETWORKS, "regtest", lambda: before_fork)
+    (SIGHASH_ALL, no FORKID) under a fork height it never reaches
+    (-uahfheight): its blocks carry no NULLFAIL, but in each of its four
+    script forms the check is the script's last operation, so every input
+    rides lanes over the legacy digest as the chain above the fork does over
+    FORKID's. No block leaves the native engine, no check runs inline."""
     chain_dir = tmp_path / "chain"
     gen = _generate(chain_dir, "--legacy-sighash")
-    inline0 = ecdsa_batch.STATS.inline_legacy_sigs
-    node, moved = _reindex(chain_dir, tmp_path / "node")
+    node, moved = _reindex(chain_dir, tmp_path / "node",
+                           "-uahfheight=1000000000")
     try:
         assert _tip(node) == (gen["tip_height"], gen["tip_hash"],
                               gen["txouts"])
         stats = node.last_import_stats
     finally:
         node.close()
-    assert stats["slow_path_blocks"] == gen["tip_height"]
-    assert stats["blocks"] == 0  # none through the native engine
-    assert stats["fast_inputs"] == stats["fallback_inputs"] == 0
-    assert moved == dict.fromkeys(moved, 0)
-    # every key trial of every walk, not one a signature: the 2-of-3s'
-    # signer sets cost 3, 3 and 2 trials (the walk starts at the last key)
-    trials = ecdsa_batch.STATS.inline_legacy_sigs - inline0
-    assert gen["sigs"] < trials <= gen["device_lanes"]
+    assert stats["slow_path_blocks"] == 0
+    assert stats["blocks"] == stats["prefork_blocks"] == gen["tip_height"]
+    assert stats["fast_inputs"] == gen["inputs_by_kind"]["p2pkh"]
+    assert (stats["fallback_inputs"], stats["template_inputs"],
+            stats["interp_inputs"]) == (
+        gen["non_p2pkh_inputs"], gen["non_p2pkh_inputs"], 0)
+    assert stats["inline_legacy_sigs"] == 0
+    # one legacy digest an input: a 2-of-3's two signatures share theirs
+    assert stats["legacy_digests"] == gen["inputs"]
+    assert moved == {
+        "eager_multisig_sigs": 0, "multisig_groups": gen["multisig_groups"],
+        "multisig_lanes": gen["multisig_lanes"],
+        "multisig_group_confirms": 0, "reject_confirm_sigs": 0,
+        "cpu_fallback_sigs": LANES, "inline_legacy_sigs": 0,
+        "prefork_lanes": LANES}
 
 
 def test_reindex_of_a_wrong_key_multisig_names_the_block(

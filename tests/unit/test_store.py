@@ -156,3 +156,61 @@ def test_concurrent_write_batches_serialize(tmp_path):
     assert not errors, errors
     assert kv.get(b"\x00\x00") is not None
     kv.close()
+
+
+@pytest.mark.parametrize("stores", [2, 4, 8])
+def test_stores_written_at_once_take_turns_at_their_rows(tmp_path, stores):
+    """The coins shards' flush pool: batches written to several stores at
+    the same time bind their rows one batch at a time (kvstore._ROWS_LOCK:
+    together the threads hand the GIL over once a row), and each store
+    ends with its own rows, its deletes applied, nobody else's."""
+    import threading
+
+    from bitcoincashplus_tpu.store import kvstore
+
+    kvs = [KVStore(str(tmp_path / f"kv{t}.sqlite")) for t in range(stores)]
+    for t, kv in enumerate(kvs):
+        kv.write_batch({bytes([t]) + b"old" + bytes([i]): b"x"
+                        for i in range(50)})
+    seen = []
+    held = kvstore._ROWS_LOCK
+    real = held.acquire
+
+    class Watch:
+        """The lock, noting how many threads are inside it at a time."""
+
+        def __init__(self):
+            self.inside = 0
+
+        def __enter__(self):
+            real()
+            self.inside += 1
+            seen.append(self.inside)
+
+        def __exit__(self, *exc):
+            self.inside -= 1
+            held.release()
+
+    kvstore._ROWS_LOCK = Watch()
+    try:
+        def writer(t: int):
+            kvs[t].write_batch(
+                {bytes([t]) + i.to_bytes(2, "big"): bytes([t]) * 8
+                 for i in range(2000)},
+                [bytes([t]) + b"old" + bytes([i]) for i in range(50)],
+                sync=True)
+
+        threads = [threading.Thread(target=writer, args=(t,))
+                   for t in range(stores)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join()
+    finally:
+        kvstore._ROWS_LOCK = held
+    assert seen == [1] * stores
+    for t, kv in enumerate(kvs):
+        rows = list(kv.iterate())
+        assert len(rows) == 2000
+        assert all(k[0] == t and v == bytes([t]) * 8 for k, v in rows)
+        kv.close()
