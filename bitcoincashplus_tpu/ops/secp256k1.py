@@ -17,8 +17,10 @@ Design (SURVEY.md §8.4 "ECDSA batch"):
     whole 256-step verify loop compiles in seconds (a fully unrolled SoA
     form measured 15s of XLA compile per single field-mul — unusable).
   - Magnitude discipline (stated per function):
-      "weak"  = 13-bit limbs (top limb <= 0x1FF + eps), value < p + 2^33
-      "loose" = limbs < 2^15 (add/sub outputs) — f_carry before multiplying
+      "weak"  = limbs <= 2^13 + eps (8,200 at most; top limb <= 0x1FF),
+                value < 2^256 + 2^238 < 2p
+      "loose" = limbs < 2^16 (sums and differences of weak values) —
+                f_carry_loose before multiplying
   - Jacobian points, branchless-complete add/double via jnp.where selects.
   - Verify needs NO field inversion: u1*G + u2*Q is compared via
     X_R == (r + k*n) * Z_R^2 for k in {0,1} (x-wraparound case included).
@@ -151,20 +153,16 @@ def field_parallel() -> bool:
 
 def _pcarry_round(v):
     """One parallel carry round: out[j] = (v[j] & MASK) + (v[j-1] >> 13).
-    Width grows by one row (the top carry). From any magnitude < 2^31,
-    three rounds converge to limbs <= 2^13 + 2:
-        R1 <= 2^13-1 + 2^18,  R2 <= 2^13-1 + 2^5.1,  R3 <= 2^13 + 2."""
+    Width grows by one row (the top carry); the value is unchanged. A row
+    of magnitude m leaves its neighbour at most MASK + (m >> 13), so from
+    any magnitude < 2^31:
+        R1 <= 2^13-1 + 2^18 - 1 = 270,334,  R2 <= 2^13-1 + 32 = 8,223,
+    and from rows < 2^24 (a fold's output): R1 <= 10,238, R2 <= 8,192."""
     z1 = jnp.zeros_like(v[:1])
     return (
         jnp.concatenate([v & MASK, z1], axis=0)
         + jnp.concatenate([z1, v >> np.uint32(LIMB_BITS)], axis=0)
     )
-
-
-def _carry3(v):
-    for _ in range(3):
-        v = _pcarry_round(v)
-    return v
 
 
 def _pad_rows(x, before: int, width: int):
@@ -212,15 +210,57 @@ def _weaken_parallel(limbs20):
 
 
 def _f_carry_parallel(limbs) -> jnp.ndarray:
-    """Parallel-form normalize: {3 carry rounds; fold} x 3 + weaken.
-    Width trajectory from 39: 42 -> fold 24 -> 27 -> fold 20 -> 23 ->
-    fold 20 -> 23 -> final fold/trim 20."""
+    """Parallel-form normalize of any accumulation ((L, B), L in [20, 39],
+    limbs < 2^31; sized by f_mul's 39 product columns): {2 carry rounds;
+    fold} x 3 + weaken. Carry rounds keep the value, a fold keeps it mod p.
+    Width from 39: 41 -> fold 23 -> 25 -> fold 20 -> 22 -> fold 20.
+
+      input    V0 < 2^31 * sum_j 2^(13j), j < 39          < 2^525.001
+      R, R     rows <= 270,334 then <= 8,223 (_pcarry_round)
+      fold 1   hi = rows 20.. <= V0 / 2^260 < 2^265.001, 8,223 * 15,632
+               < 2^27; rows <= 8,223 + 8,191 + 15,691 + 8,223 * 2^10
+               < 2^23.02;  V1 < hi * (2^36 + 15,632) + 1.004 * 2^260
+                                                           < 2^301.01
+      R, R     rows <= 9,222 then <= 8,192
+      fold 2   hi <= V1 / 2^260 < 2^41.01; rows < 2^23.01;
+               V2 < 8,192 * sum_j 2^(13j) (j < 20) + hi * (2^36 + 15,632)
+                  < 2^260 + 2^247.001 + 2^77.02
+      R, R     rows <= 9,218 then <= 8,192; row 21 is zero
+      fold 3   hi = row 20 <= V2 / 2^260 < 1 + 2^-12.9, so hi is 0 or 1:
+               the fold adds at most 7,441 / 1 / 1,024 to rows 0 / 1 / 2
+               and nothing to row 19.
+
+    No round between fold 3 and the weaken: row 19 <= 8,192 gives h <= 16,
+    the head it builds is <= 8,192 + 7,441 + 16 * 977 = 31,265 in row 0 and
+    <= 8,192 + 1,024 + 16 * 64 = 10,240 in row 2, and _weaken_parallel's
+    two head rounds settle that to <= 8,192 with a carry <= 2 into row 5.
+    Output weak: rows <= 8,194, top row <= 0x1FF, value < 2^256 + 2^238
+    < 2p. Every bound falls with L, so any L in [20, 39] holds."""
     v = limbs
     for _ in range(3):
-        v = _fold_parallel(_carry3(v))
-    v = _fold_parallel(_carry3(v))
-    v = _carry3(v)
-    v = _fold_parallel(v)[:N_LIMBS]
+        v = _fold_parallel(_pcarry_round(_pcarry_round(v)))
+    return _weaken_parallel(v)
+
+
+def _f_carry_loose_parallel(limbs20) -> jnp.ndarray:
+    """Parallel-form normalize of a (20, B) sum or difference of weak
+    values, limbs < 2^16: one carry round, one fold, weaken.
+
+      R        rows <= 8,191 + 7 = 8,198; row 20 (the carry out of row 19)
+               <= 7
+      fold     hi = row 20: 7 * 15,632 < 2^17 adds <= 8,191 / 13 / 7,168 to
+               rows 0 / 1 / 2 and nothing to row 19; 20 rows, no row left
+               above them, so the value is whole in 20 limbs
+      weaken   row 19 <= 8,198 gives h <= 16; the head is <= 8,198 + 8,191
+               + 16 * 977 = 32,021 in row 0 and <= 8,198 + 7,168 + 1,024
+               = 16,390 in row 2; its two rounds settle that to <= 8,192
+               with a carry <= 2 into row 5.
+
+    Output weak: rows <= 8,200, top row <= 0x1FF, value < 2^256 + 2^238
+    < 2p. The callers' inputs, worst first (W = 8,200, a weak row; 16,382
+    the largest row of _BIAS_2P): 4W = 32,800 (pt_double's 4C), 3W (its
+    3A), W + 16,382 (f_sub), 2W (every other f_add), 16,382 (_f_neg)."""
+    v = _fold_parallel(_pcarry_round(limbs20))
     return _weaken_parallel(v)
 
 
@@ -288,8 +328,19 @@ def f_sub(a, b):
     return a + _BIAS_2P - b
 
 
+def f_carry_loose(limbs20) -> jnp.ndarray:
+    """Normalize a (20, B) sum or difference of weak values to weak form.
+    REQUIRES limbs < 2^16 (a handful of weak values added, or one f_sub):
+    the chip's form spends one carry round where f_carry, which must take
+    any magnitude < 2^31, spends six. A function of its own because a
+    magnitude cannot be read off a shape."""
+    if field_parallel():
+        return _f_carry_loose_parallel(limbs20)
+    return f_carry(limbs20)
+
+
 def f_carry_sub(a, b):
-    return f_carry(f_sub(a, b))
+    return f_carry_loose(f_sub(a, b))
 
 
 # ---- canonical form & comparisons ----
@@ -341,7 +392,7 @@ def _exact_norm20(v):
     20 parallel single-carry rounds: a carry unit ripples at most one row
     per round, and from weak input every row is <= MASK + 1 after round 1,
     so 20 rounds fully settle. Row-19 overflow is impossible (weak top
-    limb <= 0x1FF + eps, value < p + 2^33 < 2^257). Scan-free on purpose:
+    limb <= 0x1FF, value < 2^256 + 2^238 < 2^257). Scan-free on purpose:
     this runs inside the Pallas verify kernel where lax.scan cannot lower."""
     for _ in range(N_LIMBS):
         c = v >> np.uint32(LIMB_BITS)
@@ -354,7 +405,7 @@ def _exact_norm20(v):
 def f_is_zero(a_weak, keepdims: bool = False):
     if field_parallel():
         # exact normalization, then value in {0, p} <=> zero mod p
-        # (weak value < p + 2^33 < 2p, and the 13-bit form is unique)
+        # (weak value < 2^256 + 2^238 < 2p, and the 13-bit form is unique)
         v = _exact_norm20(a_weak)
         p_limbs = jnp.broadcast_to(_P_CONST, v.shape).astype(jnp.uint32)
         z0 = jnp.all(v == 0, axis=0, keepdims=keepdims)
@@ -401,18 +452,18 @@ def pt_double(pt: dict) -> dict:
     A = f_sqr(X)
     Bb = f_sqr(Y)
     Cc = f_sqr(Bb)
-    D = f_sqr(f_carry(f_add(X, Bb)))
-    D = f_carry_sub(D, f_carry(f_add(A, Cc)))
-    D = f_carry(f_add(D, D))
-    E = f_carry(f_add(f_add(A, A), A))
+    D = f_sqr(f_carry_loose(f_add(X, Bb)))
+    D = f_carry_sub(D, f_carry_loose(f_add(A, Cc)))
+    D = f_carry_loose(f_add(D, D))
+    E = f_carry_loose(f_add(f_add(A, A), A))
     F = f_sqr(E)
-    X3 = f_carry_sub(F, f_carry(f_add(D, D)))
+    X3 = f_carry_sub(F, f_carry_loose(f_add(D, D)))
     Y3 = f_mul(E, f_carry_sub(D, X3))
-    C4 = f_carry(f_add(f_add(Cc, Cc), f_add(Cc, Cc)))
-    C8 = f_carry(f_add(C4, C4))
+    C4 = f_carry_loose(f_add(f_add(Cc, Cc), f_add(Cc, Cc)))
+    C8 = f_carry_loose(f_add(C4, C4))
     Y3 = f_carry_sub(Y3, C8)
     YZ = f_mul(Y, Z)
-    Z3 = f_carry(f_add(YZ, YZ))
+    Z3 = f_carry_loose(f_add(YZ, YZ))
     return {"X": X3, "Y": Y3, "Z": Z3, "inf": pt["inf"]}
 
 
@@ -438,7 +489,8 @@ def pt_add_mixed(pt: dict, qx, qy, q_inf, mask2d: bool = False) -> dict:
     HH = f_sqr(H)
     HHH = f_mul(H, HH)
     V = f_mul(X, HH)
-    X3 = f_carry_sub(f_sqr(R), f_carry(f_add(HHH, f_carry(f_add(V, V)))))
+    X3 = f_carry_sub(
+        f_sqr(R), f_carry_loose(f_add(HHH, f_carry_loose(f_add(V, V)))))
     Y3 = f_carry_sub(f_mul(R, f_carry_sub(V, X3)), f_mul(Y, HHH))
     Z3 = f_mul(Z, H)
     out = {"X": X3, "Y": Y3, "Z": Z3, "inf": opposite}
@@ -562,7 +614,8 @@ def _pt_add_mixed_cheap_u(pt: dict, qx, qy, q_inf_u, one):
     HH = f_sqr(H)
     HHH = f_mul(H, HH)
     V = f_mul(X, HH)
-    X3 = f_carry_sub(f_sqr(R), f_carry(f_add(HHH, f_carry(f_add(V, V)))))
+    X3 = f_carry_sub(
+        f_sqr(R), f_carry_loose(f_add(HHH, f_carry_loose(f_add(V, V)))))
     Y3 = f_carry_sub(f_mul(R, f_carry_sub(V, X3)), f_mul(Y, HHH))
     Z3 = f_mul(Z, H)
     out = {"X": X3, "Y": Y3, "Z": Z3,
@@ -596,7 +649,8 @@ def _pt_add_full_cheap_u(pt: dict, q: dict):
     HH = f_sqr(H)
     HHH = f_mul(H, HH)
     V = f_mul(U1, HH)
-    X3 = f_carry_sub(f_sqr(R), f_carry(f_add(HHH, f_carry(f_add(V, V)))))
+    X3 = f_carry_sub(
+        f_sqr(R), f_carry_loose(f_add(HHH, f_carry_loose(f_add(V, V)))))
     Y3 = f_carry_sub(f_mul(R, f_carry_sub(V, X3)), f_mul(S1, HHH))
     Z3 = f_mul(f_mul(Z1, Z2), H)
     out = {"X": X3, "Y": Y3, "Z": Z3, "inf": jnp.zeros_like(pt["inf"])}
@@ -1005,7 +1059,7 @@ def _glv_comb() -> tuple:
 def _f_neg(y):
     """-y mod p for weak y: (0 + 2p − y) via the redistributed bias, then
     carry — weak output."""
-    return f_carry(_BIAS_2P - y)
+    return f_carry_loose(_BIAS_2P - y)
 
 
 def _glv_q_tables(qx, qy, ydiff_u, q_inf_u, one):
@@ -1499,7 +1553,8 @@ def pt_add_full(pt: dict, q: dict) -> dict:
     HH = f_sqr(H)
     HHH = f_mul(H, HH)
     V = f_mul(U1, HH)
-    X3 = f_carry_sub(f_sqr(R), f_carry(f_add(HHH, f_carry(f_add(V, V)))))
+    X3 = f_carry_sub(
+        f_sqr(R), f_carry_loose(f_add(HHH, f_carry_loose(f_add(V, V)))))
     Y3 = f_carry_sub(f_mul(R, f_carry_sub(V, X3)), f_mul(S1, HHH))
     Z3 = f_mul(f_mul(Z1, Z2), H)
     out = {"X": X3, "Y": Y3, "Z": Z3, "inf": opposite}

@@ -163,9 +163,27 @@ def test_glv_window_step_lowers_without_gather_for_v5e(one_chip, chip_forms):
     assert "stablehlo.gather" not in text
 
 
+@pytest.mark.parametrize("op,ceiling", [("f_mul", 26), ("f_carry_sub", 12)])
+def test_field_op_launch_count_for_v5e(one_chip, chip_forms, op, ceiling):
+    """The ladder's time on the chip follows its launches (0.63 us each,
+    137k a dispatch until PR 33), and nine in ten of them were the field
+    normaliser's carry rounds. Compiled for the chip at the node's bucket,
+    a multiplication is 24 fusions (4 the schoolbook, 20 the product
+    normaliser; 37 before PR 33) and a normalised difference 10 (one round,
+    one fold, the weaken; 32 before): an edit that grows the normaliser
+    again fails here and not on the chip."""
+    from bitcoincashplus_tpu.ops import secp256k1 as dev
+
+    limbs = jax.ShapeDtypeStruct((dev.N_LIMBS, 8192), jnp.uint32,
+                                 sharding=one_chip)
+    text = jax.jit(getattr(dev, op)).lower(limbs, limbs).compile().as_text()
+    assert 0 < text.count(" fusion(") <= ceiling
+
+
 # the node's reindex buckets (node.py _import_block_files_native); compile
-# seconds measured on this 8-core sandbox for PR 22: 215 / 228 / 242 s
-@pytest.mark.slow(reason="AOT compile 215-242 s per bucket (PR 22, sandbox)")
+# seconds measured on this 8-core sandbox for PR 22: 215 / 228 / 242 s;
+# 114 s at 8,192 since PR 33 halved the field normaliser's launches
+@pytest.mark.slow(reason="AOT compile minutes per bucket (sandbox)")
 @pytest.mark.parametrize("bucket", [1024, 2048, 8192])
 def test_glv_verify_bucket_compiles_for_v5e(one_chip, chip_forms, bucket):
     """The DEFAULT verify kernel (fused decompose + GLV ladder) fits one

@@ -500,21 +500,23 @@ DRIFT_BUDGET = 0.10
 # run lowers differently and reports without flagging until a baseline
 # for that arrangement is recorded here.
 COST_BASELINES = {
-    "cpu": {"ecdsa_w4_bytes": 1_618_602.0,
-            # the GLV decompose+verify program, recorded with the §7
-            # census of PR 29 (jax 0.9.0) — the parallel-form lowering's
-            # whole-program flop accounting weighs the unrolled carry
-            # rounds far above their census primitive count, which is
-            # exactly why drift is per kernel against its OWN twin
-            "ecdsa_glv_decompose": 4_052_615.0,
+    # the three ECDSA programs were recorded again with the §7 census of
+    # PR 33 (jax 0.9.0), whose normalisers run 6 or 1 carry rounds where
+    # every call ran 15 (w4 1,618,602, GLV 4,052,615, MSM 3,716,708 before)
+    "cpu": {"ecdsa_w4_bytes": 992_871.0,
+            # the GLV decompose+verify program — the parallel-form
+            # lowering's whole-program flop accounting weighs the unrolled
+            # carry rounds far above their census primitive count, which
+            # is exactly why drift is per kernel against its OWN twin
+            "ecdsa_glv_decompose": 3_314_756.0,
             # Schnorr MSM batch check (ISSUE 19): compiled flops per
             # TERM-SLOT at bucket 64 (the whole batch-equation program's
             # flop count / 64 slots — the smallest, unit-test-priced
             # rung; bigger buckets amortize the fixed Horner epilogue so
             # their per-slot number is NOT comparable). §10's census
-            # counts 21.1k primitives/term at this shape — same units
+            # counts 26.1k primitives/term at this shape — same units
             # caveat as the fused decompose twin above
-            "ecdsa_msm": 3_716_708.0,
+            "ecdsa_msm": 2_334_039.0,
             # miner_resident compiled flops/nonce at tile 1024 (exact =
             # looped-compress lowering — the form a CPU backend compiles;
             # h7 = the fully-unrolled trace, which XLA's whole-program
