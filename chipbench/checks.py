@@ -25,10 +25,22 @@ def programs_compiled(before: dict, after: dict) -> dict:
 
 
 def no_fallback(before: dict, after: dict, *, sigs: int = 0,
-                lanes: int = 8190, cache_dir: str = None) -> list:
+                lanes: int = 8190, dispatches: int = None,
+                cache_dir: str = None) -> list:
     """Every way the window could have run somewhere else than on the TPU,
     from two gettpuinfo snapshots; returns the names of the checks that
-    failed, each with what was read."""
+    failed, each with what was read.
+
+    ``dispatches`` is the number of device dispatches the window has to
+    make, where the driver states one: it derives it from its reference's
+    replay of the block files and the configuration's flags (a flush every
+    n blocks drains the aggregate's tail into smaller buckets), never from
+    the program's counters. Without one the window is whole ``lanes``-wide
+    slices. Either way the count is held exactly."""
+    if dispatches is not None and (type(dispatches) is not int
+                                   or dispatches < 0):
+        raise TypeError(f"a stated dispatch count is a whole number, not "
+                        f"{dispatches!r}")
     bad: list = []
 
     def check(name: str, ok: bool, read) -> None:
@@ -58,9 +70,14 @@ def no_fallback(before: dict, after: dict, *, sigs: int = 0,
     check("dev_decompose.broken false", dd["broken"] is False, dd["broken"])
     check("dev_decompose.fallbacks did not move",
           dd["fallbacks"] == b_dd["fallbacks"], dd["fallbacks"])
-    check("dev_decompose.dispatches moved by the full buckets",
-          dd["dispatches"] - b_dd["dispatches"] == -(-sigs // lanes),
-          dd["dispatches"] - b_dd["dispatches"])
+    moved = dd["dispatches"] - b_dd["dispatches"]
+    if dispatches is None:
+        check("dev_decompose.dispatches moved by the full buckets",
+              moved == -(-sigs // lanes), moved)
+    else:
+        check("dev_decompose.dispatches moved by the count the driver "
+              "states", moved == dispatches,
+              {"moved": moved, "stated": dispatches})
     for name, br in after["breakers"].items():
         was = before["breakers"].get(name, {})
         check(f"breaker {name} closed, no fallbacks",

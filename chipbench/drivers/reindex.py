@@ -17,6 +17,7 @@ txs_per_block, fan_k, sample_sigs, rehearse.
 
 from __future__ import annotations
 
+import gc
 import json
 import os
 import shutil
@@ -98,6 +99,17 @@ def _datadir(ctx, cache: str, name: str) -> str:
     return os.path.join(ctx.workdir, name)
 
 
+def _settle_disk() -> float:
+    """The set-up's own writes go to disk before the window. It has just
+    copied the chain's block files into the work directory, and the
+    window's flush ends in a dozen fsyncs, which must not wait for them: a
+    node that reindexes finds its block files on disk. Returns the seconds
+    it took."""
+    t0 = time.monotonic()
+    os.sync()
+    return time.monotonic() - t0
+
+
 def _node(ctx, datadir: str):
     """The lines of cli/bcpd.main up to the end of the import."""
     from bitcoincashplus_tpu.node.config import Config
@@ -174,16 +186,61 @@ def warm(ctx) -> None:
         "main_generate_s": st["gen"].get("generate_s"),
         "main_cached": st["gen"]["cached"],
         "waited_for_generator_s": time.monotonic() - t0,
-        "blocks": st["gen"]["blocks"], "chain_bytes": st["gen"]["bytes"]}
+        "blocks": st["gen"]["blocks"], "chain_bytes": st["gen"]["bytes"],
+        "sync_s": _settle_disk()}
+
+
+class _GcClock:
+    """Seconds Python's cyclic collector ran inside a ``with`` block, and
+    how many of its passes were full ones."""
+
+    def __init__(self):
+        self.seconds, self.full, self._t0 = 0.0, 0, 0.0
+
+    def _note(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._t0 = time.perf_counter()
+        else:
+            self.seconds += time.perf_counter() - self._t0
+            self.full += info["generation"] == 2
+
+    def __enter__(self):
+        gc.callbacks.append(self._note)
+        return self
+
+    def __exit__(self, *exc):
+        gc.callbacks.remove(self._note)
+
+
+def _measured_import(ctx) -> tuple:
+    """The window itself: ``Node(config)`` over the measured data directory,
+    left in ``ctx.state["node"]``. Returns (host-clock seconds, where the
+    runs of a cell spread): what the one importing thread got of the window
+    (against the import's ``wall_s``: a stolen core shows as wall at the same
+    CPU seconds, a slower host as both going up) and what Python's collector
+    took. A full pass over the heap that tracing the verify program leaves
+    is ~0.55 s; a program that moves that heap out of the collector's reach
+    once the shape is traced (gc_frozen_objects in the millions, not the
+    interpreter's own few hundred) pays milliseconds a pass. The harness
+    sets nothing aside itself: bcpd pays what this process does."""
+    st = ctx.state
+    collected = _GcClock()
+    cpu0, all0, t0 = time.thread_time(), time.process_time(), time.monotonic()
+    with ctx.annotate("import"), collected:
+        st["node"] = _node(ctx, st["datadir"])
+    wall = time.monotonic() - t0
+    return wall, {"main_thread_cpu_s": time.thread_time() - cpu0,
+                  "process_cpu_s": time.process_time() - all0,
+                  "gc_s": collected.seconds,
+                  "gc_full_collections": collected.full,
+                  "gc_frozen_objects": gc.get_freeze_count()}
 
 
 def window(ctx) -> dict:
     st = ctx.state
     sigs = st["gen"]["sigs"]
-    t0 = time.monotonic()
-    with ctx.annotate("import"):
-        node = st["node"] = _node(ctx, st["datadir"])
-    wall = time.monotonic() - t0
+    wall, host = _measured_import(ctx)
+    node = st["node"]
     after = snapshot(node)
     before = st["before"]
     on_device = (after["batch"]["sigs_verified"]
@@ -198,7 +255,7 @@ def window(ctx) -> dict:
         "report": {"import": {k: stats.get(k) for k in (
             "blocks", "bytes", "wall_s", "native_connect_s", "sigscan_s",
             "verify_s", "flush_s", "slow_path_blocks", "fallback_inputs",
-            "fast_inputs")}, "sigs_on_device": on_device},
+            "fast_inputs")}, "sigs_on_device": on_device, **host},
     }
 
 

@@ -29,7 +29,6 @@ inputs_per_tx, block_bytes, keys, fan_k, sample_sigs, rehearse.
 
 from __future__ import annotations
 
-import gc
 import importlib.util
 import json
 import os
@@ -150,42 +149,16 @@ def warm(ctx) -> None:
         **{k: gen[k] for k in (
             "device_lanes", "inputs_by_kind", "multisig_groups",
             "multisig_sigs", "multisig_lanes", "signer_sets",
-            "padded_inputs", "max_block_bytes")}}
-
-
-class _GcClock:
-    """Seconds Python's cyclic collector ran inside a ``with`` block, and
-    how many of its passes were full ones."""
-
-    def __init__(self):
-        self.seconds, self.full, self._t0 = 0.0, 0, 0.0
-
-    def _note(self, phase: str, info: dict) -> None:
-        if phase == "start":
-            self._t0 = time.perf_counter()
-        else:
-            self.seconds += time.perf_counter() - self._t0
-            self.full += info["generation"] == 2
-
-    def __enter__(self):
-        gc.callbacks.append(self._note)
-        return self
-
-    def __exit__(self, *exc):
-        gc.callbacks.remove(self._note)
+            "padded_inputs", "max_block_bytes")},
+        "sync_s": sibling._settle_disk()}
 
 
 def window(ctx) -> dict:
     st = ctx.state
     gen = st["gen"]
     lanes, sigs = gen["device_lanes"], gen["sigs"]
-    collected = _GcClock()
-    cpu0, all0, t0 = time.thread_time(), time.process_time(), time.monotonic()
-    with ctx.annotate("import"), collected:
-        node = st["node"] = sibling._node(ctx, st["datadir"])
-    wall = time.monotonic() - t0
-    main_thread_cpu_s = time.thread_time() - cpu0
-    process_cpu_s = time.process_time() - all0
+    wall, host = sibling._measured_import(ctx)
+    node = st["node"]
     after = sibling.snapshot(node)
     before = st["before"]
 
@@ -211,21 +184,7 @@ def window(ctx) -> dict:
             "multisig_lanes", "multisig_group_confirms")},
             "lanes": lanes, "lanes_on_device": on_device, **still,
             "multisig_sigs": gen["multisig_sigs"],
-            # where the runs of a cell spread: what the one importing
-            # thread got of the window (against the import's fallback_s: a
-            # stolen core shows as wall at the same CPU seconds), and what
-            # Python's collector took. A full pass over the heap that
-            # tracing the verify program leaves is ~0.55 s; a program
-            # that moves that heap out of the collector's reach once the
-            # shape is traced (gc_frozen_objects in the millions, not the
-            # interpreter's own few hundred) pays milliseconds a pass. The
-            # harness sets nothing aside itself: bcpd pays what this
-            # process does
-            "main_thread_cpu_s": main_thread_cpu_s,
-            "process_cpu_s": process_cpu_s,
-            "gc_s": collected.seconds,
-            "gc_full_collections": collected.full,
-            "gc_frozen_objects": gc.get_freeze_count()},
+            **host},
     }
 
 

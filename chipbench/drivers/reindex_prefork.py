@@ -53,6 +53,7 @@ def _sibling():
 mixed = _sibling()
 sibling = mixed.sibling  # drivers/reindex.py
 close = sibling.close
+warm = mixed.warm
 # counters of gettpuinfo.batch that a sound window leaves where they were
 STILL = mixed.STILL
 
@@ -112,16 +113,6 @@ def setup(ctx) -> None:
     # the measured chain signs on the other cores while warm() traces
     st["main_cache"], st["main_proc"] = _generator(
         ctx, "main", traffic["lanes"] * buckets, ctx.fault)
-
-
-def warm(ctx) -> None:
-    """The sibling's warm-up; then the set-up's own writes go to disk. It
-    has just copied the chain's block files (~200 MB) into the work
-    directory, and the window's flush ends in a dozen fsyncs, which must
-    not wait for them: a node that reindexes finds its block files on
-    disk."""
-    mixed.warm(ctx)
-    os.sync()
 
 
 def window(ctx) -> dict:

@@ -13,11 +13,13 @@ BENCHMARK.json: the configuration (chipbench/configs), the traffic mix
 
 The last line of standard output is the result object; earlier lines are
 one JSON object each (phases, counters, every number compared beside its
-limit). There is no CPU mode that prints a result: without a TPU, or with
-fewer chips than the cell asks for, the exit code is not 0 and no result is
-printed. ``--rehearse`` runs the control flow at tiny sizes on whatever
-JAX finds and prints ``platform`` and no metric. ``--fault <name>`` makes
-the driver break its input or its limit so that the check has to fail.
+limit). The numbers compared are also the last lines of standard error and
+the result object's last key, ``compared``. There is no CPU mode that
+prints a result: without a TPU, or with fewer chips than the cell asks for,
+the exit code is not 0 and no result is printed. ``--rehearse`` runs the
+control flow at tiny sizes on whatever JAX finds and prints ``platform`` and
+no metric. ``--fault <name>`` makes the driver break its input or its limit
+so that the check has to fail.
 """
 
 import time
@@ -259,6 +261,7 @@ def _run(loaded: dict, ctx: Ctx, driver) -> int:
 
         bad = checks.no_fallback(
             before, after, sigs=result.get("sigs", 0),
+            dispatches=result.get("dispatches"),
             cache_dir=devicewatch.compile_cache_dir())
         for item in bad:
             emit({"phase": "fallback", **item})
@@ -312,6 +315,14 @@ def _run(loaded: dict, ctx: Ctx, driver) -> int:
         device.update(busy_s=trace["busy_s"], window_s=trace["window_s"])
         line["breakdown"] = {"device_ops": trace["device_ops"],
                              "idle_gaps": trace["idle_gaps"]}
+    # every number compared beside its limit: the last lines of standard
+    # error, and the last key of the result's line
+    line["compared"] = {n["name"]: {"value": n["value"], "limit": n["limit"]}
+                        for n in numbers}
+    for n in numbers:
+        print(f"compared {n['name']} = {n['value']} (limit {n['limit']})"
+              + ("" if n["ok"] else " NOT WITHIN IT"), file=sys.stderr)
+    sys.stderr.flush()
     emit(line)
     return 0
 

@@ -19,7 +19,9 @@ NEW_READERS = ("import.script_leg_share", "interp.us_per_input",
                "multisig.lanes_per_sig")
 
 
-def test_the_cell_loads_with_its_ten_per_layer_metrics():
+def test_the_cell_loads_with_at_least_its_ten_per_layer_metrics():
+    """The ten it was added with, in their order; a later PR may append
+    (PR 31 did: script_leg.template_share)."""
     loaded = run.load_cell("reindex.mixed_era")
     assert loaded["cell"]["chips"] == 1
     assert loaded["config"]["driver"] == "reindex_mixed"
@@ -27,7 +29,7 @@ def test_the_cell_loads_with_its_ten_per_layer_metrics():
     assert [m["name"] for m in loaded["end_to_end"]] == [
         "reindex_sigs_per_s", "setup_s"]
     names = [m["name"] for m in loaded["per_layer"]]
-    assert names == [
+    assert names[:10] == [
         "compile.listener_s", "import.verify_share", "import.host_share",
         "dispatch.lane_fill", "glv.kernel_ms", "glv_roofline",
         "device_idle.reindex", *NEW_READERS]
