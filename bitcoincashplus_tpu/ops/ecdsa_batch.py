@@ -18,7 +18,8 @@ _dispatch_packed_device <- dispatch_packed(blobs). Pipeline per batch:
   3. pad B up to a bucket size (bounds XLA recompiles); padded lanes are
      poisoned (q_inf) and ignored
   4. one jit dispatch under the ecdsa breaker: the selected kernel
-     (_glv_dev_program, or _w4_bytes_program under -ecdsakernel=w4), then
+     (_glv_prepare_program and _glv_dev_program back to back, or
+     _w4_bytes_program under -ecdsakernel=w4), then
      _w4_bytes_program if GLV failed, then retries, then the breaker
   5. device returns (B,) validity + degenerate masks; BatchHandle.result
      checks the two known-answer lanes and host-confirms every False
@@ -163,7 +164,7 @@ def _freeze_traced_heap() -> None:
     """Tracing and lowering a verify program leaves millions of objects
     that live as long as the process (jaxprs, the lowering's caches), and
     every full pass of Python's cyclic collector walks all of them: 0.55 s
-    a pass behind ``_glv_dev_program``, three or four passes in a 30 s
+    a pass behind the GLV programs, three or four passes in a 30 s
     import (PERF.md, PR 28). Once a shape has been traced, what is alive
     moves to the permanent generation, which no pass visits. Reference
     counts still free what dies; only a cycle alive at this moment is kept
@@ -1510,7 +1511,7 @@ def _dispatch_packed_device(pub, rs, msg, rn, wrap, n: int, br, kern: str,
     validity mask (they ride — and therefore exercise — whichever kernel
     actually ran).
 
-    Rungs: ``kern`` ("glv": _glv_dev_program, unless latched broken; "w4":
+    Rungs: ``kern`` ("glv": _glv_dev_planes, unless latched broken; "w4":
     _w4_bytes_program), then _w4_bytes_program within the same attempt if
     GLV failed (metered in STATS.glv_fallbacks). A w4 failure fails the
     attempt: retries, then the breaker, then the caller's CPU verify.
@@ -1551,11 +1552,11 @@ def _dispatch_packed_device(pub, rs, msg, rn, wrap, n: int, br, kern: str,
                     INJECTOR.on_call(GLV_DEV_SITE)
                     INJECTOR.on_call(GLV_SITE)
                     t0 = time.monotonic()
+                    # no jitfn: the call is two programs enqueued back to
+                    # back, and the watch's cost capture lowers one
                     device_ok, degen = _watched_kernel(
                         _PW_GLV_DEV, bucket, arrays,
-                        lambda: dev.ecdsa_verify_batch_glv_dev(*arrays),
-                        jitfn=(dev._glv_dev_program
-                               if bucket <= 16384 else None))
+                        lambda: dev.ecdsa_verify_batch_glv_dev(*arrays))
                     STATS.glv_dispatch_s += time.monotonic() - t0
                     if (INJECTOR.should_poison(GLV_DEV_SITE)
                             or INJECTOR.should_poison(GLV_SITE)):

@@ -1154,28 +1154,24 @@ def _glv_comb_step(carry, drow, sgrow, tab_x, tab_y, one, never_inf):
     return acc, degen
 
 
-def _verify_core_glv(w1, w2, d1, sg1, d2, sg2, qx, qy, ydiff2, q_inf2,
-                     r0, rn, wrap2):
-    """GLV verify core (flat (B,) lanes, plain XLA).
+def _glv_ladder(w1, w2, t1, t2, q_inf_u):
+    """The 32-window ladder over the Q and λQ streams (flat (B,) lanes,
+    plain XLA). w1/w2: (32, B) int32 MSB-first 4-bit windows of |s21|,
+    |s22|; t1/t2: _glv_q_tables' stacked tables; q_inf_u: (1, B) int32.
+    Returns the (acc, degen) carry the comb continues.
 
-    w1/w2: (32, B) int32 MSB-first 4-bit windows of |s21|, |s22| (the Q
-    and λQ streams). d1/d2: (16, B) int32 8-bit comb digits of |s11|,
-    |s12| (position i = weight 256^i). sg1/sg2: (B,) int32 G-stream sign
-    flags (0/1). qx/qy: (20, B) weak limbs, qy with the first Q-stream
-    sign folded. ydiff2/q_inf2/wrap2: (1, B) masks. Returns (ok, degen)
-    (1, B) int32 planes; degen lanes MUST be re-verified by the caller."""
-    B = qx.shape[1]
+    In _glv_dev_program every operand of the loop is an ARGUMENT of the
+    program that runs it, never a value the same program computed: on the
+    chip the loop's own fusions run 2x slower, the normaliser's small-row
+    ones up to 65x, when the tables are built in front of the loop in one
+    program (2.00 ms a window against 1.03 ms; PERF.md §6, PR 39). A
+    caller that jits over both stages (parallel/sig_shard.py) inlines
+    them into one program again and keeps that slow form."""
+    B = w1.shape[1]
     one = jnp.broadcast_to(_ONE_CONST, (N_LIMBS, B)).astype(jnp.uint32)
-    q_inf_u = q_inf2.astype(jnp.int32)
-    ydiff_u = ydiff2.astype(jnp.int32)
-    never_inf = jnp.zeros((1, B), jnp.int32)
-
-    t1, t2 = _glv_q_tables(qx, qy, ydiff_u, q_inf_u, one)
-    gx_tab, gy_tab, lx_tab = (jnp.asarray(c) for c in _glv_comb())
-
-    # plain-XLA core: no Mosaic/shard_map varying-init gymnastics needed
-    # (cf. the w4 core's derived-from-input accumulator init)
-    zero_v = qx * U32_0
+    # the accumulator's init derives from an input (varying under
+    # shard_map, like the w4 core's)
+    zero_v = t1[0][0] * U32_0
     acc0 = {
         "X": zero_v + one,
         "Y": zero_v + one,
@@ -1190,8 +1186,20 @@ def _verify_core_glv(w1, w2, d1, sg1, d2, sg2, qx, qy, ydiff2, q_inf2,
         return _glv_window_step(carry, wr1.astype(jnp.int32),
                                 wr2.astype(jnp.int32), t1, t2, q_inf_u)
 
-    carry = jax.lax.fori_loop(0, GLV_WINDOWS, wstep, (acc0, degen0))
+    return jax.lax.fori_loop(0, GLV_WINDOWS, wstep, (acc0, degen0))
 
+
+def _glv_comb_final(carry, d1, sg1, d2, sg2, q_inf_u, r0, rn, wrap2):
+    """The two G streams from the fixed-base comb on top of the ladder's
+    carry, then the verify equation. d1/d2: (16, B) int32 8-bit comb
+    digits of |s11|, |s12| (position i = weight 256^i); sg1/sg2: (B,)
+    int32 G-stream sign flags (0/1); r0/rn: (20, B) weak limbs; wrap2:
+    (1, B) mask. Returns (ok, degen) (1, B) int32 planes; degen lanes
+    MUST be re-verified by the caller."""
+    B = r0.shape[1]
+    one = jnp.broadcast_to(_ONE_CONST, (N_LIMBS, B)).astype(jnp.uint32)
+    never_inf = jnp.zeros((1, B), jnp.int32)
+    gx_tab, gy_tab, lx_tab = (jnp.asarray(c) for c in _glv_comb())
     sg1o = sg1.astype(jnp.int32) * 256
     sg2o = sg2.astype(jnp.int32) * 256
 
@@ -1408,7 +1416,7 @@ def _glv_decompose_program(km):
     """Decompose-only jit surface: (B, 32) uint8 big-endian scalars
     (< n) -> (|k1| LE bytes (B, 16), n1 (B,), |k2| LE bytes (B, 16),
     n2 (B,)) — the differential-test window onto the in-kernel split
-    (the fused _glv_dev_program below is the production consumer)."""
+    (_glv_prepare_program below is the production consumer)."""
     m1, n1, m2, n2 = _glv_split_device(_expand_limb_cols(km))
     b1 = _bits_to_comb_digits(_mag_bits128(m1))
     b2 = _bits_to_comb_digits(_mag_bits128(m2))
@@ -1423,14 +1431,13 @@ def glv_decompose_device_batch(scalars) -> tuple:
     return tuple(np.asarray(o) for o in out)
 
 
-@jax.jit
-def _glv_dev_program(u1m, u2m, qxb, qyb, qinf8, r0b, rnb, wrap8):
-    """The device-decompose GLV pipeline (round 11), ONE dispatch end to
-    end: byte-matrix inputs IDENTICAL to the w4 byte pipeline (so the
-    host pack is ops/ecdsa_batch.pack_lanes' pure numpy byte emission),
-    device-side exact lattice decomposition of u1/u2, window/digit/limb
-    expansion, the sign-folded λQ y-select, then the GLV verify core.
-    Returns (2, B) uint32: row 0 ok, row 1 degenerate."""
+def _glv_expand(u1m, u2m, qxb, qyb, qinf8, r0b, rnb, wrap8):
+    """Byte matrices -> what the GLV core computes with: the device-side
+    exact lattice decomposition of u1/u2, window/digit/limb expansion and
+    the sign folds. Returns (w1, w2, d1, sg1, d2, sg2, qx, qy, ydiff_u,
+    q_inf_u, r0, rn, wrap2): _glv_ladder's windows, _glv_comb_final's
+    digits, signs and r candidates, and _glv_q_tables' point (qy with the
+    first Q-stream sign folded in, ydiff_u where the two differ)."""
     B = qxb.shape[0]
     # ONE split over the stacked (2B,) lane axis — the decompose is
     # pure per-lane arithmetic, so stacking u1|u2 halves the traced
@@ -1448,36 +1455,68 @@ def _glv_dev_program(u1m, u2m, qxb, qyb, qinf8, r0b, rnb, wrap8):
     qy = _expand_limb_cols(qyb)
     nb1r = nb1.reshape(1, B)
     # the first Q-stream sign folds into qy (P − qy, in the field); the
-    # second folds into the λQ table's y-select via ydiff, which is what
-    # _verify_core_glv's qy/ydiff2 arguments mean
+    # second folds into the λQ table's y-select via ydiff
     qy = jnp.where(nb1r, _f_neg(qy), qy)
-    ydiff = (nb1r ^ nb2.reshape(1, B)).astype(jnp.uint32)
-    ok, degen = _verify_core_glv(
-        w1, w2, d1, na1.astype(jnp.int32), d2, na2.astype(jnp.int32),
-        _expand_limb_cols(qxb), qy, ydiff,
-        qinf8.astype(jnp.uint32).reshape(1, B),
-        _expand_limb_cols(r0b), _expand_limb_cols(rnb),
-        wrap8.astype(jnp.uint32).reshape(1, B))
+    ydiff_u = (nb1r ^ nb2.reshape(1, B)).astype(jnp.int32)
+    return (w1, w2, d1, na1.astype(jnp.int32), d2, na2.astype(jnp.int32),
+            _expand_limb_cols(qxb), qy, ydiff_u,
+            qinf8.astype(jnp.int32).reshape(1, B),
+            _expand_limb_cols(r0b), _expand_limb_cols(rnb),
+            wrap8.astype(jnp.uint32).reshape(1, B))
+
+
+@jax.jit
+def _glv_prepare_program(u1m, u2m, qxb, qyb, qinf8, r0b, rnb, wrap8):
+    """Stage one of the device-decompose GLV pipeline (round 11):
+    byte-matrix inputs IDENTICAL to the w4 byte pipeline (so the host
+    pack is ops/ecdsa_batch.pack_lanes' pure numpy byte emission),
+    _glv_expand, then the per-lane Q and λQ tables. Returns
+    _glv_dev_program's arguments, left on the device (63 MB of tables a
+    bucket of 8,192 lanes)."""
+    (w1, w2, d1, sg1, d2, sg2, qx, qy, ydiff_u, q_inf_u, r0, rn,
+     wrap2) = _glv_expand(u1m, u2m, qxb, qyb, qinf8, r0b, rnb, wrap8)
+    one = jnp.broadcast_to(_ONE_CONST, qx.shape).astype(jnp.uint32)
+    t1, t2 = _glv_q_tables(qx, qy, ydiff_u, q_inf_u, one)
+    return w1, w2, t1, t2, d1, sg1, d2, sg2, q_inf_u, r0, rn, wrap2
+
+
+@jax.jit
+def _glv_dev_program(w1, w2, t1, t2, d1, sg1, d2, sg2, q_inf_u, r0, rn,
+                     wrap2):
+    """Stage two, the GLV verify core: the ladder, the comb and the
+    verify equation over what _glv_prepare_program left on the device
+    (a program of its own so that the loop's operands are its arguments:
+    _glv_ladder). Returns (2, B) uint32: row 0 ok, row 1 degenerate."""
+    carry = _glv_ladder(w1, w2, t1, t2, q_inf_u)
+    ok, degen = _glv_comb_final(carry, d1, sg1, d2, sg2, q_inf_u, r0, rn,
+                                wrap2)
     return jnp.concatenate(
         [ok.astype(jnp.uint32), degen.astype(jnp.uint32)], axis=0)
 
 
+def _glv_dev_planes(u1m, u2m, qxb, qyb, qinf8, r0b, rnb, wrap8):
+    """Both stages, enqueued one behind the other with no host sync
+    between them: ONE dispatch to its callers. (2, B) uint32 planes."""
+    return _glv_dev_program(
+        *_glv_prepare_program(u1m, u2m, qxb, qyb, qinf8, r0b, rnb, wrap8))
+
+
 def ecdsa_verify_batch_glv_dev(u1m, u2m, qxb, qyb, qinf8, r0b, rnb, wrap8):
     """Byte-matrix GLV verify with the decompose ON DEVICE (see
-    _glv_dev_program). Input signature matches the w4 byte pipeline;
+    _glv_prepare_program). Input signature matches the w4 byte pipeline;
     batches beyond 16384 lanes split into 16384-lane program calls so
     compiled shapes stay the bounded bucket set. Returns (ok, degen)
     bool (B,) arrays — device futures until materialized."""
     B = qxb.shape[0]
     SPLIT = 16384
     if B <= SPLIT:
-        out = _glv_dev_program(u1m, u2m, qxb, qyb, qinf8, r0b, rnb, wrap8)
+        out = _glv_dev_planes(u1m, u2m, qxb, qyb, qinf8, r0b, rnb, wrap8)
         return out[0].astype(bool), out[1].astype(bool)
     oks, dgs = [], []
     for s in range(0, B, SPLIT):
         sl = slice(s, s + SPLIT)
-        out = _glv_dev_program(u1m[sl], u2m[sl], qxb[sl], qyb[sl],
-                               qinf8[sl], r0b[sl], rnb[sl], wrap8[sl])
+        out = _glv_dev_planes(u1m[sl], u2m[sl], qxb[sl], qyb[sl],
+                              qinf8[sl], r0b[sl], rnb[sl], wrap8[sl])
         n = min(SPLIT, B - s)
         oks.append(out[0].reshape(n))
         dgs.append(out[1].reshape(n))

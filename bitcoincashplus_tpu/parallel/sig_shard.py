@@ -15,7 +15,7 @@ On CPU meshes (the virtual-8 dryrun — no Mosaic backend) the same
 kernel runs in pallas interpret mode, so the sharded program is the real
 w4 pipeline everywhere, not a stand-in ladder.
 
-The GLV kernel (ops/secp256k1._glv_dev_program, -ecdsakernel=glv, the
+The GLV kernel (ops/secp256k1._glv_dev_planes, -ecdsakernel=glv, the
 default) shards the same way via _sharded_glv_dev_jit — plain XLA end to
 end, so no interpret split: the fixed-base comb constants replicate per
 chip, the inputs are the same raw byte matrices as the w4 pipeline, and
@@ -64,14 +64,21 @@ def _sharded_glv_dev_jit(u1m, u2m, qxb, qyb, qinf8, r0b, rnb, wrap8,
     lattice split over its own lanes — the host ships bytes, never split
     scalars. Plain XLA end to end (no interpret-mode split: the GLV core
     never enters Mosaic, and its fixed-base comb rides as captured XLA
-    constants the partitioner replicates per chip)."""
-    from ..ops.secp256k1 import _glv_dev_program
+    constants the partitioner replicates per chip).
+
+    The jit + shard_map around _glv_dev_planes inlines its two stages
+    into ONE program, so the ladder's loop reads tables the same program
+    built: the slow form on the chip (2.02 ms a window against 1.03,
+    PERF.md §6, PR 39). Nothing reaches this path from bcpd (ROADMAP S8);
+    when something does, it needs two shard_map programs, prepare and
+    ladder, with the tables handed over sharded."""
+    from ..ops.secp256k1 import _glv_dev_planes
 
     mesh = chip_mesh(n_chips)
     row = P(CHIP_AXIS)
 
     def body(u1m, u2m, qxb, qyb, qinf8, r0b, rnb, wrap8):
-        out = _glv_dev_program(u1m, u2m, qxb, qyb, qinf8, r0b, rnb, wrap8)
+        out = _glv_dev_planes(u1m, u2m, qxb, qyb, qinf8, r0b, rnb, wrap8)
         b_local = qxb.shape[0]
         ok = out[0].reshape(b_local).astype(bool)
         degen = out[1].reshape(b_local).astype(bool)

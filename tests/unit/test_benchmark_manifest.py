@@ -129,3 +129,35 @@ def test_per_layer_metric_moves_an_end_to_end_metric_its_cells_report(name):
         assert metric["unit"] == "%"
     assert os.path.isfile(os.path.join(
         ROOT, MANIFEST["paths"][0], "layer_metrics", name + ".py"))
+
+
+@pytest.mark.parametrize("modules, kernel_ms, prepare_ms", [
+    # the two stages a bucket since PR 39: each reader its own module
+    ({"jit__glv_prepare_program": {"seconds": 0.0135, "count": 4},
+      "jit__glv_dev_program": {"seconds": 0.156, "count": 4}}, 39.0, 3.375),
+    # one program a bucket (the parents of PR 39): no prepare to read
+    ({"jit__glv_dev_program": {"seconds": 0.3256, "count": 4}}, 81.4, None),
+    ({}, None, None),
+])
+def test_the_glv_readers_take_one_stage_each(modules, kernel_ms, prepare_ms):
+    """glv.kernel_ms + glv.prepare_ms is a bucket's device time; the
+    module names are the stage jits' own (ops/secp256k1)."""
+    import importlib.util
+
+    from bitcoincashplus_tpu.ops import secp256k1 as dev
+
+    def reader(name):
+        spec = importlib.util.spec_from_file_location(
+            "reader", os.path.join(ROOT, MANIFEST["paths"][0],
+                                   "layer_metrics", name + ".py"))
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+        return module
+
+    kernel, prepare = reader("glv.kernel_ms"), reader("glv.prepare_ms")
+    assert kernel.MODULE == "jit_" + dev._glv_dev_program.__name__
+    assert prepare.MODULE == "jit_" + dev._glv_prepare_program.__name__
+    for obs in ({"trace": {"modules": modules}}, {"trace": None}):
+        want = (kernel_ms, prepare_ms) if obs["trace"] else (None, None)
+        assert kernel.read(obs) == pytest.approx(want[0])
+        assert prepare.read(obs) == pytest.approx(want[1])

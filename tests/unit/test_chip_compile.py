@@ -80,6 +80,16 @@ def _verify_args(one_chip, bucket: int):
     return m, m, m, m, v, m, m, v
 
 
+def _ladder_args(one_chip, bucket: int):
+    """What _glv_prepare_program hands _glv_dev_program, as shapes."""
+    from bitcoincashplus_tpu.ops import secp256k1 as dev
+
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(dev._glv_prepare_program,
+                       *_verify_args(one_chip, bucket)))
+
+
 def test_resident_sweep_compiles_for_v5e(one_chip, chip_forms):
     """mining/resident's program: sweep_fast_jit at the node's TPU tile."""
     from bitcoincashplus_tpu.ops.sha256_sweep import sweep_fast_jit
@@ -180,17 +190,47 @@ def test_field_op_launch_count_for_v5e(one_chip, chip_forms, op, ceiling):
     assert 0 < text.count(" fusion(") <= ceiling
 
 
+def test_glv_ladder_program_takes_its_tables_for_v5e(one_chip, chip_forms):
+    """The program that runs the ladder is handed every operand of the
+    loop: the six stacked (16, 20, B) tables are arguments of its main,
+    none is stacked inside it, and the only loops in it are the ladder and
+    the comb. With the tables built in front of the loop in ONE program
+    the chip ran the loop's own fusions 2x slower (2.00 ms a window
+    against 1.03; PERF.md §6, PR 39), and nothing in the compiled text
+    shows it: the property is held here, in the lowered text."""
+    from bitcoincashplus_tpu.ops import secp256k1 as dev
+
+    lanes = 8192
+    text = dev._glv_dev_program.lower(
+        *_ladder_args(one_chip, lanes)).as_text()
+    table = f"tensor<16x{dev.N_LIMBS}x{lanes}xui32>"
+    main = next(ln for ln in text.splitlines()
+                if "func.func public @main" in ln)
+    assert main.count(table) == 6
+    assert not [ln for ln in text.splitlines()
+                if "stablehlo.concatenate" in ln
+                and ln.rstrip().endswith(table)]
+    assert text.count("stablehlo.while") == 2
+
+
 # the node's reindex buckets (node.py _import_block_files_native); compile
 # seconds measured on this 8-core sandbox for PR 22: 215 / 228 / 242 s;
-# 114 s at 8,192 since PR 33 halved the field normaliser's launches
+# 114 s at 8,192 since PR 33 halved the field normaliser's launches; since
+# PR 39 two programs, 84 + 47 s at 8,192
 @pytest.mark.slow(reason="AOT compile minutes per bucket (sandbox)")
 @pytest.mark.parametrize("bucket", [1024, 2048, 8192])
 def test_glv_verify_bucket_compiles_for_v5e(one_chip, chip_forms, bucket):
-    """The DEFAULT verify kernel (fused decompose + GLV ladder) fits one
-    chip: temp stays far below the 16 GB of HBM at every bucket."""
+    """The DEFAULT verify kernel (decompose and tables, then the GLV
+    ladder: two programs) fits one chip: temp stays far below the 16 GB
+    of HBM at every bucket, and so do the tables the first hands the
+    second."""
     from bitcoincashplus_tpu.ops import secp256k1 as dev
 
-    compiled = dev._glv_dev_program.lower(
+    prepare = dev._glv_prepare_program.lower(
         *_verify_args(one_chip, bucket)).compile()
-    mem = compiled.memory_analysis()
-    assert mem.temp_size_in_bytes < 2 << 30, mem
+    ladder = dev._glv_dev_program.lower(
+        *_ladder_args(one_chip, bucket)).compile()
+    for compiled in (prepare, ladder):
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < 2 << 30, mem
+    assert prepare.memory_analysis().output_size_in_bytes < 1 << 30

@@ -11,6 +11,7 @@ differentials; the `glv` marker selects this suite (ordered with the
 unit group by conftest).
 """
 
+import hashlib
 import random
 
 import numpy as np
@@ -240,6 +241,102 @@ def test_glv_kernel_edge_differential():
     got = ecdsa_batch.verify_batch(records, backend="device", kernel="glv")
     assert got.tolist() == expected
     assert ecdsa_batch.STATS.glv_dispatches >= 1
+
+
+def _stage_corpus():
+    """Lanes from a generator of their own (the module's is consumed in
+    test order, and PLANES_SHA256 below is of these): verify scalars whose
+    lattice split puts the two Q-stream signs in every quadrant, three
+    lanes each, so the table's y-select negates and leaves alone behind
+    both folds of qy; u2 of one nonzero window and of one nonzero bit (the
+    ladder adds nothing in the other windows), and the edge corpus' other
+    kinds: nudged twins, out-of-range s and r (the packer's infinity
+    lanes, as its padding is)."""
+    r = random.Random(39)
+    n = oracle.N
+    triples, seen = [], {}
+    while len(triples) < 12:
+        u2 = r.randrange(1, n)
+        _, n1, _, n2 = dev.glv_decompose(u2)
+        if seen.setdefault((n1, n2), 0) < 3:
+            seen[(n1, n2)] += 1
+            triples.append((r.randrange(n), u2, r.randrange(1, n)))
+    for u2 in (1, 15, 1 << 64, 9 << 124, (1 << 128) - 1, dev.LAMBDA,
+               n - dev.LAMBDA):
+        triples.append((r.randrange(n), u2, r.randrange(1, n)))
+    recs = _records_with_scalars(triples)
+    base = recs[0][0]
+    bad = [(SigCheckRecord(q.pubkey, q.r, q.s, (q.msg_hash + 1) % n), False)
+           for q, _ in recs[::2]]
+    bad += [(SigCheckRecord(base.pubkey, base.r, s, base.msg_hash), False)
+            for s in (0, n)]
+    bad.append((SigCheckRecord(base.pubkey, 0, base.s, base.msg_hash),
+                False))
+    return recs + bad
+
+
+# sha256 of the (2, 1024) uint32 planes the single program of PRs 30-38
+# gave for _stage_corpus()'s lanes (computed with that program, PR 39): the
+# two stages give the same bits
+PLANES_SHA256 = "d215287f9aff24b911e12036af735580fba6ba855f06a99e3da0a30cbf026f47"
+
+
+@pytest.mark.parametrize("corpus", ["edge", "stages"])
+def test_glv_planes_differential(corpus):
+    """The raw (ok, degen) planes of the two stages, composed as the
+    dispatch composes them, over a bucket of 1,024 lanes: every lane
+    settled (no degenerate flag), `ok` equal to the CPU verifier's verdict,
+    padding and out-of-range lanes False; on the fixed lanes of
+    _stage_corpus, bit for bit what the single program gave."""
+    pairs = _edge_corpus() if corpus == "edge" else _stage_corpus()
+    records = [r for r, _ in pairs]
+    expected = _cpu_verdicts(records)
+    assert expected == [e for _, e in pairs]
+    if corpus == "stages":
+        # u2 = r / s is the scalar of the two Q streams
+        quadrants = {dev.glv_decompose(
+            r.r * pow(r.s, oracle.N - 2, oracle.N) % oracle.N)[1::2]
+            for r in records[:12]}
+        assert quadrants == {(0, 0), (0, 1), (1, 0), (1, 1)}
+    lanes = 1024
+    arrays = ecdsa_batch.pack_lanes(
+        *ecdsa_batch.records_to_blobs(records), lanes)
+    planes = np.asarray(dev._glv_dev_planes(*arrays))
+    assert planes.shape == (2, lanes) and planes.dtype == np.uint32
+    assert not planes[1].any()
+    assert planes[0, :len(records)].astype(bool).tolist() == expected
+    assert not planes[0, len(records):].any()
+    if corpus == "stages":
+        assert hashlib.sha256(planes.tobytes()).hexdigest() == PLANES_SHA256
+
+
+def test_glv_two_stages_are_one_watched_dispatch():
+    """A bucket is two programs on the device and ONE dispatch to the
+    watch: `gettpuinfo.device.programs` keeps its one GLV entry, whose
+    `dispatches` counts buckets and whose one shape is the bucket."""
+    from types import SimpleNamespace
+
+    from bitcoincashplus_tpu.rpc.control import gettpuinfo
+    from bitcoincashplus_tpu.util import devicewatch as dw
+    from bitcoincashplus_tpu.validation.sigcache import SignatureCache
+
+    # the handle the dispatch leg holds (see the sentinel test below)
+    pw = ecdsa_batch._PW_GLV_DEV
+    records = [r for r, _ in _stage_corpus()[:9]]
+    before = pw.snapshot()["dispatches"]
+    for fill in (2, 5, 9):
+        got = ecdsa_batch.verify_batch(records[:fill], backend="device",
+                                       kernel="glv")
+        assert got.all()
+    snap = pw.snapshot()
+    assert snap["dispatches"] == before + 3
+    assert snap["retraces_unexpected"] == 0
+    node = SimpleNamespace(backend="auto", sigcache=SignatureCache(),
+                           chainstate=SimpleNamespace(bench={}))
+    programs = gettpuinfo(node, [])["device"]["programs"]
+    assert {n for n in programs if n.startswith("ecdsa_glv")} <= {pw.name}
+    if dw.program(pw.name) is pw:
+        assert programs[pw.name]["dispatches"] == snap["dispatches"]
 
 
 @pytest.mark.parametrize("site", [ecdsa_batch.GLV_SITE,

@@ -174,6 +174,67 @@ def test_parallel_cheap_adds_match_python_points(form):
         assert _affine(out, lane) == oracle.point_add(a, b_), lane
 
 
+# two ladder windows, a lane: ((w1, w2) of the first, (w1, w2) of the
+# second, whether the two Q-stream signs differ, whether Q is at infinity)
+_WINDOW_LANES = [
+    ((3, 5), (7, 9), 0, 0), ((3, 5), (7, 9), 1, 0),
+    ((0, 0), (1, 15), 0, 0),   # a window of zeros first: still at infinity
+    ((15, 0), (0, 0), 1, 0),   # then a window of zeros: four doublings only
+    ((0, 4), (4, 0), 1, 0), ((2, 2), (2, 2), 0, 0),
+    ((9, 9), (1, 1), 0, 1),    # Q at infinity: nothing is ever added
+    ((0, 0), (0, 0), 1, 0)]
+
+
+@pytest.mark.parametrize("tables", ["lambda", "twin"])
+def test_parallel_window_steps_match_python_points(tables):
+    """_glv_q_tables and two _glv_window_steps from the ladder's start,
+    against the oracle's multiples: R = 16 (a1 Q + a2 L) + b1 Q + b2 L with
+    L = phi(Q), negated where the two Q-stream signs differ ("lambda": the
+    tables as the program builds them, both signs of the y-select). "twin"
+    hands the second stream the first one's table, L = Q: the one way to
+    meet H == 0 here, where a lane's two first digits are equal, and the
+    flag must rise there and nowhere else."""
+    ks = [5, 0xDEADBEEF, 7, 11, 0xC0FFEE, 13, 3, 17]
+    pts = [oracle.point_mul(k, oracle.G) for k in ks]
+    one = jnp.asarray(_pack([1] * B))
+    plane = lambda vals: jnp.asarray([list(vals)], jnp.int32)  # noqa: E731
+    ydiff = plane(lane[2] for lane in _WINDOW_LANES)
+    q_inf = plane(lane[3] for lane in _WINDOW_LANES)
+    t1, t2 = dev._glv_q_tables(
+        jnp.asarray(_pack([x for x, _ in pts])),
+        jnp.asarray(_pack([y for _, y in pts])), ydiff, q_inf, one)
+    if tables == "twin":
+        t2 = t1
+    zero = jnp.zeros((dev.N_LIMBS, B), jnp.uint32)
+    carry = ({"X": one, "Y": one, "Z": zero,
+              "inf": jnp.ones((1, B), jnp.int32)},
+             jnp.zeros((1, B), jnp.int32))
+    for step in (0, 1):
+        carry = dev._glv_window_step(
+            carry, plane(lane[step][0] for lane in _WINDOW_LANES),
+            plane(lane[step][1] for lane in _WINDOW_LANES), t1, t2, q_inf)
+    acc, degen = carry
+    twins = [int(tables == "twin" and a1 == a2 != 0 and not inf)
+             for (a1, a2), _, _, inf in _WINDOW_LANES]
+    assert np.asarray(degen).ravel().tolist() == twins
+    for lane, ((a1, a2), (b1, b2), diff, inf) in enumerate(_WINDOW_LANES):
+        q = pts[lane]
+        lam = q if tables == "twin" else oracle.point_mul(dev.LAMBDA, q)
+        if diff and tables == "lambda":
+            lam = (lam[0], P - lam[1])
+        want = None
+        for k, base in ((16 * a1 + b1, q), (16 * a2 + b2, lam)):
+            if k and not inf:
+                want = oracle.point_add(want, oracle.point_mul(k, base))
+        if twins[lane]:
+            continue  # flagged: the caller verifies the lane again
+        if want is None:
+            assert int(np.asarray(acc["inf"])[0, lane]) == 1, lane
+        else:
+            assert int(np.asarray(acc["inf"])[0, lane]) == 0, lane
+            assert _affine(acc, lane) == want, lane
+
+
 def test_parallel_verify_final_matches_python_points():
     """X_R == r * Z^2 for r in {r0, rn}: lanes that match on r0, on rn
     (with and without the wrap gate), on neither, and at infinity."""

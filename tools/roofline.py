@@ -553,9 +553,14 @@ def run_ecdsa_live_drift(parts, bucket: int = 1024):
     print(f"\nlive cost-analysis drift check (bucket {bucket}, one real "
           "dispatch per kernel through the devicewatch registry)...")
     args = eb.pack_lanes(*eb.records_to_blobs(records), bucket)
-    with dwatch.program("ecdsa_glv_decompose").dispatch(
-            bucket, jitfn=S._glv_dev_program, args=args):
-        jax.block_until_ready(S._glv_dev_program(*args))
+    # the GLV verify is two programs a bucket since PR 39: each stage is
+    # costed as the node runs it, and a lane's flops are their sum
+    glv = dwatch.program("ecdsa_glv_decompose")
+    with glv.dispatch(bucket, "prepare", jitfn=S._glv_prepare_program,
+                      args=args):
+        prepared = jax.block_until_ready(S._glv_prepare_program(*args))
+    with glv.dispatch(bucket, jitfn=S._glv_dev_program, args=prepared):
+        jax.block_until_ready(S._glv_dev_program(*prepared))
     interp = backend_is_cpu()
     with dwatch.program("ecdsa_w4_bytes").dispatch(
             bucket, jitfn=S._w4_bytes_program, args=args,
@@ -578,12 +583,14 @@ def run_ecdsa_live_drift(parts, bucket: int = 1024):
                        "ecdsa_w4_bytes": bucket, "ecdsa_msm": msm_bucket}
     live = {}
     for name, bkt in per_name_bucket.items():
-        cost = progs.get(name, {}).get("cost", {}).get(str((bkt,)))
-        if not cost:
+        sigs = [(bkt,)] + ([(bkt, "prepare")] if name == glv.name else [])
+        costs = [progs.get(name, {}).get("cost", {}).get(str(sig))
+                 for sig in sigs]
+        if not all(costs):
             print("live drift check: cost_analysis unavailable on this "
                   "backend — skipped")
             return None
-        live[name] = cost["flops"] / bkt
+        live[name] = sum(cost["flops"] for cost in costs) / bkt
 
     arrangement = "cpu" if interp else "mosaic"
     baselines = COST_BASELINES.get(arrangement)
