@@ -264,11 +264,12 @@ class ResidentSweep:
         would be a ``convert_element_type`` program of its own)."""
         jitfn = self._jitfn()
         start, n_tiles = np.uint32(start), np.uint32(n_tiles)
-        with dw.program(PROGRAM, shape_budget=SHAPE_BUDGET).dispatch(
-                self.kernel, self.tile, jitfn=jitfn,
-                args=(self._mid_np, self._tail_np, self._tgt_np,
-                      start, n_tiles),
-                kwargs={"tile": self.tile}):
+        with tm.span("miner.enqueue", tiles=int(n_tiles)), \
+                dw.program(PROGRAM, shape_budget=SHAPE_BUDGET).dispatch(
+                    self.kernel, self.tile, jitfn=jitfn,
+                    args=(self._mid_np, self._tail_np, self._tgt_np,
+                          start, n_tiles),
+                    kwargs={"tile": self.tile}):
             out = jitfn(self._mid, self._tail, self._tgt, start, n_tiles,
                         tile=self.tile)
         dw.note_transfer("miner_resident", "h2d", 8)  # 2 uint32 scalars
@@ -307,14 +308,17 @@ class ResidentSweep:
         """Block on the oldest in-flight segment; returns (seg, found,
         cand_nonce, tiles_done). Meters the poll, beats the watchdog."""
         seg = self._segments.popleft()
+        # the blocking fetch: the span for the totals and the profiler's
+        # trace; the sizing rule keeps a clock of its own (it has to size
+        # the segments under -telemetry=off too), _POLL_H its distribution
         t0 = _now()
-        found, nonce, tiles = self._fetch(seg.out)
+        with tm.span("miner.poll_wait", tiles=seg.n_tiles):
+            found, nonce, tiles = self._fetch(seg.out)
         now = _now()
         dt = now - t0
         _POLL_H.observe(dt)
         _POLLS_C.inc()
-        dw.note_transfer("miner_resident", "d2h", 12, seconds=dt)
-        dw.note_phase("miner_resident", "fetch", dt)
+        dw.note_transfer("miner_resident", "d2h", 12)
         if self._last_poll_t is None:   # a call's first settle: no gap
             self._win = _NO_POLLS
         else:

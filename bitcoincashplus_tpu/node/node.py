@@ -41,6 +41,18 @@ from .config import Config, ConfigError
 
 DEFAULT_FLUSH_INTERVAL = 64  # blocks between periodic FlushStateToDisk calls
 
+# last_import_stats' older times as sums of the native import's spans (each
+# span's whole duration, wherever it nests): verify_s is the lanes' joining
+# (the script leg inside it), the pack, the enqueue, the wait for verdicts
+# and their settlement; fallback_s, the script leg, is inside verify_s too.
+_IMPORT_LEGS = {
+    "native_connect_s": ("import.connect",),
+    "verify_s": ("import.lanes", "import.pack", "import.enqueue",
+                 "import.settle_wait", "import.settle"),
+    "fallback_s": ("import.script_leg",),
+    "flush_s": ("import.flush",),
+}
+
 # explicit -telemetry levels a -tracefile sink contradicts (node startup
 # rejects the combination rather than writing an empty dump)
 MODES_BELOW_TRACE = ("off", "counters")
@@ -102,6 +114,31 @@ class _MultisigSettler:
             self.slices.popleft()
 
 
+class _StartupPhases:
+    """Node.__init__'s stages as ``node.init.<phase>`` spans, one open at a
+    time: enter(name) ends the stage before it. ``seconds`` is what
+    gettpuinfo["startup"] shows: {phase: seconds}, in the order they
+    ran."""
+
+    def __init__(self):
+        self.seconds: dict[str, float] = {}
+        self._name = ""
+        self._span = None
+
+    def enter(self, name: str) -> None:
+        self.close()
+        self._name = name
+        self._span = telemetry.span("node.init." + name)
+        self._span.__enter__()
+
+    def close(self) -> None:
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self.seconds[self._name] = (self.seconds.get(self._name, 0.0)
+                                        + self._span.seconds)
+            self._span = None
+
+
 class _ShadowBlockStore:
     """Block-store facade for the assumeutxo shadow chainstate: reads
     delegate to the node's real store (under cs_main — BlockStore file
@@ -141,6 +178,17 @@ class Node:
 
     def __init__(self, config: Optional[Config] = None, datadir: Optional[str] = None,
                  network: Optional[str] = None):
+        # gettpuinfo["startup"]: what a restart costs, stage by stage
+        self._startup = _StartupPhases()
+        try:
+            self._init(config, datadir, network)
+        finally:
+            self._startup.close()
+
+    def _init(self, config: Optional[Config], datadir: Optional[str],
+              network: Optional[str]) -> None:
+        phase = self._startup.enter
+        phase("config")
         if config is None:
             config = Config()
             if datadir:
@@ -190,6 +238,7 @@ class Node:
                 raise ConfigError(str(e)) from None
         self.telemetry_mode = telemetry.mode()
         log_printf("bcpd init: network=%s datadir=%s", self.params.network, self.datadir)
+        phase("stores")  # what ran before it: the log, the device's platform
 
         # -par=<n>: thread budget for the native CPU verify fallback
         # (src/init.cpp -par -> CCheckQueue worker count; here the TPU batch
@@ -357,6 +406,7 @@ class Node:
                 self._cert_checkpoints = {
                     int(h): d for h, d in doc["checkpoints"].items()}
 
+        phase("device")  # kernel selection, the compile cache, the services
         # -maxsigcachesize=<MiB>: byte budget for the signature cache
         # (src/init.cpp DEFAULT_MAX_SIG_CACHE_SIZE). The entry cap is
         # derived FROM the byte budget so the knob governs alone — a fixed
@@ -531,6 +581,7 @@ class Node:
         self.spec_hold_s = spec_hold_ms / 1e3
         self.chainstate.max_branches = self.spec_branches
         self.chainstate.spec_hold_s = self.spec_hold_s
+        phase("index")
         loaded = self.chainstate.load_block_index()
         if loaded:
             log_printf("block index loaded: tip height %d",
@@ -542,6 +593,7 @@ class Node:
             # fake linkage before candidate selection runs
             self._fake_snapshot_chaintx()
 
+        phase("import")
         if reindex:
             n = self.import_block_files()
             log_printf("-reindex: imported %d blocks, tip height %d",
@@ -558,6 +610,7 @@ class Node:
             log_printf("-loadblock: imported %d blocks, tip height %d",
                        n, self.chainstate.tip().height)
 
+        phase("verify_db")
         if self._snapshot_pending:
             # -checkblocks replays recent blocks from local data; below an
             # unvalidated snapshot tip there is none yet. The background
@@ -570,6 +623,7 @@ class Node:
                 level=config.get_int("checklevel", 3),
             )
 
+        phase("services")  # mempool, collectors, indexes, the last flush
         self.mempool = CTxMemPool(
             max_size_bytes=config.get_int("maxmempool", 300) * 1_000_000,
             expiry_seconds=config.get_int("mempoolexpiry", 336) * 3600,
@@ -1629,7 +1683,51 @@ class Node:
     def _import_block_files_native(self) -> int:
         """The fast -reindex import: native connect engine + packed TPU
         signature batches, linear-extension blocks only (anything else
-        flushes and defers to the Python engine per block)."""
+        flushes and defers to the Python engine per block).
+
+        Every second of it is inside a span (util/telemetry) on the
+        importing thread, nested under one ``import`` span, so the self
+        times of ``last_import_stats["phases"]`` add up to ``wall_s`` and
+        what no child covers reads as ``import``'s own. The stats' older
+        times are sums of those spans (_IMPORT_LEGS). Under -telemetry=off
+        spans are null: ``wall_s`` (a clock of its own) and the counters
+        are all the import reports, ``phases`` is empty and the legs read
+        0 for "not taken" (gettpuinfo.telemetry.span_times says so)."""
+        t0 = time.monotonic()
+        with telemetry.span("import", collect=True) as root:
+            n_imported, stats = self._native_import()
+        stats["wall_s"] = time.monotonic() - t0
+        phases = root.totals or {}
+        for key, names in _IMPORT_LEGS.items():
+            stats[key] = sum(phases[n]["s"] for n in names if n in phases)
+        stats["phases"] = phases
+        bench = self.chainstate.bench
+        bench["connect_ms"] += stats["native_connect_s"] * 1e3
+        bench["verify_ms"] += stats["verify_s"] * 1e3
+        bench["flush_ms"] += stats["flush_s"] * 1e3
+        self.last_import_stats = stats
+        # the operator's reading (README, Observability): the legs, the
+        # dispatch queue, then every span's self seconds, largest first
+        legs = ("connect %.1fs verify %.1fs flush %.1fs" % (
+            stats["native_connect_s"], stats["verify_s"], stats["flush_s"])
+            if phases else "-telemetry=off: no span times")
+        log_printf(
+            "native import: %d blocks (%d slow-path), %.1f MB in %.1fs "
+            "(%s); %d flushes, %d dispatches (%d of them tails, %d lanes), "
+            "unfinished at enqueue %s, queue seen empty %.2fs; self "
+            "seconds: %s",
+            n_imported, stats["slow_path_blocks"], stats["bytes"] / 1e6,
+            stats["wall_s"], legs, stats["flushes"], stats["dispatches"],
+            stats["tail_dispatches"], stats["tail_lanes"],
+            stats["inflight_at_enqueue"], stats["queue_empty_s"],
+            " ".join(f"{name} {row['self_s']:.3f}" for name, row in sorted(
+                phases.items(), key=lambda kv: -kv[1]["self_s"])))
+        return n_imported
+
+    def _native_import(self) -> tuple:
+        """The body of _import_block_files_native, inside its ``import``
+        span: returns (blocks imported, the stats its spans do not
+        fill)."""
         import struct
 
         import numpy as np
@@ -1656,6 +1754,7 @@ class Node:
             block_script_flags,
         )
 
+        span = telemetry.span
         cs = self.chainstate
         params = self.params
         consensus = params.consensus
@@ -1665,11 +1764,11 @@ class Node:
                                              DEFAULT_FLUSH_INTERVAL)
         dbcache_bytes = max(
             1, self.config.get_int("dbcache", 300)) * 1024 * 1024
-        t_start = time.perf_counter()
         cs.flush()  # the engine's base view must be current before takeover
 
         eng = native.ConnectEngine()
         eng.set_best(cs.coins.best_block())
+        MAX_INFLIGHT = 3
         # fallback_s (the generic-script leg) is inside verify_s too.
         # fallback_inputs: every input the P2PKH scan did not take =
         # template_inputs (a native script template wrote their lanes, its
@@ -1679,7 +1778,23 @@ class Node:
         # NULLFAIL (history below the fork height); the scan's threads'
         # seconds (sigscan_thread_s; sigscan_s is its wall) and, of them,
         # those inside the legacy SignatureHash, with its digests and the
-        # bytes of serialised transaction they hashed
+        # bytes of serialised transaction they hashed.
+        # The times that are not the scan's own are sums of spans, filled
+        # in when the import ends (_IMPORT_LEGS). At the dispatch queue, by
+        # what the runtime says of each handle (BatchHandle.done, which
+        # blocks on nothing; the list `inflight` itself is no guide: a
+        # handle stays on it until a fourth is enqueued, long after the chip
+        # has finished it): inflight_at_enqueue[k] counts the enqueues that
+        # found k dispatches unfinished (the last slot: 3 or more; near 3
+        # the chip paces the import, near 0 the host does). queue_empty_s,
+        # for the log alone, is the wall from the moment the import saw
+        # every dispatch finished (it looks after each native connect, block
+        # and settle; not at all under -telemetry=off) until it has handed
+        # the next one over: a lower bound of the chip's idle time by the
+        # host's doing, since the import cannot look inside the native call
+        # (the device trace has the time itself: tools/trace_view.py
+        # --xplane). tail_dispatches and tail_lanes are the <= 2,046-lane
+        # chunks of a drain, flushes the drains made.
         stats = {"blocks": 0, "bytes": 0, "native_connect_s": 0.0,
                  "sigscan_s": 0.0, "verify_s": 0.0, "fallback_s": 0.0,
                  "flush_s": 0.0, "slow_path_blocks": 0,
@@ -1687,7 +1802,21 @@ class Node:
                  "interp_inputs": 0, "fast_inputs": 0,
                  "prefork_blocks": 0, "sigscan_thread_s": 0.0,
                  "legacy_digests": 0, "legacy_sighash_bytes": 0,
-                 "legacy_sighash_s": 0.0}
+                 "legacy_sighash_s": 0.0,
+                 "dispatches": 0, "tail_dispatches": 0, "tail_lanes": 0,
+                 "flushes": 0, "queue_empty_s": 0.0,
+                 "inflight_at_enqueue": [0] * (MAX_INFLIGHT + 1)}
+        queue_empty_since: list = [None]
+        watch_queue = telemetry.mode() != "off"
+
+        def unfinished() -> int:
+            return sum(1 for entry in inflight if not entry[1].done())
+
+        def note_queue_empty() -> None:
+            if (watch_queue and queue_empty_since[0] is None
+                    and stats["dispatches"] and not unfinished()):
+                queue_empty_since[0] = time.monotonic()
+
         scan_keys = ("sigscan_s", "sigscan_thread_s", "legacy_digests",
                      "legacy_sighash_bytes", "legacy_sighash_s")
         # counters of gettpuinfo.batch reported as the import's own deltas
@@ -1699,7 +1828,6 @@ class Node:
         # in-flight signature batches: (block hash, BatchHandle, number of
         # its first lane, its candidate mask)
         inflight: list[tuple] = []
-        MAX_INFLIGHT = 3
         # cross-block record aggregation: mainnet blocks carry ~2-5k sig
         # inputs, and per-dispatch latency amortizes over wider buckets
         # (the rate gain is not measured on the current machine) —
@@ -1743,19 +1871,30 @@ class Node:
         settler = _MultisigSettler(confirm_on_host)
 
         def dispatch(arrays, sl: slice) -> None:
-            cand = arrays[5][sl]
-            handle = ecdsa_batch.dispatch_packed(
-                *(a[sl] for a in arrays[:5]), backend=self.connect_backend,
-                candidate=cand if cand.any() else None)
+            stats["dispatches"] += 1
+            if watch_queue:
+                stats["inflight_at_enqueue"][min(unfinished(),
+                                                 MAX_INFLIGHT)] += 1
+            with span("import.enqueue", lanes=sl.stop - sl.start):
+                cand = arrays[5][sl]
+                handle = ecdsa_batch.dispatch_packed(
+                    *(a[sl] for a in arrays[:5]),
+                    backend=self.connect_backend,
+                    candidate=cand if cand.any() else None)
+            if queue_empty_since[0] is not None:
+                # the chip had nothing until this program was handed over
+                stats["queue_empty_s"] += (time.monotonic()
+                                           - queue_empty_since[0])
+                queue_empty_since[0] = None
             inflight.append((agg_last_hash[0], handle,
                              lanes_dispatched[0] + sl.start, cand))
 
         def flush_agg(everything: bool = True):
             if not agg:
                 return
-            t0 = time.perf_counter()
-            arrays = [np.concatenate([a[i] for a in agg])
-                      for i in range(6)]
+            with span("import.pack", blocks=len(agg)):
+                arrays = [np.concatenate([a[i] for a in agg])
+                          for i in range(6)]
             agg.clear()
             pos = 0
             total = len(arrays[2])
@@ -1774,23 +1913,24 @@ class Node:
                 # the whole import
                 while pos < total:
                     end = min(pos + 2046, total)
+                    stats["tail_dispatches"] += 1
+                    stats["tail_lanes"] += end - pos
                     dispatch(arrays, slice(pos, end))
                     pos = end
             if pos < total:
                 agg.append(tuple(a[pos:] for a in arrays))
             agg_count[0] = total - pos
             lanes_dispatched[0] += pos
-            dt = time.perf_counter() - t0
-            stats["verify_s"] += dt
-            cs.bench["verify_ms"] += dt * 1e3
             while len(inflight) > MAX_INFLIGHT:
                 settle_oldest()
 
         def settle_oldest():
             h, handle, first, cand = inflight.pop(0)
-            t0 = time.perf_counter()
-            try:
+            # the thread blocked on the chip, and nothing else
+            with span("import.settle_wait", inflight=len(inflight) + 1):
                 ok = handle.result()
+            note_queue_empty()
+            with span("import.settle"):
                 # a must-verify lane has to verify; a candidate lane's
                 # verdict feeds its group's walk
                 if not bool(np.all(ok | cand)):
@@ -1798,10 +1938,6 @@ class Node:
                         f"sig batch failed in block {hash_to_hex(h)[:16]}"
                     )
                 settler.settled(first, ok)
-            finally:
-                dt = time.perf_counter() - t0
-                stats["verify_s"] += dt
-                cs.bench["verify_ms"] += dt * 1e3
 
         def settle_all():
             flush_agg()
@@ -1818,44 +1954,46 @@ class Node:
                     f"{settler.pending[0][0]} of {lanes_dispatched[0]}")
 
         def fast_flush():
-            settle_all()
-            t0 = time.perf_counter()
-            self.block_store.flush()
-            cs.flush_index()
-            best = eng.best()
-            self.coins_db.batch_write_serialized(eng.flush_entries(), best)
-            eng.clear()
-            # keep the Python cache's best-block in step: a later
-            # cs.flush() must not rewind the marker to its stale cached
-            # value (it survives CoinsCache.flush)
-            cs.coins.set_best_block(best)
-            dt = time.perf_counter() - t0
-            stats["flush_s"] += dt
-            cs.bench["flush_ms"] += dt * 1e3
+            with span("import.drain"):
+                settle_all()
+            stats["flushes"] += 1
+            with span("import.flush", flush=stats["flushes"]):
+                self.block_store.flush()
+                cs.flush_index()
+                best = eng.best()
+                self.coins_db.batch_write_serialized(eng.flush_entries(),
+                                                     best)
+                eng.clear()
+                # keep the Python cache's best-block in step: a later
+                # cs.flush() must not rewind the marker to its stale cached
+                # value (it survives CoinsCache.flush)
+                cs.coins.set_best_block(best)
 
         def service_misses(missing_keys) -> int:
-            rows = self.coins_db.get_serialized_many(missing_keys)
-            for key, ser in rows.items():
-                r = ByteReader(ser)
-                from ..consensus.serialize import (
-                    deser_compact_size,
-                    deser_var_bytes,
-                )
+            from ..consensus.serialize import (
+                deser_compact_size,
+                deser_var_bytes,
+            )
 
-                code = deser_compact_size(r, range_check=False)
-                value = deser_compact_size(r, range_check=False)
-                spk = deser_var_bytes(r)
-                eng.insert(key, code, value, spk)
+            with span("import.store_read", keys=len(missing_keys)):
+                rows = self.coins_db.get_serialized_many(missing_keys)
+                for key, ser in rows.items():
+                    r = ByteReader(ser)
+                    code = deser_compact_size(r, range_check=False)
+                    value = deser_compact_size(r, range_check=False)
+                    spk = deser_var_bytes(r)
+                    eng.insert(key, code, value, spk)
             return len(rows)
 
         def slow_path(raw: bytes, pos_info: Optional[tuple]) -> bool:
             """Flush engine state, process via the Python engine, resync."""
             stats["slow_path_blocks"] += 1
-            fast_flush()
-            block = CBlock.from_bytes(raw)
-            connected = try_process(block, pos_info)
-            cs.flush()
-            eng.set_best(cs.coins.best_block())
+            with span("import.slow_path"):
+                fast_flush()
+                block = CBlock.from_bytes(raw)
+                connected = try_process(block, pos_info)
+                cs.flush()
+                eng.set_best(cs.coins.best_block())
             return connected
 
         def try_process(block: CBlock, pos_info: Optional[tuple]) -> bool:
@@ -1949,147 +2087,170 @@ class Node:
                 cand = np.concatenate([cand, ecand])
             return (*lanes, cand), native_groups + groups
 
+        def join_lanes(raw: bytes, res, h: bytes, height: int, flags: int,
+                       prefork: bool) -> bool:
+            """One block's lanes into the aggregation: those the P2PKH scan
+            wrote, then the script leg's. False where the leg sends the
+            block to the Python path."""
+            status = res.sig_status
+            fast_idx = np.nonzero(status == 0)[0]
+            stats["fast_inputs"] += int(fast_idx.size)
+            ecdsa_batch.STATS.p2pkh_fast_path += int(fast_idx.size)
+            pub = res.sig_pub[fast_idx]
+            rs = res.sig_rs[fast_idx]
+            msg = res.sig_msg[fast_idx]
+            rn = res.sig_rn[fast_idx]
+            wrap = res.sig_wrap[fast_idx]
+            cand = np.zeros(len(msg), bool)
+            n_leg = res.n_inputs - int(fast_idx.size)
+            if n_leg:
+                # generic-script inputs: the lanes of those a native
+                # template fits, the Python interpreter the authority
+                # for the rest; both join the same batch
+                interp_idx = np.nonzero(status == 1)[0]
+                stats["fallback_inputs"] += n_leg
+                stats["template_inputs"] += len(res.leg_table)
+                stats["interp_inputs"] += int(interp_idx.size)
+                with span("import.script_leg", height=height, inputs=n_leg):
+                    leg = script_leg(raw, res, interp_idx, flags, h)
+                if leg is None:
+                    return False
+                leg_lanes, groups = leg
+                settler.add(lanes_dispatched[0] + agg_count[0]
+                            + len(msg), groups)
+                pub, rs, msg, rn, wrap, cand = (
+                    np.concatenate(pair) for pair in zip(
+                        (pub, rs, msg, rn, wrap, cand), leg_lanes))
+            if len(msg):
+                agg.append((pub, rs, msg, rn, wrap, cand))
+                agg_count[0] += len(msg)
+                agg_last_hash[0] = h
+                if prefork:
+                    ecdsa_batch.STATS.prefork_lanes += len(msg)
+            return True
+
         def fast_connect(raw: bytes, h: bytes, prev, pos_info) -> bool:
             """One linear-extension block through the native engine.
             Returns False when the block must go through the Python path."""
             nonlocal n_imported
-            header = CBlockHeader.deserialize(ByteReader(raw[:80]))
-            try:
-                cs.check_block_header(header)
-                cs.contextual_check_block_header(header, prev)
-            except BlockValidationError:
-                return False  # Python path gives the authoritative verdict
-            height = prev.height + 1
-            idx = CBlockIndex(header, h, prev)
-            check_scripts = (cs.script_checks_needed(idx)
-                             and cs.script_verifier is not None)
-            flags = block_script_flags(height, header.time, params)
-            prefork = check_scripts and not flags & SCRIPT_VERIFY_NULLFAIL
-            bip34 = (script_int(height)
-                     if height >= consensus.bip34_height else None)
-            mtp = prev.get_median_time_past()
-            subsidy = get_block_subsidy(height, consensus)
-            t0 = time.perf_counter()
-            try:
+            with span("import.header"):
+                header = CBlockHeader.deserialize(ByteReader(raw[:80]))
                 try:
-                    res = eng.connect_block(
-                        raw, height, subsidy, params.max_block_size,
-                        consensus.coinbase_maturity, mtp, bip34, flags,
-                        want_sigs=check_scripts, commit=False,
-                        nthreads=native.PAR_THREADS)
-                except native.EngineMissing as miss:
-                    if service_misses(miss.keys) == 0:
-                        return False  # truly missing inputs: Python path
-                    res = eng.connect_block(
-                        raw, height, subsidy, params.max_block_size,
-                        consensus.coinbase_maturity, mtp, bip34, flags,
-                        want_sigs=check_scripts, commit=False,
-                        nthreads=native.PAR_THREADS)
-            except (native.EngineMissing, native.EngineError):
-                eng.abort()
-                return False
-            stats["native_connect_s"] += time.perf_counter() - t0
+                    cs.check_block_header(header)
+                    cs.contextual_check_block_header(header, prev)
+                except BlockValidationError:
+                    return False  # Python path gives the verdict
+                height = prev.height + 1
+                idx = CBlockIndex(header, h, prev)
+                check_scripts = (cs.script_checks_needed(idx)
+                                 and cs.script_verifier is not None)
+                flags = block_script_flags(height, header.time, params)
+                prefork = check_scripts and not flags & SCRIPT_VERIFY_NULLFAIL
+                bip34 = (script_int(height)
+                         if height >= consensus.bip34_height else None)
+                mtp = prev.get_median_time_past()
+                subsidy = get_block_subsidy(height, consensus)
+            # native/connect.cpp's own stopwatches (scan_keys) stay what
+            # they are, inside this span
+            with span("import.connect", height=height):
+                try:
+                    try:
+                        res = eng.connect_block(
+                            raw, height, subsidy, params.max_block_size,
+                            consensus.coinbase_maturity, mtp, bip34, flags,
+                            want_sigs=check_scripts, commit=False,
+                            nthreads=native.PAR_THREADS)
+                    except native.EngineMissing as miss:
+                        if service_misses(miss.keys) == 0:
+                            return False  # truly missing inputs: Python path
+                        res = eng.connect_block(
+                            raw, height, subsidy, params.max_block_size,
+                            consensus.coinbase_maturity, mtp, bip34, flags,
+                            want_sigs=check_scripts, commit=False,
+                            nthreads=native.PAR_THREADS)
+                except (native.EngineMissing, native.EngineError):
+                    eng.abort()
+                    return False
             for key in scan_keys:
                 stats[key] += getattr(res, key)
-            cs.bench["connect_ms"] += (time.perf_counter() - t0) * 1e3
+            note_queue_empty()  # the native call is where a kernel ends
 
             # BIP30 base-store leg: only pre-BIP34 heights can mint
             # duplicate txids (the engine checked its in-memory map; rows
             # flushed out of it need the batched base lookup)
             if height < consensus.bip34_height and res.n_tx:
-                keys = []
-                for i in range(res.n_tx):
-                    txid = res.txid(i)
-                    for o in range(int(res.tx_out_counts[i])):
-                        keys.append(txid + struct.pack("<I", o))
-                if self.coins_db.get_serialized_many(keys):
+                with span("import.store_read", bip30=res.n_tx):
+                    keys = []
+                    for i in range(res.n_tx):
+                        txid = res.txid(i)
+                        for o in range(int(res.tx_out_counts[i])):
+                            keys.append(txid + struct.pack("<I", o))
+                    clash = self.coins_db.get_serialized_many(keys)
+                if clash:
                     eng.abort()
                     return False  # Python path raises bad-txns-BIP30
 
             if check_scripts and res.n_inputs:
-                t0 = time.perf_counter()
-                status = res.sig_status
-                fast_idx = np.nonzero(status == 0)[0]
-                stats["fast_inputs"] += int(fast_idx.size)
-                ecdsa_batch.STATS.p2pkh_fast_path += int(fast_idx.size)
-                pub = res.sig_pub[fast_idx]
-                rs = res.sig_rs[fast_idx]
-                msg = res.sig_msg[fast_idx]
-                rn = res.sig_rn[fast_idx]
-                wrap = res.sig_wrap[fast_idx]
-                cand = np.zeros(len(msg), bool)
-                n_leg = res.n_inputs - int(fast_idx.size)
-                if n_leg:
-                    # generic-script inputs: the lanes of those a native
-                    # template fits, the Python interpreter the authority
-                    # for the rest; both join the same batch
-                    interp_idx = np.nonzero(status == 1)[0]
-                    stats["fallback_inputs"] += n_leg
-                    stats["template_inputs"] += len(res.leg_table)
-                    stats["interp_inputs"] += int(interp_idx.size)
-                    t_leg = time.perf_counter()
-                    with telemetry.span("import.script_leg", height=height,
-                                        inputs=n_leg):
-                        leg = script_leg(raw, res, interp_idx, flags, h)
-                    stats["fallback_s"] += time.perf_counter() - t_leg
-                    if leg is None:
-                        eng.abort()
-                        return False  # Python path re-derives the verdict
-                    leg_lanes, groups = leg
-                    settler.add(lanes_dispatched[0] + agg_count[0]
-                                + len(msg), groups)
-                    pub, rs, msg, rn, wrap, cand = (
-                        np.concatenate(pair) for pair in zip(
-                            (pub, rs, msg, rn, wrap, cand), leg_lanes))
-                if len(msg):
-                    agg.append((pub, rs, msg, rn, wrap, cand))
-                    agg_count[0] += len(msg)
-                    agg_last_hash[0] = h
-                    if prefork:
-                        ecdsa_batch.STATS.prefork_lanes += len(msg)
-                dt = time.perf_counter() - t0
-                stats["verify_s"] += dt
-                cs.bench["verify_ms"] += dt * 1e3
+                with span("import.lanes", inputs=res.n_inputs):
+                    joined = join_lanes(raw, res, h, height, flags, prefork)
+                if not joined:
+                    eng.abort()
+                    return False  # Python path re-derives the verdict
 
-            eng.commit()
-            # -- Python bookkeeping (index, chain, stores) --
-            idx.n_tx = res.n_tx
-            cs._seq += 1
-            idx.sequence_id = cs._seq
-            idx.status |= BlockStatus.HAVE_DATA | BlockStatus.HAVE_UNDO
-            idx.raise_validity(
-                BlockStatus.VALID_SCRIPTS if check_scripts
-                else BlockStatus.VALID_CHAIN)
-            idx.chain_tx = prev.chain_tx + idx.n_tx
-            cs.block_index[h] = idx
-            cs._dirty_index.add(idx)
-            if pos_info is not None:
-                self.block_store.positions.setdefault(h, pos_info)
-            self.block_store.put_undo(h, res.undo)
-            cs.chain.set_tip(idx)
-            cs.bench["blocks"] += 1
+            with span("import.index", height=height):
+                eng.commit()
+                # -- Python bookkeeping (index, chain, stores) --
+                idx.n_tx = res.n_tx
+                cs._seq += 1
+                idx.sequence_id = cs._seq
+                idx.status |= BlockStatus.HAVE_DATA | BlockStatus.HAVE_UNDO
+                idx.raise_validity(
+                    BlockStatus.VALID_SCRIPTS if check_scripts
+                    else BlockStatus.VALID_CHAIN)
+                idx.chain_tx = prev.chain_tx + idx.n_tx
+                cs.block_index[h] = idx
+                cs._dirty_index.add(idx)
+                if pos_info is not None:
+                    self.block_store.positions.setdefault(h, pos_info)
+                self.block_store.put_undo(h, res.undo)
+                cs.chain.set_tip(idx)
+                cs.bench["blocks"] += 1
+                n_imported += 1
+                stats["blocks"] += 1
+                stats["prefork_blocks"] += prefork
+            note_queue_empty()
             if agg_count[0] >= AGG_LANES:
                 flush_agg(everything=False)
-            n_imported += 1
-            stats["blocks"] += 1
-            stats["prefork_blocks"] += prefork
             return True
 
-        def process_raw(raw: bytes, pos_info: Optional[tuple]) -> bool:
+        def classify(raw: bytes, pos_info: Optional[tuple]) -> tuple:
+            """Where one record goes, from the index alone: (route, block
+            hash, parent's index entry); a duplicate and a block without
+            its parent are dealt with here (route None)."""
             h = sha256d_py(raw[:80])
             idx = cs.block_index.get(h)
             if idx is not None and (idx.status & BlockStatus.HAVE_DATA):
                 if pos_info is not None:
                     self.block_store.positions.setdefault(h, pos_info)
-                return False  # duplicate
+                return None, h, None  # duplicate
             prev_hash = raw[4:36]
             prev = cs.block_index.get(prev_hash)
             if prev is None:
                 pending.setdefault(prev_hash, []).append((raw, pos_info))
+                return None, h, None
+            fast = prev is cs.chain.tip() and idx is None
+            return ("fast" if fast else "slow"), h, prev
+
+        def process_raw(raw: bytes, pos_info: Optional[tuple],
+                        route: Optional[tuple] = None) -> bool:
+            if route is None:  # a parked child: the loop has not read it
+                with span("import.read"):
+                    route = classify(raw, pos_info)
+            kind, h, prev = route
+            if kind is None:
                 return False
-            if prev is cs.chain.tip() and idx is None:
-                if fast_connect(raw, h, prev, pos_info):
-                    return True
+            if kind == "fast" and fast_connect(raw, h, prev, pos_info):
+                return True
             return slow_path(raw, pos_info)
 
         from ..crypto.hashes import sha256d as sha256d_py
@@ -2108,24 +2269,27 @@ class Node:
                                     f"blk{n_file:05d}.dat")
                 if not os.path.exists(path):
                     break
-                with open(path, "rb") as f:
-                    data = f.read()
+                with span("import.read", file=n_file):
+                    with open(path, "rb") as f:
+                        data = f.read()
                 pos = 0
                 blocks_since_flush = 0
                 while pos + 8 <= len(data):
                     if data[pos:pos + 4] != magic:
                         pos += 1
                         continue
-                    (size,) = struct.unpack_from("<I", data, pos + 4)
-                    start = pos + 8
-                    if start + size > len(data):
-                        break  # truncated tail record (crash mid-append)
-                    raw = data[start:start + size]
-                    pos_info = (n_file, start, size)
-                    stats["bytes"] += size
-                    if process_raw(raw, pos_info):
+                    with span("import.read"):
+                        (size,) = struct.unpack_from("<I", data, pos + 4)
+                        start = pos + 8
+                        if start + size > len(data):
+                            break  # truncated tail record (crash mid-append)
+                        raw = data[start:start + size]
+                        pos_info = (n_file, start, size)
+                        stats["bytes"] += size
+                        route = classify(raw, pos_info)
+                    if process_raw(raw, pos_info, route):
                         # cascade children parked on this block
-                        queue = [sha256d_py(raw[:80])]
+                        queue = [route[1]]
                         while queue:
                             hh = queue.pop()
                             for c_raw, c_pos in pending.pop(hh, ()):
@@ -2147,20 +2311,14 @@ class Node:
                     handle.result()
                 except Exception:  # noqa: BLE001 — abort-path drain
                     pass
-        cs.activate_best_chain()  # safety: settle any side-chain candidates
-        cs.flush()
-        eng.close()
-        stats["wall_s"] = time.perf_counter() - t_start
+        with span("import.close"):
+            # safety: settle any side-chain candidates
+            cs.activate_best_chain()
+            cs.flush()
+            eng.close()
         for key, was in zip(delta_keys, delta0):
             stats[key] = getattr(ecdsa_batch.STATS, key) - was
-        self.last_import_stats = stats
-        log_printf(
-            "native import: %d blocks (%d slow-path), %.1f MB in %.1fs "
-            "(connect %.1fs verify %.1fs flush %.1fs)",
-            n_imported, stats["slow_path_blocks"], stats["bytes"] / 1e6,
-            stats["wall_s"], stats["native_connect_s"], stats["verify_s"],
-            stats["flush_s"])
-        return n_imported
+        return n_imported, stats
 
     def _import_block_files_python(self, paths: Optional[list[str]] = None) -> int:
         """The Python-engine import loop (reference implementation) — and

@@ -41,7 +41,6 @@ import time
 from dataclasses import dataclass
 from typing import Callable, Optional
 
-from ..util import devicewatch as dw
 from ..util import telemetry as tm
 from ..util.faults import INJECTOR, Backoff, PoisonedOutput, retry_call
 from ..util.log import log_print, log_printf
@@ -303,11 +302,6 @@ def supervised_call(site: str, device_fn: Callable, cpu_fn: Callable,
                 _RETRIES.labels(site=site).inc(calls[0] - 1)
             dt = time.monotonic() - t0
             _LAT.labels(site=site, path="device").observe(dt)
-            # synchronous crossing: the whole device leg (dispatch +
-            # blocking materialization inside device_fn) is one
-            # "execute" phase — async sites split execute/fetch
-            # themselves (util/devicewatch phase vocabulary)
-            dw.note_phase(site, "execute", dt)
             return out, True
         except (KeyboardInterrupt, SystemExit):
             raise
@@ -376,9 +370,6 @@ class SupervisedHandle:
             br.record_success()
             dt = time.monotonic() - t0
             _LAT.labels(site=self._site, path="settle").observe(dt)
-            # async crossing: result() blocks on materialization — the
-            # "fetch" phase of the dispatch decomposition
-            dw.note_phase(self._site, "fetch", dt)
             self._result = out
         except (KeyboardInterrupt, SystemExit):
             raise

@@ -47,17 +47,14 @@ from ..util.faults import INJECTOR, Backoff, PoisonedOutput
 from ..util.log import log_printf
 from . import dispatch
 
-# -- telemetry families (util/telemetry): per-stage host-pack latency,
-# device settle-wait distribution, dispatch/flush lane-size histograms,
-# and the lane-fill / in-flight gauges. STATS itself is projected onto
-# the registry by the collector below, so getmetrics' /metrics namespace
-# and gettpuinfo's `batch` section read the same counters.
-_STAGE_H = tm.histogram(
-    "bcp_ecdsa_stage_seconds",
-    "Host pack-stage latency per dispatch (emit = byte-matrix padding)",
-    labels=("stage",),
-    buckets=(0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
-             1.0, 2.5, 5.0))
+# -- telemetry families (util/telemetry): device settle-wait
+# distribution, dispatch/flush lane-size histograms, and the lane-fill /
+# in-flight gauges. STATS itself is projected onto the registry by the
+# collector below, so getmetrics' /metrics namespace and gettpuinfo's
+# `batch` section read the same counters. The host's stages a dispatch are
+# spans (ecdsa.pack, ecdsa.enqueue, ecdsa.settle: bcp_span_* on /metrics,
+# host events on a profiler trace); STATS.glv_emit_s, glv_dispatch_s and
+# device_seconds are views of those spans' seconds.
 _SETTLE_H = tm.histogram(
     "bcp_ecdsa_settle_wait_seconds",
     "Blocking wait at BatchHandle.result() — near zero when the pipeline "
@@ -151,10 +148,8 @@ def _watched_kernel(pw, bucket: int, arrays, fn, jitfn=None, kwargs=None,
                      sum(int(a.nbytes) for a in arrays))
     sig = bucket if split is None else min(bucket, split)
     traced = (sig,) not in pw.signatures
-    t0 = time.monotonic()
     with pw.dispatch(sig, jitfn=jitfn, args=arrays, kwargs=kwargs):
         out = fn()
-    dw.note_phase("ecdsa", "execute", time.monotonic() - t0)
     if traced:
         _freeze_traced_heap()
     return out
@@ -300,7 +295,8 @@ class BatchStats:
     # GLV kernel accounting (gettpuinfo `ecdsa` section): dispatches that
     # ran the GLV program, GLV failures that degraded to the w4 kernel,
     # and the host's two stages a dispatch: emit_s is the numpy byte
-    # emission (pack_lanes), dispatch_s the enqueue of the GLV program
+    # emission (pack_lanes: the ecdsa.pack spans' seconds), dispatch_s the
+    # enqueue of the GLV program (the ecdsa.enqueue spans' seconds)
     glv_dispatches: int = 0
     glv_fallbacks: int = 0
     glv_emit_s: float = 0.0
@@ -428,14 +424,11 @@ def pack_lanes(pub: np.ndarray, rs: np.ndarray, msg: np.ndarray,
     q_inf[:m] = range_bad.astype(np.uint8)
     wrap8 = np.zeros(bucket, np.uint8)
     wrap8[:m] = wrap
-    t0 = time.monotonic()
-    with dw.phase("ecdsa", "pack"):
+    with tm.span("ecdsa.pack", lanes=m, bucket=bucket) as packed:
         arrays = [_pad(u1, bucket), _pad(u2, bucket),
                   _pad(pub[:, :32], bucket), _pad(pub[:, 32:], bucket),
                   q_inf, _pad(rs[:, :32], bucket), _pad(rn, bucket), wrap8]
-    dt = time.monotonic() - t0
-    STATS.glv_emit_s += dt
-    _STAGE_H.labels(stage="emit").observe(dt)
+    STATS.glv_emit_s += packed.seconds
     return arrays
 
 
@@ -640,7 +633,7 @@ def _msm_device_check(pairs, rng: random.Random) -> bool:
         terms.append((rec.pubkey[0], rec.pubkey[1], (a * e) % oracle.N))
     terms.append((oracle.GX, oracle.GY, (oracle.N - s_acc) % oracle.N))
     bucket = _msm_bucket_for(len(terms))
-    with dw.phase("ecdsa", "pack"):
+    with tm.span("ecdsa.pack", terms=len(terms), bucket=bucket):
         arrays = _msm_pack(terms, bucket)
     out = _watched_kernel(
         _PW_MSM, bucket, arrays,
@@ -924,13 +917,18 @@ class BatchHandle:
         self._cpu_ok = np.asarray(out, dtype=bool)
         return self._cpu_ok
 
+    def done(self) -> bool:
+        """Whether result() would return without waiting for the device:
+        asks the runtime, blocks on nothing."""
+        return self._device_ok is None or self._device_ok.is_ready()
+
     def result(self) -> np.ndarray:
         if self._device_ok is None:
             return self._cpu_ok
-        t0 = time.monotonic()
+        settle = tm.span("ecdsa.settle", parent=self._ctx, lanes=self._n,
+                         bucket=self._bucket)
         try:
-            with tm.span("ecdsa.settle", parent=self._ctx, lanes=self._n,
-                         bucket=self._bucket):
+            with settle:
                 ok = np.asarray(self._device_ok)  # blocks until chip done
         except (KeyboardInterrupt, SystemExit):
             raise
@@ -942,15 +940,12 @@ class BatchHandle:
         # device_seconds counts only the blocking wait — when the P3
         # overlap is doing its job the host hid the latency and this is
         # near zero; summing dispatch->settle spans would double-count
-        # concurrent chunks and absorb host interpreter time.
-        wait = time.monotonic() - t0
-        STATS.device_seconds += wait
-        _SETTLE_H.observe(wait)
-        # result fetch: the d2h crossing this settle actually paid
-        # (validity mask bytes; the wait is the isolatable transfer time)
-        dw.note_transfer("ecdsa", "d2h", int(np.asarray(ok).nbytes),
-                         seconds=wait)
-        dw.note_phase("ecdsa", "fetch", wait)
+        # concurrent chunks and absorb host interpreter time. The wait is
+        # timed once, by the span; _SETTLE_H keeps its distribution.
+        STATS.device_seconds += settle.seconds
+        _SETTLE_H.observe(settle.seconds)
+        # result fetch: the d2h crossing this settle paid (the mask's bytes)
+        dw.note_transfer("ecdsa", "d2h", int(np.asarray(ok).nbytes))
         STATS.in_flight = max(0, STATS.in_flight - 1)
         _IN_FLIGHT_G.set(STATS.in_flight)
         self._device_ok = None
@@ -1551,13 +1546,14 @@ def _dispatch_packed_device(pub, rs, msg, rn, wrap, n: int, br, kern: str,
                 try:
                     INJECTOR.on_call(GLV_DEV_SITE)
                     INJECTOR.on_call(GLV_SITE)
-                    t0 = time.monotonic()
                     # no jitfn: the call is two programs enqueued back to
                     # back, and the watch's cost capture lowers one
-                    device_ok, degen = _watched_kernel(
-                        _PW_GLV_DEV, bucket, arrays,
-                        lambda: dev.ecdsa_verify_batch_glv_dev(*arrays))
-                    STATS.glv_dispatch_s += time.monotonic() - t0
+                    with tm.span("ecdsa.enqueue", lanes=n,
+                                 bucket=bucket) as enqueued:
+                        device_ok, degen = _watched_kernel(
+                            _PW_GLV_DEV, bucket, arrays,
+                            lambda: dev.ecdsa_verify_batch_glv_dev(*arrays))
+                    STATS.glv_dispatch_s += enqueued.seconds
                     if (INJECTOR.should_poison(GLV_DEV_SITE)
                             or INJECTOR.should_poison(GLV_SITE)):
                         device_ok = ~device_ok

@@ -137,6 +137,7 @@ def sweep_header(header80: bytes, target: int, start_nonce: int = 0,
     the boundary is the resident loop's job (mining/resident.py).
     """
     from ..util import devicewatch as dw
+    from ..util import telemetry as tm
 
     assert len(header80) == 80
     midstate = np.array(header_midstate(header80), dtype=np.uint32)
@@ -148,21 +149,20 @@ def sweep_header(header80: bytes, target: int, start_nonce: int = 0,
     # so a sweep that starts recompiling per call trips the sentinel
     dw.note_transfer("miner", "h2d",
                      int(midstate.nbytes + tail.nbytes + tgt.nbytes))
-    t0 = time.perf_counter()
-    with dw.program("miner_sweep", shape_budget=4).dispatch(
-            tile, jitfn=sweep_jit,
-            args=(midstate, tail, tgt, np.uint32(start_nonce),
-                  np.uint32(n_tiles)),
-            kwargs={"tile": tile}):
+    with tm.span("miner.sweep", tiles=n_tiles), \
+            dw.program("miner_sweep", shape_budget=4).dispatch(
+                tile, jitfn=sweep_jit,
+                args=(midstate, tail, tgt, np.uint32(start_nonce),
+                      np.uint32(n_tiles)),
+                kwargs={"tile": tile}):
         found, nonce, tiles = sweep_jit(
             jnp.asarray(midstate), jnp.asarray(tail), jnp.asarray(tgt),
             jnp.uint32(start_nonce), jnp.uint32(n_tiles), tile=tile,
         )
-        # the jit call above only ENQUEUES — settle inside the watch so
-        # the sweep itself lands in the execute phase (the int() fetch
-        # below would otherwise be billed the whole kernel as "transfer")
+        # the jit call above only ENQUEUES — settle inside the watch and
+        # the span so the sweep itself lands there (the int() fetch below
+        # would otherwise be billed the whole kernel as "transfer")
         jax.block_until_ready(tiles)
-    dw.note_phase("miner", "execute", time.perf_counter() - t0)
     t0 = time.perf_counter()
     # attempted-hash accounting is also boundary-clamped: the final tile
     # may straddle 2^32, but nonces past the boundary were never part of

@@ -116,7 +116,9 @@ def gettpuinfo(node, params):
     assumeutxo snapshot progress — store/sharded.py), and — when the
     fleet front door is up — the gateway (``gateway``: admission/shed/
     coalesce/failover tallies and the replica rotation with per-replica
-    breaker state and probed tips — serving/gateway.py)."""
+    breaker state and probed tips — serving/gateway.py), the node's start-up
+    by stage (``startup``) and the RPC server's calls by method with the
+    wait for cs_main (``rpc``)."""
     from ..ops import dispatch, ecdsa_batch
     from ..util import faults
 
@@ -185,9 +187,14 @@ def gettpuinfo(node, params):
         # unified-telemetry view (util/telemetry): the active level, span
         # ring-buffer occupancy, and the serving path's p50/p90/p99
         # mempool accept latency (the registry's histogram — getmetrics /
-        # /metrics expose the full distribution)
+        # /metrics expose the full distribution). span_times false
+        # (-telemetry=off): every time here that is a span's seconds was
+        # not taken and reads 0 or is absent: batch.device_seconds,
+        # ecdsa.emit_s / dispatch_s, connectblock.*_ms of a native import,
+        # store.last_flush.spans / per_shard_s, startup, rpc.*_s
         "telemetry": {
             "mode": telemetry.mode(),
+            "span_times": telemetry.mode() != "off",
             "spans": telemetry.TRACER.stats(),
             "accept_latency": accept_latency_quantiles(),
         },
@@ -205,6 +212,14 @@ def gettpuinfo(node, params):
         # and any inversions/cycles; {"enabled": False} unless the
         # process runs with BCP_LOCKWATCH=1
         "lockwatch": lockwatch.snapshot(),
+        # Node.__init__'s stages, {phase: seconds} in the order they ran
+        # (the node.init.* spans: what a restart costs), and the RPC
+        # server's calls by method: {"calls", "lock_wait_s", "handler_s"},
+        # the wait for cs_main apart from the handler (rpc/server.py)
+        "startup": dict(getattr(getattr(node, "_startup", None),
+                                "seconds", {})),
+        "rpc": (node.rpc_server.call_stats()
+                if getattr(node, "rpc_server", None) is not None else {}),
     }
 
 

@@ -19,6 +19,7 @@ import secrets
 import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
+from ..util import telemetry as tm
 from ..util.log import log_print, log_printf
 from .registry import (
     RPC_INTERNAL_ERROR,
@@ -56,6 +57,9 @@ class RPCServer:
         self.port = self._httpd.server_address[1]
         self._thread = threading.Thread(target=self._httpd.serve_forever,
                                         name="rpc", daemon=True)
+        # gettpuinfo["rpc"]: {method: [calls, lock_wait_s, handler_s]}
+        self._calls: dict[str, list] = {}
+        self._calls_lock = threading.Lock()
 
     def start(self) -> None:
         self._thread.start()
@@ -86,16 +90,42 @@ class RPCServer:
             if getattr(handler, "no_cs_main", False):
                 # blocking handlers (longpoll, waitfor*) manage cs_main
                 # themselves so other RPC threads aren't starved
-                result = handler(self.node, params)
+                result = self._handle(handler, method, params, 0.0)
             else:
-                with self.node.cs_main:
-                    result = handler(self.node, params)
+                with tm.span("rpc.lock_wait", method=method) as waited:
+                    self.node.cs_main.acquire()
+                try:
+                    result = self._handle(handler, method, params,
+                                          waited.seconds)
+                finally:
+                    self.node.cs_main.release()
         except RPCError as e:
             return _error_obj(req_id, e.code, e.message)
         except Exception as e:  # the reference wraps these the same way
             log_printf("RPC internal error in %s: %r", method, e)
             return _error_obj(req_id, RPC_INTERNAL_ERROR, str(e))
         return {"result": result, "error": None, "id": req_id}
+
+
+    def _handle(self, handler, method: str, params: list,
+                lock_wait_s: float):
+        """The handler under its ``rpc.handler`` span; the call is counted
+        whether it returns or raises."""
+        ran = tm.span("rpc.handler", method=method)
+        try:
+            with ran:
+                return handler(self.node, params)
+        finally:
+            with self._calls_lock:
+                row = self._calls.setdefault(method, [0, 0.0, 0.0])
+                row[0] += 1
+                row[1] += lock_wait_s
+                row[2] += ran.seconds
+
+    def call_stats(self) -> dict:
+        with self._calls_lock:
+            return {method: {"calls": n, "lock_wait_s": w, "handler_s": h}
+                    for method, (n, w, h) in sorted(self._calls.items())}
 
 
 def _error_obj(req_id, code: int, message: str) -> dict:

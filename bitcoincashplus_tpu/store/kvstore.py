@@ -16,6 +16,7 @@ import threading
 from typing import Iterator, Optional
 
 from ..util import lockwatch
+from ..util import telemetry as tm
 from ..util.faults import maybe_crash
 
 
@@ -145,7 +146,9 @@ class KVStore:
             cur.execute("BEGIN")
             maybe_crash("kv:begin")
             try:
-                with _ROWS_LOCK:
+                with tm.span("store.rows_lock_wait"):
+                    _ROWS_LOCK.acquire()
+                try:
                     if deletes:
                         cur.executemany("DELETE FROM kv WHERE k = ?",
                                         [(k,) for k in deletes])
@@ -155,6 +158,8 @@ class KVStore:
                             "ON CONFLICT(k) DO UPDATE SET v=excluded.v",
                             list(puts.items()),
                         )
+                finally:
+                    _ROWS_LOCK.release()
                 # a hard kill here leaves an uncommitted WAL transaction
                 # that sqlite discards on reopen — the torn-commit case the
                 # crash-injection tests cover

@@ -271,7 +271,27 @@ class ShardedCoinsDB(CoinsView):
     # -- the commit protocol ---------------------------------------------
 
     def _commit_sharded(self, entries, best_block: bytes) -> None:
-        """entries: iterable of (key36, coin_ser | None-for-delete)."""
+        """entries: iterable of (key36, coin_ser | None-for-delete).
+
+        The commit's stages are spans under one ``store.commit``:
+        store.old_reads (the bloom pre-pass and the reads of persisted old
+        values), store.muhash, store.journal, store.shard_write (one a
+        shard, on the flush pool's threads; store.rows_lock_wait inside it
+        is the wait for kvstore._ROWS_LOCK), store.manifest. Their totals
+        land in ``last_flush["spans"]``, the shard threads' summed."""
+        with tm.span("store.commit", collect=True) as commit:
+            shard_spans = self._commit_stages(entries, best_block)
+        spans = commit.totals or {}
+        for totals in shard_spans:
+            for name, row in (totals or {}).items():
+                into = spans.setdefault(name, {"s": 0.0, "self_s": 0.0,
+                                               "n": 0})
+                for key, value in row.items():
+                    into[key] += value
+        self.last_flush["spans"] = spans
+
+    def _commit_stages(self, entries, best_block: bytes) -> list:
+        """The commit itself; returns the shard threads' span totals."""
         per_puts: list[dict] = [{} for _ in range(self.n_shards)]
         per_dels: list[list] = [[] for _ in range(self.n_shards)]
         n_coins = 0
@@ -292,23 +312,26 @@ class ShardedCoinsDB(CoinsView):
         flush_bloom = {"checked": 0, "skipped": 0}
         for i in range(self.n_shards):
             changed = list(per_puts[i]) + per_dels[i]
-            # bloom pre-pass: keys the filter proves absent (fresh coin
-            # creates, the flood-common case) skip the old-value lookup;
-            # false positives just pay the lookup, false negatives are
-            # impossible (every persisted key passed through add_many)
-            if changed and self.bloom_enabled:
-                maybe = self._bloom_for(i).filter(changed)
-                flush_bloom["checked"] += len(changed)
-                flush_bloom["skipped"] += len(changed) - len(maybe)
-            else:
-                maybe = changed
-            old = self.shards[i].get_serialized_many(maybe) if maybe \
-                else {}
-            removed = [(k, old[k]) for k in changed if k in old]
-            acc = muhash.MuHash(self._accs[i].state)
-            acc.apply(
-                [muhash.coin_product(per_puts[i].items())],
-                [muhash.coin_product(removed)] if removed else [])
+            with tm.span("store.old_reads", shard=i, keys=len(changed)):
+                # bloom pre-pass: keys the filter proves absent (fresh
+                # coin creates, the flood-common case) skip the old-value
+                # lookup; false positives just pay the lookup, false
+                # negatives are impossible (every persisted key passed
+                # through add_many)
+                if changed and self.bloom_enabled:
+                    maybe = self._bloom_for(i).filter(changed)
+                    flush_bloom["checked"] += len(changed)
+                    flush_bloom["skipped"] += len(changed) - len(maybe)
+                else:
+                    maybe = changed
+                old = self.shards[i].get_serialized_many(maybe) if maybe \
+                    else {}
+                removed = [(k, old[k]) for k in changed if k in old]
+            with tm.span("store.muhash", shard=i):
+                acc = muhash.MuHash(self._accs[i].state)
+                acc.apply(
+                    [muhash.coin_product(per_puts[i].items())],
+                    [muhash.coin_product(removed)] if removed else [])
             new_accs.append(acc)
             if self.bloom_enabled and per_puts[i]:
                 # the new puts become persisted rows below — future
@@ -336,8 +359,10 @@ class ShardedCoinsDB(CoinsView):
         try:
             for i, shard in enumerate(self.shards):
                 INJECTOR.on_call(STORE_SHARD_SITE)
-                atomic_write_bytes(shard.journal_path,
-                                   _encode_journal(kv_puts[i], kv_dels[i]))
+                with tm.span("store.journal", shard=i):
+                    atomic_write_bytes(
+                        shard.journal_path,
+                        _encode_journal(kv_puts[i], kv_dels[i]))
                 maybe_crash("journal:durable")
                 written.append(shard.journal_path)
         except BaseException:
@@ -352,26 +377,31 @@ class ShardedCoinsDB(CoinsView):
         t0 = time.perf_counter()
         per_shard_s = [0.0] * self.n_shards
 
-        def _apply(i: int) -> None:
-            ta = time.perf_counter()
-            self.shards[i].kv.write_batch(kv_puts[i], kv_dels[i], sync=True)
-            dt = time.perf_counter() - ta
-            per_shard_s[i] = dt
-            _FLUSH_HIST.labels(shard=str(i)).observe(dt)
+        def _apply(i: int):
+            # collect: on the pool's threads this span is nobody's child
+            with tm.span("store.shard_write", collect=True,
+                         shard=i) as wrote:
+                self.shards[i].kv.write_batch(kv_puts[i], kv_dels[i],
+                                              sync=True)
+            per_shard_s[i] = wrote.seconds
+            _FLUSH_HIST.labels(shard=str(i)).observe(wrote.seconds)
+            return wrote.totals
 
+        shard_spans = []
         if self._pool is not None:
             futures = [self._pool.submit(_apply, i)
                        for i in range(self.n_shards)]
             for f in futures:
-                f.result()
+                shard_spans.append(f.result())
         else:
-            _apply(0)
+            _apply(0)  # on this thread: inside store.commit's own totals
         maybe_crash("shard:applied")
 
         # step 3: the cross-shard epoch marker, written last
         self._accs = new_accs
         self._epoch = epoch
-        self._write_manifest()
+        with tm.span("store.manifest"):
+            self._write_manifest()
         maybe_crash("manifest:written")
 
         # step 4: clear
@@ -389,6 +419,7 @@ class ShardedCoinsDB(CoinsView):
         }
         for i in range(self.n_shards):
             _SHARD_BYTES.labels(shard=str(i)).set(self.shard_bytes(i))
+        return shard_spans
 
     def recover_journal(self) -> bool:
         """Startup replay/rollback across every shard, landing all of

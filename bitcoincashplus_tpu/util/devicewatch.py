@@ -26,11 +26,14 @@ monitor registered around every jit entrypoint:
   collector projecting ``device.memory_stats()`` into HBM gauges —
   graceful no-op on CPU backends, whose ``memory_stats()`` is None.
 
-- **Dispatch-phase profiling** (``phase()``, ``start_profile``/
-  ``stop_profile``): per-dispatch pack/transfer/execute/fetch legs into
-  ``bcp_dispatch_phase_seconds{site,phase}``, plus an on-demand
+- **Profiling** (``start_profile``/``stop_profile``): an on-demand
   ``jax.profiler`` wrapper (TensorBoard-compatible dump into the
   datadir) surfaced as the ``startprofile``/``stopprofile`` RPC pair.
+  The host's legs of a dispatch (pack, enqueue, the wait for the result)
+  are spans (util/telemetry: ecdsa.pack, ecdsa.enqueue, ecdsa.settle,
+  miner.enqueue, miner.poll_wait, dispatch.*), which a session records as
+  ``bcp.<name>`` host events on the device trace's own clock;
+  ``tools/trace_view.py --xplane`` puts the device's idle gaps under them.
 
 - **Stall watchdog** (``Watchdog``/``WATCHDOG``): a no-progress sentinel
   for threads that must keep draining work (the SigService flush loop,
@@ -101,12 +104,6 @@ _XFER_H = tm.histogram(
     labels=("site", "direction"),
     buckets=(0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025,
              0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0))
-_PHASE_H = tm.histogram(
-    "bcp_dispatch_phase_seconds",
-    "Per-dispatch phase decomposition (pack = host SoA/byte-matrix "
-    "emit, transfer = explicit staging, execute = program call, fetch = "
-    "blocking result materialization)",
-    labels=("site", "phase"))
 _WD_STALLED_G = tm.gauge(
     "bcp_watchdog_stalled",
     "1 while a subsystem has pending work but made no progress for its "
@@ -411,7 +408,7 @@ def program(name: str, shape_budget: Optional[int] = None) -> ProgramWatch:
 
 
 # ---------------------------------------------------------------------------
-# Transfer accounting + phase profiling
+# Transfer accounting
 # ---------------------------------------------------------------------------
 
 _TRANSFERS: dict[tuple, int] = {}  # (site, direction) -> bytes, ungated
@@ -429,21 +426,6 @@ def note_transfer(site: str, direction: str, nbytes: int,
     _XFER_B.labels(site=site, direction=direction).inc(n)
     if seconds is not None:
         _XFER_H.labels(site=site, direction=direction).observe(seconds)
-
-
-def note_phase(site: str, phase_name: str, seconds: float) -> None:
-    _PHASE_H.labels(site=site, phase=phase_name).observe(seconds)
-
-
-@contextmanager
-def phase(site: str, phase_name: str):
-    """Time one dispatch phase (pack/transfer/execute/fetch) into the
-    per-site phase histogram."""
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        note_phase(site, phase_name, time.perf_counter() - t0)
 
 
 def transfer_snapshot() -> dict:

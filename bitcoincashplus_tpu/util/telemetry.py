@@ -29,10 +29,16 @@ settle horizon. This module is the single aggregation surface:
 
 Gating: ``-telemetry=off|counters|trace`` (env ``BCP_TELEMETRY`` seeds the
 default for subprocesses). ``off`` turns every record call into a cheap
-flag check; ``counters`` (default) enables the registry, whose budget
-is under 2 % of a block import's wall time (held on the CPU backend
-when it was set; not measured on the chip); ``trace`` additionally
-records spans.
+flag check and every span into the shared null span. ``counters``
+(default) enables the registry and the spans' totals: a span adds its
+duration, its self time (duration less what its child spans on the same
+thread covered) and 1 to a per-name total (``span_totals()``), and enters
+a ``jax.profiler.TraceAnnotation("bcp." + name)`` where jax is already
+imported, so that the same span is a host event on the device trace's own
+clock under ``startprofile`` or any profiler session (a TraceMe costs an
+atomic load while no session is open). ``trace`` additionally records
+every span into the ring buffer. PERF.md section 6 (PR 40) has what the
+modes cost on the chip.
 
 Metric naming scheme: ``bcp_<subsystem>_<what>[_<unit>]`` — e.g.
 ``bcp_dispatch_latency_seconds{site="ecdsa",path="device"}``,
@@ -46,6 +52,7 @@ import bisect
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -478,12 +485,14 @@ _SPANS_CAP = int(os.environ.get("BCP_TRACE_SPANS", "65536"))
 
 
 class _NullSpan:
-    """The no-op span returned when tracing is off — one shared instance,
+    """The no-op span returned under -telemetry=off: one shared instance,
     no allocation on the hot path."""
 
     __slots__ = ()
     corr = None
     span_id = None
+    seconds = 0.0
+    totals = None
 
     def __enter__(self):
         return self
@@ -494,31 +503,93 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, once jax is imported
+
+
+def _annotation(name: str):
+    """A profiler TraceAnnotation for ``name``, or None where jax has not
+    been imported by real work (a span never imports it)."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        _ANNOTATION = getattr(sys.modules.get("jax.profiler"),
+                              "TraceAnnotation", None)
+        if _ANNOTATION is None:
+            return None
+    return _ANNOTATION(name)
+
+
+def _add_total(table: dict, name: str, dur: float, self_s: float) -> None:
+    row = table.get(name)
+    if row is None:
+        table[name] = [dur, self_s, 1]
+    else:
+        row[0] += dur
+        row[1] += self_s
+        row[2] += 1
+
+
+def _totals_view(table: dict) -> dict:
+    return {name: {"s": s, "self_s": self_s, "n": n}
+            for name, (s, self_s, n) in table.items()}
+
 
 class _Span:
-    __slots__ = ("_tracer", "name", "args", "corr", "span_id", "parent",
-                 "_t0")
+    """One open span. ``seconds`` is its duration once it has exited. A
+    span opened with ``collect=True`` also keeps the totals of its own
+    subtree on this thread (itself included) in ``totals``, in the shape of
+    span_totals(): the self times there add up to its duration."""
 
-    def __init__(self, tracer, name, args, corr, span_id, parent):
+    __slots__ = ("_tracer", "name", "args", "corr", "span_id", "parent",
+                 "_t0", "_child_s", "_ann", "_collected", "seconds")
+
+    def __init__(self, tracer, name, args, corr, span_id, parent, collect):
         self._tracer = tracer
         self.name = name
         self.args = args
         self.corr = corr
-        self.span_id = span_id
+        self.span_id = span_id      # None unless the ring records it
         self.parent = parent
         self._t0 = 0.0
+        self._child_s = 0.0
+        self._ann = None
+        self._collected = {} if collect else None
+        self.seconds = 0.0
+
+    @property
+    def totals(self):
+        return (None if self._collected is None
+                else _totals_view(self._collected))
 
     def __enter__(self):
         self._tracer._stack().append(self)
+        self._ann = _annotation("bcp." + self.name)
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.monotonic()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.monotonic()
-        stack = self._tracer._stack()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
+        tracer = self._tracer
+        stack = tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
-        self._tracer._record(self, self._t0, t1)
+        self.seconds = dur = t1 - self._t0
+        self_s = dur - self._child_s
+        if stack:
+            stack[-1]._child_s += dur
+        with tracer._lock:
+            _add_total(tracer._totals, self.name, dur, self_s)
+        for outer in stack:
+            if outer._collected is not None:
+                _add_total(outer._collected, self.name, dur, self_s)
+        if self._collected is not None:
+            _add_total(self._collected, self.name, dur, self_s)
+        if self.span_id is not None:
+            tracer._record(self, self._t0, t1)
         return False
 
 
@@ -539,6 +610,10 @@ class Tracer:
         self._ids = itertools.count(1)
         self._local = threading.local()
         self._epoch = time.monotonic()
+        # the same instant on the wall clock: a dumptrace laid beside an
+        # xplane (chrome_trace()["otherData"]["epoch_unix_ns"])
+        self._epoch_unix_ns = time.time_ns()
+        self._totals: dict = {}  # name -> [seconds, self seconds, count]
         self.recorded = 0  # total ever recorded (dropped = recorded - len)
 
     def _stack(self) -> list:
@@ -547,12 +622,18 @@ class Tracer:
             stack = self._local.stack = []
         return stack
 
-    def span(self, name: str, parent: Optional[tuple] = None, **args):
-        """Context manager recording one complete ('X') span. ``parent``
-        is a context() capture for cross-thread correlation; otherwise the
-        enclosing span on this thread (if any) is the parent."""
-        if not trace_enabled():
+    def span(self, name: str, parent: Optional[tuple] = None,
+             collect: bool = False, **args):
+        """Context manager around one span (module docstring: what it does
+        in each mode). ``parent`` is a context() capture for cross-thread
+        correlation in the ring; otherwise the enclosing span on this
+        thread (if any) is the parent. Self times follow the thread's own
+        nesting alone."""
+        m = mode()
+        if m == "off":
             return _NULL_SPAN
+        if m != "trace":
+            return _Span(self, name, args, None, None, None, collect)
         sid = next(self._ids)
         if parent is not None:
             corr, parent_id = parent
@@ -562,7 +643,7 @@ class Tracer:
                 corr, parent_id = stack[-1].corr, stack[-1].span_id
             else:
                 corr, parent_id = sid, None
-        return _Span(self, name, args, corr, sid, parent_id)
+        return _Span(self, name, args, corr, sid, parent_id, collect)
 
     def context(self) -> Optional[tuple]:
         """(corr, span_id) of this thread's active span, or None — the
@@ -620,9 +701,17 @@ class Tracer:
             return list(self._events)
 
     def clear(self) -> None:
+        """Drop the buffered events and the spans' totals."""
         with self._lock:
             self._events.clear()
+            self._totals.clear()
             self.recorded = 0
+
+    def span_totals(self) -> dict:
+        """{name: {"s", "self_s", "n"}} over every span that has exited,
+        on any thread, since the process started (or reset())."""
+        with self._lock:
+            return _totals_view(self._totals)
 
     def stats(self) -> dict:
         with self._lock:
@@ -638,7 +727,8 @@ class Tracer:
         return {
             "traceEvents": self.events(),
             "displayTimeUnit": "ms",
-            "otherData": {"producer": "bitcoincashplus-tpu telemetry"},
+            "otherData": {"producer": "bitcoincashplus-tpu telemetry",
+                          "epoch_unix_ns": self._epoch_unix_ns},
         }
 
     def dump(self, path: str) -> int:
@@ -655,8 +745,31 @@ class Tracer:
 TRACER = Tracer()
 
 
-def span(name: str, parent: Optional[tuple] = None, **args):
-    return TRACER.span(name, parent=parent, **args)
+def span(name: str, parent: Optional[tuple] = None, collect: bool = False,
+         **args):
+    return TRACER.span(name, parent=parent, collect=collect, **args)
+
+
+def span_totals() -> dict:
+    return TRACER.span_totals()
+
+
+def _collect_span_totals() -> list:
+    """Registry collector: the spans' totals on /metrics, one sample a
+    span name."""
+    totals = span_totals()
+    return [
+        {"name": f"bcp_span_{leaf}", "type": "counter", "help": text,
+         "samples": [({"span": name}, row[key])
+                     for name, row in sorted(totals.items())]}
+        for leaf, key, text in (
+            ("seconds_total", "s", "Seconds inside a span, by name"),
+            ("self_seconds_total", "self_s",
+             "Seconds inside a span and inside none of its child spans"),
+            ("count_total", "n", "Spans exited, by name"))]
+
+
+REGISTRY.register_collector("span_totals", _collect_span_totals)
 
 
 def trace_context() -> Optional[tuple]:
@@ -672,9 +785,9 @@ def instant(name: str, **args) -> None:
 
 
 def reset() -> None:
-    """Test isolation: zero every family, drop buffered spans, and
-    re-read the mode from env. Families and collectors survive (module-
-    level handles keep pointing at registered metrics)."""
+    """Test isolation: zero every family and the spans' totals, drop
+    buffered spans, and re-read the mode from env. Families and collectors
+    survive (module-level handles keep pointing at registered metrics)."""
     global _MODE
     _MODE = None
     REGISTRY.reset()

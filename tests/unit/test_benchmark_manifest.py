@@ -1,16 +1,27 @@
 """BENCHMARK.json against the limits the driver refuses a PR for before any
-run (PR 27 was: a configuration's ``source`` of 201 characters), and against
-the files it names."""
+run (PR 27 was: a configuration's ``source`` of 201 characters), against
+the files it names and against PERF.md (chipbench/tests holds the harness
+to the same, but the driver's test command does not collect it): every cell
+loads by name, every per-layer metric has a layer PERF.md section 3 names,
+and every reader reads an observation recorded on the chip
+(tests/unit/data/obs_*.json) and, without raising, the same observation cut
+to the keys the parent commit's has."""
 
+import copy
 import json
 import os
 import re
 import string
+import sys
 
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
+sys.path[:0] = [ROOT, os.path.join(ROOT, "chipbench")]
+
+import run  # noqa: E402  (chipbench/run.py)
+
 with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
     MANIFEST = json.load(_f)
 NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}")
@@ -20,6 +31,41 @@ CONFIGS = {c["name"]: c for c in MANIFEST["configs"]}
 CELLS = {w["name"]: w for w in MANIFEST["workloads"]}
 END_TO_END = {m["name"]: m for m in MANIFEST["end_to_end"]}
 PER_LAYER = {m["name"]: m for m in MANIFEST["per_layer"]}
+DATA = os.path.join(os.path.dirname(__file__), "data")
+# what a reader sees, recorded on the chip from the tree that last added a
+# reader and cut to what a reader may look at, by the cell it was recorded
+# in (every metric of a reindex cell lists reindex.pre_fork). Each file also
+# holds "parent_keys": the keys of a snapshot and of last_import_stats at
+# the parent of that tree, which the driver runs under this tree's readers.
+RECORDED = {"reindex.pre_fork": "obs_reindex_pre_fork.json",
+            "mine.diff1_solo": "obs_mine_diff1_solo.json"}
+
+
+def recorded(metric: dict, as_the_parent_has_it: bool) -> dict:
+    cells = metric.get("workloads", list(CELLS))
+    with open(os.path.join(DATA, next(
+            RECORDED[cell] for cell in RECORDED if cell in cells))) as f:
+        obs = json.load(f)
+    if as_the_parent_has_it:
+        keys = obs["parent_keys"]
+        for part in ("before", "after", "setup"):
+            snap = {k: copy.deepcopy(v) for k, v in obs[part].items()
+                    if k in keys["snapshot"]}
+            if snap.get("import"):
+                snap["import"] = {k: v for k, v in snap["import"].items()
+                                  if k in keys["import"]}
+            obs[part] = snap
+    return obs
+
+
+def layers_of_perf_md() -> set:
+    """The first column of the table in PERF.md's section 3."""
+    with open(os.path.join(ROOT, "PERF.md")) as f:
+        text = f.read()
+    section = re.search(r"^## 3\. .*?(?=^## 4\. )", text, re.M | re.S).group()
+    rows = [line.split("|")[1].strip() for line in section.splitlines()
+            if line.startswith("| ")]
+    return {row for row in rows if row not in ("layer", "---")}
 
 
 def line_ok(text) -> bool:
@@ -45,6 +91,9 @@ def test_the_manifest_is_small_and_its_names_are_unique():
     assert all(line_ok(word) for word in MANIFEST["command"])
     pairs = [(w["config"], w["traffic"]) for w in MANIFEST["workloads"]]
     assert len(set(pairs)) == len(pairs)
+    readers = {leaf[:-3] for leaf in os.listdir(os.path.join(
+        ROOT, MANIFEST["paths"][0], "layer_metrics")) if leaf.endswith(".py")}
+    assert readers == set(PER_LAYER), "a reader without its metric"
 
 
 @pytest.mark.parametrize("name", sorted(CONFIGS))
@@ -92,6 +141,15 @@ def test_cell_entry_and_its_files(name):
     assert "setup_s" in {m["name"] for m in felt} and len(felt) >= 2
     assert any(name in m.get("workloads", [name])
                for m in MANIFEST["per_layer"])
+    # and loads by name, as the harness loads it
+    loaded = run.load_cell(name, ROOT)
+    assert loaded["config"]["name"] == cell["config"]
+    module = run.load_module("drivers", driver, loaded["bench"])
+    for door in ("setup", "warm", "window", "check", "close"):
+        assert callable(getattr(module, door)), door
+    assert {m["name"] for m in loaded["end_to_end"]} == {
+        m["name"] for m in felt}
+    assert loaded["per_layer"]
 
 
 def test_at_most_half_the_cells_ask_for_four_chips():
@@ -129,6 +187,31 @@ def test_per_layer_metric_moves_an_end_to_end_metric_its_cells_report(name):
         assert metric["unit"] == "%"
     assert os.path.isfile(os.path.join(
         ROOT, MANIFEST["paths"][0], "layer_metrics", name + ".py"))
+    assert metric["layer"] in layers_of_perf_md(), (
+        f"PERF.md section 3 has no layer {metric['layer']!r}")
+    for cell in metric.get("workloads", CELLS):
+        assert name in {m["name"] for m in
+                        run.load_cell(cell, ROOT)["per_layer"]}
+
+
+@pytest.mark.parametrize("shape", ["recorded", "parent"])
+@pytest.mark.parametrize("name", sorted(PER_LAYER))
+def test_the_reader_on_a_recorded_observation(name, shape):
+    """Every reader reads a number, a share within 0..100, from the
+    observation as this tree gives it. The driver also runs the parent
+    commit under this tree's readers: cut to the parent's keys no reader
+    raises, and one that still reads there reads what it read before (what
+    a PR adds to the program moves no accepted metric); the others, those
+    the PR added, find nothing."""
+    metric = PER_LAYER[name]
+    reader = run.load_module("layer_metrics", name)
+    value = reader.read(recorded(metric, False))
+    if shape == "parent":
+        assert reader.read(recorded(metric, True)) in (None, value)
+        return
+    assert isinstance(value, (int, float)) and value == value, value
+    if metric["unit"] == "%":
+        assert 0.0 <= value <= 100.0
 
 
 @pytest.mark.parametrize("modules, kernel_ms, prepare_ms", [

@@ -228,15 +228,22 @@ def test_memory_collector_is_a_graceful_noop_on_cpu():
         assert f"# TYPE {name}" in text, name
 
 
-def test_phase_histogram_records_per_site_phases():
-    with dw.phase("ecdsa", "pack"):
+def test_a_dispatchs_legs_are_spans_not_a_phase_histogram():
+    """bcp_dispatch_phase_seconds went with PR 40 (every sample sat beside a
+    span or a latency histogram of the same interval): a dispatch's host
+    legs are spans, their totals on /metrics by name, and the ecdsa fetch
+    keeps its bytes without a second timing."""
+    assert not hasattr(dw, "phase") and not hasattr(dw, "note_phase")
+    with tm.span("ecdsa.pack"):
         pass
-    dw.note_phase("ecdsa", "execute", 0.01)
-    fam = tm.REGISTRY.snapshot()["bcp_dispatch_phase_seconds"]
-    seen = {(v["labels"]["site"], v["labels"]["phase"]): v["count"]
-            for v in fam["values"]}
-    assert seen[("ecdsa", "pack")] == 1
-    assert seen[("ecdsa", "execute")] == 1
+    dw.note_transfer("ecdsa", "d2h", 16)
+    snap = tm.REGISTRY.snapshot()
+    assert "bcp_dispatch_phase_seconds" not in snap
+    seen = {v["labels"]["span"]: v["value"]
+            for v in snap["bcp_span_count_total"]["values"]}
+    assert seen["ecdsa.pack"] == 1
+    assert sum(v["count"] for v in
+               snap["bcp_device_transfer_seconds"]["values"]) == 0
 
 
 # ---------------------------------------------------------------------------

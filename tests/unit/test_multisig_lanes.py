@@ -969,6 +969,8 @@ class _Flipping:
         outer = self
 
         class Handle:
+            done = handle.done
+
             def result(self):
                 ok = handle.result().copy()
                 if candidate is not None and not outer.flipped:

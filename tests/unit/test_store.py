@@ -182,12 +182,12 @@ def test_stores_written_at_once_take_turns_at_their_rows(tmp_path, stores):
         def __init__(self):
             self.inside = 0
 
-        def __enter__(self):
+        def acquire(self):
             real()
             self.inside += 1
             seen.append(self.inside)
 
-        def __exit__(self, *exc):
+        def release(self):
             self.inside -= 1
             held.release()
 

@@ -2,6 +2,7 @@
 
 Usage:
     python tools/trace_view.py <trace.json>
+    python tools/trace_view.py --xplane <file.xplane.pb | planes.json>
 
 Reads a Chrome-trace/perfetto JSON dump produced by util/telemetry
 (``-tracefile`` at shutdown, or the ``dumptrace`` RPC mid-flight) and
@@ -30,10 +31,28 @@ interpolation): sorted[ceil(q*n) - 1]. All times are milliseconds.
 The report is plain deterministic text (golden-tested by
 tests/unit/test_trace_view.py); pipe it wherever, or load the same JSON
 at ui.perfetto.dev for the interactive view.
+
+``--xplane`` reads a profiler trace instead (a ``--keep-trace`` run of
+chipbench/run.py, or a ``startprofile`` / ``stopprofile`` dump): under
+-telemetry=counters and above every span is also a ``bcp.<name>`` host
+event on the device trace's own clock, and the report puts the device's
+idle time under them. Each idle gap of the first device inside the window
+(the harness's ``chipbench.window`` annotation, else the extent of the
+``bcp.*`` events) is cut where spans start and end, and each piece goes
+to the innermost span open at that instant (of several threads' spans, the
+one that started last). It also checks that every ``bcp.import*`` event
+lies inside ``chipbench.import``, and prints the device's idle time between
+the first ``bcp.import.enqueue`` and the end of the last
+``bcp.import.settle_wait``, whole and in gaps of a millisecond or more: the
+chip idle while the import had work for it (the ``queue_empty_s`` the import
+logs is a lower bound of this, from the host's side). The trace is loaded
+with chipbench/xplane.load; a ``.json`` file holds the same planes as plain
+lists (the form chipbench/tests keeps).
 """
 
 from __future__ import annotations
 
+import functools
 import json
 import math
 import sys
@@ -280,7 +299,180 @@ def summarize(events: list[dict]) -> str:
     return "\n".join(lines) + "\n"
 
 
+# ---------------------------------------------------------------------------
+# --xplane: the device's idle time under the program's own spans
+# ---------------------------------------------------------------------------
+
+SPAN_PREFIX = "bcp."
+HARNESS_PREFIX = "chipbench."
+
+
+@functools.lru_cache(maxsize=None)
+def _xplane():
+    """chipbench/xplane.py (it imports JAX only to read a .pb)."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chipbench", "xplane.py")
+    spec = importlib.util.spec_from_file_location("chipbench_xplane", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def load_planes(path: str) -> list:
+    """The planes of chipbench/xplane.py's ``load``: device planes whole,
+    of the host planes the ``bcp.*`` and ``chipbench.*`` events."""
+    if path.endswith(".json"):
+        with open(path) as f:
+            return json.load(f)
+    return _xplane().load(path, keep_host=(SPAN_PREFIX, HARNESS_PREFIX))
+
+
+def _host_events(planes: list, prefix: str) -> list:
+    """[(name, start_ns, end_ns)] of the host planes' events under
+    ``prefix``, by start."""
+    out = []
+    for plane in planes:
+        if plane["name"].startswith("/device:"):
+            continue
+        for line in plane["lines"]:
+            out += [(name, start, start + dur)
+                    for name, start, dur in line["events"]
+                    if name.startswith(prefix)]
+    return sorted(out, key=lambda ev: (ev[1], -ev[2]))
+
+
+def _device_busy(planes: list) -> list:
+    """Merged busy intervals of the first device plane ('XLA Ops', else
+    'XLA Modules'), by the harness's own union."""
+    for plane in planes:
+        if not plane["name"].startswith("/device:"):
+            continue
+        lines = {line["name"]: line for line in plane["lines"]}
+        line = lines.get("XLA Ops") or lines.get("XLA Modules")
+        if line is None:
+            continue
+        return _xplane().union(
+            [(s, s + d) for _, s, d in line["events"] if d > 0])
+    raise ValueError("the trace has no device plane with operations")
+
+
+def idle_by_span(gaps: list, spans: list) -> dict:
+    """{span name or 'unannotated': idle ns}: each gap cut at the spans'
+    edges, each piece to the span open there that started last."""
+    cuts = sorted({t for gap in gaps for t in gap}
+                  | {t for _, a, b in spans for t in (a, b)})
+    starts = sorted(spans, key=lambda ev: ev[1])
+    out: dict = {}
+    active: list = []
+    nxt = gap_i = 0
+    for left, right in zip(cuts, cuts[1:]):
+        while nxt < len(starts) and starts[nxt][1] <= left:
+            active.append(starts[nxt])
+            nxt += 1
+        active = [ev for ev in active if ev[2] > left]
+        while gap_i < len(gaps) and gaps[gap_i][1] <= left:
+            gap_i += 1
+        if gap_i == len(gaps):
+            break
+        if not (gaps[gap_i][0] <= left and right <= gaps[gap_i][1]):
+            continue
+        name = (max(active, key=lambda ev: (ev[1], -ev[2]))[0]
+                if active else "unannotated")
+        out[name] = out.get(name, 0) + right - left
+    return out
+
+
+LONG_GAP_NS = 1_000_000
+
+
+def dispatch_stretch(spans: list):
+    """(start of the first ``bcp.import.enqueue``, end of the last
+    ``bcp.import.settle_wait``), or None: the stretch in which the import
+    has work for the device."""
+    starts = [a for n, a, _ in spans if n == SPAN_PREFIX + "import.enqueue"]
+    ends = [b for n, _, b in spans
+            if n == SPAN_PREFIX + "import.settle_wait"]
+    if not starts or not ends or max(ends) <= min(starts):
+        return None
+    return min(starts), max(ends)
+
+
+def xplane_report(planes: list) -> str:
+    spans = _host_events(planes, SPAN_PREFIX)
+    harness = {name: (a, b) for name, a, b in
+               _host_events(planes, HARNESS_PREFIX)}
+    busy = _device_busy(planes)
+    if HARNESS_PREFIX + "window" in harness:
+        window = harness[HARNESS_PREFIX + "window"]
+    elif spans:
+        window = (min(a for _, a, _ in spans), max(b for _, _, b in spans))
+    else:
+        window = (busy[0][0], busy[-1][1])
+    edges = [window[0]] + [min(max(t, window[0]), window[1])
+                           for iv in busy for t in iv] + [window[1]]
+    gaps = [(a, b) for a, b in zip(edges[0::2], edges[1::2]) if b > a]
+    idle = sum(b - a for a, b in gaps)
+    length = window[1] - window[0]
+    lines = [
+        f"xplane summary: window {length / 1e6:.3f} ms, device idle "
+        f"{idle / 1e6:.3f} ms ({100.0 * idle / length:.2f}%) in "
+        f"{len(gaps)} gaps, {len(spans)} bcp.* host events",
+        "",
+        "device idle time by the innermost span open",
+        f"{'span':<34}{'idle_ms':>12}{'of idle':>9}",
+    ]
+    table = idle_by_span(gaps, spans)
+    for name, ns in sorted(table.items(), key=lambda kv: (-kv[1], kv[0])):
+        lines.append(f"{name:<34}{ns / 1e6:>12.3f}"
+                     f"{100.0 * ns / idle if idle else 0.0:>8.2f}%")
+
+    inside = harness.get(HARNESS_PREFIX + "import")
+    imports = [ev for ev in spans
+               if ev[0].startswith(SPAN_PREFIX + "import")]
+    if inside is not None:
+        in_gaps = [(max(a, inside[0]), min(b, inside[1])) for a, b in gaps
+                   if b > inside[0] and a < inside[1]]
+        in_idle = sum(b - a for a, b in in_gaps)
+
+        def named(events):
+            table = idle_by_span(in_gaps, events)
+            ns = sum(v for name, v in table.items() if name != "unannotated")
+            return 100.0 * ns / in_idle if in_idle else 0.0
+
+        outside = sum(1 for _, a, b in imports
+                      if a < inside[0] or b > inside[1])
+        lines += [
+            "",
+            f"inside {HARNESS_PREFIX}import: idle {in_idle / 1e6:.3f} ms, "
+            f"{named(imports):.2f}% of it under a {SPAN_PREFIX}import* span, "
+            f"{named(spans):.2f}% under any {SPAN_PREFIX}* span",
+            f"{SPAN_PREFIX}import* events outside {HARNESS_PREFIX}import: "
+            f"{outside} of {len(imports)}",
+        ]
+    stretch = dispatch_stretch(spans)
+    if stretch is not None:
+        inside_gaps = [(max(a, stretch[0]), min(b, stretch[1]))
+                       for a, b in gaps if b > stretch[0] and a < stretch[1]]
+        total = sum(b - a for a, b in inside_gaps)
+        long_ns = sum(b - a for a, b in inside_gaps
+                      if b - a >= LONG_GAP_NS)
+        lines += ["", f"between the first enqueue and the last settle "
+                      f"({(stretch[1] - stretch[0]) / 1e6:.3f} ms): device "
+                      f"idle {total / 1e6:.3f} ms, {long_ns / 1e6:.3f} ms of "
+                      f"it in gaps of 1 ms or more"]
+    return "\n".join(lines) + "\n"
+
+
 def main(argv: list[str]) -> int:
+    if argv[1:2] == ["--xplane"]:
+        if len(argv) != 3:
+            print(f"usage: {argv[0]} --xplane <file>", file=sys.stderr)
+            return 2
+        sys.stdout.write(xplane_report(load_planes(argv[2])))
+        return 0
     if len(argv) != 2:
         print(__doc__.strip().splitlines()[0], file=sys.stderr)
         print(f"usage: {argv[0]} <trace.json>", file=sys.stderr)
