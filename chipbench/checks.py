@@ -16,6 +16,29 @@ def compared(name: str, value, limit, ok: bool = None, note: str = "") -> dict:
             **({"note": note} if note else {})}
 
 
+def worst_of(windows: list) -> list:
+    """The numbers of a run's windows (one list a window, the same names
+    in the same order) as one list: each name once, with the reading of
+    the first window that is not within the limit, else the largest, so
+    that one failing window makes the run's number fail."""
+    if len(windows) == 1:
+        return windows[0]
+    out = []
+    for readings in zip(*windows, strict=True):
+        if len({n["name"] for n in readings}) != 1:
+            raise ValueError(f"windows compared different numbers: "
+                             f"{[n['name'] for n in readings]}")
+        bad = [i for i, n in enumerate(readings) if not n["ok"]]
+        at = bad[0] if bad else max(range(len(readings)),
+                                    key=lambda i: readings[i]["value"])
+        where = (f"window {at + 1} of {len(readings)}; not within it in "
+                 f"{[i + 1 for i in bad]}" if bad
+                 else f"worst of {len(readings)} windows")
+        note = "; ".join(filter(None, (readings[at].get("note"), where)))
+        out.append({**readings[at], "note": note})
+    return out
+
+
 def programs_compiled(before: dict, after: dict) -> dict:
     """{program: compiles inside the window}, the non-zero ones."""
     b, a = before["device"]["programs"], after["device"]["programs"]

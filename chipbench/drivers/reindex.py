@@ -10,9 +10,15 @@ The chains come from chipbench/gen/sigchain.py, run as children pinned to
 the CPU. The measured chain's child signs while this process traces and
 lowers the verify program for the warm-up: the two do not share a core.
 
+A run measures ``windows`` such imports one after the other (``run_windows``),
+each over a work copy of the same block files made before the first, the
+node before it closed outside the timed region: the rate is every window's
+signatures over every window's seconds, and each window is held to every
+check against its own snapshots. A traced run is one window.
+
 Traffic parameters (chipbench/traffic/<mix>.json): lanes,
-buckets_per_window_second, warm_buckets, trace_buckets, inputs_per_tx,
-txs_per_block, fan_k, sample_sigs, rehearse.
+buckets_per_window_second, windows, warm_buckets, trace_buckets,
+inputs_per_tx, txs_per_block, fan_k, sample_sigs, rehearse.
 """
 
 from __future__ import annotations
@@ -21,6 +27,7 @@ import gc
 import json
 import os
 import shutil
+import statistics
 import subprocess
 import sys
 import time
@@ -31,6 +38,9 @@ import reference
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(HERE)
 FAULTS = ("wrong-key-sig",)
+# the device programs of one dispatch, by their XLA module names: a trace
+# that kept every device event has one event of each a dispatch
+DISPATCH_MODULES = ("jit__glv_prepare_program", "jit__glv_dev_program")
 KEEP_SEEDS = 8  # cached seeds kept on disk (a 30 s chain is ~80 MB)
 
 
@@ -97,6 +107,14 @@ def _datadir(ctx, cache: str, name: str) -> str:
         if leaf.startswith("blk") and leaf.endswith(".dat"):
             shutil.copy(os.path.join(src, leaf), dst)
     return os.path.join(ctx.workdir, name)
+
+
+def _datadirs(ctx, cache: str) -> list:
+    """The work copies a run needs, all made before its first window: one a
+    window, and for a traced run its one window's and a spare (run.py
+    traces once more where the profiler lost device events)."""
+    count = 2 if ctx.trace else ctx.traffic.get("windows", 1)
+    return [_datadir(ctx, cache, f"main{i}") for i in range(count)]
 
 
 def _settle_disk() -> float:
@@ -179,7 +197,7 @@ def warm(ctx) -> None:
     st["setup"] = st["before"] = snap
     t0 = time.monotonic()
     st["gen"] = _collect(st["main_cache"], st.pop("main_proc"))
-    st["datadir"] = _datadir(ctx, st["main_cache"], "main")
+    st["datadirs"] = _datadirs(ctx, st["main_cache"])
     st["setup_report"] = {
         "buckets": st["buckets"], "sigs": st["gen"]["sigs"],
         "warm_generate_s": st["warm_gen_s"], "warm_import_s": warm_s,
@@ -212,9 +230,9 @@ class _GcClock:
         gc.callbacks.remove(self._note)
 
 
-def _measured_import(ctx) -> tuple:
-    """The window itself: ``Node(config)`` over the measured data directory,
-    left in ``ctx.state["node"]``. Returns (host-clock seconds, where the
+def _measured_import(ctx, datadir: str) -> tuple:
+    """The window itself: ``Node(config)`` over one work copy of the
+    measured chain, left in ``ctx.state["node"]``. Returns (host-clock seconds, where the
     runs of a cell spread): what the one importing thread got of the window
     (against the import's ``wall_s``: a stolen core shows as wall at the same
     CPU seconds, a slower host as both going up) and what Python's collector
@@ -227,7 +245,7 @@ def _measured_import(ctx) -> tuple:
     collected = _GcClock()
     cpu0, all0, t0 = time.thread_time(), time.process_time(), time.monotonic()
     with ctx.annotate("import"), collected:
-        st["node"] = _node(ctx, st["datadir"])
+        st["node"] = _node(ctx, datadir)
     wall = time.monotonic() - t0
     return wall, {"main_thread_cpu_s": time.thread_time() - cpu0,
                   "process_cpu_s": time.process_time() - all0,
@@ -236,13 +254,61 @@ def _measured_import(ctx) -> tuple:
                   "gc_frozen_objects": gc.get_freeze_count()}
 
 
-def window(ctx) -> dict:
+def _window_line(result: dict) -> dict:
+    """What the ``window`` phase line says of one window: where its seconds
+    went, by the import's own spans (self seconds: they add up to the
+    import's ``wall_s``), so that whoever reads a refused set sees which
+    window was off and in which span."""
+    stats = result["after"]["import"] or {}
+    report = result["report"]
+    return {"window_s": result["window_s"],
+            "sigs_per_s": result["values"]["reindex_sigs_per_s"],
+            "wall_s": stats.get("wall_s"),
+            **{k: report[k] for k in ("main_thread_cpu_s", "process_cpu_s",
+                                      "gc_s", "gc_full_collections")},
+            "dispatches": stats.get("dispatches"),
+            "spans": {name: phase["self_s"] for name, phase in
+                      sorted((stats.get("phases") or {}).items())}}
+
+
+def run_windows(ctx, one_window) -> dict:
+    """The run's windows one after the other. ``one_window(ctx, datadir,
+    before)`` measures one import and returns its result: ``before``,
+    ``after``, ``window_s``, ``sigs`` and ``dispatches`` (what
+    checks.no_fallback holds it to), ``attempted``, ``failed``, ``values``,
+    ``report``. The node of the window before is closed here, outside the
+    timed region; the last stays open in ``ctx.state["node"]``. Returns the
+    last window's result with the run's totals over it: ``window_s``,
+    ``attempted`` and ``failed`` are sums, the rate is all the signatures
+    over all the seconds (the windows' median stands beside it in the
+    report), and ``windows`` holds each window's result for the checks."""
     st = ctx.state
-    sigs = st["gen"]["sigs"]
-    wall, host = _measured_import(ctx)
-    node = st["node"]
-    after = snapshot(node)
-    before = st["before"]
+    count = 1 if ctx.trace else ctx.traffic.get("windows", 1)
+    each = []
+    for _ in range(count):
+        node = st.pop("node", None)
+        if node is not None:
+            node.close()
+        if not st["datadirs"]:
+            raise RuntimeError("no work copy of the chain is left to import")
+        each.append(one_window(ctx, st["datadirs"].pop(0), st["before"]))
+        st["before"] = each[-1]["after"]
+    total = {key: sum(w[key] for w in each)
+             for key in ("window_s", "attempted", "failed")}
+    rates = [w["values"]["reindex_sigs_per_s"] for w in each]
+    return {**each[-1], **total, "before": each[0]["before"],
+            "values": {"reindex_sigs_per_s":
+                       total["attempted"] / total["window_s"]},
+            "report": {**each[-1]["report"],
+                       "median_sigs_per_s": statistics.median(rates),
+                       "windows": [_window_line(w) for w in each]},
+            "windows": each}
+
+
+def _one_window(ctx, datadir: str, before: dict) -> dict:
+    sigs = ctx.state["gen"]["sigs"]
+    wall, host = _measured_import(ctx, datadir)
+    after = snapshot(ctx.state["node"])
     on_device = (after["batch"]["sigs_verified"]
                  - before["batch"]["sigs_verified"])
     if ctx.rehearse:
@@ -250,6 +316,8 @@ def window(ctx) -> dict:
     stats = after["import"] or {}
     return {
         "before": before, "after": after, "window_s": wall, "sigs": sigs,
+        "dispatches": -(-sigs // ctx.traffic["lanes"]),
+        "dispatch_modules": DISPATCH_MODULES,
         "attempted": sigs, "failed": max(0, sigs - on_device),
         "values": {"reindex_sigs_per_s": sigs / wall},
         "report": {"import": {k: stats.get(k) for k in (
@@ -259,12 +327,33 @@ def window(ctx) -> dict:
     }
 
 
+def window(ctx) -> dict:
+    return run_windows(ctx, _one_window)
+
+
+def _numbers(one: dict, ref: dict) -> list:
+    """One window's numbers against the replay."""
+    chain = one["after"]["chain"]
+    return [
+        checks.compared("tip_height_gap",
+                        abs(chain["height"] - ref["height"]), 0),
+        checks.compared("tip_hash_differs",
+                        int(chain["tip_hash"] != ref["tip_hash"]), 0),
+        checks.compared("utxo_count_gap",
+                        abs(chain["utxos"] - ref["utxos"]), 0),
+        checks.compared("signatures_not_verified_on_device",
+                        one["failed"], 0, note=f"of {one['sigs']}"),
+        checks.compared("sampled_signatures_refused_by_reference",
+                        int(ref["first_bad_height"] is not None), 0,
+                        note=f"{ref['sampled']} sampled"),
+    ]
+
+
 def check(ctx, result: dict) -> list:
-    """The node's chain against an independent replay of the same block
-    files (chipbench/reference.py), made after the window has closed and
-    the node's stores are shut."""
+    """Every window's chain against one independent replay of the same
+    block files (chipbench/reference.py), made after the last window has
+    closed and the node's stores are shut."""
     st = ctx.state
-    chain = result["after"]["chain"]
     node = st.pop("node", None)
     if node is not None:
         node.close()
@@ -277,7 +366,7 @@ def check(ctx, result: dict) -> list:
     ctx.emit({"phase": "reference", "seconds": time.monotonic() - t0,
               **ref, "generator_tip": st["gen"]["tip_hash"],
               "generator_height": st["gen"]["tip_height"],
-              "node": chain})
+              "node": [w["after"]["chain"] for w in result["windows"]]})
     if not ctx.fault:
         # the generator's word is no reference, but a disagreement between
         # it and the replay is a fault of the harness, not of the node
@@ -286,20 +375,7 @@ def check(ctx, result: dict) -> list:
                 st["gen"]["txouts"]):
             raise RuntimeError(f"reference {ref} and generator "
                                f"{st['gen']} disagree on a sound chain")
-    return [
-        checks.compared("tip_height_gap",
-                        abs(chain["height"] - ref["height"]), 0),
-        checks.compared("tip_hash_differs",
-                        int(chain["tip_hash"] != ref["tip_hash"]), 0),
-        checks.compared("utxo_count_gap",
-                        abs(chain["utxos"] - ref["utxos"]), 0),
-        checks.compared("signatures_not_verified_on_device",
-                        result["failed"], 0,
-                        note=f"of {result['sigs']}"),
-        checks.compared("sampled_signatures_refused_by_reference",
-                        int(ref["first_bad_height"] is not None), 0,
-                        note=f"{ref['sampled']} sampled"),
-    ]
+    return checks.worst_of([_numbers(w, ref) for w in result["windows"]])
 
 
 def close(ctx) -> None:

@@ -1,9 +1,10 @@
 """Driver ``reindex_mixed``: ``bcpd -reindex`` over a generated mixed-script
 chain, in-process.
 
-The sibling of drivers/reindex.py, whose window it keeps: the host clock
-around ``Node(config)``, a first ``Node(config)`` over a one-bucket chain of
-the same mix as warm-up, the process otherwise as bcpd would have it. It
+The sibling of drivers/reindex.py, whose windows it keeps (``run_windows``:
+the host clock around ``Node(config)``, as many imports a run as the traffic
+file says), a first ``Node(config)`` over a one-bucket chain of the same mix
+as warm-up, the process otherwise as bcpd would have it. It
 calls the sibling's helpers that take all they need as arguments (the chain
 cache, the work copy, ``Node(config)``, the snapshot) and has its own
 ``setup`` and ``warm``. What differs is the chain
@@ -23,7 +24,7 @@ on the host: the cell does not describe it, and ``setup`` refuses before
 anything is generated.
 
 Traffic parameters (chipbench/traffic/<mix>.json): lanes,
-buckets_per_window_second, warm_buckets, trace_buckets, input_mix,
+buckets_per_window_second, windows, warm_buckets, trace_buckets, input_mix,
 inputs_per_tx, block_bytes, keys, fan_k, sample_sigs, rehearse.
 """
 
@@ -138,7 +139,7 @@ def warm(ctx) -> None:
     st["setup"] = st["before"] = snap
     t0 = time.monotonic()
     gen = st["gen"] = sibling._collect(st["main_cache"], st.pop("main_proc"))
-    st["datadir"] = sibling._datadir(ctx, st["main_cache"], "main")
+    st["datadirs"] = sibling._datadirs(ctx, st["main_cache"])
     st["setup_report"] = {
         "buckets": st["buckets"], "sigs": gen["sigs"],
         "warm_generate_s": st["warm_gen_s"], "warm_import_s": warm_s,
@@ -153,14 +154,11 @@ def warm(ctx) -> None:
         "sync_s": sibling._settle_disk()}
 
 
-def window(ctx) -> dict:
-    st = ctx.state
-    gen = st["gen"]
+def _one_window(ctx, datadir: str, before: dict) -> dict:
+    gen = ctx.state["gen"]
     lanes, sigs = gen["device_lanes"], gen["sigs"]
-    wall, host = sibling._measured_import(ctx)
-    node = st["node"]
-    after = sibling.snapshot(node)
-    before = st["before"]
+    wall, host = sibling._measured_import(ctx, datadir)
+    after = sibling.snapshot(ctx.state["node"])
 
     def moved(key: str) -> int:
         return after["batch"][key] - before["batch"][key]
@@ -175,6 +173,8 @@ def window(ctx) -> dict:
     stats = after["import"] or {}
     return {
         "before": before, "after": after, "window_s": wall, "sigs": lanes,
+        "dispatches": -(-lanes // ctx.traffic["lanes"]),
+        "dispatch_modules": sibling.DISPATCH_MODULES,
         "attempted": sigs, "failed": missed,
         "values": {"reindex_sigs_per_s": sigs / wall},
         "report": {"import": {k: stats.get(k) for k in (
@@ -186,6 +186,10 @@ def window(ctx) -> dict:
             "multisig_sigs": gen["multisig_sigs"],
             **host},
     }
+
+
+def window(ctx) -> dict:
+    return sibling.run_windows(ctx, _one_window)
 
 
 def _walks_differ(cache: str, replayed: list) -> int:
@@ -204,36 +208,12 @@ def _walks_differ(cache: str, replayed: list) -> int:
     return differ
 
 
-def check(ctx, result: dict) -> list:
-    """The node's chain against an independent replay of the same block
-    files (chipbench/reference_mixed.py), and its counters against the
-    chain's own counts; made after the window has closed and the node's
-    stores are shut."""
-    st = ctx.state
-    gen = st["gen"]
-    chain = result["after"]["chain"]
-    stats = result["after"]["import"] or {}
-    report = result["report"]
-    node = st.pop("node", None)
-    if node is not None:
-        node.close()
-    t0 = time.monotonic()
-    ref = reference_mixed.scan_chain(
-        os.path.join(st["main_cache"], "regtest", "blocks"), ctx.seed,
-        ctx.traffic["sample_sigs"])
-    replayed = ref.pop("multisig_sampled")
-    ctx.emit({"phase": "reference", "seconds": time.monotonic() - t0,
-              **ref, "generator_tip": gen["tip_hash"],
-              "generator_height": gen["tip_height"], "node": chain})
-    if not ctx.fault:
-        # the generator's word is no reference, but a disagreement between
-        # it and the replay is a fault of the harness, not of the node
-        said = (gen["tip_height"], gen["tip_hash"], gen["txouts"],
-                gen["inputs_by_kind"], 0)
-        if (ref["height"], ref["tip_hash"], ref["utxos"],
-                ref["inputs_by_kind"], ref["inputs_of_unknown_kind"]) != said:
-            raise RuntimeError(f"reference {ref} and generator {gen} "
-                               f"disagree on a sound chain")
+def _numbers(one: dict, ref: dict, gen: dict, walks_differ: int,
+             replayed: int) -> list:
+    """One window's numbers against the replay and the chain's counts."""
+    chain = one["after"]["chain"]
+    stats = one["after"]["import"] or {}
+    report = one["report"]
     return [
         checks.compared("tip_height_gap",
                         abs(chain["height"] - ref["height"]), 0),
@@ -242,17 +222,16 @@ def check(ctx, result: dict) -> list:
         checks.compared("utxo_count_gap",
                         abs(chain["utxos"] - ref["utxos"]), 0),
         checks.compared("lanes_not_verified_on_device",
-                        max(0, result["sigs"] - report["lanes_on_device"]),
-                        0, note=f"of {result['sigs']}"),
+                        max(0, one["sigs"] - report["lanes_on_device"]),
+                        0, note=f"of {one['sigs']}"),
         checks.compared("multisig_lanes_gap",
                         abs((stats.get("multisig_lanes") or 0)
                             - gen["multisig_lanes"]), 0,
                         note=f"of {gen['multisig_lanes']} in "
                              f"{gen['multisig_groups']} groups"),
         *(checks.compared(key + "_moved", report[key], 0) for key in STILL),
-        checks.compared("sampled_multisig_walks_differ",
-                        _walks_differ(st["main_cache"], replayed), 0,
-                        note=f"{len(replayed)} replayed"),
+        checks.compared("sampled_multisig_walks_differ", walks_differ, 0,
+                        note=f"{replayed} replayed"),
         checks.compared("slow_path_blocks",
                         stats.get("slow_path_blocks", -1), 0, ok=(
                             stats.get("slow_path_blocks") == 0)),
@@ -264,3 +243,36 @@ def check(ctx, result: dict) -> list:
                         int(ref["first_bad_height"] is not None), 0,
                         note=f"{ref['sampled']} sampled"),
     ]
+
+
+def check(ctx, result: dict) -> list:
+    """Every window's chain against one independent replay of the same
+    block files (chipbench/reference_mixed.py), and its counters against
+    the chain's own counts; made after the last window has closed and the
+    node's stores are shut."""
+    st = ctx.state
+    gen = st["gen"]
+    node = st.pop("node", None)
+    if node is not None:
+        node.close()
+    t0 = time.monotonic()
+    ref = reference_mixed.scan_chain(
+        os.path.join(st["main_cache"], "regtest", "blocks"), ctx.seed,
+        ctx.traffic["sample_sigs"])
+    replayed = ref.pop("multisig_sampled")
+    ctx.emit({"phase": "reference", "seconds": time.monotonic() - t0,
+              **ref, "generator_tip": gen["tip_hash"],
+              "generator_height": gen["tip_height"],
+              "node": [w["after"]["chain"] for w in result["windows"]]})
+    if not ctx.fault:
+        # the generator's word is no reference, but a disagreement between
+        # it and the replay is a fault of the harness, not of the node
+        said = (gen["tip_height"], gen["tip_hash"], gen["txouts"],
+                gen["inputs_by_kind"], 0)
+        if (ref["height"], ref["tip_hash"], ref["utxos"],
+                ref["inputs_by_kind"], ref["inputs_of_unknown_kind"]) != said:
+            raise RuntimeError(f"reference {ref} and generator {gen} "
+                               f"disagree on a sound chain")
+    differ = _walks_differ(st["main_cache"], replayed)
+    return checks.worst_of([_numbers(w, ref, gen, differ, len(replayed))
+                            for w in result["windows"]])

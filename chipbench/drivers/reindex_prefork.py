@@ -115,35 +115,65 @@ def setup(ctx) -> None:
         ctx, "main", traffic["lanes"] * buckets, ctx.fault)
 
 
-def window(ctx) -> dict:
+def _one_window(ctx, datadir: str, before: dict) -> dict:
     """The sibling's window, with the era's counters beside it: a check
     that ran inline on the host is a signature the device did not decide."""
-    result = mixed.window(ctx)
+    result = mixed._one_window(ctx, datadir, before)
     stats = result["after"]["import"] or {}
-    before, after = result["before"]["batch"], result["after"]["batch"]
+    was, now = before["batch"], result["after"]["batch"]
     report = result["report"]
     report["import"].update({k: stats.get(k) for k in (
         "template_inputs", "interp_inputs", "prefork_blocks",
         "sigscan_thread_s", "legacy_digests", "legacy_sighash_bytes",
         "legacy_sighash_s", "inline_legacy_sigs")})
-    report["inline_legacy_sigs"] = (after["inline_legacy_sigs"]
-                                    - before["inline_legacy_sigs"])
-    report["prefork_lanes"] = after["prefork_lanes"] - before["prefork_lanes"]
+    report["inline_legacy_sigs"] = (now["inline_legacy_sigs"]
+                                    - was["inline_legacy_sigs"])
+    report["prefork_lanes"] = now["prefork_lanes"] - was["prefork_lanes"]
     result["failed"] = min(result["attempted"], result["failed"]
                            + report["inline_legacy_sigs"])
     return result
 
 
+def window(ctx) -> dict:
+    return sibling.run_windows(ctx, _one_window)
+
+
+def _numbers(one: dict, ref: dict, gen: dict, walks_differ: int,
+             replayed: int) -> list:
+    """One window's numbers: the sibling's, and the era's among them."""
+    stats = one["after"]["import"] or {}
+    report = one["report"]
+    hashed = stats.get("legacy_sighash_bytes") or 0
+    return mixed._numbers(one, ref, gen, walks_differ, replayed) + [
+        checks.compared("prefork_lanes_gap",
+                        abs(report["prefork_lanes"] - one["sigs"]), 0,
+                        note=f"of {one['sigs']}"),
+        checks.compared("prefork_blocks_gap",
+                        abs((stats.get("prefork_blocks") or 0)
+                            - ref["blocks"]), 0,
+                        note=f"of {ref['blocks']}, {ref['spend_blocks']} "
+                             f"with spends"),
+        checks.compared("inline_legacy_sigs_moved",
+                        report["inline_legacy_sigs"], 0),
+        checks.compared("interp_inputs", stats.get("interp_inputs", -1), 0,
+                        ok=stats.get("interp_inputs") == 0),
+        checks.compared("legacy_digests_gap",
+                        abs((stats.get("legacy_digests") or 0)
+                            - ref["legacy_digests"]), 0,
+                        note=f"of {ref['legacy_digests']}"),
+        checks.compared("legacy_sighash_bytes_gap",
+                        abs(hashed - ref["legacy_sighash_bytes"]), 0,
+                        note=f"of {ref['legacy_sighash_bytes']}"),
+    ]
+
+
 def check(ctx, result: dict) -> list:
-    """The node's chain against an independent replay of the same block
-    files (chipbench/reference_prefork.py), and its counters against the
-    chain's own counts; made after the window has closed and the node's
-    stores are shut."""
+    """Every window's chain against one independent replay of the same
+    block files (chipbench/reference_prefork.py), and its counters against
+    the chain's own counts; made after the last window has closed and the
+    node's stores are shut."""
     st = ctx.state
     gen = st["gen"]
-    chain = result["after"]["chain"]
-    stats = result["after"]["import"] or {}
-    report = result["report"]
     node = st.pop("node", None)
     if node is not None:
         node.close()
@@ -154,7 +184,8 @@ def check(ctx, result: dict) -> list:
     replayed = ref.pop("multisig_sampled")
     ctx.emit({"phase": "reference", "seconds": time.monotonic() - t0,
               **ref, "generator_tip": gen["tip_hash"],
-              "generator_height": gen["tip_height"], "node": chain})
+              "generator_height": gen["tip_height"],
+              "node": [w["after"]["chain"] for w in result["windows"]]})
     if not ctx.fault:
         # the generator's word is no reference, but a disagreement between
         # it and the replay is a fault of the harness, not of the node
@@ -165,53 +196,6 @@ def check(ctx, result: dict) -> list:
                 ref["legacy_digests"]) != said:
             raise RuntimeError(f"reference {ref} and generator {gen} "
                                f"disagree on a sound chain")
-    hashed = stats.get("legacy_sighash_bytes") or 0
-    return [
-        checks.compared("tip_height_gap",
-                        abs(chain["height"] - ref["height"]), 0),
-        checks.compared("tip_hash_differs",
-                        int(chain["tip_hash"] != ref["tip_hash"]), 0),
-        checks.compared("utxo_count_gap",
-                        abs(chain["utxos"] - ref["utxos"]), 0),
-        checks.compared("lanes_not_verified_on_device",
-                        max(0, result["sigs"] - report["lanes_on_device"]),
-                        0, note=f"of {result['sigs']}"),
-        checks.compared("prefork_lanes_gap",
-                        abs(report["prefork_lanes"] - result["sigs"]), 0,
-                        note=f"of {result['sigs']}"),
-        checks.compared("prefork_blocks_gap",
-                        abs((stats.get("prefork_blocks") or 0)
-                            - ref["blocks"]), 0,
-                        note=f"of {ref['blocks']}, {ref['spend_blocks']} "
-                             f"with spends"),
-        checks.compared("multisig_lanes_gap",
-                        abs((stats.get("multisig_lanes") or 0)
-                            - gen["multisig_lanes"]), 0,
-                        note=f"of {gen['multisig_lanes']} in "
-                             f"{gen['multisig_groups']} groups"),
-        checks.compared("inline_legacy_sigs_moved",
-                        report["inline_legacy_sigs"], 0),
-        *(checks.compared(key + "_moved", report[key], 0) for key in STILL),
-        checks.compared("sampled_multisig_walks_differ",
-                        mixed._walks_differ(st["main_cache"], replayed), 0,
-                        note=f"{len(replayed)} replayed"),
-        checks.compared("slow_path_blocks",
-                        stats.get("slow_path_blocks", -1), 0, ok=(
-                            stats.get("slow_path_blocks") == 0)),
-        checks.compared("fallback_inputs_gap",
-                        abs(stats.get("fallback_inputs", 0)
-                            - gen["non_p2pkh_inputs"]), 0,
-                        note=f"of {gen['non_p2pkh_inputs']}"),
-        checks.compared("interp_inputs", stats.get("interp_inputs", -1), 0,
-                        ok=stats.get("interp_inputs") == 0),
-        checks.compared("legacy_digests_gap",
-                        abs((stats.get("legacy_digests") or 0)
-                            - ref["legacy_digests"]), 0,
-                        note=f"of {ref['legacy_digests']}"),
-        checks.compared("legacy_sighash_bytes_gap",
-                        abs(hashed - ref["legacy_sighash_bytes"]), 0,
-                        note=f"of {ref['legacy_sighash_bytes']}"),
-        checks.compared("sampled_inputs_refused_by_reference",
-                        int(ref["first_bad_height"] is not None), 0,
-                        note=f"{ref['sampled']} sampled"),
-    ]
+    differ = mixed._walks_differ(st["main_cache"], replayed)
+    return checks.worst_of([_numbers(w, ref, gen, differ, len(replayed))
+                            for w in result["windows"]])

@@ -180,9 +180,21 @@ class Ctx:
         return jax.profiler.TraceAnnotation("chipbench." + name)
 
 
-def traced_window(ctx: Ctx, driver) -> tuple:
-    """The window under jax.profiler, in a run of its own; returns the
-    driver's result and the trace's reduction (chipbench/xplane.py)."""
+def lost_module_events(result: dict, modules: dict) -> dict:
+    """{module: events in the trace} of the programs the driver says every
+    dispatch runs (``dispatch_modules``) whose count of module events is not
+    the window's ``dispatches``: the profiler's device plane came back
+    without them, and every device time read from such a trace is wrong.
+    Empty where the trace kept them all, or the driver states no count."""
+    stated = result.get("dispatches")
+    if stated is None:
+        return {}
+    counts = {name: modules.get(name, {}).get("count", 0)
+              for name in result.get("dispatch_modules", ())}
+    return {name: n for name, n in counts.items() if n != stated}
+
+
+def _traced_once(ctx: Ctx, driver) -> tuple:
     import jax
 
     trace_dir = os.path.join(ctx.cache_root, "trace-" + ctx.workload)
@@ -201,10 +213,28 @@ def traced_window(ctx: Ctx, driver) -> tuple:
         os.makedirs(ctx.keep_trace, exist_ok=True)
         shutil.copy(path, ctx.keep_trace)
     t0 = time.monotonic()
-    trace = xplane.reduce(xplane.load(path))
+    trace = xplane.reduce(xplane.load(path, keep_host=xplane.HOST_PREFIXES))
+    lost = lost_module_events(result, trace["modules"])
     emit({"phase": "trace", "xplane_bytes": os.path.getsize(path),
-          "reduce_s": time.monotonic() - t0, "modules": trace["modules"]})
+          "reduce_s": time.monotonic() - t0, "modules": trace["modules"],
+          "dispatches": result.get("dispatches"), "lost": lost})
     shutil.rmtree(trace_dir, ignore_errors=True)
+    return result, trace, lost
+
+
+def traced_window(ctx: Ctx, driver) -> tuple:
+    """The window under jax.profiler, in a run of its own; returns the
+    driver's result and the trace's reduction (chipbench/xplane.py). Where
+    the trace has another count of module events than the window made
+    dispatches, the window is traced once more; twice is a failed run."""
+    result, trace, lost = _traced_once(ctx, driver)
+    if lost:
+        result, trace, lost = _traced_once(ctx, driver)
+    if lost:
+        raise BenchError(
+            f"the profiler's trace lost device events twice: the window "
+            f"made {result['dispatches']} dispatches and the trace has "
+            f"{lost} module events; no device time can be read from it")
     if not trace["busy_s"] > 0:
         raise BenchError("no operation ran on the device in the traced "
                          "window")
@@ -248,21 +278,25 @@ def _run(loaded: dict, ctx: Ctx, driver) -> int:
           "attempted": result["attempted"], "failed": result["failed"],
           **result.get("report", {})})
 
-    before, after = result["before"], result["after"]
-    compiled = checks.programs_compiled(before, after)
-    if compiled and not ctx.fault:  # a fault may leave the warmed path
-        raise BenchError(f"programs compiled or retraced inside the "
-                         f"window: {compiled}")
+    # a run of several windows holds each to its own snapshots
+    windows = result.get("windows") or [result]
+    for one in windows:
+        compiled = checks.programs_compiled(one["before"], one["after"])
+        if compiled and not ctx.fault:  # a fault may leave the warmed path
+            raise BenchError(f"programs compiled or retraced inside the "
+                             f"window: {compiled}")
 
     t0 = time.monotonic()
     numbers = driver.check(ctx, result)
     if not ctx.rehearse:
         from bitcoincashplus_tpu.util import devicewatch
 
-        bad = checks.no_fallback(
-            before, after, sigs=result.get("sigs", 0),
-            dispatches=result.get("dispatches"),
-            cache_dir=devicewatch.compile_cache_dir())
+        bad = [dict(item, window=i + 1)
+               for i, one in enumerate(windows)
+               for item in checks.no_fallback(
+                   one["before"], one["after"], sigs=one.get("sigs", 0),
+                   dispatches=one.get("dispatches"),
+                   cache_dir=devicewatch.compile_cache_dir())]
         for item in bad:
             emit({"phase": "fallback", **item})
         numbers.append(checks.compared("fallback_checks_failed",
@@ -280,7 +314,8 @@ def _run(loaded: dict, ctx: Ctx, driver) -> int:
         return 0 if correct or ctx.fault else 1
 
     values = dict(result["values"], setup_s=setup_s)
-    obs = {"before": before, "after": after, "setup": ctx.state["setup"],
+    obs = {"before": result["before"], "after": result["after"],
+           "setup": ctx.state["setup"],
            "trace": trace, "result": result, "traffic": ctx.traffic,
            "config": ctx.config, **tables}
     if ctx.trace:

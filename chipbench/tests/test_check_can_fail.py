@@ -22,7 +22,7 @@ def test_reindex_sound_run_is_correct(capsys):
     assert rc == 0 and last["correct"] is True
     assert all(n["ok"] for n in compared.values())
     assert compared["sampled_signatures_refused_by_reference"]["note"] \
-        == "600 sampled"
+        == "600 sampled; worst of 3 windows"
 
 
 def test_reindex_fault_chain_is_not_correct(capsys):

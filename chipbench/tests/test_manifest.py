@@ -53,6 +53,17 @@ def test_a_reindex_window_says_where_its_rate_comes_from(workload):
     assert len(traffic["rate_from"]) > 80 and "sweep" in traffic["rate_from"]
 
 
+@pytest.mark.parametrize("workload", RATED)
+def test_a_reindex_run_says_how_many_windows_it_measures_and_why(workload):
+    """``windows`` imports a run, their signatures over their seconds the
+    rate; ``windows_from`` keeps the reading on the chip that chose it."""
+    traffic = run.load_cell(workload)["traffic"]
+    assert type(traffic["windows"]) is int and 1 <= traffic["windows"] <= 5
+    assert "windows" not in traffic["rehearse"]  # a rehearsal runs them all
+    said = traffic["windows_from"]
+    assert len(said) > 80 and "PR 43" in said and "s_w" in said
+
+
 def test_every_configuration_has_a_cell_and_every_reader_a_metric():
     used = {w["config"] for w in MANIFEST["workloads"]}
     assert used == {c["name"] for c in MANIFEST["configs"]}

@@ -70,3 +70,38 @@ def test_a_trace_without_a_device_plane_is_an_error(planes):
     hosts = [p for p in planes if not p["name"].startswith("/device:TPU")]
     with pytest.raises(ValueError, match="no TPU device plane"):
         xplane.reduce(hosts)
+
+
+def test_gaps_are_named_by_the_innermost_span_the_program_or_the_harness():
+    """A device busy 10-20 and 50-60 in a window 0-100; the program's spans
+    (bcp.*) lie inside the harness's: each idle gap is cut at their edges
+    and each piece goes to the span open there that started last."""
+    planes = [
+        {"name": "/device:TPU:0", "lines": [
+            {"name": "XLA Ops", "events": [["%a = x", 10, 10],
+                                           ["%b = y", 50, 10]]}]},
+        {"name": "/host:CPU", "lines": [
+            {"name": "main", "events": [
+                ["chipbench.window", 0, 100], ["chipbench.import", 5, 85],
+                ["bcp.import.connect", 20, 25], ["bcp.import.flush", 60, 20],
+                ["other.thing", 0, 100]]},
+            {"name": "shard", "events": [["bcp.store.commit", 70, 5]]}]}]
+    red = xplane.reduce(planes)
+    assert red["window_s"] == pytest.approx(100e-9)
+    assert {n: round(v * 1e9) for n, v in red["idle_gaps"]} == {
+        "chipbench.window": 5 + 10,     # 0-5, 90-100
+        "chipbench.import": 5 + 5 + 10,  # 5-10, 45-50, 80-90
+        "bcp.import.connect": 25,       # 20-45
+        "bcp.import.flush": 10 + 5,     # 60-70, 75-80
+        "bcp.store.commit": 5}          # 70-75: it started last
+    # the window is the harness's outermost annotation, whatever else the
+    # trace was loaded with
+    planes[1]["lines"][0]["events"].append(["bcp.node.init", 0, 500])
+    assert xplane.reduce(planes)["window_s"] == pytest.approx(100e-9)
+    assert xplane.HOST_PREFIXES == ("chipbench.", "bcp.")
+
+
+def test_idle_by_span_leaves_what_no_span_covers_unannotated():
+    table = xplane.idle_by_span([(0, 10), (20, 30)], [("s", 5, 25)])
+    assert table == {"unannotated": 5 + 5, "s": 5 + 5}
+    assert xplane.idle_by_span([(0, 10)], []) == {"unannotated": 10}

@@ -19,6 +19,9 @@ DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 ANNOTATION_PREFIX = "chipbench."
+# host events kept from a trace: the harness's annotations and the program's
+# own spans (util/telemetry writes each as a ``bcp.<span>`` TraceAnnotation)
+HOST_PREFIXES = (ANNOTATION_PREFIX, "bcp.")
 _MODULE_ID = re.compile(r"\(\d+\)$")
 
 
@@ -30,9 +33,10 @@ def find_xplane(logdir: str) -> str:
     return paths[-1]
 
 
-def load(path: str, keep_host: str = ANNOTATION_PREFIX) -> list:
+def load(path: str, keep_host=ANNOTATION_PREFIX) -> list:
     """Device planes whole; of the host planes only the events whose name
-    starts with ``keep_host`` (the harness's own TraceAnnotations)."""
+    starts with ``keep_host``, a prefix or a tuple of them (by default the
+    harness's own TraceAnnotations)."""
     from jax.profiler import ProfileData
 
     planes = []
@@ -79,6 +83,34 @@ def module_name(event_name: str) -> str:
     return _MODULE_ID.sub("", event_name).strip()
 
 
+def idle_by_span(gaps: list, spans: list) -> dict:
+    """{span name or 'unannotated': idle ns}: each gap (start, end) cut at
+    the edges of the spans (name, start, end), each piece to the span open
+    there that started last (of two that started together, the shorter).
+    One pass over the sorted edges: a trace has tens of thousands of gaps."""
+    cuts = sorted({t for gap in gaps for t in gap}
+                  | {t for _, a, b in spans for t in (a, b)})
+    starts = sorted(spans, key=lambda ev: ev[1])
+    out: dict = {}
+    active: list = []
+    nxt = gap_i = 0
+    for left, right in zip(cuts, cuts[1:]):
+        while nxt < len(starts) and starts[nxt][1] <= left:
+            active.append(starts[nxt])
+            nxt += 1
+        active = [ev for ev in active if ev[2] > left]
+        while gap_i < len(gaps) and gaps[gap_i][1] <= left:
+            gap_i += 1
+        if gap_i == len(gaps):
+            break
+        if not (gaps[gap_i][0] <= left and right <= gaps[gap_i][1]):
+            continue
+        name = (max(active, key=lambda ev: (ev[1], -ev[2]))[0]
+                if active else "unannotated")
+        out[name] = out.get(name, 0) + right - left
+    return out
+
+
 def reduce(planes: list, window_ns: tuple = None, top: int = 10) -> dict:
     """``window_ns`` = (start, end) on the trace's clock; by default the
     span of the harness's outermost annotation, else of the device events.
@@ -86,13 +118,17 @@ def reduce(planes: list, window_ns: tuple = None, top: int = 10) -> dict:
     Returns busy_s (union of the device-op intervals, averaged over the
     device planes), window_s, idle_share, modules {name: {"seconds",
     "count"}} (summed over devices), device_ops and idle_gaps (lists of
-    [name, seconds], at most ``top``)."""
+    [name, seconds], at most ``top``). An idle gap is cut at the edges of
+    the host events the trace was loaded with, and each piece goes to the
+    event open there that started last: the innermost of the harness's
+    annotations and the program's spans."""
     devices = [p for p in planes if DEVICE_PLANE.match(p["name"])]
     if not devices:
         raise ValueError("the trace has no TPU device plane")
-    annotations = [ev for p in planes if p not in devices
-                   for line in p["lines"] for ev in line["events"]
-                   if ev[0].startswith(ANNOTATION_PREFIX)]
+    spans = [ev for p in planes if p not in devices
+             for line in p["lines"] for ev in line["events"]
+             if ev[0].startswith(HOST_PREFIXES)]
+    annotations = [ev for ev in spans if ev[0].startswith(ANNOTATION_PREFIX)]
     if window_ns is None and annotations:
         outer = max(annotations, key=lambda ev: ev[2])
         window_ns = (outer[1], outer[1] + outer[2])
@@ -101,7 +137,7 @@ def reduce(planes: list, window_ns: tuple = None, top: int = 10) -> dict:
     modules: dict = {}
     op_time: dict = {}
     first_busy = None
-    spans = []
+    busy_ends = []
     for plane in devices:
         line = _line(plane, OPS_LINE) or _line(plane, MODULES_LINE)
         if line is None:
@@ -117,7 +153,7 @@ def reduce(planes: list, window_ns: tuple = None, top: int = 10) -> dict:
             first_busy = merged
         busy_total += sum(e - s for s, e in merged)
         if merged:
-            spans.append((merged[0][0], merged[-1][1]))
+            busy_ends.append((merged[0][0], merged[-1][1]))
         for name, _, dur in line["events"]:
             name = op_name(name)
             op_time[name] = op_time.get(name, 0) + dur
@@ -128,27 +164,19 @@ def reduce(planes: list, window_ns: tuple = None, top: int = 10) -> dict:
             m["seconds"] += dur / 1e9
             m["count"] += 1
     if window_ns is None:
-        if not spans:
+        if not busy_ends:
             raise ValueError("no device operation in the trace")
-        window_ns = (min(s for s, _ in spans), max(e for _, e in spans))
+        window_ns = (min(s for s, _ in busy_ends),
+                     max(e for _, e in busy_ends))
     window_s = (window_ns[1] - window_ns[0]) / 1e9
     busy_s = busy_total / len(devices) / 1e9
 
-    # idle gaps of the first device, each named for the harness annotation
-    # (the innermost, i.e. shortest) that covers most of it
+    # idle gaps of the first device
     edges = [window_ns[0]] + [t for iv in first_busy for t in iv] \
         + [window_ns[1]]
-    gap_time: dict = {}
-    for start, end in zip(edges[0::2], edges[1::2]):
-        if end <= start:
-            continue
-        best, best_cover = "unannotated", 0
-        for name, a_start, a_dur in sorted(annotations,
-                                           key=lambda ev: -ev[2]):
-            cover = min(end, a_start + a_dur) - max(start, a_start)
-            if cover > 0 and cover >= best_cover * 0.999:
-                best, best_cover = name, cover
-        gap_time[best] = gap_time.get(best, 0) + (end - start)
+    gaps = [(a, b) for a, b in zip(edges[0::2], edges[1::2]) if b > a]
+    gap_time = idle_by_span(gaps, [(name, start, start + dur)
+                                   for name, start, dur in spans])
 
     def ranked(table: dict) -> list:
         return [[n, v / 1e9] for n, v in
