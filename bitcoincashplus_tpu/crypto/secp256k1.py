@@ -293,8 +293,17 @@ def schnorr_sign(secret: int, msg_hash: int) -> tuple[int, int]:
     as a FULL field element (may exceed n, unlike ECDSA's r)."""
     assert 1 <= secret < N
     k = rfc6979_nonce(secret, msg_hash, extra=b"Schnorr+SHA256  ")
+    return schnorr_sign_with_nonce(secret, msg_hash, k)
+
+
+def schnorr_sign_with_nonce(secret: int, msg_hash: int, k: int,
+                            negate: bool = True) -> tuple[int, int]:
+    """The signing equation for a given nonce. ``negate=False`` keeps k
+    where jacobi(R.y) != 1: the equation s*G = R + e*P and R.x = r still
+    hold, only the Jacobi gate fails: the signature a verifier without
+    that gate accepts (tests, and the benchmark's wrong-jacobi fault)."""
     Rp = point_mul(k, G)
-    if jacobi(Rp[1]) != 1:
+    if negate and jacobi(Rp[1]) != 1:
         k = N - k
     r = Rp[0]
     e = schnorr_challenge(r, point_mul(secret, G), msg_hash)

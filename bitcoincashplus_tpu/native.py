@@ -131,6 +131,7 @@ def _bind(lib: ctypes.CDLL) -> None:
         ("bcp_engine_sig_pub", u8p),
         ("bcp_engine_sig_rn", u8p),
         ("bcp_engine_sig_wrap", u8p),
+        ("bcp_engine_sig_kind", u8p),
         ("bcp_engine_sig_txin", ctypes.POINTER(ctypes.c_uint32)),
     ):
         fn = getattr(lib, name)
@@ -200,6 +201,18 @@ def _bind(lib: ctypes.CDLL) -> None:
     lib.bcp_ecdsa_precompute.restype = None
     lib.bcp_ecdsa_sign.argtypes = [ctypes.c_char_p] * 4
     lib.bcp_ecdsa_sign.restype = ctypes.c_int
+    lib.bcp_schnorr_sign.argtypes = [ctypes.c_char_p] * 4
+    lib.bcp_schnorr_sign.restype = ctypes.c_int
+    lib.bcp_schnorr_challenge.argtypes = [
+        ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p, ctypes.c_long,
+        ctypes.c_char_p, ctypes.c_char_p, ctypes.c_int,
+    ]
+    lib.bcp_schnorr_challenge.restype = None
+    lib.bcp_schnorr_verify_batch.argtypes = [
+        ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p, ctypes.c_long,
+        ctypes.c_char_p, ctypes.c_int,
+    ]
+    lib.bcp_schnorr_verify_batch.restype = None
     lib.bcp_pubkey_parse.argtypes = [ctypes.c_char_p, ctypes.c_long,
                                      ctypes.c_char_p]
     lib.bcp_pubkey_parse.restype = ctypes.c_int
@@ -374,6 +387,26 @@ def ecdsa_sign(secret: int, e: int) -> tuple[int, int]:
             int.from_bytes(out.raw[32:], "big"))
 
 
+def schnorr_sign(secret: int, e: int) -> tuple[int, int]:
+    """Deterministic BCH Schnorr sign, bit-identical to the oracle signer
+    (crypto/secp256k1.schnorr_sign): the RFC6979 nonce with the spec's
+    "Schnorr+SHA256  " runs in Python, the two base multiplications, the
+    Jacobi test of R.y (k negated where it fails) and the challenge hash
+    run native. Returns (r, s), r a full field element."""
+    from .crypto.secp256k1 import rfc6979_nonce
+
+    lib = load()
+    assert lib is not None, "native library unavailable"
+    k = rfc6979_nonce(secret, e, extra=b"Schnorr+SHA256  ")
+    out = ctypes.create_string_buffer(64)
+    ok = lib.bcp_schnorr_sign(secret.to_bytes(32, "big"),
+                              (e % (1 << 256)).to_bytes(32, "big"),
+                              k.to_bytes(32, "big"), out)
+    assert ok, "secret or nonce out of range"
+    return (int.from_bytes(out.raw[:32], "big"),
+            int.from_bytes(out.raw[32:], "big"))
+
+
 def merkle_root(txids: list[bytes]) -> tuple[bytes, bool]:
     """(root, mutated) — ComputeMerkleRoot with the CVE-2012-2459 flag."""
     lib = load()
@@ -443,8 +476,8 @@ class NativeConnectResult:
     ``sig_status`` per input: 0 = the P2PKH scan's record is in the input's
     slot of ``sig_pub`` .. ``sig_wrap``; 1 = the Python interpreter decides
     it; 2 = a script template (P2PK, bare or P2SH CHECKMULTISIG) wrote its
-    lanes into ``leg_lanes``, the arrays (pub, rs, msg, rn, wrap, cand) in
-    input order, cand marking a multisig group's candidate lanes.
+    lanes into ``leg_lanes``, the arrays (pub, rs, msg, rn, wrap, cand, kind)
+    in input order, cand marking a multisig group's candidate lanes.
     ``leg_table`` has one row a template input: (input number, first lane,
     m, n) of its OP_CHECKMULTISIG, m = 0 for the one lane of an
     OP_CHECKSIG.
@@ -453,13 +486,23 @@ class NativeConnectResult:
     ``sigscan_thread_s`` is their seconds in the scan, ``legacy_sighash_s``
     those inside the legacy SignatureHash, which made ``legacy_digests``
     digests over ``legacy_sighash_bytes`` bytes of serialised
-    transaction."""
+    transaction.
+
+    ``sig_kind`` per input, and the seventh array of ``leg_lanes`` per
+    template lane: 0 an ECDSA lane, 1 a BCH Schnorr lane (a 65-byte
+    signature the scan took from the fork height on). A Schnorr lane's
+    ``rn`` slot holds (n - e) mod n, the scalar of its key in
+    R' = s*G + (n - e)*P, and its ``wrap`` is 0: ``schnorr_inputs`` such
+    inputs, ``schnorr_challenge_s`` the threads' seconds in the challenge
+    hash and n - e."""
 
     __slots__ = ("block_hash", "n_tx", "n_inputs", "undo", "txids_blob",
                  "sigscan_s", "sigscan_thread_s", "legacy_digests",
                  "legacy_sighash_bytes", "legacy_sighash_s",
+                 "schnorr_inputs", "schnorr_challenge_s",
                  "tx_offsets", "tx_out_counts", "sig_status", "sig_msg",
-                 "sig_rs", "sig_pub", "sig_rn", "sig_wrap", "sig_txin",
+                 "sig_rs", "sig_pub", "sig_rn", "sig_wrap", "sig_kind",
+                 "sig_txin",
                  "spent_values", "spent_heightcodes", "spent_spk_offsets",
                  "spent_spk_blob", "leg_lanes", "leg_table")
 
@@ -597,11 +640,12 @@ class ConnectEngine:
         res = NativeConnectResult()
         res.block_hash = hash_out.raw
         res.sigscan_s = lib.bcp_engine_sigscan_ns(self._h) / 1e9
-        scan = (ctypes.c_uint64 * 4)()
+        scan = (ctypes.c_uint64 * 6)()
         lib.bcp_engine_scan_counters(self._h, scan)
         res.legacy_digests, res.legacy_sighash_bytes = scan[0], scan[1]
         res.legacy_sighash_s, res.sigscan_thread_s = (scan[2] / 1e9,
                                                       scan[3] / 1e9)
+        res.schnorr_inputs, res.schnorr_challenge_s = scan[4], scan[5] / 1e9
         res.n_tx = lib.bcp_engine_n_tx(self._h)
         res.n_inputs = lib.bcp_engine_n_inputs(self._h)
         ulen = ctypes.c_size_t()
@@ -639,6 +683,9 @@ class ConnectEngine:
                 res.sig_wrap = np.frombuffer(
                     ctypes.string_at(lib.bcp_engine_sig_wrap(self._h), n),
                     np.uint8)
+                res.sig_kind = np.frombuffer(
+                    ctypes.string_at(lib.bcp_engine_sig_kind(self._h), n),
+                    np.uint8)
             res.spent_values = np.frombuffer(
                 ctypes.string_at(lib.bcp_engine_spent_values(self._h),
                                  8 * n), np.int64)
@@ -662,8 +709,8 @@ class ConnectEngine:
 
         res.leg_lanes = tuple(
             leg_blob(which, np.uint8, width)
-            for which, width in enumerate((64, 64, 32, 32, 1, 1)))
-        res.leg_table = leg_blob(6, np.uint32, 4)
+            for which, width in enumerate((64, 64, 32, 32, 1, 1, 1)))
+        res.leg_table = leg_blob(7, np.uint32, 4)
         return res
 
     def commit(self) -> None:
@@ -782,4 +829,61 @@ def ecdsa_verify_batch_blobs(pub: bytes, rs: bytes, msg: bytes, n: int,
     lib.bcp_ecdsa_verify_batch(pub, rs, msg, n, ok,
                                nthreads if nthreads is not None
                                else PAR_THREADS)
+    return [b == 1 for b in ok.raw]
+
+
+# -- blob-level BCH Schnorr entries: the lane kind beside ECDSA's ------------
+
+def schnorr_challenge_blobs(pub: bytes, rs: bytes, msg: bytes, n: int,
+                            nthreads: int | None = None):
+    """The Schnorr lanes' key scalars from raw (x||y, r||s, msg) blobs:
+    u2 = (n - e) mod n per lane, e the challenge hash over (r, compressed
+    key, msg), as one n*32-byte big-endian blob, + validity flags (False:
+    r >= p or s >= n). u1 is s as it stands. What native/connect.cpp's scan
+    computes on its own threads for the lanes it takes."""
+    lib = load()
+    assert lib is not None, "native library unavailable"
+    if n == 0:
+        return b"", []
+    u2 = ctypes.create_string_buffer(32 * n)
+    ok = ctypes.create_string_buffer(n)
+    lib.bcp_schnorr_challenge(pub, rs, msg, n, u2, ok,
+                              nthreads if nthreads is not None
+                              else PAR_THREADS)
+    return u2.raw, [b == 1 for b in ok.raw]
+
+
+def schnorr_verify_batch(records, nthreads: int | None = None) -> list[bool]:
+    """Batch BCH Schnorr verify of SigCheckRecord-shaped objects across host
+    threads: the CPU lane of the record path (ops/ecdsa_batch) and of the
+    interpreter's eager check. A record without a key reads False."""
+    keyed = [i for i, rec in enumerate(records) if rec.pubkey is not None]
+    out = [False] * len(records)
+    if keyed:
+        recs = [records[i] for i in keyed]
+        pub = b"".join(
+            rec.pubkey[0].to_bytes(32, "big")
+            + rec.pubkey[1].to_bytes(32, "big") for rec in recs)
+        rs, msg = _pack_rs_msg(recs)
+        in_range = [0 <= rec.r < (1 << 256) and 0 <= rec.s < (1 << 256)
+                    for rec in recs]
+        for i, ok, fits in zip(keyed, schnorr_verify_batch_blobs(
+                pub, rs, msg, len(recs), nthreads), in_range):
+            out[i] = ok and fits
+    return out
+
+
+def schnorr_verify_batch_blobs(pub: bytes, rs: bytes, msg: bytes, n: int,
+                               nthreads: int | None = None) -> list[bool]:
+    """Threaded native BCH Schnorr verify over blobs: the rung under the
+    device's Schnorr program and the re-check of its degenerate lanes
+    (same acceptance set as crypto/secp256k1.schnorr_verify)."""
+    lib = load()
+    assert lib is not None, "native library unavailable"
+    if n == 0:
+        return []
+    ok = ctypes.create_string_buffer(n)
+    lib.bcp_schnorr_verify_batch(pub, rs, msg, n, ok,
+                                 nthreads if nthreads is not None
+                                 else PAR_THREADS)
     return [b == 1 for b in ok.raw]

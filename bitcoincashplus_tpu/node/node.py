@@ -1778,7 +1778,11 @@ class Node:
         # NULLFAIL (history below the fork height); the scan's threads'
         # seconds (sigscan_thread_s; sigscan_s is its wall) and, of them,
         # those inside the legacy SignatureHash, with its digests and the
-        # bytes of serialised transaction they hashed.
+        # bytes of serialised transaction they hashed. schnorr_inputs: the
+        # inputs whose 65-byte signature the scan took as a BCH Schnorr
+        # lane (from the fork height on), schnorr_challenge_s the scan's
+        # threads' seconds in their challenge hash and n - e (inside
+        # sigscan_thread_s, as legacy_sighash_s is).
         # The times that are not the scan's own are sums of spans, filled
         # in when the import ends (_IMPORT_LEGS). At the dispatch queue, by
         # what the runtime says of each handle (BatchHandle.done, which
@@ -1803,6 +1807,7 @@ class Node:
                  "prefork_blocks": 0, "sigscan_thread_s": 0.0,
                  "legacy_digests": 0, "legacy_sighash_bytes": 0,
                  "legacy_sighash_s": 0.0,
+                 "schnorr_inputs": 0, "schnorr_challenge_s": 0.0,
                  "dispatches": 0, "tail_dispatches": 0, "tail_lanes": 0,
                  "flushes": 0, "queue_empty_s": 0.0,
                  "inflight_at_enqueue": [0] * (MAX_INFLIGHT + 1)}
@@ -1818,7 +1823,8 @@ class Node:
                 queue_empty_since[0] = time.monotonic()
 
         scan_keys = ("sigscan_s", "sigscan_thread_s", "legacy_digests",
-                     "legacy_sighash_bytes", "legacy_sighash_s")
+                     "legacy_sighash_bytes", "legacy_sighash_s",
+                     "schnorr_inputs", "schnorr_challenge_s")
         # counters of gettpuinfo.batch reported as the import's own deltas
         delta_keys = ("multisig_groups", "multisig_lanes",
                       "multisig_group_confirms", "inline_legacy_sigs")
@@ -1826,7 +1832,7 @@ class Node:
         n_imported = 0
         pending: dict[bytes, list[tuple[bytes, Optional[tuple]]]] = {}
         # in-flight signature batches: (block hash, BatchHandle, number of
-        # its first lane, its candidate mask)
+        # its first lane, its candidate mask, its lane kind)
         inflight: list[tuple] = []
         # cross-block record aggregation: mainnet blocks carry ~2-5k sig
         # inputs, and per-dispatch latency amortizes over wider buckets
@@ -1839,12 +1845,19 @@ class Node:
         # (ops/ecdsa_batch appends them per batch; an exact-8192 slice
         # would spill into the 10240 bucket and pay a fresh compile).
         AGG_LANES = 8190
-        # (pub, rs, msg, rn, wrap, cand) per block; cand marks the
-        # candidate lanes of deferred OP_CHECKMULTISIG groups
-        agg: list[tuple] = []
-        agg_count = [0]
+        # A bucket is of one lane kind (ops/ecdsa_batch.dispatch_packed):
+        # ECDSA lanes and the BCH Schnorr lanes of 65-byte signatures
+        # aggregate apart, so a block with both fills two open buckets and
+        # a flush drains both. Per kind: (pub, rs, msg, rn, wrap, cand) per
+        # block; cand marks the candidate lanes of deferred
+        # OP_CHECKMULTISIG groups (ECDSA only: OP_CHECKMULTISIG takes no
+        # Schnorr signature), and a Schnorr lane's rn is (n - e) mod n
+        ECDSA, SCHNORR = 0, 1
+        agg: dict[int, list] = {ECDSA: [], SCHNORR: []}
+        agg_count = {ECDSA: 0, SCHNORR: 0}
         agg_last_hash = [b""]
-        lanes_dispatched = [0]  # lane numbers run over the whole import
+        # lane numbers run over the whole import, each kind its own
+        lanes_dispatched = {ECDSA: 0, SCHNORR: 0}
 
         def confirm_on_host(owner) -> None:
             """A multisig group's walk failed on the batch's verdicts: the
@@ -1870,7 +1883,7 @@ class Node:
 
         settler = _MultisigSettler(confirm_on_host)
 
-        def dispatch(arrays, sl: slice) -> None:
+        def dispatch(kind: int, arrays, sl: slice) -> None:
             stats["dispatches"] += 1
             if watch_queue:
                 stats["inflight_at_enqueue"][min(unfinished(),
@@ -1880,22 +1893,30 @@ class Node:
                 handle = ecdsa_batch.dispatch_packed(
                     *(a[sl] for a in arrays[:5]),
                     backend=self.connect_backend,
-                    candidate=cand if cand.any() else None)
+                    candidate=cand if cand.any() else None,
+                    schnorr=kind == SCHNORR)
             if queue_empty_since[0] is not None:
                 # the chip had nothing until this program was handed over
                 stats["queue_empty_s"] += (time.monotonic()
                                            - queue_empty_since[0])
                 queue_empty_since[0] = None
             inflight.append((agg_last_hash[0], handle,
-                             lanes_dispatched[0] + sl.start, cand))
+                             lanes_dispatched[kind] + sl.start, cand, kind))
 
         def flush_agg(everything: bool = True):
-            if not agg:
-                return
-            with span("import.pack", blocks=len(agg)):
-                arrays = [np.concatenate([a[i] for a in agg])
+            for kind in (ECDSA, SCHNORR):
+                if agg[kind] and (everything
+                                  or agg_count[kind] >= AGG_LANES):
+                    flush_kind(kind, everything)
+            while len(inflight) > MAX_INFLIGHT:
+                settle_oldest()
+
+        def flush_kind(kind: int, everything: bool) -> None:
+            blocks = agg[kind]
+            with span("import.pack", blocks=len(blocks)):
+                arrays = [np.concatenate([a[i] for a in blocks])
                           for i in range(6)]
-            agg.clear()
+            blocks.clear()
             pos = 0
             total = len(arrays[2])
             # dispatch EXACT AGG_LANES slices: the jit bakes the bucket
@@ -1904,7 +1925,7 @@ class Node:
             # minutes-long compile); only the final sub-AGG_LANES tail
             # may hit a second bucket
             while total - pos >= AGG_LANES:
-                dispatch(arrays, slice(pos, pos + AGG_LANES))
+                dispatch(kind, arrays, slice(pos, pos + AGG_LANES))
                 pos += AGG_LANES
             if everything:
                 # drain the tail in <=2046-lane chunks (2048-bucket minus
@@ -1915,17 +1936,15 @@ class Node:
                     end = min(pos + 2046, total)
                     stats["tail_dispatches"] += 1
                     stats["tail_lanes"] += end - pos
-                    dispatch(arrays, slice(pos, end))
+                    dispatch(kind, arrays, slice(pos, end))
                     pos = end
             if pos < total:
-                agg.append(tuple(a[pos:] for a in arrays))
-            agg_count[0] = total - pos
-            lanes_dispatched[0] += pos
-            while len(inflight) > MAX_INFLIGHT:
-                settle_oldest()
+                blocks.append(tuple(a[pos:] for a in arrays))
+            agg_count[kind] = total - pos
+            lanes_dispatched[kind] += pos
 
         def settle_oldest():
-            h, handle, first, cand = inflight.pop(0)
+            h, handle, first, cand, kind = inflight.pop(0)
             # the thread blocked on the chip, and nothing else
             with span("import.settle_wait", inflight=len(inflight) + 1):
                 ok = handle.result()
@@ -1937,7 +1956,8 @@ class Node:
                     raise _NativeImportAbort(
                         f"sig batch failed in block {hash_to_hex(h)[:16]}"
                     )
-                settler.settled(first, ok)
+                if kind == ECDSA:  # multisig groups ride ECDSA lanes only
+                    settler.settled(first, ok)
 
         def settle_all():
             flush_agg()
@@ -1951,7 +1971,7 @@ class Node:
                 raise _NativeImportAbort(
                     f"{len(settler.pending)} multisig group(s) left "
                     f"unsettled after the last dispatch, the first at lane "
-                    f"{settler.pending[0][0]} of {lanes_dispatched[0]}")
+                    f"{settler.pending[0][0]} of {lanes_dispatched[ECDSA]}")
 
         def fast_flush():
             with span("import.drain"):
@@ -2052,10 +2072,11 @@ class Node:
             """The generic-script leg of one block: the lanes the native
             scan's templates wrote for the inputs they fit, then every
             input they declined (``interp_idx``) through the Python
-            interpreter. Returns (the six lane arrays, multisig groups), or
-            None where the block has to take the Python path: a script
-            failed, or a record is not ECDSA's (a 65-byte Schnorr signature
-            has no lane in the packed batch)."""
+            interpreter. Returns (the ECDSA lanes' six arrays, multisig
+            groups, the Schnorr lanes' six arrays or None), or None where the block
+            has to take the Python path: a script failed. A Schnorr record
+            (a 65-byte signature in a script no template fits, deferred by
+            the interpreter) joins the Schnorr lanes the templates wrote."""
             records: list = []
             groups: list = []
             inline = len(interp_idx) and not flags & SCRIPT_VERIFY_NULLFAIL
@@ -2066,17 +2087,37 @@ class Node:
                     interpret(raw, res, interp_idx, flags, h, records, groups)
             except ScriptError:
                 return None
-            if any(r.algo != "ecdsa" for r in records):
-                return None
-            *lanes, cand = res.leg_lanes
+            *lanes, cand, kind = res.leg_lanes
             cand = cand.view(bool)
-            # the templates' groups, counted as defer_multisig counts its own
             table = res.leg_table
+            schnorr = None
+            if kind.any():
+                # each kind's lanes apart, and a group's first lane counted
+                # among the ECDSA lanes alone
+                is_ecdsa = kind == ECDSA
+                schnorr = [a[~is_ecdsa] for a in (*lanes, cand)]
+                lanes = [a[is_ecdsa] for a in lanes]
+                cand = cand[is_ecdsa]
+                table = table.copy()
+                table[:, 1] = (np.cumsum(is_ecdsa) - 1)[table[:, 1]]
+            # the templates' groups, counted as defer_multisig counts its own
             native_groups = [
                 MultisigGroup(first, m, n, (raw, res, g, flags, h))
                 for g, first, m, n in table[table[:, 2] > 0].tolist()]
             ecdsa_batch.STATS.multisig_groups += len(native_groups)
             ecdsa_batch.STATS.multisig_lanes += int(cand.sum())
+            deferred = [r for r in records if r.algo == "schnorr"]
+            if deferred:
+                # the groups' starts count ECDSA records alone (a group
+                # holds no Schnorr record: defer_multisig)
+                before = np.cumsum([r.algo == "schnorr" for r in records])
+                for grp in groups:
+                    grp.start -= int(before[grp.start])
+                records = [r for r in records if r.algo != "schnorr"]
+                blobs = (*ecdsa_batch.schnorr_records_to_blobs(deferred),
+                         np.zeros(len(deferred), bool))
+                schnorr = blobs if schnorr is None else [
+                    np.concatenate(pair) for pair in zip(schnorr, blobs)]
             if records:
                 ecand = np.zeros(len(records), bool)
                 for grp in groups:
@@ -2085,24 +2126,34 @@ class Node:
                 lanes = [np.concatenate(pair) for pair in zip(
                     lanes, ecdsa_batch.records_to_blobs(records))]
                 cand = np.concatenate([cand, ecand])
-            return (*lanes, cand), native_groups + groups
+            return (*lanes, cand), native_groups + groups, schnorr
 
         def join_lanes(raw: bytes, res, h: bytes, height: int, flags: int,
                        prefork: bool) -> bool:
-            """One block's lanes into the aggregation: those the P2PKH scan
-            wrote, then the script leg's. False where the leg sends the
-            block to the Python path."""
+            """One block's lanes into the aggregations, each kind into its
+            own: those the P2PKH scan wrote, then the script leg's. False
+            where the leg sends the block to the Python path."""
             status = res.sig_status
-            fast_idx = np.nonzero(status == 0)[0]
-            stats["fast_inputs"] += int(fast_idx.size)
-            ecdsa_batch.STATS.p2pkh_fast_path += int(fast_idx.size)
+            taken = status == 0
+            n_fast = int(taken.sum())
+            stats["fast_inputs"] += n_fast
+            ecdsa_batch.STATS.p2pkh_fast_path += n_fast
+            if res.schnorr_inputs:
+                fast_idx = np.nonzero(taken & (res.sig_kind == ECDSA))[0]
+                s_idx = np.nonzero(taken & (res.sig_kind == SCHNORR))[0]
+                schnorr = [res.sig_pub[s_idx], res.sig_rs[s_idx],
+                           res.sig_msg[s_idx], res.sig_rn[s_idx],
+                           res.sig_wrap[s_idx], np.zeros(len(s_idx), bool)]
+            else:
+                fast_idx = np.nonzero(taken)[0]
+                schnorr = None
             pub = res.sig_pub[fast_idx]
             rs = res.sig_rs[fast_idx]
             msg = res.sig_msg[fast_idx]
             rn = res.sig_rn[fast_idx]
             wrap = res.sig_wrap[fast_idx]
             cand = np.zeros(len(msg), bool)
-            n_leg = res.n_inputs - int(fast_idx.size)
+            n_leg = res.n_inputs - n_fast
             if n_leg:
                 # generic-script inputs: the lanes of those a native
                 # template fits, the Python interpreter the authority
@@ -2115,18 +2166,26 @@ class Node:
                     leg = script_leg(raw, res, interp_idx, flags, h)
                 if leg is None:
                     return False
-                leg_lanes, groups = leg
-                settler.add(lanes_dispatched[0] + agg_count[0]
+                leg_lanes, groups, leg_schnorr = leg
+                settler.add(lanes_dispatched[ECDSA] + agg_count[ECDSA]
                             + len(msg), groups)
                 pub, rs, msg, rn, wrap, cand = (
                     np.concatenate(pair) for pair in zip(
                         (pub, rs, msg, rn, wrap, cand), leg_lanes))
+                if leg_schnorr is not None:
+                    schnorr = leg_schnorr if schnorr is None else [
+                        np.concatenate(pair)
+                        for pair in zip(schnorr, leg_schnorr)]
             if len(msg):
-                agg.append((pub, rs, msg, rn, wrap, cand))
-                agg_count[0] += len(msg)
+                agg[ECDSA].append((pub, rs, msg, rn, wrap, cand))
+                agg_count[ECDSA] += len(msg)
                 agg_last_hash[0] = h
                 if prefork:
                     ecdsa_batch.STATS.prefork_lanes += len(msg)
+            if schnorr is not None and len(schnorr[2]):
+                agg[SCHNORR].append(tuple(schnorr))
+                agg_count[SCHNORR] += len(schnorr[2])
+                agg_last_hash[0] = h
             return True
 
         def fast_connect(raw: bytes, h: bytes, prev, pos_info) -> bool:
@@ -2219,7 +2278,7 @@ class Node:
                 stats["blocks"] += 1
                 stats["prefork_blocks"] += prefork
             note_queue_empty()
-            if agg_count[0] >= AGG_LANES:
+            if max(agg_count.values()) >= AGG_LANES:
                 flush_agg(everything=False)
             return True
 

@@ -7,7 +7,12 @@ packed into byte matrices and verified in ONE device dispatch
 (SURVEY.md §3.2 P1, §8.4 "ECDSA batch").
 
 One arrow: dispatch_batch(records) -> records_to_blobs ->
-_dispatch_packed_device <- dispatch_packed(blobs). Pipeline per batch:
+_dispatch_packed_device <- dispatch_packed(blobs). A bucket is of one lane
+kind: ECDSA's (below), or BCH Schnorr's (dispatch_packed(schnorr=True): the
+65-byte signatures the import's scan took; u1 = s, u2 = n - e, no modular
+inverse, the program _glv_schnorr_program behind the same prepare stage;
+its ladder of rungs has no w4 form: device program, retries, breaker,
+native threaded Schnorr verify, Python oracle). Pipeline per ECDSA batch:
   1. host: w = s⁻¹ mod n, u1 = e·w, u2 = r·w (native C++, threaded;
      Python ints without the library). The GLV lattice split
      (k = k1 + λ·k2, |k1|,|k2| < 2^128) runs inside the device program
@@ -113,6 +118,11 @@ PALLAS_SHAPE_BUDGET = 9
 _PW_GLV_DEV = dw.program("ecdsa_glv_decompose",
                          shape_budget=PALLAS_SHAPE_BUDGET)
 _PW_W4_BYTES = dw.program("ecdsa_w4_bytes", shape_budget=PALLAS_SHAPE_BUDGET)
+# the Schnorr bucket's two programs (the shared prepare stage and
+# _glv_schnorr_program): traced and compiled by a node that sees a Schnorr
+# lane, never before
+_PW_GLV_SCHNORR = dw.program("ecdsa_glv_schnorr",
+                             shape_budget=PALLAS_SHAPE_BUDGET)
 # Pippenger MSM batch-verification program (ISSUE 19): term counts pad to
 # the _MSM_BUCKETS ladder, and the canary batches reuse the smallest
 # bucket, so the compiled-shape set is exactly that ladder.
@@ -321,6 +331,12 @@ class BatchStats:
     # msm_canary_failures are canary-gate trips (also kat_failures).
     schnorr_sigs: int = 0
     schnorr_cpu_sigs: int = 0
+    # Schnorr as a lane kind of the packed path (PR 44): lanes verified by
+    # the device's Schnorr program (inside sigs_verified too) and its
+    # dispatches (inside glv_dispatches too: the prepare stage does the
+    # device split for both kinds)
+    schnorr_lanes: int = 0
+    schnorr_dispatches: int = 0
     msm_dispatches: int = 0
     msm_batches_accepted: int = 0
     msm_batches_rejected: int = 0
@@ -390,8 +406,18 @@ def _pad(mat: np.ndarray, bucket: int) -> np.ndarray:
     return out
 
 
+def _ge_be(rows: np.ndarray, bound: int) -> np.ndarray:
+    """rows (m, 32) big-endian uint8 >= bound, per row."""
+    const = np.frombuffer(bound.to_bytes(32, "big"), np.uint8)
+    differs = rows != const
+    first = differs.argmax(axis=1)
+    at = np.arange(len(rows))
+    return ~differs.any(axis=1) | (rows[at, first] > const[first])
+
+
 def pack_lanes(pub: np.ndarray, rs: np.ndarray, msg: np.ndarray,
-               rn: np.ndarray, wrap: np.ndarray, bucket: int) -> list:
+               rn: np.ndarray, wrap: np.ndarray, bucket: int,
+               schnorr: bool = False) -> list:
     """The one packer: m blob rows (records_to_blobs' layout, which is the
     native scan's) -> the eight arrays both byte programs take, padded to
     ``bucket`` lanes: u1, u2, qx, qy, r, rn as (bucket, 32) big-endian
@@ -400,10 +426,25 @@ def pack_lanes(pub: np.ndarray, rs: np.ndarray, msg: np.ndarray,
     (q_inf = 1: the kernel reports False), so they can never turn a bad
     batch good or a good batch bad. u1/u2 come from the threaded native
     modular-inverse leg; the Python-int loop only if the library is
-    missing."""
+    missing.
+
+    A Schnorr bucket (``schnorr``; schnorr_records_to_blobs' layout, the
+    scan's for its Schnorr lanes) needs no inverse: u1 is s, u2 the rn
+    slot's (n - e) mod n; lanes with r >= p or s >= n are poisoned. Six
+    arrays: u1, u2, qx, qy, q_inf, r."""
     from .. import native
 
     m = len(msg)
+    if schnorr:
+        q_inf = np.ones(bucket, np.uint8)
+        q_inf[:m] = _ge_be(rs[:, :32], oracle.P) | _ge_be(rs[:, 32:],
+                                                           oracle.N)
+        with tm.span("ecdsa.pack", lanes=m, bucket=bucket) as packed:
+            arrays = [_pad(rs[:, 32:], bucket), _pad(rn, bucket),
+                      _pad(pub[:, :32], bucket), _pad(pub[:, 32:], bucket),
+                      q_inf, _pad(rs[:, :32], bucket)]
+        STATS.glv_emit_s += packed.seconds
+        return arrays
     if native.available():
         u1_blob, u2_blob, ok = native.ecdsa_precompute_blobs(
             rs.tobytes(), msg.tobytes(), m)
@@ -450,10 +491,17 @@ def _verify_cpu_ecdsa(records: Sequence) -> np.ndarray:
 
 
 def _schnorr_oracle(records: Sequence) -> np.ndarray:
-    """Per-lane Schnorr verify on the Python-int oracle — the accept/
-    reject reference every MSM verdict must match byte-identically (and
-    the reject-side engine the bisection funnels into)."""
+    """Per-lane Schnorr verify on the CPU — the accept/reject reference
+    every MSM verdict must match byte-identically (and the reject-side
+    engine the bisection funnels into): the native threaded verify when the
+    library loaded (differentially tested against the Python-int oracle,
+    tests/unit/test_schnorr_lanes.py; 123 ms a signature without it), else
+    that oracle."""
+    from .. import native
+
     STATS.schnorr_cpu_sigs += len(records)
+    if native.available():
+        return np.array(native.schnorr_verify_batch(records), dtype=bool)
     return np.array(
         [
             oracle.schnorr_verify(rec.pubkey, rec.r, rec.s, rec.msg_hash)
@@ -556,6 +604,59 @@ def _schnorr_kat_records() -> tuple:
         bad = SigCheckRecord(pub, r, s, (e + 1) % oracle.N, algo="schnorr")
         _SCHNORR_KAT = (good, bad)
     return _SCHNORR_KAT
+
+
+def _verify_cpu_schnorr_blobs(pub, rs, msg, n: int) -> np.ndarray:
+    """CPU verdicts for n Schnorr blob rows: the native threaded Schnorr
+    verify, else the Python oracle (counted in schnorr_cpu_sigs)."""
+    from .. import native
+
+    if native.available():
+        return np.asarray(native.schnorr_verify_batch_blobs(
+            pub.tobytes(), rs.tobytes(), msg.tobytes(), n), bool)
+    recs = _LazyRecords(pub, rs, msg, "schnorr")
+    return _schnorr_oracle([recs[i] for i in range(n)])
+
+
+def schnorr_records_to_blobs(records: Sequence):
+    """Schnorr SigCheckRecords in the blob layout of a Schnorr bucket: pub
+    (n,64), r||s (n,64), msg (n,32), and in the rn slot (n - e) mod n, the
+    key's scalar in R' = s*G + (n - e)*P; wrap is 0. r, s and msg go in
+    mod 2^256 (pack_lanes range-flags r >= p, s >= n). The challenge hash
+    runs on the native library's threads, else in Python, inside the span
+    ``schnorr.precompute`` (the import's scan has done it for the lanes it
+    took: last_import_stats.schnorr_challenge_s)."""
+    from .. import native
+
+    n = len(records)
+    if any(getattr(r, "algo", "ecdsa") != "schnorr" for r in records):
+        raise ValueError("schnorr_records_to_blobs packs Schnorr records only")
+    top = 1 << 256
+    pub, rs, msg = _record_rows(records)
+    with tm.span("schnorr.precompute", lanes=n):
+        if native.available():
+            blob, _ = native.schnorr_challenge_blobs(
+                pub.tobytes(), rs.tobytes(), msg.tobytes(), n)
+        else:
+            blob = b"".join(
+                ((oracle.N - oracle.schnorr_challenge(
+                    r.r % top, r.pubkey, r.msg_hash)) % oracle.N
+                 ).to_bytes(32, "big") for r in records)
+    u2 = np.frombuffer(blob, np.uint8).reshape(n, 32)
+    return pub, rs, msg, u2, np.zeros(n, np.uint8)
+
+
+_SCHNORR_KAT_BLOBS = None
+
+
+def _schnorr_kat_blobs() -> tuple:
+    """The two known-answer lanes of a Schnorr bucket (_schnorr_kat_records:
+    one that MUST verify, one that MUST NOT), in blob layout; made once."""
+    global _SCHNORR_KAT_BLOBS
+    if _SCHNORR_KAT_BLOBS is None:
+        _SCHNORR_KAT_BLOBS = schnorr_records_to_blobs(
+            list(_schnorr_kat_records()))
+    return _SCHNORR_KAT_BLOBS
 
 
 def _msm_rng() -> random.Random:
@@ -877,11 +978,11 @@ class BatchHandle:
 
     __slots__ = ("_n", "_bucket", "_device_ok", "_cpu_ok", "_degen",
                  "_records", "_breaker", "_kat", "_recover", "_ctx",
-                 "_candidate")
+                 "_candidate", "_recheck")
 
     def __init__(self, n, bucket=0, device_ok=None, cpu_ok=None,
                  degen=None, records=None, breaker=None, kat=False,
-                 recover=None, ctx=None, candidate=None):
+                 recover=None, ctx=None, candidate=None, recheck=None):
         self._n = n
         self._bucket = bucket
         self._device_ok = device_ok
@@ -898,6 +999,14 @@ class BatchHandle:
         # multisig candidate lanes (bool mask over the n real lanes, or
         # None): a False there is an answer, not an alarm
         self._candidate = candidate
+        # CPU verdicts for a few lanes by index (degenerate lanes, device
+        # Falses); None: _verify_cpu over ``records``
+        self._recheck = recheck
+
+    def _redo(self, idxs) -> np.ndarray:
+        if self._recheck is not None:
+            return self._recheck(idxs)
+        return _verify_cpu([self._records[i] for i in idxs])
 
     def _device_failed(self, err: BaseException) -> np.ndarray:
         """Settle-time device failure: breaker bookkeeping + CPU re-verify
@@ -913,14 +1022,15 @@ class BatchHandle:
                    type(err).__name__, str(err)[:120], self._n)
         out = self._recover()
         self._degen = None
-        self._records = self._recover = None
+        self._records = self._recover = self._recheck = None
         self._cpu_ok = np.asarray(out, dtype=bool)
         return self._cpu_ok
 
     def done(self) -> bool:
         """Whether result() would return without waiting for the device:
         asks the runtime, blocks on nothing."""
-        return self._device_ok is None or self._device_ok.is_ready()
+        ready = getattr(self._device_ok, "is_ready", None)
+        return ready is None or ready()  # a host array is ready
 
     def result(self) -> np.ndarray:
         if self._device_ok is None:
@@ -969,8 +1079,7 @@ class BatchHandle:
             idxs = np.nonzero(degen)[0]
             if idxs.size:
                 STATS.degenerate_rechecks += int(idxs.size)
-                redo = _verify_cpu([self._records[i] for i in idxs])
-                out[idxs] = redo
+                out[idxs] = self._redo(idxs)
             self._degen = None
         if self._records is not None:
             # reject-side host confirmation: a device False is never
@@ -991,10 +1100,10 @@ class BatchHandle:
             bad = np.nonzero(bad)[0]
             if bad.size:
                 STATS.reject_confirm_sigs += int(bad.size)
-                out[bad] = _verify_cpu([self._records[i] for i in bad])
+                out[bad] = self._redo(bad)
         if self._breaker is not None:
             self._breaker.record_success()
-        self._records = self._recover = None  # let the blobs go
+        self._records = self._recover = self._recheck = None  # the blobs go
         self._cpu_ok = out
         return self._cpu_ok
 
@@ -1083,11 +1192,12 @@ def glv_enabled() -> bool:
     return not _GLV_BROKEN
 
 
-def _note_glv_failure(e: Exception) -> None:
+def _note_glv_failure(e: Exception, then: str = "w4 fallback") -> None:
     """GLV-rung failure bookkeeping: the dispatch degrades to the w4
-    kernel (same supervised attempt). Programming errors re-raise — same
-    invariant as _note_pallas_failure: a NameError in the GLV core must
-    not hide behind a green w4 fallback forever."""
+    kernel (same supervised attempt; a Schnorr bucket has no w4 form and
+    fails the attempt: ``then`` says which, for the log). Programming
+    errors re-raise — same invariant as _note_pallas_failure: a NameError
+    in the GLV core must not hide behind a green w4 fallback forever."""
     global _GLV_BROKEN
     if isinstance(e, SURFACE_ERRORS):
         raise e
@@ -1095,8 +1205,8 @@ def _note_glv_failure(e: Exception) -> None:
     text = f"{type(e).__name__}: {e}"
     if _compiler_refused(e):
         _GLV_BROKEN = True
-    log_printf("glv ECDSA kernel failed (%s) — w4 fallback%s",
-               text[:200],
+    log_printf("glv verify kernel failed (%s) — %s%s",
+               text[:200], then,
                " (latched)" if _GLV_BROKEN else "")
 
 
@@ -1393,12 +1503,14 @@ class _LazyRecords:
     """SigCheckRecord view over packed blobs, materialized per index — only
     degenerate-lane rechecks (rare) ever touch it."""
 
-    __slots__ = ("pub", "rs", "msg")
+    __slots__ = ("pub", "rs", "msg", "algo")
 
-    def __init__(self, pub: np.ndarray, rs: np.ndarray, msg: np.ndarray):
+    def __init__(self, pub: np.ndarray, rs: np.ndarray, msg: np.ndarray,
+                 algo: str = "ecdsa"):
         self.pub = pub
         self.rs = rs
         self.msg = msg
+        self.algo = algo
 
     def __getitem__(self, i: int):
         from ..script.interpreter import SigCheckRecord
@@ -1408,8 +1520,26 @@ class _LazyRecords:
         return SigCheckRecord(
             (int.from_bytes(pub[:32], "big"), int.from_bytes(pub[32:], "big")),
             int.from_bytes(rs[:32], "big"), int.from_bytes(rs[32:], "big"),
-            int.from_bytes(self.msg[i].tobytes(), "big"),
+            int.from_bytes(self.msg[i].tobytes(), "big"), algo=self.algo,
         )
+
+
+def _record_rows(records: Sequence) -> tuple:
+    """pub (n,64), r||s (n,64), msg (n,32) of SigCheckRecords, r, s and msg
+    mod 2^256: the rows both lane kinds' blob layouts start with."""
+    n = len(records)
+    top = 1 << 256
+    pub = np.frombuffer(
+        b"".join(r.pubkey[0].to_bytes(32, "big") + r.pubkey[1].to_bytes(32, "big")
+                 for r in records), np.uint8).reshape(n, 64)
+    rs = np.frombuffer(
+        b"".join((r.r % top).to_bytes(32, "big")
+                 + (r.s % top).to_bytes(32, "big")
+                 for r in records), np.uint8).reshape(n, 64)
+    msg = np.frombuffer(
+        b"".join((r.msg_hash % top).to_bytes(32, "big")
+                 for r in records), np.uint8).reshape(n, 32)
+    return pub, rs, msg
 
 
 def records_to_blobs(records: Sequence):
@@ -1423,16 +1553,7 @@ def records_to_blobs(records: Sequence):
     if any(getattr(r, "algo", "ecdsa") != "ecdsa" for r in records):
         raise ValueError("records_to_blobs packs ECDSA records only")
     top = 1 << 256
-    pub = np.frombuffer(
-        b"".join(r.pubkey[0].to_bytes(32, "big") + r.pubkey[1].to_bytes(32, "big")
-                 for r in records), np.uint8).reshape(n, 64)
-    rs = np.frombuffer(
-        b"".join((r.r % top).to_bytes(32, "big")
-                 + (r.s % top).to_bytes(32, "big")
-                 for r in records), np.uint8).reshape(n, 64)
-    msg = np.frombuffer(
-        b"".join((r.msg_hash % top).to_bytes(32, "big")
-                 for r in records), np.uint8).reshape(n, 32)
+    pub, rs, msg = _record_rows(records)
     wraps = [0 <= r.r and r.r + oracle.N < oracle.P for r in records]
     rn = np.frombuffer(
         b"".join((r.r + oracle.N if w else r.r % top).to_bytes(32, "big")
@@ -1448,13 +1569,19 @@ PACKED_DEVICE_FLOOR = 512
 def dispatch_packed(pub: np.ndarray, rs: np.ndarray, msg: np.ndarray,
                     rn: np.ndarray, wrap: np.ndarray,
                     backend: str = "auto",
-                    candidate: Optional[np.ndarray] = None) -> BatchHandle:
+                    candidate: Optional[np.ndarray] = None,
+                    schnorr: bool = False) -> BatchHandle:
     """Enqueue a packed verify batch: pub (n,64), rs (n,64), msg (n,32),
     rn (n,32), wrap (n,) — all uint8, big-endian fields, caller-validated
     ranges (1 <= r,s < N; pubkey on-curve affine). Device leg is breaker-
     supervised like dispatch_batch (same KAT lanes, same CPU re-verify on
     failure). ``candidate`` (n,) bool marks multisig candidate lanes, whose
-    False the caller settles by group (BatchHandle.result)."""
+    False the caller settles by group (BatchHandle.result).
+
+    ``schnorr``: every lane is a BCH Schnorr signature (a bucket is of one
+    kind), in schnorr_records_to_blobs' layout: rn holds (n - e) mod n,
+    wrap is unused. Two Schnorr known-answer lanes ride the bucket; a
+    failed dispatch is verified by the native threaded Schnorr verify."""
     _check_backend(backend)
     n = len(msg)
     if n == 0:
@@ -1463,17 +1590,18 @@ def dispatch_packed(pub: np.ndarray, rs: np.ndarray, msg: np.ndarray,
         backend == "auto" and n >= PACKED_DEVICE_FLOOR and _device_available()
     )
     if not use_device:
-        return _packed_cpu_handle(pub, rs, msg, n)
+        return _packed_cpu_handle(pub, rs, msg, n, schnorr)
     br = dispatch.breaker("ecdsa")
     if not br.allow():
         br.note_fallback(n)
         STATS.fault_fallback_sigs += n
-        return _packed_cpu_handle(pub, rs, msg, n)
+        return _packed_cpu_handle(pub, rs, msg, n, schnorr)
     handle = _dispatch_packed_device(pub, rs, msg, rn, wrap, n, br,
-                                     active_kernel(), candidate=candidate)
+                                     active_kernel(), candidate=candidate,
+                                     schnorr=schnorr)
     if handle is None:
         STATS.fault_fallback_sigs += n
-        return _packed_cpu_handle(pub, rs, msg, n)
+        return _packed_cpu_handle(pub, rs, msg, n, schnorr)
     return handle
 
 
@@ -1491,14 +1619,16 @@ def _verify_cpu_blobs(pub, rs, msg, n: int) -> np.ndarray:
     return _verify_cpu([recs[i] for i in range(n)])
 
 
-def _packed_cpu_handle(pub, rs, msg, n: int) -> BatchHandle:
+def _packed_cpu_handle(pub, rs, msg, n: int,
+                       schnorr: bool = False) -> BatchHandle:
     STATS.cpu_fallback_sigs += n
-    return BatchHandle(n, cpu_ok=_verify_cpu_blobs(pub, rs, msg, n))
+    verify = _verify_cpu_schnorr_blobs if schnorr else _verify_cpu_blobs
+    return BatchHandle(n, cpu_ok=verify(pub, rs, msg, n))
 
 
 def _dispatch_packed_device(pub, rs, msg, rn, wrap, n: int, br, kern: str,
-                            candidate=None,
-                            records=None) -> Optional[BatchHandle]:
+                            candidate=None, records=None,
+                            schnorr: bool = False) -> Optional[BatchHandle]:
     """The one supervised device enqueue (retries + KAT lanes), fed by
     blobs; None when every attempt failed or no rung is left — the caller
     owns the CPU fallback. Two known-answer lanes (good + bad signature)
@@ -1512,9 +1642,18 @@ def _dispatch_packed_device(pub, rs, msg, rn, wrap, n: int, br, kern: str,
     attempt: retries, then the breaker, then the caller's CPU verify.
     ``records`` are the caller's SigCheckRecords where it has them
     (dispatch_batch), for the degenerate-lane and reject-side rechecks;
-    the packed entry has none and gets a lazy view over the blobs."""
+    the packed entry has none and gets a lazy view over the blobs.
+
+    A Schnorr bucket (``schnorr``) has one device rung, the GLV ladder's
+    _glv_schnorr_program behind the shared prepare stage, whatever
+    -ecdsakernel says, and no w4 form: a failed attempt goes to the
+    retries, then the breaker, then the caller's native Schnorr verify. Its
+    known-answer lanes are Schnorr's; its degenerate lanes and device
+    Falses are re-checked by the native Schnorr verify."""
     from . import secp256k1 as dev
 
+    if schnorr:
+        return _dispatch_schnorr_bucket(dev, pub, rs, msg, rn, n, br)
     if kern == "msm":
         # the MSM batch equation verifies Schnorr sigs only; ECDSA lanes
         # under -ecdsakernel=msm keep the strongest per-lane ladder
@@ -1602,5 +1741,69 @@ def _dispatch_packed_device(pub, rs, msg, rn, wrap, n: int, br, kern: str,
     br.record_failure(last)
     br.note_fallback(n)
     log_printf("ecdsa device dispatch failed (%s: %s) — CPU fallback for "
+               "%d sig(s)", type(last).__name__, str(last)[:120], n)
+    return None
+
+
+def _dispatch_schnorr_bucket(dev, pub, rs, msg, u2, n: int,
+                             br) -> Optional[BatchHandle]:
+    """_dispatch_packed_device for a bucket of Schnorr lanes."""
+    if not glv_enabled():
+        br.note_fallback(n)
+        return None
+    kpub, krs, kmsg, ku2, _ = _schnorr_kat_blobs()
+    pub2 = np.concatenate([pub, kpub])
+    rs2 = np.concatenate([rs, krs])
+    msg2 = np.concatenate([msg, kmsg])
+    u22 = np.concatenate([u2, ku2])
+    bucket = _bucket_for(n + 2)
+
+    boff = Backoff(base=br.cfg.backoff_base, maximum=1.0)
+    last: Optional[BaseException] = None
+    ctx = tm.trace_context()
+    for attempt in range(br.cfg.retries + 1):
+        try:
+            INJECTOR.on_call("ecdsa")
+            arrays = pack_lanes(pub2, rs2, msg2, u22, None, bucket,
+                                schnorr=True)
+            try:
+                INJECTOR.on_call(GLV_DEV_SITE)
+                INJECTOR.on_call(GLV_SITE)
+                with tm.span("ecdsa.enqueue", lanes=n,
+                             bucket=bucket) as enqueued:
+                    device_ok, degen = _watched_kernel(
+                        _PW_GLV_SCHNORR, bucket, arrays,
+                        lambda: dev.schnorr_verify_batch_glv_dev(*arrays))
+                STATS.glv_dispatch_s += enqueued.seconds
+                if (INJECTOR.should_poison(GLV_DEV_SITE)
+                        or INJECTOR.should_poison(GLV_SITE)):
+                    device_ok = ~device_ok
+            except (KeyboardInterrupt, SystemExit):
+                raise
+            except Exception as e:
+                _note_glv_failure(e, then="the attempt fails")
+                raise
+            STATS.glv_dispatches += 1
+            STATS.schnorr_dispatches += 1
+            STATS.schnorr_lanes += n
+            _note_device_dispatch(n, bucket)
+            return BatchHandle(
+                n, bucket, device_ok, degen=degen,
+                records=_LazyRecords(pub, rs, msg, "schnorr"),
+                breaker=br, kat=True, ctx=ctx,
+                recheck=lambda idxs: _verify_cpu_schnorr_blobs(
+                    pub[idxs], rs[idxs], msg[idxs], len(idxs)),
+                recover=lambda: _verify_cpu_schnorr_blobs(pub, rs, msg, n))
+        except (KeyboardInterrupt, SystemExit):
+            raise
+        except SURFACE_ERRORS:
+            raise  # programming errors must not degrade silently
+        except Exception as e:  # noqa: BLE001 — supervised boundary
+            last = e
+            if attempt < br.cfg.retries:
+                time.sleep(boff.next())
+    br.record_failure(last)
+    br.note_fallback(n)
+    log_printf("schnorr device dispatch failed (%s: %s) — CPU fallback for "
                "%d sig(s)", type(last).__name__, str(last)[:120], n)
     return None

@@ -1189,14 +1189,12 @@ def _glv_ladder(w1, w2, t1, t2, q_inf_u):
     return jax.lax.fori_loop(0, GLV_WINDOWS, wstep, (acc0, degen0))
 
 
-def _glv_comb_final(carry, d1, sg1, d2, sg2, q_inf_u, r0, rn, wrap2):
+def _glv_comb_streams(carry, d1, sg1, d2, sg2):
     """The two G streams from the fixed-base comb on top of the ladder's
-    carry, then the verify equation. d1/d2: (16, B) int32 8-bit comb
-    digits of |s11|, |s12| (position i = weight 256^i); sg1/sg2: (B,)
-    int32 G-stream sign flags (0/1); r0/rn: (20, B) weak limbs; wrap2:
-    (1, B) mask. Returns (ok, degen) (1, B) int32 planes; degen lanes
-    MUST be re-verified by the caller."""
-    B = r0.shape[1]
+    carry. d1/d2: (16, B) int32 8-bit comb digits of |s11|, |s12|
+    (position i = weight 256^i); sg1/sg2: (B,) int32 G-stream sign flags
+    (0/1). Returns the (acc, degen) carry a final stage reads."""
+    B = d1.shape[1]
     one = jnp.broadcast_to(_ONE_CONST, (N_LIMBS, B)).astype(jnp.uint32)
     never_inf = jnp.zeros((1, B), jnp.int32)
     gx_tab, gy_tab, lx_tab = (jnp.asarray(c) for c in _glv_comb())
@@ -1216,7 +1214,14 @@ def _glv_comb_final(carry, d1, sg1, d2, sg2, q_inf_u, r0, rn, wrap2):
         return _glv_comb_step(carry, dr2.astype(jnp.int32), sg2o, tlx, ty,
                               one, never_inf)
 
-    acc, degen = jax.lax.fori_loop(0, GLV_COMB_TEETH, cstep, carry)
+    return jax.lax.fori_loop(0, GLV_COMB_TEETH, cstep, carry)
+
+
+def _glv_comb_final(carry, d1, sg1, d2, sg2, q_inf_u, r0, rn, wrap2):
+    """_glv_comb_streams, then ECDSA's verify equation. r0/rn: (20, B) weak
+    limbs; wrap2: (1, B) mask. Returns (ok, degen) (1, B) int32 planes;
+    degen lanes MUST be re-verified by the caller."""
+    acc, degen = _glv_comb_streams(carry, d1, sg1, d2, sg2)
     return _verify_final(acc, degen, q_inf_u, r0, rn, wrap2)
 
 
@@ -1522,6 +1527,95 @@ def ecdsa_verify_batch_glv_dev(u1m, u2m, qxb, qyb, qinf8, r0b, rnb, wrap8):
         dgs.append(out[1].reshape(n))
     return (jnp.concatenate(oks).astype(bool),
             jnp.concatenate(dgs).astype(bool))
+
+
+# ---- BCH Schnorr as a lane of the GLV ladder (PR 44) -----------------------
+#
+# A 2019-05-15 Schnorr signature (r, s) over digest m under key P verifies
+# iff R' = s*G + (n - e)*P is finite, R'.x = r and jacobi(R'.y) = 1, with
+# e = SHA256(r || ser(P) || m) mod n (crypto/secp256k1.schnorr_verify is
+# the oracle). That is ECDSA's two-scalar ladder with u1 = s, u2 = n - e
+# and Q = P, so _glv_prepare_program serves it unchanged (its rn and wrap2
+# outputs are not read) and only the final stage differs: in Jacobian
+# coordinates R'.x = r is X == r*Z^2, and jacobi(Y/Z^3) = jacobi(Y*Z) is
+# one Euler power (Y*Z)^((p-1)/2) == 1. No x-wraparound candidate: r is a
+# field element, compared as it is. A program of its own name, in buckets
+# of one kind (ops/ecdsa_batch): an ECDSA lane never pays for the power.
+
+def _euler_chain(x, sqr_n, mul):
+    """x^((p-1)/2) by an addition chain: 254 squarings, 14 multiplications.
+    (p-1)/2 is, in bits, 223 ones, a zero, 22 ones, 0000, 1, 0, 111; the
+    runs of ones are libsecp256k1's x2 .. x223 ladder. ``sqr_n(v, k)`` is
+    v^(2^k) and ``mul`` the product, so the same text runs on limbs and, in
+    tests/unit/test_schnorr_lanes.py, on exponents."""
+    x2 = mul(sqr_n(x, 1), x)
+    x3 = mul(sqr_n(x2, 1), x)
+    x6 = mul(sqr_n(x3, 3), x3)
+    x9 = mul(sqr_n(x6, 3), x3)
+    x11 = mul(sqr_n(x9, 2), x2)
+    x22 = mul(sqr_n(x11, 11), x11)
+    x44 = mul(sqr_n(x22, 22), x22)
+    x88 = mul(sqr_n(x44, 44), x44)
+    x176 = mul(sqr_n(x88, 88), x88)
+    x220 = mul(sqr_n(x176, 44), x44)
+    x223 = mul(sqr_n(x220, 3), x3)
+    t = mul(sqr_n(x223, 23), x22)
+    t = mul(sqr_n(t, 5), x)
+    return mul(sqr_n(t, 4), x3)
+
+
+def _f_sqr_n(a, k: int):
+    """a^(2^k): the squarings run as one loop over a carry, not unrolled
+    (254 of them would be ~6,000 fusions beside the ladder window's
+    2,223)."""
+    return jax.lax.fori_loop(0, k, lambda _, v: f_sqr(v), a)
+
+
+def _schnorr_final(acc, degen, q_inf_u, r0):
+    """Schnorr's final stage over the comb's carry: R' finite, X == r*Z^2
+    and (Y*Z)^((p-1)/2) == 1. Returns (ok, degen) (1, B) int32 planes;
+    degen lanes MUST be re-verified by the caller."""
+    ZZ = f_sqr(acc["Z"])
+    x_ok = _is_zero_u(f_carry_sub(acc["X"], f_mul(r0, ZZ)))
+    euler = _euler_chain(f_mul(acc["Y"], acc["Z"]), _f_sqr_n, f_mul)
+    one = jnp.broadcast_to(_ONE_CONST, euler.shape).astype(jnp.uint32)
+    y_ok = _is_zero_u(f_carry_sub(euler, one))
+    ok = (1 - acc["inf"]) * (1 - q_inf_u) * x_ok * y_ok
+    return ok, degen * (1 - q_inf_u)
+
+
+@jax.jit
+def _glv_schnorr_program(w1, w2, t1, t2, d1, sg1, d2, sg2, q_inf_u, r0):
+    """Stage two of a Schnorr bucket: the ladder and the comb of
+    _glv_dev_program over what _glv_prepare_program left on the device,
+    then _schnorr_final. Like _glv_dev_program it is handed every operand
+    of the ladder's loop as an argument (_glv_ladder). Returns (2, B)
+    uint32: row 0 ok, row 1 degenerate."""
+    carry = _glv_comb_streams(_glv_ladder(w1, w2, t1, t2, q_inf_u), d1, sg1,
+                              d2, sg2)
+    ok, degen = _schnorr_final(*carry, q_inf_u, r0)
+    return jnp.concatenate(
+        [ok.astype(jnp.uint32), degen.astype(jnp.uint32)], axis=0)
+
+
+def _glv_schnorr_planes(u1m, u2m, qxb, qyb, qinf8, r0b):
+    """Both stages of a Schnorr bucket, enqueued one behind the other with
+    no host sync between them: u1m = s, u2m = n - e, Q = P, r0b = r as
+    (B, 32) big-endian bytes (the x-wraparound inputs of the prepare stage
+    are r and 0: nothing reads them). (2, B) uint32 planes."""
+    prepared = _glv_prepare_program(u1m, u2m, qxb, qyb, qinf8, r0b, r0b,
+                                    np.zeros(len(qinf8), np.uint8))
+    return _glv_schnorr_program(*prepared[:10])
+
+
+def schnorr_verify_batch_glv_dev(u1m, u2m, qxb, qyb, qinf8, r0b):
+    """Byte-matrix BCH Schnorr verify on the GLV ladder; at most 16,384
+    lanes a call (the import's buckets are 8,192). Returns (ok, degen)
+    bool (B,) arrays: device futures until materialized."""
+    if qxb.shape[0] > 16384:
+        raise ValueError("a Schnorr bucket is at most 16,384 lanes")
+    out = _glv_schnorr_planes(u1m, u2m, qxb, qyb, qinf8, r0b)
+    return out[0].astype(bool), out[1].astype(bool)
 
 
 # ---- Pippenger/bucket MSM — Schnorr batch verification (round 19) ----------

@@ -275,6 +275,18 @@ def _ecdsa_verify_scalar(pt, r: int, s: int, e: int) -> bool:
     return secp.ecdsa_verify(pt, r, s, e)
 
 
+def _schnorr_verify_scalar(pt, r: int, s: int, e: int) -> bool:
+    """Scalar BCH Schnorr verify: the native module when present, else the
+    oracle (123 ms a signature in Python integers). Same acceptance set
+    (tests/unit/test_schnorr_lanes.py runs the differential)."""
+    from .. import native
+
+    if native.available():
+        return native.schnorr_verify_batch(
+            [SigCheckRecord(pt, r, s, e, algo="schnorr")], nthreads=1)[0]
+    return secp.schnorr_verify(pt, r, s, e)
+
+
 # ---- signature checkers (interpreter.h BaseSignatureChecker) ----
 
 def _signature_scalars(sig: bytes):
@@ -416,7 +428,7 @@ class TransactionSignatureChecker(BaseSignatureChecker):
             return False
         pt, r, s, e, algo = parsed
         if algo == "schnorr":
-            return secp.schnorr_verify(pt, r, s, e)
+            return _schnorr_verify_scalar(pt, r, s, e)
         return _ecdsa_verify_scalar(pt, r, s, e)
 
     def check_locktime(self, locktime: int) -> bool:

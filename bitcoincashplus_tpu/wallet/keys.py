@@ -81,6 +81,19 @@ class CKey:
             r, s = secp.ecdsa_sign(self.secret, e)
         return secp.sig_der_encode(r, s)
 
+    def sign_schnorr(self, msg_hash32: bytes) -> bytes:
+        """64-byte BCH Schnorr signature r || s WITHOUT hashtype byte
+        (spec 2019-05-15-schnorr.md; the deterministic nonce of
+        crypto/secp256k1.schnorr_sign)."""
+        e = int.from_bytes(msg_hash32, "big")
+        from .. import native
+
+        if native.available():
+            r, s = native.schnorr_sign(self.secret, e)
+        else:
+            r, s = secp.schnorr_sign(self.secret, e)
+        return r.to_bytes(32, "big") + s.to_bytes(32, "big")
+
 
 def address_to_script(addr: str, params: ChainParams) -> Optional[bytes]:
     """CBitcoinAddress → scriptPubKey (DecodeDestination + GetScriptForDestination)."""

@@ -38,17 +38,22 @@ def make_signature(
     *,
     enable_forkid: bool = False,
     cache: Optional[SighashCache] = None,
+    schnorr: bool = False,
 ) -> bytes:
     """One input signature: DER + 1-byte hashtype (sign.cpp ProduceSignature
     inner Sign1). Pass hashtype WITHOUT the forkid bit; it is added when
-    enable_forkid is set (TransactionSignatureCreator does the same)."""
+    enable_forkid is set (TransactionSignatureCreator does the same).
+    ``schnorr``: the 65-byte form of the 2019-05-15 upgrade, r || s ||
+    hashtype over the same digest (OP_CHECKSIG only: OP_CHECKMULTISIG
+    takes no Schnorr signature)."""
     if enable_forkid:
         hashtype |= SIGHASH_FORKID
     ehash = signature_hash(
         script_code, tx, in_idx, hashtype, amount,
         enable_forkid=enable_forkid, cache=cache,
     )
-    return key.sign(ehash) + bytes([hashtype & 0xFF])
+    sign = key.sign_schnorr if schnorr else key.sign
+    return sign(ehash) + bytes([hashtype & 0xFF])
 
 
 def solve_script_sig(
@@ -62,11 +67,13 @@ def solve_script_sig(
     enable_forkid: bool = False,
     redeem_script: Optional[bytes] = None,
     cache: Optional[SighashCache] = None,
+    schnorr: bool = False,
 ) -> bytes:
     """Build a scriptSig for one input (sign.cpp SignStep).
 
     ``key_for_id`` maps a pubkey-hash (for pubkeyhash) or raw pubkey (for
-    pubkey/multisig) to a CKey, or None if unknown.
+    pubkey/multisig) to a CKey, or None if unknown. ``schnorr`` signs
+    pubkeyhash and pubkey inputs with BCH Schnorr; multisig stays ECDSA.
     """
     kind = classify_script(script_pubkey)
     if kind == "pubkeyhash":
@@ -77,7 +84,7 @@ def solve_script_sig(
             raise SignError("missing key for pubkeyhash")
         sig = make_signature(
             key, script_pubkey, tx, in_idx, amount, hashtype,
-            enable_forkid=enable_forkid, cache=cache,
+            enable_forkid=enable_forkid, cache=cache, schnorr=schnorr,
         )
         return push_data_raw(sig) + push_data_raw(key.pubkey)
     if kind == "pubkey":
@@ -88,7 +95,7 @@ def solve_script_sig(
             raise SignError("missing key for pubkey")
         sig = make_signature(
             key, script_pubkey, tx, in_idx, amount, hashtype,
-            enable_forkid=enable_forkid, cache=cache,
+            enable_forkid=enable_forkid, cache=cache, schnorr=schnorr,
         )
         return push_data_raw(sig)
     if kind == "multisig":
@@ -118,7 +125,7 @@ def solve_script_sig(
             raise SignError("missing redeem script for P2SH input")
         inner = solve_script_sig(
             redeem_script, tx, in_idx, amount, key_for_id, hashtype,
-            enable_forkid=enable_forkid, cache=cache,
+            enable_forkid=enable_forkid, cache=cache, schnorr=schnorr,
         )
         return inner + push_data_raw(redeem_script)
     raise SignError(f"cannot sign {kind} script")
@@ -132,8 +139,10 @@ def sign_transaction(
     *,
     enable_forkid: bool = False,
     redeem_scripts: Optional[dict[bytes, bytes]] = None,  # hash160 -> script
+    schnorr: bool = False,
 ) -> CTransaction:
     """SignSignature over every input; returns a new signed CTransaction.
+    ``schnorr`` signs every OP_CHECKSIG input with BCH Schnorr (65 bytes).
 
     Signatures commit to the final scriptSig-free layout, so the unsigned
     ``tx`` must already have its full vin/vout; scriptSigs are replaced.
@@ -148,6 +157,7 @@ def sign_transaction(
         script_sig = solve_script_sig(
             spk, tx, i, amount, key_for_id, hashtype,
             enable_forkid=enable_forkid, redeem_script=redeem, cache=cache,
+            schnorr=schnorr,
         )
         new_vin.append(CTxIn(txin.prevout, script_sig, txin.sequence))
     return CTransaction(tx.version, tuple(new_vin), tx.vout, tx.locktime)

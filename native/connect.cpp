@@ -34,6 +34,9 @@
 
 // from secp256k1.cpp (same .so)
 extern "C" int bcp_pubkey_parse(const uint8_t* data, long len, uint8_t* out64);
+extern "C" void bcp_schnorr_neg_challenge(const uint8_t* r32,
+                                          const uint8_t* pub64,
+                                          const uint8_t* m32, uint8_t* out32);
 
 namespace {
 
@@ -195,12 +198,17 @@ struct CoinEnt {
 
 // The lanes the script templates wrote for inputs of the generic-script
 // leg (P2PK, bare and P2SH CHECKMULTISIG), in input order, in the format of
-// sig_pub .. sig_wrap; `cand` marks the candidate lanes of multisig groups.
+// sig_pub .. sig_wrap; `cand` marks the candidate lanes of multisig groups,
+// `kind` a lane's scheme (LANE_ECDSA, LANE_SCHNORR: a Schnorr lane's RN slot
+// holds (n - e) mod n, the scalar of its key, and its WRAP is 0).
 // One table row an input: its number g, its first lane, and m and n of its
 // OP_CHECKMULTISIG (m(n-m+1) lanes), or m = 0 for the one lane of an
 // OP_CHECKSIG.
+constexpr uint8_t LANE_ECDSA = 0;
+constexpr uint8_t LANE_SCHNORR = 1;
+
 struct LegLanes {
-    enum { PUB, RS, MSG, RN, WRAP, CAND, N_BLOBS };
+    enum { PUB, RS, MSG, RN, WRAP, CAND, KIND, N_BLOBS };
     std::vector<uint8_t> blob[N_BLOBS];
     std::vector<uint32_t> table;
 
@@ -230,6 +238,21 @@ struct LegLanes {
         blob[RN].insert(blob[RN].end(), x, x + 32);
         blob[WRAP].push_back(wrapped ? 1 : 0);
         blob[CAND].push_back(candidate ? 1 : 0);
+        blob[KIND].push_back(LANE_ECDSA);
+    }
+
+    // the one lane of an OP_CHECKSIG under a 65-byte Schnorr signature
+    void schnorr_lane(const uint8_t pub64[64], const uint8_t r32[32],
+                      const uint8_t s32[32], const uint8_t msg32[32],
+                      const uint8_t u2[32]) {
+        blob[PUB].insert(blob[PUB].end(), pub64, pub64 + 64);
+        blob[RS].insert(blob[RS].end(), r32, r32 + 32);
+        blob[RS].insert(blob[RS].end(), s32, s32 + 32);
+        blob[MSG].insert(blob[MSG].end(), msg32, msg32 + 32);
+        blob[RN].insert(blob[RN].end(), u2, u2 + 32);
+        blob[WRAP].push_back(0);
+        blob[CAND].push_back(0);
+        blob[KIND].push_back(LANE_SCHNORR);
     }
 
     // a later thread's lanes behind this one's
@@ -250,17 +273,23 @@ static uint64_t ns_since(std::chrono::steady_clock::time_point t0) {
 // What the legacy digest cost one scan thread (summed after the join):
 // digests made, bytes of serialised transaction they hashed, nanoseconds
 // inside sighash_legacy; and the thread's nanoseconds in the scan as a whole.
+// Beside them the inputs whose 65-byte signature the scan took as a Schnorr
+// lane, and the nanoseconds in their challenge hash and n - e.
 struct ScanCounters {
     uint64_t legacy_digests = 0;
     uint64_t legacy_bytes = 0;
     uint64_t legacy_ns = 0;
     uint64_t thread_ns = 0;
+    uint64_t schnorr_inputs = 0;
+    uint64_t schnorr_ns = 0;
 
     void add(const ScanCounters& o) {
         legacy_digests += o.legacy_digests;
         legacy_bytes += o.legacy_bytes;
         legacy_ns += o.legacy_ns;
         thread_ns += o.thread_ns;
+        schnorr_inputs += o.schnorr_inputs;
+        schnorr_ns += o.schnorr_ns;
     }
 };
 
@@ -291,6 +320,8 @@ struct Engine {
     std::vector<uint8_t> sig_pub;     // n * 64
     std::vector<uint8_t> sig_rn;      // n * 32
     std::vector<uint8_t> sig_wrap;    // n
+    std::vector<uint8_t> sig_kind;    // n: LANE_ECDSA, or LANE_SCHNORR
+                                      // (then sig_rn holds (n - e) mod n)
     std::vector<uint32_t> sig_txin;   // n * 2 (tx index, input index)
     LegLanes leg;                     // the template inputs' lanes
 
@@ -889,6 +920,36 @@ static bool template_sig(Push sig, uint32_t flags, uint8_t r32[32],
     return hashtype_modelled(sig.p[sig.len - 1], flags);
 }
 
+// A 65-byte signature an OP_CHECKSIG lane can carry as BCH Schnorr (spec
+// 2019-05-15-schnorr.md): r = sig[0:32] a field element, s = sig[32:64] a
+// scalar, then the hashtype byte. Taken in blocks from the fork height on
+// (flags carry FORKID and NULLFAIL; the program has no separate activation
+// for the 2019-05-15 upgrade), with a FORKID hashtype the scan models.
+// r >= p and s >= n never verify: declined, the interpreter fails them.
+// OP_CHECKMULTISIG takes no Schnorr signature (template_sig).
+static bool schnorr_sig(const uint8_t* sig, uint32_t sig_len, uint32_t flags,
+                        uint8_t r32[32], uint8_t s32[32]) {
+    if (sig_len != 65 || !(flags & F_FORKID) || !(flags & F_NULLFAIL))
+        return false;
+    if (!(sig[64] & SIGHASH_FORKID) || !hashtype_modelled(sig[64], flags))
+        return false;
+    if (cmp256(sig, SECP_P) >= 0 || cmp256(sig + 32, SECP_N) >= 0)
+        return false;
+    memcpy(r32, sig, 32);
+    memcpy(s32, sig + 32, 32);
+    return true;
+}
+
+// (n - e) mod n of a Schnorr lane, timed for the thread's counters
+static void schnorr_scalar(ScanCounters& c, const uint8_t r32[32],
+                           const uint8_t pub64[64], const uint8_t msg[32],
+                           uint8_t u2[32]) {
+    auto t0 = std::chrono::steady_clock::now();
+    bcp_schnorr_neg_challenge(r32, pub64, msg, u2);
+    c.schnorr_inputs++;
+    c.schnorr_ns += ns_since(t0);
+}
+
 // a key in one of STRICTENC's two forms that is a point of the curve
 static bool template_key(Push key, uint8_t pub64[64]) {
     return strict_key_form(key.p, key.len) &&
@@ -925,14 +986,22 @@ static bool scan_templates(const Engine& e, TxDigests& d, uint32_t in_idx,
     // P2PK: <sig> | <key> OP_CHECKSIG
     if ((spk_len == 35 || spk_len == 67) && spk[0] == spk_len - 2 &&
         spk[spk_len - 1] == OP_CHECKSIG) {
-        if (!direct_push(in.ss, in.ss_len, &pos, &sig) || pos != in.ss_len ||
-            !template_sig(sig, flags, r32[0], s32[0]) ||
+        if (!direct_push(in.ss, in.ss_len, &pos, &sig) || pos != in.ss_len)
+            return false;
+        bool schnorr = schnorr_sig(sig.p, sig.len, flags, r32[0], s32[0]);
+        if ((!schnorr && !template_sig(sig, flags, r32[0], s32[0])) ||
             !template_key(Push{spk + 1, spk_len - 2}, pub[0]))
             return false;
         sighash(d, in_idx, sig.p[sig.len - 1], flags, spk, spk_len, amount,
                 msg);
         out.row(g, 0, 0);
-        out.lane(pub[0], r32[0], s32[0], msg, false);
+        if (schnorr) {
+            uint8_t u2[32];
+            schnorr_scalar(d.counters, r32[0], pub[0], msg, u2);
+            out.schnorr_lane(pub[0], r32[0], s32[0], msg, u2);
+        } else {
+            out.lane(pub[0], r32[0], s32[0], msg, false);
+        }
         return true;
     }
 
@@ -1029,6 +1098,31 @@ static long scan_input(Engine& e, TxDigests& d, uint32_t in_idx, uint32_t g,
     uint8_t h20[20];
     bcpn::hash160(pub, pub_len, h20);
     if (memcmp(h20, spk + 3, 20) != 0) return E_S_EQUALVERIFY;
+    // 65 bytes from the fork height on: BCH Schnorr by length, before DER's
+    // encoding check could answer sig-der. What the lane cannot carry (a
+    // hashtype or key form the scan does not model, r >= p, s >= n, a key
+    // off the curve) goes to the interpreter, which fails it by name.
+    if (sig_len == 65 && (flags & F_FORKID) && (flags & F_NULLFAIL)) {
+        uint8_t r32[32], s32[32], pub64[64], msg[32];
+        if (!schnorr_sig(sig, sig_len, flags, r32, s32) ||
+            !strict_key_form(pub, pub_len) ||
+            !bcp_pubkey_parse(pub, long(pub_len), pub64)) {
+            e.sig_status[g] = 1;
+            return OK;
+        }
+        sighash(d, in_idx, sig[64], flags, spk, spk_len, e.spent_values[g],
+                msg);
+        memcpy(e.sig_msg.data() + 32 * g, msg, 32);
+        memcpy(e.sig_rs.data() + 64 * g, r32, 32);
+        memcpy(e.sig_rs.data() + 64 * g + 32, s32, 32);
+        memcpy(e.sig_pub.data() + 64 * g, pub64, 64);
+        schnorr_scalar(d.counters, r32, pub64, msg,
+                       e.sig_rn.data() + 32 * g);
+        e.sig_wrap[g] = 0;
+        e.sig_kind[g] = LANE_SCHNORR;
+        e.sig_status[g] = 0;
+        return OK;
+    }
     // check_signature_encoding (empty sig passes encoding, fails later)
     if (sig_len != 0) {
         if ((flags & (F_DERSIG | F_LOW_S | F_STRICTENC)) &&
@@ -1111,6 +1205,7 @@ static long scan_input(Engine& e, TxDigests& d, uint32_t in_idx, uint32_t g,
     bool wrap = (carry == 0) && (cmp256(rn, SECP_P) < 0);
     memcpy(e.sig_rn.data() + 32 * g, wrap ? rn : r32, 32);
     e.sig_wrap[g] = wrap ? 1 : 0;
+    e.sig_kind[g] = LANE_ECDSA;
     e.sig_status[g] = 0;
     return OK;
 }
@@ -1297,13 +1392,16 @@ const uint8_t* bcp_engine_sig_rn(void* ep) {
 const uint8_t* bcp_engine_sig_wrap(void* ep) {
     return static_cast<Engine*>(ep)->sig_wrap.data();
 }
+const uint8_t* bcp_engine_sig_kind(void* ep) {
+    return static_cast<Engine*>(ep)->sig_kind.data();
+}
 const uint32_t* bcp_engine_sig_txin(void* ep) {
     return static_cast<Engine*>(ep)->sig_txin.data();
 }
 
 // The template lanes of the last connect (LegLanes): blob `which` in the
-// order pub, rs, msg, rn, wrap, cand, then 6 = the table's uint32 rows; its
-// length in bytes.
+// order pub, rs, msg, rn, wrap, cand, kind, then 7 = the table's uint32
+// rows; its length in bytes.
 const uint8_t* bcp_engine_leg_blob(void* ep, int which, size_t* len) {
     LegLanes& leg = static_cast<Engine*>(ep)->leg;
     if (which == LegLanes::N_BLOBS) {
@@ -1577,6 +1675,7 @@ long bcp_engine_connect_block(
         e.sig_pub.resize(size_t(n_inputs) * 64);
         e.sig_rn.resize(size_t(n_inputs) * 32);
         e.sig_wrap.assign(size_t(n_inputs), 0);
+        e.sig_kind.assign(size_t(n_inputs), LANE_ECDSA);
         e.sig_txin.resize(size_t(n_inputs) * 2);
         e.leg.clear();
         unsigned hw = nthreads > 0 ? unsigned(nthreads)
@@ -1672,13 +1771,16 @@ uint64_t bcp_engine_sigscan_ns(void* ep) {
 
 // The last successful connect's scan counters (ScanCounters): legacy
 // digests, the bytes they hashed, nanoseconds of the scan's threads inside
-// them, and the threads' nanoseconds in the scan as a whole.
-void bcp_engine_scan_counters(void* ep, uint64_t out[4]) {
+// them, the threads' nanoseconds in the scan as a whole, the inputs taken
+// as Schnorr lanes and the nanoseconds in their challenge scalars.
+void bcp_engine_scan_counters(void* ep, uint64_t out[6]) {
     const ScanCounters& c = static_cast<Engine*>(ep)->scan;
     out[0] = c.legacy_digests;
     out[1] = c.legacy_bytes;
     out[2] = c.legacy_ns;
     out[3] = c.thread_ns;
+    out[4] = c.schnorr_inputs;
+    out[5] = c.schnorr_ns;
 }
 
 // sighash_legacy over one serialised transaction, for the differential

@@ -32,6 +32,8 @@
 #include <thread>
 #include <vector>
 
+#include "common.h"
+
 namespace {
 
 typedef uint64_t u64;
@@ -439,10 +441,8 @@ static void build_g_tab() {
     }
 }
 
-// ---- u1*G + u2*Q with the r / r+n x-coordinate acceptance check ----
-
-static bool ecmult_check(const N256& u1, const N256& u2, const Aff& Q,
-                         const N256& r_sig) {
+// acc = u1*G + u2*Q (Straus, wNAF); false where it is the point at infinity
+static bool ecmult(const N256& u1, const N256& u2, const Aff& Q, Jac& acc) {
     std::call_once(g_tab_once, build_g_tab);
 
     // per-verify w=5 table of odd Q multiples (1Q, 3Q, ..., 15Q)
@@ -460,7 +460,6 @@ static bool ecmult_check(const N256& u1, const N256& u2, const Aff& Q,
     int l2 = wnaf_recode(u2, 5, w2);
     int len = l1 > l2 ? l1 : l2;
 
-    Jac acc;
     acc.inf = true;
     for (int i = len - 1; i >= 0; i--) {
         pt_double(acc, acc);
@@ -485,7 +484,15 @@ static bool ecmult_check(const N256& u1, const N256& u2, const Aff& Q,
             }
         }
     }
-    if (acc.inf || is_zero_n(acc.Z)) return false;
+    return !(acc.inf || is_zero_n(acc.Z));
+}
+
+// ---- u1*G + u2*Q with the r / r+n x-coordinate acceptance check ----
+
+static bool ecmult_check(const N256& u1, const N256& u2, const Aff& Q,
+                         const N256& r_sig) {
+    Jac acc;
+    if (!ecmult(u1, u2, Q, acc)) return false;
 
     // x_R == r (mod n) without inverting Z: X == r*Z^2, or the wraparound
     // candidate X == (r+n)*Z^2 admissible only when r + n < p.
@@ -646,10 +653,10 @@ static void precompute_range(long lo, long hi, void* p) {
     }
 }
 
-// k*G as affine x (mod p), via the fixed wNAF G table. Returns false for
-// k = 0 / k >= n or if the ladder lands at infinity (unreachable for
-// valid k, kept for safety).
-static bool base_mult_affine_x(const N256& k, N256& x_out) {
+// k*G as affine x and, where asked for, y (mod p), via the fixed wNAF G
+// table. Returns false for k = 0 / k >= n or if the ladder lands at
+// infinity (unreachable for valid k, kept for safety).
+static bool base_mult_affine(const N256& k, N256& x_out, N256* y_out) {
     std::call_once(g_tab_once, build_g_tab);
     if (is_zero_n(k) || cmp_n(k, N_M) >= 0) return false;
     int8_t w1[260];
@@ -675,7 +682,105 @@ static bool base_mult_affine_x(const N256& k, N256& x_out) {
     modpow(acc.Z, pm2, P_K, P_M, zi);
     fsqr(zi2, zi);
     fmul(x_out, acc.X, zi2);
+    if (y_out) {
+        fmul(zi2, zi2, zi);
+        fmul(*y_out, acc.Y, zi2);
+    }
     return true;
+}
+
+static bool base_mult_affine_x(const N256& k, N256& x_out) {
+    return base_mult_affine(k, x_out, nullptr);
+}
+
+// ---- BCH Schnorr (spec 2019-05-15-schnorr.md; the oracle is
+// crypto/secp256k1.py schnorr_verify / schnorr_sign) ----
+
+// (p - 1) / 2: Euler's criterion, jacobi(a) = a^((p-1)/2)
+static const N256 P_HALF = {{0xFFFFFFFF7FFFFE17ULL, 0xFFFFFFFFFFFFFFFFULL,
+                             0xFFFFFFFFFFFFFFFFULL, 0x7FFFFFFFFFFFFFFFULL}};
+
+static bool is_residue(const N256& a) {
+    N256 pw;
+    modpow(a, P_HALF, P_K, P_M, pw);
+    return cmp_n(pw, ONE_C) == 0;
+}
+
+// u2 = (n - e) mod n for e = SHA256(r32 || compressed(P) || m32) mod n,
+// 32 bytes big-endian: the scalar of P in R' = s*G + (n - e)*P.
+static void schnorr_neg_challenge(const uint8_t r32[32],
+                                  const uint8_t pub64[64],
+                                  const uint8_t m32[32], uint8_t out32[32]) {
+    uint8_t buf[97], h[32];
+    memcpy(buf, r32, 32);
+    buf[32] = 2 | (pub64[63] & 1);
+    memcpy(buf + 33, pub64, 32);
+    memcpy(buf + 65, m32, 32);
+    bcpn::sha256(buf, 97, h);
+    N256 e = load_be(h);
+    if (cmp_n(e, N_M) >= 0) sub_n(e, N_M);
+    N256 u2 = {{0, 0, 0, 0}};
+    if (!is_zero_n(e)) {
+        u2 = N_M;
+        sub_n(u2, e);
+    }
+    store_be(u2, out32);
+}
+
+// r < p and s < n: the spec's range rule (0 is in range for both)
+static bool schnorr_in_range(const uint8_t rs[64]) {
+    N256 r = load_be(rs), s = load_be(rs + 32);
+    return cmp_n(r, P_M) < 0 && cmp_n(s, N_M) < 0;
+}
+
+static bool schnorr_verify_one(const uint8_t pub[64], const uint8_t rs[64],
+                               const uint8_t msg[32]) {
+    N256 qx = load_be(pub), qy = load_be(pub + 32);
+    if (cmp_n(qx, P_M) >= 0 || cmp_n(qy, P_M) >= 0) return false;
+    N256 y2, x3, seven = {{7, 0, 0, 0}};
+    fsqr(y2, qy);
+    fsqr(x3, qx);
+    fmul(x3, x3, qx);
+    fadd(x3, x3, seven);
+    if (cmp_n(y2, x3) != 0 || !schnorr_in_range(rs)) return false;
+    uint8_t u2b[32];
+    schnorr_neg_challenge(rs, pub, msg, u2b);
+    Aff Q = {qx, qy};
+    Jac acc;
+    if (!ecmult(load_be(rs + 32), load_be(u2b), Q, acc)) return false;
+    // R'.x == r as X == r*Z^2; jacobi(R'.y) = jacobi(Y/Z^3) = jacobi(Y*Z)
+    N256 zz, cand, yz;
+    fsqr(zz, acc.Z);
+    fmul(cand, load_be(rs), zz);
+    if (cmp_n(cand, acc.X) != 0) return false;
+    fmul(yz, acc.Y, acc.Z);
+    return is_residue(yz);
+}
+
+static void schnorr_verify_range(long lo, long hi, void* p) {
+    VerifyCtx* c = (VerifyCtx*)p;
+    for (long i = lo; i < hi; i++)
+        c->ok[i] = schnorr_verify_one(c->pub + 64 * i, c->rs + 64 * i,
+                                      c->msg + 32 * i)
+                       ? 1
+                       : 0;
+}
+
+struct ChallengeCtx {
+    const uint8_t* pub;
+    const uint8_t* rs;
+    const uint8_t* msg;
+    uint8_t* u2;
+    uint8_t* ok;
+};
+
+static void schnorr_challenge_range(long lo, long hi, void* p) {
+    ChallengeCtx* c = (ChallengeCtx*)p;
+    for (long i = lo; i < hi; i++) {
+        c->ok[i] = schnorr_in_range(c->rs + 64 * i) ? 1 : 0;
+        schnorr_neg_challenge(c->rs + 64 * i, c->pub + 64 * i,
+                              c->msg + 32 * i, c->u2 + 32 * i);
+    }
 }
 
 }  // namespace
@@ -744,6 +849,64 @@ void bcp_ecdsa_precompute(const uint8_t* rs, const uint8_t* msg, long n,
                           int nthreads) {
     PrecompCtx c = {rs, msg, u1, u2, ok};
     run_chunked(n, nthreads, precompute_range, &c);
+}
+
+// The Schnorr lane's scalars for the device's GLV ladder (ops/ecdsa_batch
+// pack_lanes): u1 is s as it stands, u2[i] = (n - e_i) mod n with e_i the
+// challenge hash over (r_i, compressed P_i, m_i). ok[i] = 0 flags r >= p or
+// s >= n (the spec refuses them before any arithmetic). No modular inverse:
+// lighter than bcp_ecdsa_precompute.
+void bcp_schnorr_challenge(const uint8_t* pub, const uint8_t* rs,
+                           const uint8_t* msg, long n, uint8_t* u2,
+                           uint8_t* ok, int nthreads) {
+    ChallengeCtx c = {pub, rs, msg, u2, ok};
+    run_chunked(n, nthreads, schnorr_challenge_range, &c);
+}
+
+// The one lane's form of the above, for native/connect.cpp's scan threads.
+void bcp_schnorr_neg_challenge(const uint8_t* r32, const uint8_t* pub64,
+                               const uint8_t* m32, uint8_t* out32) {
+    schnorr_neg_challenge(r32, pub64, m32, out32);
+}
+
+// BCH Schnorr batch verify across nthreads host threads: the rung under the
+// device program, and the re-check of its degenerate lanes.
+void bcp_schnorr_verify_batch(const uint8_t* pub, const uint8_t* rs,
+                              const uint8_t* msg, long n, uint8_t* ok,
+                              int nthreads) {
+    VerifyCtx c = {pub, rs, msg, ok};
+    run_chunked(n, nthreads, schnorr_verify_range, &c);
+}
+
+// BCH Schnorr sign with a caller-supplied nonce (the RFC6979 derivation with
+// "Schnorr+SHA256  " stays in Python, as for bcp_ecdsa_sign): R = k*G, k
+// negated where jacobi(R.y) != 1, r = R.x, s = k + e*sk mod n. Writes r||s.
+// Returns 1, or 0 where sk or k is out of range.
+int bcp_schnorr_sign(const uint8_t* sk32, const uint8_t* m32,
+                     const uint8_t* k32, uint8_t* rs64_out) {
+    N256 sk = load_be(sk32), k = load_be(k32);
+    if (is_zero_n(sk) || cmp_n(sk, N_M) >= 0) return 0;
+    N256 rx, ry, px, py;
+    if (!base_mult_affine(k, rx, &ry) || !base_mult_affine(sk, px, &py))
+        return 0;
+    if (!is_residue(ry)) {
+        N256 nk = N_M;
+        sub_n(nk, k);
+        k = nk;
+    }
+    uint8_t pub[64], r32[32], u2b[32];
+    store_be(px, pub);
+    store_be(py, pub + 32);
+    store_be(rx, r32);
+    schnorr_neg_challenge(r32, pub, m32, u2b);
+    // s = k + e*sk = k - (n - e)*sk mod n
+    N256 prod, s = k;
+    modmul(load_be(u2b), sk, N_K, N_M, prod);
+    if (cmp_n(s, prod) < 0) add_n(s, N_M);
+    sub_n(s, prod);
+    memcpy(rs64_out, r32, 32);
+    store_be(s, rs64_out + 32);
+    return 1;
 }
 
 // Pubkey parse/decompress (CPubKey / secp256k1_ec_pubkey_parse semantics,

@@ -171,7 +171,8 @@ def fault_harness(monkeypatch):
 
 class StubVerifyKernels:
     """What ``stub_verify_kernels`` hands a test: ``calls`` lists every
-    kernel call as (rung, arrays) in order, rung "glv" or "w4"; ``fail``
+    kernel call as (rung, arrays) in order, rung "glv", "w4" or "schnorr"
+    (the Schnorr bucket's program, six arrays); ``fail``
     maps a rung to the exception its next calls raise; ``verdicts``, when
     set, replaces the oracle: arrays -> (bucket,) bool."""
 
@@ -225,6 +226,27 @@ def stub_verify_kernels(monkeypatch):
             return np.asarray(ok, bool), np.zeros(len(ok), bool)
         return call
 
+    def schnorr_verdicts(arrays):
+        u1m, u2m, qxb, qyb, q_inf, r0b = arrays
+        ok = np.zeros(len(q_inf), bool)
+        for i in np.nonzero(np.asarray(q_inf) == 0)[0]:
+            u1, u2, qx, qy, r0 = (
+                int.from_bytes(m[i].tobytes(), "big")
+                for m in (u1m, u2m, qxb, qyb, r0b))
+            pt = oracle.point_add(oracle.point_mul(u1, oracle.G),
+                                  oracle.point_mul(u2, (qx, qy)))
+            ok[i] = (pt is not None and pt[0] == r0
+                     and oracle.jacobi(pt[1]) == 1)
+        return ok
+
+    def schnorr_kernel(*arrays):
+        stub.calls.append(("schnorr", arrays))
+        if "schnorr" in stub.fail:
+            raise stub.fail["schnorr"]
+        ok = (stub.verdicts or schnorr_verdicts)(arrays)
+        return np.asarray(ok, bool), np.zeros(len(ok), bool)
+
+    monkeypatch.setattr(dev, "schnorr_verify_batch_glv_dev", schnorr_kernel)
     monkeypatch.setattr(dev, "ecdsa_verify_batch_glv_dev", kernel("glv"))
     monkeypatch.setattr(dev, "ecdsa_verify_batch_pallas_w4_bytes",
                         kernel("w4"))

@@ -38,7 +38,8 @@ DATA = os.path.join(os.path.dirname(__file__), "data")
 # holds "parent_keys": the keys of a snapshot and of last_import_stats at
 # the parent of that tree, which the driver runs under this tree's readers.
 RECORDED = {"reindex.pre_fork": "obs_reindex_pre_fork.json",
-            "mine.diff1_solo": "obs_mine_diff1_solo.json"}
+            "mine.diff1_solo": "obs_mine_diff1_solo.json",
+            "reindex.schnorr_dense": "obs_reindex_schnorr_dense.json"}
 
 
 def recorded(metric: dict, as_the_parent_has_it: bool) -> dict:
@@ -244,3 +245,69 @@ def test_the_glv_readers_take_one_stage_each(modules, kernel_ms, prepare_ms):
         want = (kernel_ms, prepare_ms) if obs["trace"] else (None, None)
         assert kernel.read(obs) == pytest.approx(want[0])
         assert prepare.read(obs) == pytest.approx(want[1])
+
+
+def _reader(name):
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "reader", os.path.join(ROOT, MANIFEST["paths"][0], "layer_metrics",
+                               name + ".py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+@pytest.mark.parametrize("modules, lanes, kernel_ms, roofline", [
+    # a Schnorr bucket's two stages: the roofline's divisor is both
+    ({"jit__glv_prepare_program": {"seconds": 0.0136, "count": 4},
+      "jit__glv_schnorr_program": {"seconds": 0.1864, "count": 4}},
+     4 * 8190, 46.6, 100 * 4 * 8190 * 956918 / 6.17e12 / 0.2),
+    # the prepare stage also served ECDSA buckets: its seconds an event,
+    # for as many events as the Schnorr stage has
+    ({"jit__glv_prepare_program": {"seconds": 0.0272, "count": 8},
+      "jit__glv_schnorr_program": {"seconds": 0.1864, "count": 4},
+      "jit__glv_dev_program": {"seconds": 0.156, "count": 4}},
+     4 * 8190, 46.6, 100 * 4 * 8190 * 956918 / 6.17e12 / 0.2),
+    # a program without the Schnorr stage (every parent of PR 44)
+    ({"jit__glv_prepare_program": {"seconds": 0.0135, "count": 4},
+      "jit__glv_dev_program": {"seconds": 0.156, "count": 4}},
+     0, None, None),
+    ({}, 0, None, None),
+])
+def test_the_schnorr_readers_take_both_stages(modules, lanes, kernel_ms,
+                                              roofline):
+    """schnorr.kernel_ms reads the second stage alone (a bucket's device
+    time is schnorr.kernel_ms + glv.prepare_ms); schnorr_roofline divides
+    the lanes' operations (chipbench/opcounts_schnorr.json, which it loads
+    itself) by the seconds of both stages, so that numerator and divisor
+    cover the same work; the module names are the stage jits' own."""
+    from bitcoincashplus_tpu.ops import secp256k1 as dev
+
+    kernel, share = _reader("schnorr.kernel_ms"), _reader("schnorr_roofline")
+    assert kernel.MODULE == share.MODULE == (
+        "jit_" + dev._glv_schnorr_program.__name__)
+    assert share.PREPARE == "jit_" + dev._glv_prepare_program.__name__
+    assert share.OPS_PER_LANE == 956918
+    obs = {"trace": {"modules": modules},
+           "peaks": {"vpu_u32_ops_per_s": 6.17e12},
+           "before": {"batch": {"schnorr_lanes": 16}},
+           "after": {"batch": {"schnorr_lanes": 16 + lanes}}}
+    assert kernel.read(obs) == pytest.approx(kernel_ms)
+    assert share.read(obs) == pytest.approx(roofline)
+    for less in (dict(obs, trace=None),
+                 dict(obs, before={"batch": {}}, after={"batch": {}})):
+        assert share.read(less) is None
+
+
+@pytest.mark.parametrize("stats, want", [
+    ({"sigscan_thread_s": 2.0, "schnorr_challenge_s": 0.05}, 2.5),
+    ({"sigscan_thread_s": 2.0, "schnorr_challenge_s": 0.0}, 0.0),
+    ({"sigscan_thread_s": 2.0}, None),       # a program before PR 44
+    ({"sigscan_thread_s": 0.0, "schnorr_challenge_s": 0.0}, None),
+    (None, None),                            # the import aborted
+], ids=["some", "none", "no-stopwatch", "no-scan", "no-import"])
+def test_schnorr_challenge_share_reads_the_scans_stopwatches(stats, want):
+    reader = _reader("sigscan.schnorr_challenge_share")
+    assert reader.read({"after": {"import": stats}}) == (
+        None if want is None else pytest.approx(want))

@@ -211,6 +211,60 @@ def test_glv_ladder_program_takes_its_tables_for_v5e(one_chip, chip_forms):
                 if "stablehlo.concatenate" in ln
                 and ln.rstrip().endswith(table)]
     assert text.count("stablehlo.while") == 2
+    # PR 44 put a Schnorr program beside this one and split a helper out
+    # of _glv_comb_final: the ECDSA program's lowered text is what it was
+    # at PR 43 (letter for letter; the chip's warm cache is keyed on it)
+    assert _digest(text) == ECDSA_LOWERED["_glv_dev_program"]
+
+
+# sha256 of the text each ECDSA stage lowers to for a described v5e at the
+# node's bucket of 8,192 lanes, chip forms, as of PR 43 (2428f59): an edit
+# that moves either compiles both anew on every machine, and the three
+# ECDSA cells' glv.kernel_ms / glv.prepare_ms are no longer the ledger's
+ECDSA_LOWERED = {
+    "_glv_prepare_program":
+        "76661e55a7c2d57f7cb5c38a35f6801b121e5470362511e9863e56ac5eec1cbf",
+    "_glv_dev_program":
+        "14c3d1693436da6970b89c2a86509e6ebeec30420e988fa2fcd22e253372c612",
+}
+
+
+def _digest(text: str) -> str:
+    import hashlib
+
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+def test_glv_prepare_program_lowers_to_the_text_it_had_for_v5e(one_chip,
+                                                                chip_forms):
+    """The stage both bucket kinds share: a Schnorr bucket hands it u1 = s,
+    u2 = n - e, Q = P, r and a zero wrap plane and changes nothing of it."""
+    from bitcoincashplus_tpu.ops import secp256k1 as dev
+
+    text = dev._glv_prepare_program.lower(
+        *_verify_args(one_chip, 8192)).as_text()
+    assert _digest(text) == ECDSA_LOWERED["_glv_prepare_program"]
+
+
+def test_glv_schnorr_program_takes_its_tables_for_v5e(one_chip, chip_forms):
+    """The Schnorr bucket's second stage has PR 39's form: the six stacked
+    tables are arguments of its main and none is stacked inside it (the
+    prepare stage's first ten outputs are its arguments). Its loops are
+    the ladder, the comb and the fourteen runs of squarings of the Euler
+    power: the squarings are not unrolled."""
+    from bitcoincashplus_tpu.ops import secp256k1 as dev
+
+    lanes = 8192
+    text = dev._glv_schnorr_program.lower(
+        *_ladder_args(one_chip, lanes)[:10]).as_text()
+    table = f"tensor<16x{dev.N_LIMBS}x{lanes}xui32>"
+    main = next(ln for ln in text.splitlines()
+                if "func.func public @main" in ln)
+    assert main.count(table) == 6
+    assert not [ln for ln in text.splitlines()
+                if "stablehlo.concatenate" in ln
+                and ln.rstrip().endswith(table)]
+    assert text.count("stablehlo.while") == 2 + 14
 
 
 # the node's reindex buckets (node.py _import_block_files_native); compile
@@ -221,7 +275,7 @@ def test_glv_ladder_program_takes_its_tables_for_v5e(one_chip, chip_forms):
 @pytest.mark.parametrize("bucket", [1024, 2048, 8192])
 def test_glv_verify_bucket_compiles_for_v5e(one_chip, chip_forms, bucket):
     """The DEFAULT verify kernel (decompose and tables, then the GLV
-    ladder: two programs) fits one chip: temp stays far below the 16 GB
+    ladder: two programs) and the Schnorr bucket's second stage fit one chip: temp stays far below the 16 GB
     of HBM at every bucket, and so do the tables the first hands the
     second."""
     from bitcoincashplus_tpu.ops import secp256k1 as dev
@@ -230,7 +284,9 @@ def test_glv_verify_bucket_compiles_for_v5e(one_chip, chip_forms, bucket):
         *_verify_args(one_chip, bucket)).compile()
     ladder = dev._glv_dev_program.lower(
         *_ladder_args(one_chip, bucket)).compile()
-    for compiled in (prepare, ladder):
+    schnorr = dev._glv_schnorr_program.lower(
+        *_ladder_args(one_chip, bucket)[:10]).compile()
+    for compiled in (prepare, ladder, schnorr):
         mem = compiled.memory_analysis()
         assert mem.temp_size_in_bytes < 2 << 30, mem
     assert prepare.memory_analysis().output_size_in_bytes < 1 << 30

@@ -677,12 +677,14 @@ class _DiskChain:
         return Node(config=cfg)
 
 
-def test_fast_import_sends_a_schnorr_signature_to_the_python_engine(tmp_path):
+def test_fast_import_keeps_a_schnorr_signature_in_the_native_engine(tmp_path):
     """A 65-byte Schnorr signature under a pay-to-pubkey output reaches the
     generic-script leg of the native import (the P2PKH scan never matches
-    it, the P2PK template declines it). Its record is no ECDSA lane: the
-    block takes the slow path, where the Python engine verifies it under
-    its own scheme, and the import goes on through the native engine."""
+    it) and, from the fork height on, the P2PK template takes it as a
+    Schnorr lane (until PR 44 it declined, the record was no ECDSA lane and
+    the block took the slow path through the Python engine): the block
+    stays in the native engine and the lane rides a bucket of its own
+    kind (tests/unit/test_schnorr_lanes.py holds the kinds apart)."""
     from bitcoincashplus_tpu.crypto import secp256k1 as secp
     from bitcoincashplus_tpu.script.script import p2pk_script, push_data_raw
     from bitcoincashplus_tpu.script.sighash import signature_hash
@@ -710,9 +712,10 @@ def test_fast_import_sends_a_schnorr_signature_to_the_python_engine(tmp_path):
     try:
         assert node.chainstate.tip().hash == last.get_hash()
         stats = node.last_import_stats
-        assert stats["slow_path_blocks"] == 1
+        assert stats["slow_path_blocks"] == 0
         assert stats["fallback_inputs"] == 1  # the Schnorr input, once
-        assert (stats["template_inputs"], stats["interp_inputs"]) == (0, 1)
+        assert (stats["template_inputs"], stats["interp_inputs"]) == (1, 0)
+        assert stats["schnorr_inputs"] == 1
     finally:
         node.close()
 

@@ -198,7 +198,7 @@ def native_scan(spend: Spend, flags: int):
                   res.sig_msg[g:g + 1], res.sig_rn[g:g + 1],
                   res.sig_wrap[g:g + 1], np.zeros(1, np.uint8))
         return status, arrays, [[g, 0, 0, 0]]
-    return status, res.leg_lanes, res.leg_table.tolist()
+    return status, res.leg_lanes[:6], res.leg_table.tolist()
 
 
 def python_lanes(spend: Spend, flags: int) -> tuple:

@@ -36,11 +36,14 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."
 # --mining: sweep-kernel census (generic vs chunk-2-hoisted, ISSUE 10)
 # plus the live compiled-flops drift check of the resident miner program;
 # CPU-pinned the same way.
+# --schnorr: the same census of the Schnorr bucket's two programs (PR 44),
+# the count chipbench/opcounts_schnorr.json keeps.
 ECDSA_MODE = "--ecdsa" in sys.argv
 MINING_MODE = "--mining" in sys.argv
-if ECDSA_MODE or MINING_MODE:
+SCHNORR_MODE = "--schnorr" in sys.argv
+if ECDSA_MODE or MINING_MODE or SCHNORR_MODE:
     os.environ["JAX_PLATFORMS"] = "cpu"
-if ECDSA_MODE:
+if ECDSA_MODE or SCHNORR_MODE:
     os.environ["BCP_SECP_PARALLEL"] = "1"
 
 import jax
@@ -197,6 +200,26 @@ def run_sweep_rate(sublanes=64, max_tiles=262144):
 # once. Same counting convention as the SHA census: only ops whose output
 # carries the lane axis; scalar/host work is excluded.
 
+def _lane_ops(B: int, f, *args) -> int:
+    """The equations of f's jaxpr, loop and call bodies once each, whose
+    output carries the lane axis (at least B elements)."""
+    total = 0
+
+    def walk(jx):
+        nonlocal total
+        for eqn in jx.eqns:
+            for sub in eqn.params.values():
+                if hasattr(sub, "jaxpr"):
+                    walk(sub.jaxpr)
+            shapes = [v.aval.shape for v in eqn.outvars
+                      if hasattr(v.aval, "shape")]
+            if any(s and int(np.prod(s)) >= B for s in shapes):
+                total += 1
+
+    walk(jax.make_jaxpr(f)(*args).jaxpr)
+    return total
+
+
 def _ecdsa_census_parts(B: int = 128):
     import jax.numpy as jnp
 
@@ -223,22 +246,7 @@ def _ecdsa_census_parts(B: int = 128):
     shape = (S.N_LIMBS, B)
 
     def count(f, *args):
-        jaxpr = jax.make_jaxpr(f)(*args)
-        total = 0
-
-        def walk(jx):
-            nonlocal total
-            for eqn in jx.eqns:
-                for sub in eqn.params.values():
-                    if hasattr(sub, "jaxpr"):
-                        walk(sub.jaxpr)
-                shapes = [v.aval.shape for v in eqn.outvars
-                          if hasattr(v.aval, "shape")]
-                if any(s and int(np.prod(s)) >= B for s in shapes):
-                    total += 1
-
-        walk(jaxpr.jaxpr)
-        return total
+        return _lane_ops(B, f, *args)
 
     # w4 phases
     w4_tables = count(
@@ -342,6 +350,72 @@ def run_ecdsa_census():
     print(f"{'fused verify total':<28}"
           f"{glv['total_with_decompose']:>12,}  "
           f"(+{oh * 100:.2f}% over the ladder)")
+    return parts
+
+
+# ---- Schnorr lane census (--schnorr, PR 44) ---------------------------------
+#
+# A Schnorr bucket runs _glv_prepare_program (the lattice split of u1 = s
+# and u2 = n - e, the plane emits, the per-lane tables) and
+# _glv_schnorr_program (the ladder's 32 windows, the comb's 32 adds, the
+# Schnorr final stage). Everything but the final stage is the ECDSA census's
+# own count; the final stage's squarings run as 14 loops whose bodies a
+# jaxpr walk counts once, so the 254 - 14 further squarings are added.
+
+def _schnorr_census_parts(B: int = 128):
+    import jax.numpy as jnp
+
+    from bitcoincashplus_tpu.crypto import secp256k1 as orc
+    from bitcoincashplus_tpu.ops import secp256k1 as S
+
+    rng = random.Random(44)
+
+    def limbs():
+        return jnp.asarray(
+            S.pack_batch_np([rng.randrange(orc.P) for _ in range(B)]))
+
+    x, y, z, r0 = limbs(), limbs(), limbs(), limbs()
+    acc = {"X": x, "Y": y, "Z": z, "inf": jnp.zeros((1, B), jnp.int32)}
+    mask = jnp.zeros((1, B), jnp.int32)
+    steps = [0, 0]  # squarings, loops
+
+    def sqr_n(v, k):
+        steps[0] += k
+        steps[1] += 1
+        return v
+
+    S._euler_chain(0, sqr_n, lambda a, b: a)
+    squarings, loops = steps
+    f_sqr = _lane_ops(B, S.f_sqr, x)
+    final_once = _lane_ops(
+        B, lambda a, r: S._schnorr_final(a, mask, mask, r), acc, r0)
+    glv = _ecdsa_census_parts(B)["glv"]
+    prepare = glv["tables"] + glv["decompose_total"]
+    ladder = glv["windows"] * glv["window"]
+    comb = glv["comb_adds"] * glv["comb_tooth"]
+    final = final_once + (squarings - loops) * f_sqr
+    return {"prepare": prepare, "ladder": ladder, "comb": comb,
+            "final": final, "f_sqr": f_sqr, "squarings": squarings,
+            "ecdsa_final": glv["final"],
+            "schnorr_program": ladder + comb + final,
+            "total": prepare + ladder + comb + final}
+
+
+def run_schnorr_census():
+    parts = _schnorr_census_parts()
+    print("Schnorr bucket, two programs: vector ops per lane "
+          "(parallel field forms, jaxpr census)")
+    for name, key in (
+            ("prepare: split x2, emits, tables", "prepare"),
+            ("ladder: 32 windows", "ladder"),
+            ("comb: 32 adds", "comb"),
+            ("final: X == r*Z^2, Euler power", "final"),
+            ("  one squaring", "f_sqr"),
+            ("  squarings in the power", "squarings"),
+            ("  (ECDSA's final stage)", "ecdsa_final"),
+            ("_glv_schnorr_program", "schnorr_program"),
+            ("TOTAL per Schnorr lane", "total")):
+        print(f"{name:<36}{parts[key]:>12,}")
     return parts
 
 
@@ -731,6 +805,9 @@ def run_mining_live_drift(census_d, tile: int = 1024):
 
 
 def main():
+    if SCHNORR_MODE:
+        run_schnorr_census()
+        return
     if ECDSA_MODE:
         parts = run_ecdsa_census()
         run_msm_census()
