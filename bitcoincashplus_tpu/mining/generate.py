@@ -16,7 +16,7 @@ from ..consensus.block import CBlock
 from ..ops.dispatch import supervised_sweep
 from ..ops.miner import DEFAULT_TILE
 from ..validation.chainstate import ChainstateManager
-from .assembler import BlockAssembler, increment_extranonce
+from .assembler import BlockAssembler, BlockTemplate, increment_extranonce
 
 # generateBlocks' nInnerLoopCount is 0x10000 (one extranonce bump per 64Ki
 # nonces) in the reference — far too small a stride for a vectorized sweep.
@@ -24,31 +24,17 @@ from .assembler import BlockAssembler, increment_extranonce
 MAX_TRIES_DEFAULT = 1_000_000  # reference default nMaxTries
 
 
-def mine_block(assembler: BlockAssembler, script_pubkey: bytes,
-               max_tries: int = MAX_TRIES_DEFAULT,
-               tile: int = DEFAULT_TILE,
-               sweep=None,
-               time_override: Optional[int] = None,
-               extranonce_start: int = 0) -> Optional[CBlock]:
-    """Assemble + PoW-search one block. Returns the mined block or None if
-    max_tries hashes were exhausted. `sweep` is injectable (single-chip
-    default; parallel.nonce_shard.sweep_header_sharded for a mesh;
-    node._select_sweep wires mining/resident.ResidentSweep.sweep — there,
-    each extranonce bump below is a device-side template BUFFER SWAP into
-    the persistent resident loop, not a fresh dispatch); the
-    default is the SUPERVISED single-chip sweep (ops/dispatch): a claimed
-    hit is host re-verified and a dead device degrades to the scalar CPU
-    loop under the miner circuit breaker.
-
-    ``extranonce_start`` seeds the coinbase extranonce counter: two nodes
-    assembling from the same parent with the same payout script and a
-    MTP-pinned header time would otherwise mine byte-identical blocks
-    (sub-second regtest mining made that collision real — the node layer
-    passes per-block entropy; the default 0 keeps unit-test chains
-    deterministic)."""
+def search_block(tmpl: BlockTemplate, max_tries: int = MAX_TRIES_DEFAULT,
+                 tile: int = DEFAULT_TILE, sweep=None,
+                 extranonce_start: int = 0) -> Optional[CBlock]:
+    """The search half of mine_block: bump the extranonce, sweep the nonce
+    space, until a hit or max_tries hashes. It reads the template and
+    nothing else — no chain state, no mempool — so a caller that shares
+    the chain with other threads runs it under no chain lock
+    (node.generate_to_script does; the reference's generateBlocks runs its
+    CheckProofOfWork loop outside cs_main the same way)."""
     if sweep is None:
         sweep = supervised_sweep()
-    tmpl = assembler.create_new_block(script_pubkey, time_override)
     height, target = tmpl.height, tmpl.target
     block = tmpl.block
     tries_left = max_tries
@@ -65,6 +51,35 @@ def mine_block(assembler: BlockAssembler, script_pubkey: bytes,
             mined = CBlock(block.header.with_nonce(nonce), block.vtx)
             return mined
     return None
+
+
+def mine_block(assembler: BlockAssembler, script_pubkey: bytes,
+               max_tries: int = MAX_TRIES_DEFAULT,
+               tile: int = DEFAULT_TILE,
+               sweep=None,
+               time_override: Optional[int] = None,
+               extranonce_start: int = 0) -> Optional[CBlock]:
+    """Assemble + PoW-search one block. Returns the mined block or None if
+    max_tries hashes were exhausted: the template half
+    (assembler.create_new_block, which reads the tip and the mempool) and
+    the search half (search_block), composed for callers that own their
+    chainstate. `sweep` is injectable (single-chip
+    default; parallel.nonce_shard.sweep_header_sharded for a mesh;
+    node._select_sweep wires mining/resident.ResidentSweep.sweep — there,
+    each extranonce bump of the search is a device-side template BUFFER
+    SWAP into the persistent resident loop, not a fresh dispatch); the
+    default is the SUPERVISED single-chip sweep (ops/dispatch): a claimed
+    hit is host re-verified and a dead device degrades to the scalar CPU
+    loop under the miner circuit breaker.
+
+    ``extranonce_start`` seeds the coinbase extranonce counter: two nodes
+    assembling from the same parent with the same payout script and a
+    MTP-pinned header time would otherwise mine byte-identical blocks
+    (sub-second regtest mining made that collision real — the node layer
+    passes per-block entropy; the default 0 keeps unit-test chains
+    deterministic)."""
+    tmpl = assembler.create_new_block(script_pubkey, time_override)
+    return search_block(tmpl, max_tries, tile, sweep, extranonce_start)
 
 
 def generate_blocks(chainstate: ChainstateManager, script_pubkey: bytes,

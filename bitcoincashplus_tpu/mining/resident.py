@@ -526,7 +526,11 @@ class ResidentSweep:
             self._watchdog = False
 
     def snapshot(self) -> dict:
-        """gettpuinfo's ``mining`` section (resident-loop state)."""
+        """gettpuinfo's ``mining`` section (resident-loop state). An
+        unlocked read of counters by design: it runs beside a live sweep
+        (node.mining_snapshot takes no ``miner`` lock — it would wait out
+        the mining call), so the values are each current, not one
+        instant's."""
         return {
             "resident": True,
             "kernel": self.kernel,

@@ -8,6 +8,10 @@ src/init.cpp:~150).
 
 The whole node shares one re-entrant lock (`cs_main`) — RPC worker threads
 and the P2P event loop serialize on it exactly like the reference's cs_main.
+The exception, as in the reference, is the miner's nonce search:
+`generate_to_script` holds `cs_main` for the template and for the connect,
+and the `miner` lock (one search at a time; taken before `cs_main`, never
+under it) for the whole call.
 """
 
 from __future__ import annotations
@@ -25,7 +29,7 @@ from ..consensus.serialize import hash_to_hex
 from ..mempool.accept import accept_to_memory_pool
 from ..mempool.mempool import CTxMemPool, MempoolError
 from ..mining.assembler import BlockAssembler
-from ..mining.generate import MAX_TRIES_DEFAULT, mine_block
+from ..mining.generate import MAX_TRIES_DEFAULT, search_block
 from ..store.blockstore import BlockStore
 from ..store.chainstatedb import BlockIndexDB, CoinsDB
 from ..store.kvstore import KVStore
@@ -176,10 +180,34 @@ class Node:
     """One full node over a datadir. Construct → (optionally) start_rpc/start_p2p
     → work → close(). Usable in-process (tests) or via bcpd (cli/)."""
 
+    # machine-enforced by bcplint BCP009 (the CConnman.GUARDED_BY
+    # pattern): the sweep engine and the mining calls' tallies are written
+    # by the one generate_to_script call that holds the miner lock (and by
+    # close(), which takes it). mining_snapshot() reads them unlocked.
+    GUARDED_BY = {
+        "resident_miner": "miner_lock",
+        "sweep_engine": "miner_lock",
+        "_mining_calls": "miner_lock",
+        "_mining_search_s": "miner_lock",
+        "_mining_held_s": "miner_lock",
+    }
+
     def __init__(self, config: Optional[Config] = None, datadir: Optional[str] = None,
                  network: Optional[str] = None):
         # gettpuinfo["startup"]: what a restart costs, stage by stage
         self._startup = _StartupPhases()
+        # one generate_to_script call at a time owns the sweep engine
+        # (ResidentSweep has no lock of its own: set_template / sweep from
+        # two threads would interleave template generations). Order:
+        # miner before cs_main, never the other way.
+        self.miner_lock = lockwatch.watched_lock("miner")
+        lockwatch.declare_guards("miner", self.GUARDED_BY)
+        self.resident_miner = None
+        self.sweep_engine = "unselected"
+        # gettpuinfo.mining: calls, search_s, cs_main_held_s
+        self._mining_calls = 0
+        self._mining_search_s = 0.0
+        self._mining_held_s = 0.0
         try:
             self._init(config, datadir, network)
         finally:
@@ -538,8 +566,6 @@ class Node:
         # "force" overrides the regtest-CPU scalar fast path too (test/
         # bench hook: exercises the resident loop where mining is trivial)
         self.resident_force = res_mode == "force"
-        self.resident_miner = None
-        self.sweep_engine = "unselected"
         self.sigservice = None
         if svc_mode in ("on", "1"):
             from ..serving import SigService
@@ -1158,35 +1184,72 @@ class Node:
         return supervised_sweep(inner)
 
     def mining_snapshot(self) -> dict:
-        """gettpuinfo's ``mining`` section: the active sweep engine and,
-        when the resident loop is live, its full state (template
+        """gettpuinfo's ``mining`` section: the active sweep engine, what
+        the generate_to_script calls spent where (``calls``; ``search_s``
+        in the nonce search, under no chain lock; ``cs_main_held_s``
+        holding cs_main for the templates and the connects) and, when the
+        resident loop is live, its full state (template
         generation, tiles swept, candidate FIFO, buffer swaps, poll
-        cadence)."""
+        cadence). It runs beside a live search and takes no lock: not
+        ``miner``, which the mining call holds to its end."""
         out = {"engine": self.sweep_engine, "resident": False,
-               "resident_enabled": self.resident_mode}
-        if self.resident_miner is not None:
-            out.update(self.resident_miner.snapshot())
+               "resident_enabled": self.resident_mode,
+               "calls": self._mining_calls,
+               "search_s": self._mining_search_s,
+               "cs_main_held_s": self._mining_held_s}
+        miner = self.resident_miner
+        if miner is not None:
+            out.update(miner.snapshot())
         return out
 
     def generate_to_script(self, script_pubkey: bytes, n_blocks: int,
                            max_tries: int = MAX_TRIES_DEFAULT) -> list[bytes]:
-        """generatetoaddress backend (src/rpc/mining.cpp generateBlocks)."""
+        """generatetoaddress backend (src/rpc/mining.cpp generateBlocks).
+        As there, cs_main is held for the template (the tip, the mempool
+        selection, the target) and again for ProcessNewBlock, and the
+        nonce search between them runs under no chain lock: reads, block
+        arrival and mempool accepts interleave with it. A tip that moved
+        during the search is ProcessNewBlock's to deal with, as for any
+        block whose parent is no longer the tip. The ``miner`` lock is
+        held for the whole call: one search at a time owns the sweep
+        engine. Lock order: miner, then cs_main. A caller that already
+        holds cs_main (it is re-entrant) stays correct while no other
+        thread mines — the holds below nest inside its own — and merely
+        keeps the lock through the search, as every caller did before the
+        split; beside a second mining thread that order can deadlock, so
+        call with no lock held."""
         hashes: list[bytes] = []
-        asm = self.assembler()
-        sweep = self._select_sweep()
-        for _ in range(n_blocks):
-            # per-block extranonce entropy: with sub-second mining the
-            # header time pins to MTP+1, and two nodes extending the same
-            # parent toward the same script would otherwise assemble
-            # byte-identical blocks — a reorg race that never forks
-            block = mine_block(asm, script_pubkey, max_tries=max_tries,
-                               sweep=sweep,
-                               extranonce_start=int.from_bytes(
-                                   os.urandom(4), "little"))
-            if block is None:
-                break
-            self.chainstate.process_new_block(block)
-            hashes.append(block.get_hash())
+        clock = time.monotonic
+        with self.miner_lock:
+            self._mining_calls += 1
+            asm, sweep = self.assembler(), None
+            for _ in range(n_blocks):
+                if self.shutdown_event.is_set():
+                    break  # close() waits on the miner lock for this
+                sweep = sweep or self._select_sweep()
+                # the two holds are `with self.cs_main` blocks here, not a
+                # helper's: bcplint reads them where they stand
+                with self.cs_main, telemetry.span("miner.template"):
+                    t0 = clock()
+                    tmpl = asm.create_new_block(script_pubkey)
+                    t1 = clock()
+                # per-block extranonce entropy: with sub-second mining the
+                # header time pins to MTP+1, and two nodes extending the
+                # same parent toward the same script would otherwise
+                # assemble byte-identical blocks — a reorg race that never
+                # forks
+                block = search_block(tmpl, max_tries=max_tries, sweep=sweep,
+                                     extranonce_start=int.from_bytes(
+                                         os.urandom(4), "little"))
+                self._mining_held_s += t1 - t0
+                self._mining_search_s += clock() - t1
+                if block is None:
+                    break
+                with self.cs_main, telemetry.span("miner.connect"):
+                    t0 = clock()
+                    self.chainstate.process_new_block(block)
+                    self._mining_held_s += clock() - t0
+                hashes.append(block.get_hash())
         return hashes
 
     def submit_block(self, block: CBlock) -> Optional[str]:
@@ -2875,6 +2938,15 @@ class Node:
             # drain pending lanes before the stores close (a late settle
             # still inserts into the in-memory sigcache — harmless)
             self.sigservice.stop()
+        with self.miner_lock:
+            # a generate_to_script call in flight searches under no chain
+            # lock: it sees shutdown_event before its next block, and must
+            # have connected its last one before the stores close below
+            if self.resident_miner is not None:
+                # drops the device template buffers and the miner watchdog
+                # registration (same closure-leak lesson as the collectors)
+                self.resident_miner.close()
+                self.resident_miner = None
         with self.cs_main:
             if self.persist_mempool:
                 from ..mempool.persist import dump_mempool
@@ -2904,11 +2976,6 @@ class Node:
         for name in ("sigcache", "pipeline", "mempool", "mempool_perf",
                      "serving", "mining", "store", "lockwatch"):
             telemetry.REGISTRY.unregister_collector(name)
-        if self.resident_miner is not None:
-            # drops the device template buffers and the miner watchdog
-            # registration (same closure-leak lesson as the collectors)
-            self.resident_miner.close()
-            self.resident_miner = None
         # same lesson for the watchdog: its pending_fn closures must not
         # keep a closed node alive (sigservice.stop() already dropped its
         # own registration above)

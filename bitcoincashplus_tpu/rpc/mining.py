@@ -36,6 +36,11 @@ def generatetoaddress(node, params):
     return [hash_to_hex(h) for h in hashes]
 
 
+# generate_to_script takes cs_main itself, for the template and for the
+# connect: the nonce search between them holds no chain lock
+generatetoaddress.no_cs_main = True
+
+
 @rpc_method("getblocktemplate")
 def getblocktemplate(node, params):
     """getblocktemplate (src/rpc/mining.cpp:~350) — BIP22 shape. A

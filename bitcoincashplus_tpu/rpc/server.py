@@ -7,7 +7,11 @@ src/rpc/protocol.cpp (GenerateAuthCookie — the `.cookie` file contract that
 bitcoin-cli and the functional framework rely on).
 
 All handlers run under node.cs_main — the RPC layer is the reference's
-"everything takes cs_main" model, minus the footguns.
+"everything takes cs_main" model, minus the footguns — except those marked
+``no_cs_main``, which take it themselves where they touch the chain: the
+blocking ones (getblocktemplate's longpoll, waitfor*, getaddednodeinfo) and
+generatetoaddress, whose nonce search runs under no chain lock between a
+hold for the template and a hold for the connect (node.generate_to_script).
 """
 
 from __future__ import annotations
@@ -88,8 +92,9 @@ class RPCServer:
         log_print("rpc", "ThreadRPCServer method=%s", method)
         try:
             if getattr(handler, "no_cs_main", False):
-                # blocking handlers (longpoll, waitfor*) manage cs_main
-                # themselves so other RPC threads aren't starved
+                # blocking handlers (longpoll, waitfor*) and the nonce
+                # search (generatetoaddress) manage cs_main themselves so
+                # other RPC threads aren't starved
                 result = self._handle(handler, method, params, 0.0)
             else:
                 with tm.span("rpc.lock_wait", method=method) as waited:

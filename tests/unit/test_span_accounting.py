@@ -251,14 +251,27 @@ def test_import_self_times_add_up_to_its_wall(imported):
     stats = imported.stats
     phases = stats["phases"]
     assert stats["blocks"] == 132 and stats["slow_path_blocks"] == 0
+    # the identity, by the spans' own clock: every span's self time is its
+    # duration less its children's, so over the root's subtree they add up
+    # to the root's duration, whatever the host was doing meanwhile
+    root_s = phases["import"]["s"]
     assert sum(row["self_s"] for row in phases.values()) == pytest.approx(
-        stats["wall_s"], rel=0.02)
+        root_s, abs=1e-9 * sum(row["n"] for row in phases.values()))
     # wall_s is a clock of its own around the root span (it reads under
-    # -telemetry=off too)
-    assert phases["import"]["s"] == pytest.approx(stats["wall_s"], abs=1e-3)
-    # what no child span covers is the import's own, and it is little
-    # (2.3% here on an idle sandbox; 0.4-0.9% on the chip, PERF.md section 5)
-    assert phases["import"]["self_s"] < 0.05 * stats["wall_s"]
+    # -telemetry=off too): it encloses the span, by two clock reads
+    assert stats["wall_s"] >= root_s
+    # every phase of the import is inside a child span of its own ...
+    assert {"import.read", "import.header", "import.connect",
+            "import.store_read", "import.lanes", "import.index",
+            "import.pack", "import.enqueue", "import.settle_wait",
+            "import.settle", "import.drain", "import.flush",
+            "import.close"} <= set(phases)
+    # ... so what no child covers, the loop's glue between ~700 spans, is
+    # the import's own, and it is little: 1.8-2.8% here on an idle sandbox,
+    # 2.5-4.9% beside sixteen busy loops on its eight cores (the 5% this
+    # line held until PR 45 failed there); 0.4-0.9% on the chip, PERF.md
+    # section 5
+    assert phases["import"]["self_s"] < 0.15 * root_s
     assert phases["import.connect"]["n"] == stats["blocks"]
     assert phases["import.index"]["n"] == stats["blocks"]
     # one read span a record and one a block file

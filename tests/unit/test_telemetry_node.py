@@ -43,8 +43,7 @@ def test_node_trace_smoke(tmp_path, monkeypatch, restore_mode):
 
     # phase 1: mine a small chain (telemetry default: counters)
     node = _mk_node(datadir)
-    with node.cs_main:
-        node.generate_to_script(SPK, 6)
+    node.generate_to_script(SPK, 6)
     node.close()
 
     # phase 2: -reindex through the PIPELINED PYTHON engine with
