@@ -173,7 +173,11 @@ Options:
                          (default: 200); -gatewaysoft/-gatewayhard in-flight
                          ceilings where read-only / all traffic sheds
                          (defaults: 64 / 256)
-  -flushinterval=<n>     Flush chainstate every <n> connected blocks (default: 64)
+  -flushinterval=<n>     Flush chainstate every <n> connected blocks (default:
+                         64). A -reindex counts the blocks it connects,
+                         across block files: a duplicate or parked record
+                         is none, so never more than <n> blocks are ahead
+                         of the store; -dbcache pressure flushes too
 """
 
 

@@ -1776,11 +1776,15 @@ class Node:
             if phases else "-telemetry=off: no span times")
         log_printf(
             "native import: %d blocks (%d slow-path), %.1f MB in %.1fs "
-            "(%s); %d flushes, %d dispatches (%d of them tails, %d lanes), "
+            "(%s); %d flushes (%d rows: %d put, %d deleted), store reads "
+            "%d of %d keys, %d dispatches (%d of them tails, %d lanes), "
             "unfinished at enqueue %s, queue seen empty %.2fs; self "
             "seconds: %s",
             n_imported, stats["slow_path_blocks"], stats["bytes"] / 1e6,
-            stats["wall_s"], legs, stats["flushes"], stats["dispatches"],
+            stats["wall_s"], legs, stats["flushes"], stats["flush_rows"],
+            stats["flush_puts"], stats["flush_deletes"],
+            stats["store_read_rows"], stats["store_read_keys"],
+            stats["dispatches"],
             stats["tail_dispatches"], stats["tail_lanes"],
             stats["inflight_at_enqueue"], stats["queue_empty_s"],
             " ".join(f"{name} {row['self_s']:.3f}" for name, row in sorted(
@@ -1861,7 +1865,13 @@ class Node:
         # host's doing, since the import cannot look inside the native call
         # (the device trace has the time itself: tools/trace_view.py
         # --xplane). tail_dispatches and tail_lanes are the <= 2,046-lane
-        # chunks of a drain, flushes the drains made.
+        # chunks of a drain, flushes the drains made. flush_puts and
+        # flush_deletes are the rows those flushes handed the store
+        # (flush_rows their sum; a tombstone counts, a FRESH coin spent
+        # before a flush reaches neither), flush_log one entry a flush
+        # (height, the store's epoch, rows, the commit's seconds);
+        # store_read_keys the spent coins service_misses asked the store
+        # for because a flush had cleared them, store_read_rows those found.
         stats = {"blocks": 0, "bytes": 0, "native_connect_s": 0.0,
                  "sigscan_s": 0.0, "verify_s": 0.0, "fallback_s": 0.0,
                  "flush_s": 0.0, "slow_path_blocks": 0,
@@ -1873,7 +1883,13 @@ class Node:
                  "schnorr_inputs": 0, "schnorr_challenge_s": 0.0,
                  "dispatches": 0, "tail_dispatches": 0, "tail_lanes": 0,
                  "flushes": 0, "queue_empty_s": 0.0,
-                 "inflight_at_enqueue": [0] * (MAX_INFLIGHT + 1)}
+                 "inflight_at_enqueue": [0] * (MAX_INFLIGHT + 1),
+                 "flush_rows": 0, "flush_puts": 0, "flush_deletes": 0,
+                 "store_read_keys": 0, "store_read_rows": 0,
+                 "flush_log": []}
+        # the height the last flush left on disk: the cadence counts the
+        # blocks connected since, across block files
+        flushed_height = [cs.chain.tip().height]
         queue_empty_since: list = [None]
         watch_queue = telemetry.mode() != "off"
 
@@ -2044,13 +2060,24 @@ class Node:
                 self.block_store.flush()
                 cs.flush_index()
                 best = eng.best()
-                self.coins_db.batch_write_serialized(eng.flush_entries(),
-                                                     best)
+                entries = eng.flush_entries()
+                t_commit = time.monotonic()
+                self.coins_db.batch_write_serialized(entries, best)
+                commit_s = time.monotonic() - t_commit
                 eng.clear()
                 # keep the Python cache's best-block in step: a later
                 # cs.flush() must not rewind the marker to its stale cached
                 # value (it survives CoinsCache.flush)
                 cs.coins.set_best_block(best)
+            deletes = sum(1 for _, ser in entries if ser is None)
+            stats["flush_deletes"] += deletes
+            stats["flush_puts"] += len(entries) - deletes
+            stats["flush_rows"] += len(entries)
+            flushed_height[0] = cs.chain.tip().height
+            stats["flush_log"].append({
+                "height": flushed_height[0],
+                "epoch": getattr(self.coins_db, "epoch", None),
+                "rows": len(entries), "seconds": round(commit_s, 6)})
 
         def service_misses(missing_keys) -> int:
             from ..consensus.serialize import (
@@ -2060,6 +2087,8 @@ class Node:
 
             with span("import.store_read", keys=len(missing_keys)):
                 rows = self.coins_db.get_serialized_many(missing_keys)
+                stats["store_read_keys"] += len(missing_keys)
+                stats["store_read_rows"] += len(rows)
                 for key, ser in rows.items():
                     r = ByteReader(ser)
                     code = deser_compact_size(r, range_check=False)
@@ -2077,6 +2106,7 @@ class Node:
                 connected = try_process(block, pos_info)
                 cs.flush()
                 eng.set_best(cs.coins.best_block())
+                flushed_height[0] = cs.chain.tip().height
             return connected
 
         def try_process(block: CBlock, pos_info: Optional[tuple]) -> bool:
@@ -2375,6 +2405,16 @@ class Node:
                 return True
             return slow_path(raw, pos_info)
 
+        def flush_if_due() -> None:
+            """After a block has connected: -flushinterval counts the
+            connected blocks the last flush does not hold (a duplicate or a
+            parked record is none, a new block file changes nothing), so no
+            more than that many are ever ahead of the store; -dbcache
+            pressure flushes too."""
+            if (cs.chain.tip().height - flushed_height[0] >= flush_interval
+                    or eng.mem_bytes() >= dbcache_bytes):
+                fast_flush()
+
         from ..crypto.hashes import sha256d as sha256d_py
 
         # enumerate the store's own blk files (reindex source of truth).
@@ -2395,7 +2435,6 @@ class Node:
                     with open(path, "rb") as f:
                         data = f.read()
                 pos = 0
-                blocks_since_flush = 0
                 while pos + 8 <= len(data):
                     if data[pos:pos + 4] != magic:
                         pos += 1
@@ -2410,19 +2449,16 @@ class Node:
                         stats["bytes"] += size
                         route = classify(raw, pos_info)
                     if process_raw(raw, pos_info, route):
+                        flush_if_due()
                         # cascade children parked on this block
                         queue = [route[1]]
                         while queue:
                             hh = queue.pop()
                             for c_raw, c_pos in pending.pop(hh, ()):
                                 if process_raw(c_raw, c_pos):
+                                    flush_if_due()
                                     queue.append(sha256d_py(c_raw[:80]))
                     pos = start + size
-                    blocks_since_flush += 1
-                    if (blocks_since_flush >= flush_interval
-                            or eng.mem_bytes() >= dbcache_bytes):
-                        fast_flush()
-                        blocks_since_flush = 0
                 n_file += 1
 
             fast_flush()

@@ -225,6 +225,10 @@ class ShardedCoinsDB(CoinsView):
                             "rebuilds": 0}
         self.last_flush = {"fanout": 0, "seconds": 0.0, "coins": 0,
                            "per_shard_s": []}
+        # every commit of this facade's life, where last_flush keeps one:
+        # rows_deleted counts the tombstones handed in, a row on disk or not
+        self.totals = {"commits": 0, "rows_put": 0, "rows_deleted": 0,
+                       "commit_seconds": 0.0}
 
     # -- meta helpers ----------------------------------------------------
 
@@ -289,6 +293,8 @@ class ShardedCoinsDB(CoinsView):
                 for key, value in row.items():
                     into[key] += value
         self.last_flush["spans"] = spans
+        self.totals["commits"] += 1
+        self.totals["commit_seconds"] += commit.seconds
 
     def _commit_stages(self, entries, best_block: bytes) -> list:
         """The commit itself; returns the shard threads' span totals."""
@@ -301,6 +307,8 @@ class ShardedCoinsDB(CoinsView):
                 per_dels[shard_of(k, self.n_shards)].append(k)
             else:
                 per_puts[shard_of(k, self.n_shards)][k] = ser
+        self.totals["rows_put"] += sum(map(len, per_puts))
+        self.totals["rows_deleted"] += sum(map(len, per_dels))
         epoch = self._epoch + 1
 
         # accumulator batch delta, per shard: divide out every changed
@@ -636,6 +644,7 @@ class ShardedCoinsDB(CoinsView):
             "muhash": self.muhash_digest().hex(),
             "bloom": {"enabled": self.bloom_enabled, **self.bloom_stats},
             "last_flush": dict(self.last_flush),
+            **self.totals,
             "shard_bytes": [self.shard_bytes(i)
                             for i in range(self.n_shards)],
             "snapshot": self._snapshot_state,

@@ -39,7 +39,8 @@ DATA = os.path.join(os.path.dirname(__file__), "data")
 # the parent of that tree, which the driver runs under this tree's readers.
 RECORDED = {"reindex.pre_fork": "obs_reindex_pre_fork.json",
             "mine.diff1_solo": "obs_mine_diff1_solo.json",
-            "reindex.schnorr_dense": "obs_reindex_schnorr_dense.json"}
+            "reindex.schnorr_dense": "obs_reindex_schnorr_dense.json",
+            "reindex.flush64": "obs_reindex_flush64.json"}
 
 
 def recorded(metric: dict, as_the_parent_has_it: bool) -> dict:
@@ -311,3 +312,130 @@ def test_schnorr_challenge_share_reads_the_scans_stopwatches(stats, want):
     reader = _reader("sigscan.schnorr_challenge_share")
     assert reader.read({"after": {"import": stats}}) == (
         None if want is None else pytest.approx(want))
+
+
+# -- reindex.flush64 (PR 46): the shipped flush cadence ----------------------
+
+CADENCE = ("import.flush_share", "store.us_per_row",
+           "store.rows_lock_wait_share", "import.store_read_share",
+           "import.drain_share")
+
+
+def test_the_default_deployment_states_what_it_runs_and_holds():
+    """archival-reindex-default: the flags written out, the four
+    guarantees, every cut and every assumption with its reason, the files
+    the cell needs beside the harness's."""
+    entry = CONFIGS["archival-reindex-default"]
+    assert len(entry["source"]) <= 200  # PR 27 was refused for 201
+    assert entry["reduced"] == ["chain_length", "keys", "coin_set"]
+    with open(os.path.join(ROOT, entry["file"])) as f:
+        config = json.load(f)
+    assert config["flags"] == ["-regtest", "-tpu=1", "-reindex", "-listen=0",
+                               "-flushinterval=64", "-dbcache=300"]
+    assert [flag for flag in config["rehearse_flags"] if "tpu" not in flag] \
+        == [flag for flag in config["flags"] if "tpu" not in flag]
+    assert set(config["guarantees"]) == {"chain", "signatures", "sample",
+                                         "durability"}
+    assert {"spent_output_age", "intervals", "aligned_flushes"} <= set(
+        config["assumed"])
+    age = config["assumed"]["spent_output_age"]
+    for words in ("worst case", "not a measured share", "2019/611",
+                  "CCoinsViewCache::Flush", "not for a share"):
+        assert words in age, words
+    cell = CELLS["reindex.flush64"]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "archival-reindex-default", "flush64", 1)
+    bench = os.path.join(ROOT, MANIFEST["paths"][0])
+    for leaf in ("gen/agedchain.py", "reference_flush64.py",
+                 "drivers/reindex_flush64.py", "traffic/flush64.json"):
+        assert os.path.isfile(os.path.join(bench, leaf)), leaf
+    with open(os.path.join(bench, "traffic", "flush64.json")) as f:
+        traffic = json.load(f)
+    assert traffic["flush_interval"] == 64 and traffic["windows"] == 1
+    # the cell joins the control's metrics as they stand
+    joined = {m["name"] for m in run.load_cell("reindex.flush64",
+                                               ROOT)["per_layer"]}
+    control = {m["name"] for m in run.load_cell("reindex.p2pkh_dense",
+                                                ROOT)["per_layer"]}
+    assert joined == control | set(CADENCE)
+
+
+@pytest.mark.parametrize("name", CADENCE)
+def test_the_cadences_metrics_list_the_one_cell_that_runs_it(name):
+    metric = PER_LAYER[name]
+    assert metric["workloads"] == ["reindex.flush64"]
+    assert (metric["moves"], metric["source"], metric["better"]) == (
+        "reindex_sigs_per_s", "program_span", "lower")
+    assert metric["layer"] in ("import engine", "coins store")
+
+
+def test_the_cadences_shares_are_parts_of_one_wall():
+    """import.flush, import.drain and import.store_read are spans of the
+    importing thread that do not nest in each other: their shares of the
+    recorded window add up to no more than it."""
+    obs = recorded(PER_LAYER["import.flush_share"], False)
+    shares = [_reader(name).read(obs) for name in (
+        "import.flush_share", "import.store_read_share",
+        "import.drain_share")]
+    assert all(share is not None for share in shares)
+    assert sum(shares) <= 100.0
+    stats = obs["after"]["import"]
+    assert stats["flush_rows"] == stats["flush_puts"] + stats["flush_deletes"]
+    assert stats["tail_dispatches"] == 0
+    assert len(stats["flush_log"]) == stats["flushes"]
+
+
+def _cadence_obs(stats=None, spans=(None, None)) -> dict:
+    return {"before": {"spans": spans[0]} if spans[0] is not None else {},
+            "after": {"import": stats,
+                      **({"spans": spans[1]} if spans[1] is not None
+                         else {})}}
+
+
+@pytest.mark.parametrize("name, obs, want", [
+    ("import.flush_share", _cadence_obs(
+        {"wall_s": 50.0, "flush_rows": 10,
+         "phases": {"import.flush": {"s": 30.0, "self_s": 1.0, "n": 6}}}),
+     60.0),
+    ("import.drain_share", _cadence_obs(
+        {"wall_s": 50.0, "flush_rows": 10,
+         "phases": {"import.drain": {"s": 0.5, "self_s": 0.1, "n": 6}}}),
+     1.0),
+    ("import.store_read_share", _cadence_obs(
+        {"wall_s": 50.0, "store_read_rows": 7,
+         "phases": {"import.store_read": {"s": 10.0, "self_s": 10.0,
+                                          "n": 99}}}), 20.0),
+    ("store.us_per_row", _cadence_obs(
+        {"wall_s": 50.0, "flush_rows": 1_000_000,
+         "phases": {"store.commit": {"s": 25.0, "self_s": 2.0, "n": 8}}}),
+     25.0),
+    ("store.rows_lock_wait_share", _cadence_obs(
+        {}, ({"store.shard_write": {"s": 1.0}, "store.rows_lock_wait":
+              {"s": 0.5}},
+             {"store.shard_write": {"s": 21.0}, "store.rows_lock_wait":
+              {"s": 8.5}})), 40.0),
+    # a program without the cadence's counters (every parent of PR 46):
+    # the spans are there since PR 40, the metrics are not its to report
+    ("import.flush_share", _cadence_obs(
+        {"wall_s": 50.0,
+         "phases": {"import.flush": {"s": 30.0, "self_s": 1.0, "n": 6}}}),
+     None),
+    ("import.drain_share", _cadence_obs(
+        {"wall_s": 50.0,
+         "phases": {"import.drain": {"s": 0.5, "self_s": 0.1, "n": 6}}}),
+     None),
+    ("import.store_read_share", _cadence_obs({"wall_s": 50.0, "phases": {}}),
+     None),
+    ("store.us_per_row", _cadence_obs(
+        {"wall_s": 50.0,
+         "phases": {"store.commit": {"s": 25.0, "self_s": 2.0, "n": 8}}}),
+     None),
+    ("store.us_per_row", _cadence_obs(None), None),  # the import aborted
+    ("store.rows_lock_wait_share", _cadence_obs({}), None),
+    ("store.rows_lock_wait_share", _cadence_obs(
+        {}, ({"store.shard_write": {"s": 1.0}},
+             {"store.shard_write": {"s": 1.0}})), None),  # no commit
+], ids=lambda v: v if isinstance(v, str) else None)
+def test_the_cadences_readers(name, obs, want):
+    value = _reader(name).read(obs)
+    assert value == (None if want is None else pytest.approx(want))
