@@ -12,22 +12,17 @@ from __future__ import annotations
 import json
 import os
 import sqlite3
-import threading
+from itertools import chain
 from typing import Iterator, Optional
 
 from ..util import lockwatch
-from ..util import telemetry as tm
 from ..util.faults import maybe_crash
 
 
-# sqlite3 binds each row of an executemany under the GIL and steps it
-# without: stores whose batches are written at the same time (the coins
-# shards' flush pool) hand the GIL to each other once a row. Four batches
-# of 14.5k rows take 0.29 s one after the other and 2.0-2.8 s together,
-# a different time every flush. The rows of one batch at a time; the
-# commits' and checkpoints' I/O, which needs no GIL, still overlaps. A
-# leaf lock: taken inside a store's write lock, nothing is taken inside it.
-_ROWS_LOCK = threading.Lock()
+# Rows go to sqlite a statement a chunk: sqlite3 binds under the GIL and
+# steps without it, so stores written at the same time (the coins shards'
+# flush pool) hand the GIL over once a statement and run side by side.
+ROWS_PER_STATEMENT = 4000
 
 
 def atomic_write_bytes(path: str, data: bytes) -> None:
@@ -86,8 +81,12 @@ class KVStore:
         # never conflated into a false ordering edge.
         self._write_lock = lockwatch.watched_lock(
             "kvstore:%s" % os.path.basename(path))
+        # cached_statements: a prepared statement is as large as its text
+        # (a chunk's is ~0.35 KB a row) and a tail chunk of every length is
+        # a text of its own; sqlite3's default would keep 128 of them
         self._db = sqlite3.connect(path, isolation_level=None,
-                                   check_same_thread=False)
+                                   check_same_thread=False,
+                                   cached_statements=16)
         self._db.execute("PRAGMA journal_mode=WAL")
         # wal=False (default): synchronous=NORMAL + an explicit
         # wal_checkpoint(FULL) on every sync'd batch — the checkpoint IS
@@ -104,6 +103,10 @@ class KVStore:
         self._db.execute(
             "CREATE TABLE IF NOT EXISTS kv (k BLOB PRIMARY KEY, v BLOB NOT NULL)"
         )
+        # a put binds two variables
+        self._rows_per_statement = min(
+            ROWS_PER_STATEMENT,
+            self._db.getlimit(sqlite3.SQLITE_LIMIT_VARIABLE_NUMBER) // 2)
 
     def get(self, key: bytes) -> Optional[bytes]:
         row = self._db.execute("SELECT v FROM kv WHERE k = ?", (key,)).fetchone()
@@ -139,27 +142,31 @@ class KVStore:
         return self.get(key) is not None
 
     def write_batch(self, puts: dict[bytes, bytes], deletes: list[bytes] = (),
-                    sync: bool = False) -> None:
-        """CDBBatch + WriteBatch: all-or-nothing (one sqlite transaction)."""
+                    sync: bool = False) -> int:
+        """CDBBatch + WriteBatch: all-or-nothing (one sqlite transaction).
+        Deletes go before puts, ROWS_PER_STATEMENT rows a statement;
+        returns the number of row statements it took."""
+        chunk = self._rows_per_statement
+        deletes = list(deletes)
+        flat_puts = list(chain.from_iterable(puts.items()))
+        statements = 0
         with self._write_lock:
             cur = self._db.cursor()
             cur.execute("BEGIN")
             maybe_crash("kv:begin")
             try:
-                with tm.span("store.rows_lock_wait"):
-                    _ROWS_LOCK.acquire()
-                try:
-                    if deletes:
-                        cur.executemany("DELETE FROM kv WHERE k = ?",
-                                        [(k,) for k in deletes])
-                    if puts:
-                        cur.executemany(
-                            "INSERT INTO kv (k, v) VALUES (?, ?) "
-                            "ON CONFLICT(k) DO UPDATE SET v=excluded.v",
-                            list(puts.items()),
-                        )
-                finally:
-                    _ROWS_LOCK.release()
+                for i in range(0, len(deletes), chunk):
+                    part = deletes[i:i + chunk]
+                    cur.execute("DELETE FROM kv WHERE k IN (%s)"
+                                % ",".join(["?"] * len(part)), part)
+                    statements += 1
+                for i in range(0, len(flat_puts), 2 * chunk):
+                    part = flat_puts[i:i + 2 * chunk]
+                    cur.execute(
+                        "INSERT INTO kv (k, v) VALUES %s "
+                        "ON CONFLICT(k) DO UPDATE SET v=excluded.v"
+                        % ",".join(["(?,?)"] * (len(part) // 2)), part)
+                    statements += 1
                 # a hard kill here leaves an uncommitted WAL transaction
                 # that sqlite discards on reopen — the torn-commit case the
                 # crash-injection tests cover
@@ -171,6 +178,7 @@ class KVStore:
                 raise
             if sync and not self.wal:
                 self._db.execute("PRAGMA wal_checkpoint(FULL)")
+        return statements
 
     def iterate(self, prefix: bytes = b"") -> Iterator[tuple[bytes, bytes]]:
         """Ordered iteration over keys with the given prefix — CDBIterator."""

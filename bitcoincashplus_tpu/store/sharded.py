@@ -226,9 +226,10 @@ class ShardedCoinsDB(CoinsView):
         self.last_flush = {"fanout": 0, "seconds": 0.0, "coins": 0,
                            "per_shard_s": []}
         # every commit of this facade's life, where last_flush keeps one:
-        # rows_deleted counts the tombstones handed in, a row on disk or not
+        # rows_deleted counts the tombstones handed in, a row on disk or
+        # not; write_statements the row statements the shards' flushes took
         self.totals = {"commits": 0, "rows_put": 0, "rows_deleted": 0,
-                       "commit_seconds": 0.0}
+                       "write_statements": 0, "commit_seconds": 0.0}
 
     # -- meta helpers ----------------------------------------------------
 
@@ -280,8 +281,7 @@ class ShardedCoinsDB(CoinsView):
         The commit's stages are spans under one ``store.commit``:
         store.old_reads (the bloom pre-pass and the reads of persisted old
         values), store.muhash, store.journal, store.shard_write (one a
-        shard, on the flush pool's threads; store.rows_lock_wait inside it
-        is the wait for kvstore._ROWS_LOCK), store.manifest. Their totals
+        shard, on the flush pool's threads), store.manifest. Their totals
         land in ``last_flush["spans"]``, the shard threads' summed."""
         with tm.span("store.commit", collect=True) as commit:
             shard_spans = self._commit_stages(entries, best_block)
@@ -384,13 +384,15 @@ class ShardedCoinsDB(CoinsView):
         # FORWARD — an error leaves the journals in place for replay.
         t0 = time.perf_counter()
         per_shard_s = [0.0] * self.n_shards
+        per_shard_statements = [0] * self.n_shards
 
         def _apply(i: int):
             # collect: on the pool's threads this span is nobody's child
-            with tm.span("store.shard_write", collect=True,
-                         shard=i) as wrote:
-                self.shards[i].kv.write_batch(kv_puts[i], kv_dels[i],
-                                              sync=True)
+            with tm.span("store.shard_write", collect=True, shard=i,
+                         rows=len(kv_puts[i]) + len(kv_dels[i])) as wrote:
+                per_shard_statements[i] = self.shards[i].kv.write_batch(
+                    kv_puts[i], kv_dels[i], sync=True)
+                wrote.note(statements=per_shard_statements[i])
             per_shard_s[i] = wrote.seconds
             _FLUSH_HIST.labels(shard=str(i)).observe(wrote.seconds)
             return wrote.totals
@@ -403,6 +405,7 @@ class ShardedCoinsDB(CoinsView):
                 shard_spans.append(f.result())
         else:
             _apply(0)  # on this thread: inside store.commit's own totals
+        self.totals["write_statements"] += sum(per_shard_statements)
         maybe_crash("shard:applied")
 
         # step 3: the cross-shard epoch marker, written last

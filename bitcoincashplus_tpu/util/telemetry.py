@@ -500,6 +500,9 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def note(self, **args) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
@@ -559,6 +562,11 @@ class _Span:
     def totals(self):
         return (None if self._collected is None
                 else _totals_view(self._collected))
+
+    def note(self, **args) -> None:
+        """Arguments the work itself yields (a count of what it wrote):
+        recorded with the span's own when the ring keeps it."""
+        self.args.update(args)
 
     def __enter__(self):
         self._tracer._stack().append(self)

@@ -345,10 +345,33 @@ def test_import_under_off_keeps_its_wall_and_counters_and_says_so(tmp_path):
 def test_the_store_says_where_a_commit_went(imported):
     spans = imported.info["store"]["last_flush"]["spans"]
     assert {"store.commit", "store.old_reads", "store.muhash",
-            "store.journal", "store.shard_write", "store.rows_lock_wait",
+            "store.journal", "store.shard_write",
             "store.manifest"} <= set(spans)
+    assert "store.rows_lock_wait" not in spans
     assert spans["store.shard_write"]["n"] == 4  # one a shard
     assert spans["store.commit"]["s"] >= spans["store.manifest"]["s"]
+
+
+@pytest.mark.parametrize("name", ["off", "counters", "trace"])
+def test_a_shard_write_says_its_rows_and_statements(tmp_path, mode, name):
+    """The ring's store.shard_write events carry what the shard wrote;
+    the other modes keep no event and the commit is the same commit."""
+    from bitcoincashplus_tpu.store.sharded import ShardedCoinsDB
+
+    mode(name)
+    db = ShardedCoinsDB(str(tmp_path), n_shards=2)
+    db.batch_write_serialized(
+        [(os.urandom(36), b"\x02\x05\x01\x51") for _ in range(40)],
+        b"\x01" * 32)
+    stats = db.stats()
+    db.close()
+    assert stats["write_statements"] == 2 and stats["rows_put"] == 40
+    writes = [ev["args"] for ev in tm.TRACER.events()
+              if ev["name"] == "store.shard_write"]
+    assert len(writes) == (2 if name == "trace" else 0)
+    assert sorted(w["shard"] for w in writes) == list(range(len(writes)))
+    assert all(w["statements"] == 1 for w in writes)
+    assert sum(w["rows"] for w in writes) == (40 + 2 * 3 if writes else 0)
 
 
 # ---------------------------------------------------------------------------
