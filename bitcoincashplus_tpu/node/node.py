@@ -1831,6 +1831,9 @@ class Node:
                                              DEFAULT_FLUSH_INTERVAL)
         dbcache_bytes = max(
             1, self.config.get_int("dbcache", 300)) * 1024 * 1024
+        # the sharded store keeps the rows it served until its next write
+        # (store/sharded.py); the legacy single file keeps nothing
+        served_bytes = getattr(self.coins_db, "served_bytes", lambda: 0)
         cs.flush()  # the engine's base view must be current before takeover
 
         eng = native.ConnectEngine()
@@ -2410,9 +2413,10 @@ class Node:
             connected blocks the last flush does not hold (a duplicate or a
             parked record is none, a new block file changes nothing), so no
             more than that many are ever ahead of the store; -dbcache
-            pressure flushes too."""
+            pressure flushes too, the engine's coins and the store's memory
+            of the ones it served counted together."""
             if (cs.chain.tip().height - flushed_height[0] >= flush_interval
-                    or eng.mem_bytes() >= dbcache_bytes):
+                    or eng.mem_bytes() + served_bytes() >= dbcache_bytes):
                 fast_flush()
 
         from ..crypto.hashes import sha256d as sha256d_py

@@ -38,6 +38,15 @@ Each shard also maintains a MuHash accumulator over its coin rows
 delta — the global UTXO-set digest is the product of the shard
 accumulators, independent of the shard count, and is what snapshots
 stamp and ``gettxoutsetinfo`` surfaces.
+
+The delta divides out the PERSISTED old value of every row a commit
+changes. Which changed keys still reach sqlite for it: a key the facade
+served through ``get_serialized_many`` since its last write does not (the
+facade remembers the bytes it returned, and is the only writer of its
+shards, so they are the persisted value until the next write drops the
+memory); of the rest, a key the shard's write-side bloom proves absent
+does not; what is left (a row nobody read first, a bloom false positive)
+is looked up, 500 keys a statement.
 """
 
 from __future__ import annotations
@@ -74,6 +83,10 @@ STORE_SHARD_SITE = "store_shard"
 
 _EPOCH = b"E"          # per-shard meta: LE64 commit epoch
 _ACC = b"M"            # per-shard meta: 384-byte BE MuHash accumulator
+# what a remembered row costs beyond its key's and value's own bytes: two
+# bytes objects' headers (33 each) and a dict entry with its share of the
+# table's slack
+_SERVED_ROW_OVERHEAD = 2 * 33 + 64
 MANIFEST_NAME = "chainstate.manifest.json"
 
 _FLUSH_HIST = tm.histogram(
@@ -98,9 +111,10 @@ class _KeyBloom:
     satellite).
 
     The accumulator delta must divide out every changed row's PERSISTED
-    old value — which costs a point lookup per changed key even when the
-    key was never persisted (the common case under flood: fresh coin
-    creates). The bloom answers "definitely absent" for those keys so
+    old value — which, for a key the facade has not served since its last
+    write (module docstring), costs a point lookup even when the key was
+    never persisted (the common case under flood: fresh coin creates).
+    The bloom answers "definitely absent" for those keys so
     they skip ``get_serialized_many`` entirely; a maybe-present answer
     falls through to the lookup, so a false positive costs only the old
     price and a false negative is impossible (every persisted key was
@@ -223,6 +237,14 @@ class ShardedCoinsDB(CoinsView):
         self._blooms: list[Optional[_KeyBloom]] = [None] * n_shards
         self.bloom_stats = {"checked": 0, "skipped": 0, "builds": 0,
                             "rebuilds": 0}
+        # the rows get_serialized_many has served since the last write, a
+        # shard: a commit takes a changed key's persisted old value from
+        # here before the bloom and sqlite are asked (module docstring).
+        # Dropped wherever a coin row can change; never persisted.
+        self._served: list[dict[bytes, bytes]] = [
+            {} for _ in range(n_shards)]
+        self._served_bytes = 0
+        self.old_value_stats = {"remembered": 0, "looked_up": 0, "found": 0}
         self.last_flush = {"fanout": 0, "seconds": 0.0, "coins": 0,
                            "per_shard_s": []}
         # every commit of this facade's life, where last_flush keeps one:
@@ -279,12 +301,20 @@ class ShardedCoinsDB(CoinsView):
         """entries: iterable of (key36, coin_ser | None-for-delete).
 
         The commit's stages are spans under one ``store.commit``:
-        store.old_reads (the bloom pre-pass and the reads of persisted old
-        values), store.muhash, store.journal, store.shard_write (one a
-        shard, on the flush pool's threads), store.manifest. Their totals
-        land in ``last_flush["spans"]``, the shard threads' summed."""
-        with tm.span("store.commit", collect=True) as commit:
-            shard_spans = self._commit_stages(entries, best_block)
+        store.old_reads (a shard's changed keys split into those the
+        facade remembers serving and the rest, the bloom pre-pass over the
+        rest, the reads of persisted old values for what the bloom lets
+        through, a saturated bloom's rebuild), store.muhash, store.journal,
+        store.shard_write (one a shard, on the flush pool's threads),
+        store.manifest. Their totals land in ``last_flush["spans"]``, the
+        shard threads' summed."""
+        try:
+            with tm.span("store.commit", collect=True) as commit:
+                shard_spans = self._commit_stages(entries, best_block)
+        finally:
+            # a row served while the applies ran (no caller does: see
+            # get_serialized_many) must not outlive them
+            self._forget_served()
         spans = commit.totals or {}
         for totals in shard_spans:
             for name, row in (totals or {}).items():
@@ -318,23 +348,41 @@ class ShardedCoinsDB(CoinsView):
         # shard per commit (muhash.MuHash.apply).
         new_accs = []
         flush_bloom = {"checked": 0, "skipped": 0}
+        flush_old = {"remembered": 0, "looked_up": 0, "found": 0}
         for i in range(self.n_shards):
             changed = list(per_puts[i]) + per_dels[i]
-            with tm.span("store.old_reads", shard=i, keys=len(changed)):
+            with tm.span("store.old_reads", shard=i,
+                         keys=len(changed)) as reads:
+                # a key this facade served since its last write: the bytes
+                # it returned are the persisted value
+                served = self._served[i]
+                removed, ask = [], changed
+                if served:
+                    ask = []
+                    for k in changed:
+                        ser = served.get(k)
+                        if ser is None:
+                            ask.append(k)
+                        else:
+                            removed.append((k, ser))
                 # bloom pre-pass: keys the filter proves absent (fresh
                 # coin creates, the flood-common case) skip the old-value
                 # lookup; false positives just pay the lookup, false
                 # negatives are impossible (every persisted key passed
                 # through add_many)
-                if changed and self.bloom_enabled:
-                    maybe = self._bloom_for(i).filter(changed)
-                    flush_bloom["checked"] += len(changed)
-                    flush_bloom["skipped"] += len(changed) - len(maybe)
+                if ask and self.bloom_enabled:
+                    maybe = self._bloom_for(i).filter(ask)
+                    flush_bloom["checked"] += len(ask)
+                    flush_bloom["skipped"] += len(ask) - len(maybe)
                 else:
-                    maybe = changed
+                    maybe = ask
                 old = self.shards[i].get_serialized_many(maybe) if maybe \
                     else {}
-                removed = [(k, old[k]) for k in changed if k in old]
+                reads.note(remembered=len(removed), looked_up=len(maybe))
+                flush_old["remembered"] += len(removed)
+                flush_old["looked_up"] += len(maybe)
+                flush_old["found"] += len(old)
+                removed += [(k, old[k]) for k in maybe if k in old]
             with tm.span("store.muhash", shard=i):
                 acc = muhash.MuHash(self._accs[i].state)
                 acc.apply(
@@ -345,8 +393,15 @@ class ShardedCoinsDB(CoinsView):
                 # the new puts become persisted rows below — future
                 # commits must see them as maybe-present
                 self._bloom_for(i).add_many(list(per_puts[i]))
-        self.bloom_stats["checked"] += flush_bloom["checked"]
-        self.bloom_stats["skipped"] += flush_bloom["skipped"]
+        # the old values are taken and the rows are about to change: from
+        # here what was served is no longer known to be what is persisted
+        # (a commit that aborts in step 1 changes no row and only loses the
+        # memory)
+        self._forget_served()
+        for name, n in flush_bloom.items():
+            self.bloom_stats[name] += n
+        for name, n in flush_old.items():
+            self.old_value_stats[name] += n
 
         meta_epoch = struct.pack("<Q", epoch)
         kv_puts = []
@@ -427,6 +482,7 @@ class ShardedCoinsDB(CoinsView):
             "coins": n_coins,
             "per_shard_s": [round(s, 6) for s in per_shard_s],
             "bloom": flush_bloom,
+            "old_values": flush_old,
         }
         for i in range(self.n_shards):
             _SHARD_BYTES.labels(shard=str(i)).set(self.shard_bytes(i))
@@ -488,6 +544,7 @@ class ShardedCoinsDB(CoinsView):
         # replay: every journal present (or the absent ones already
         # applied + cleared). Idempotent per shard.
         n_puts = n_dels = 0
+        self._forget_served()
         for i, d in enumerate(decoded):
             if d is None:
                 continue
@@ -549,14 +606,33 @@ class ShardedCoinsDB(CoinsView):
         self._commit_sharded(entries, best_block)
 
     def get_serialized_many(self, keys36: list[bytes]) -> dict[bytes, bytes]:
+        """{key36: coin_serialization} for present rows; each is remembered
+        until the next write (a miss is not: an absent key still goes
+        through the bloom at a commit). Must not overlap a commit of this
+        facade: both callers run on the thread that commits."""
         per: list[list[bytes]] = [[] for _ in range(self.n_shards)]
         for k in keys36:
             per[shard_of(k, self.n_shards)].append(k)
         out: dict[bytes, bytes] = {}
         for i, keys in enumerate(per):
             if keys:
-                out.update(self.shards[i].get_serialized_many(keys))
+                rows = self.shards[i].get_serialized_many(keys)
+                self._served[i].update(rows)
+                self._served_bytes += (
+                    len(rows) * (len(keys[0]) + _SERVED_ROW_OVERHEAD)
+                    + sum(map(len, rows.values())))
+                out.update(rows)
         return out
+
+    def served_bytes(self) -> int:
+        """What the memory of served rows holds of the process, as Python
+        objects: the import counts it against -dbcache beside the engine's
+        own bytes, since every row here is one the engine holds too."""
+        return self._served_bytes
+
+    def _forget_served(self) -> None:
+        self._served = [{} for _ in range(self.n_shards)]
+        self._served_bytes = 0
 
     def count_coins(self) -> int:
         return sum(s.count_coins() for s in self.shards)
@@ -594,6 +670,7 @@ class ShardedCoinsDB(CoinsView):
             _load(0)
         # bulk rows bypassed the commit path: rebuild lazily on next use
         self._blooms = [None] * self.n_shards
+        self._forget_served()
 
     def clear_coins(self) -> None:
         """Drop every coin row (failed snapshot load cleanup)."""
@@ -602,6 +679,7 @@ class ShardedCoinsDB(CoinsView):
             for i in range(0, len(dels), 10000):
                 shard.kv.write_batch({}, dels[i:i + 10000])
         self._blooms = [None] * self.n_shards
+        self._forget_served()
 
     def finalize_bulk_load(self, best_block: bytes,
                            shard_states: list[int],
@@ -646,6 +724,8 @@ class ShardedCoinsDB(CoinsView):
             "epoch": self._epoch,
             "muhash": self.muhash_digest().hex(),
             "bloom": {"enabled": self.bloom_enabled, **self.bloom_stats},
+            "old_values": dict(self.old_value_stats),
+            "remembered_rows": sum(map(len, self._served)),
             "last_flush": dict(self.last_flush),
             **self.totals,
             "shard_bytes": [self.shard_bytes(i)

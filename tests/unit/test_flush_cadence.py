@@ -225,6 +225,17 @@ def test_the_shipped_cadence_against_the_reference(aged, tmp_path):
     assert store["rows_deleted"] == stats["flush_deletes"]
     assert store["commits"] >= stats["flushes"] + 1
     assert store["commit_seconds"] > 0
+    # MuHash's old values: every row a flush deleted was on disk and had
+    # been served to the import since the flush before, so the store
+    # remembered it; sqlite is asked for what the bloom lets through of the
+    # rest alone. That the remembered bytes were the persisted ones is what
+    # the digests of the manifest's versions, below, prove.
+    old = store["old_values"]
+    assert old["remembered"] == sum(f["deletes"] for f in made)
+    assert old["remembered"] == replay["store_reads"]
+    assert old["looked_up"] <= (store["bloom"]["checked"]
+                                - store["bloom"]["skipped"])
+    assert old["found"] == 0 and store["remembered_rows"] == 0
     # every version a client saw: the reference's states in order, none
     # skipped, each one epoch on
     assert driver._walk_versions(versions, flushes) == {

@@ -3,12 +3,14 @@
 Differential against the single-file CoinsDB reference (the facade is a
 pure partition of the same contract), incremental-accumulator equality
 with a from-scratch recompute, the store_shard fault site's whole-commit
-abort semantics, manifest shard-count pinning, and dump/load round-trips
-across shard counts including digest-rejection.
+abort semantics, manifest shard-count pinning, dump/load round-trips
+across shard counts including digest-rejection, and the facade's memory of
+the rows it served since its last write (a commit's old values for MuHash).
 """
 
 import os
 import struct
+import time
 
 import pytest
 
@@ -158,6 +160,264 @@ class TestShardFaultSite:
         db.batch_write_serialized(_entries(0, 10), b"\x01" * 32)
         assert db.count_coins() == 10
         db.close()
+
+
+# -- the rows served since the last write, as a commit's old values ----------
+
+BEST = b"\x01" * 32
+
+
+def _open(path, n_shards: int) -> ShardedCoinsDB:
+    return ShardedCoinsDB(str(path), n_shards=n_shards)
+
+
+def _read(db: ShardedCoinsDB, ids) -> dict:
+    return db.get_serialized_many([_key(i) for i in ids])
+
+
+def _shard_states(db: ShardedCoinsDB) -> list:
+    """Each shard's accumulator state from its rows (what a bulk load's
+    caller hands finalize_bulk_load)."""
+    return [muhash.batch_product([muhash.coin_element(k, v) for k, v
+                                  in db.iterate_shard_coins(i)])
+            for i in range(db.n_shards)]
+
+
+def _served_half_case(tmp_path, n_shards, fault_harness):
+    """(a) half of the deleted rows were served: they are remembered, the
+    other half is looked up, and the digest is the one of a store that was
+    reopened before the commit and remembers nothing."""
+    db = _open(tmp_path / "a", n_shards)
+    twin = _open(tmp_path / "twin", n_shards)
+    for store in (db, twin):
+        store.batch_write_serialized(_entries(0, 200), BEST)
+    twin.close()
+    twin = _open(tmp_path / "twin", n_shards)
+    try:
+        # a miss is not remembered
+        assert len(_read(db, range(0, 100))) == 100
+        assert _read(db, range(500, 520)) == {}
+        assert db.stats()["remembered_rows"] == 100
+        assert twin.stats()["remembered_rows"] == 0
+        for store in (db, twin):
+            store.batch_write_serialized(
+                _entries(200, 400, delete=range(0, 200)), BEST)
+        assert (db.muhash_digest() == db.recompute_digest()
+                == twin.muhash_digest())
+        mine, theirs = (s.last_flush["old_values"] for s in (db, twin))
+        bloom = db.last_flush["bloom"]
+        assert mine["remembered"] == 100 and mine["found"] == 100
+        # the other half and the bloom's false positives among the puts;
+        # the remembered keys never reached the bloom
+        assert bloom["checked"] == 300
+        assert mine["looked_up"] == bloom["checked"] - bloom["skipped"]
+        assert 100 <= mine["looked_up"] < 300
+        assert theirs["remembered"] == 0 and theirs["found"] == 200
+        assert theirs["looked_up"] == mine["looked_up"] + 100
+        totals = db.stats()["old_values"]
+        assert totals["remembered"] == 100
+        assert totals["found"] == 100
+    finally:
+        db.close()
+        twin.close()
+
+
+def _overwritten_case(tmp_path, n_shards, fault_harness):
+    """(b) a served row the commit overwrites is divided out with its
+    persisted value, and (c) a served row a commit does not touch is
+    forgotten by that commit all the same."""
+    db = _open(tmp_path, n_shards)
+    try:
+        db.batch_write_serialized(_entries(0, 50), BEST)
+        _read(db, range(0, 50))
+        db.batch_write_serialized(
+            [(_key(i), _coin(i + 7)) for i in range(0, 25)], BEST)
+        assert db.last_flush["old_values"] == {
+            "remembered": 25, "looked_up": 0, "found": 0}
+        assert db.stats()["remembered_rows"] == 0
+        assert db.muhash_digest() == db.recompute_digest()
+        # rows 25-49 were served before that commit and not since: the
+        # next commit looks them up
+        db.batch_write_serialized(_entries(0, 0, delete=range(25, 50)), BEST)
+        assert db.last_flush["old_values"] == {
+            "remembered": 0, "looked_up": 25, "found": 25}
+        assert db.muhash_digest() == db.recompute_digest()
+    finally:
+        db.close()
+
+
+def _untouched_case(tmp_path, n_shards, fault_harness):
+    """(c) the memory is empty after every commit, whatever it changed, so
+    it cannot grow across intervals."""
+    db = _open(tmp_path, n_shards)
+    try:
+        db.batch_write_serialized(_entries(0, 50), BEST)
+        for lo in (50, 60, 70):
+            _read(db, range(0, 50))
+            assert db.stats()["remembered_rows"] == 50
+            # what the import counts against -dbcache beside the engine:
+            # more than the rows' own bytes, and nothing once forgotten
+            assert db.served_bytes() > sum(
+                len(_key(i)) + len(_coin(i)) for i in range(50))
+            db.batch_write_serialized(_entries(lo, lo + 10), BEST)
+            assert db.last_flush["old_values"]["remembered"] == 0
+            assert db.stats()["remembered_rows"] == 0
+            assert db.served_bytes() == 0
+        assert db.muhash_digest() == db.recompute_digest()
+        # an empty commit drops it too
+        _read(db, range(0, 50))
+        db.batch_write_serialized([], BEST)
+        assert db.stats()["remembered_rows"] == 0
+    finally:
+        db.close()
+
+
+def _ingest_rows_case(tmp_path, n_shards, fault_harness):
+    """(d) rows loaded behind the commit path: what was served before is
+    not what is persisted after."""
+    db = _open(tmp_path, n_shards)
+    try:
+        db.batch_write_serialized(_entries(0, 50), BEST)
+        _read(db, range(0, 50))
+        db.ingest_rows([(_key(i), _coin(i + 9)) for i in range(0, 50)])
+        assert db.stats()["remembered_rows"] == 0
+        db.finalize_bulk_load(BEST, _shard_states(db))
+        db.batch_write_serialized(_entries(0, 0, delete=range(0, 50)), BEST)
+        assert db.last_flush["old_values"]["remembered"] == 0
+        assert db.last_flush["old_values"]["found"] == 50
+        assert db.muhash_digest() == db.recompute_digest()
+    finally:
+        db.close()
+
+
+def _clear_coins_case(tmp_path, n_shards, fault_harness):
+    """(d) every row dropped behind the commit path: nothing that was
+    served is on disk any more."""
+    db = _open(tmp_path, n_shards)
+    try:
+        db.batch_write_serialized(_entries(0, 50), BEST)
+        _read(db, range(0, 50))
+        db.clear_coins()
+        assert db.stats()["remembered_rows"] == 0
+        db.finalize_bulk_load(BEST, [1] * n_shards)
+        db.batch_write_serialized(
+            [(_key(i), _coin(i + 3)) for i in range(0, 30)], BEST)
+        # and the blooms, rebuilt from no rows, prove every key absent
+        assert db.last_flush["old_values"] == {
+            "remembered": 0, "looked_up": 0, "found": 0}
+        assert db.muhash_digest() == db.recompute_digest()
+    finally:
+        db.close()
+
+
+def _replay_case(tmp_path, n_shards, fault_harness):
+    """(d) a commit that failed in step 2 on one shard, rows served while
+    its journals wait, then the replay: the rows the replay changes are no
+    longer the ones that were served."""
+    db = _open(tmp_path, n_shards)
+    try:
+        db.batch_write_serialized(_entries(0, 50), BEST)
+        epoch = db.epoch
+        kv = db.shards[0].kv
+        real = kv.write_batch
+
+        def refuse(*args, **kwargs):
+            raise OSError("no space left on device")
+
+        kv.write_batch = refuse
+        batch = ([(_key(i), _coin(i + 5)) for i in range(20, 50)]
+                 + _entries(50, 80, delete=range(0, 20)))
+        try:
+            with pytest.raises(OSError):
+                db.batch_write_serialized(batch, BEST)
+        finally:
+            kv.write_batch = real
+        deadline = time.monotonic() + 30
+        while any(db._shard_epoch(i) != epoch + 1
+                  for i in range(1, n_shards)):
+            assert time.monotonic() < deadline
+            time.sleep(0.01)
+        assert db._shard_epoch(0) == epoch
+        served = _read(db, range(0, 80))
+        stale = [i for i in range(20, 50) if shard_of(_key(i), n_shards) == 0]
+        assert stale and all(served[_key(i)] == _coin(i) for i in stale)
+        assert db.stats()["remembered_rows"] == len(served)
+        assert db.recover_journal() is True
+        assert db.stats()["remembered_rows"] == 0
+        assert db.epoch == epoch + 1
+        assert db.muhash_digest() == db.recompute_digest()
+        db.batch_write_serialized(_entries(0, 0, delete=range(20, 80)), BEST)
+        assert db.last_flush["old_values"] == {
+            "remembered": 0, "looked_up": 60, "found": 60}
+        assert db.count_coins() == 0
+        assert db.muhash_digest() == db.recompute_digest()
+    finally:
+        db.close()
+
+
+def _aborted_then_retried_case(tmp_path, n_shards, fault_harness):
+    """(e) a commit the store_shard fault site aborts in step 1 has changed
+    no row and has dropped the memory; the retry looks every row up."""
+    db = _open(tmp_path, n_shards)
+    try:
+        db.batch_write_serialized(_entries(0, 100), BEST)
+        digest = db.muhash_digest()
+        _read(db, range(0, 50))
+        batch = _entries(100, 150, delete=range(0, 100))
+        fault_harness("fail-once", ops=STORE_SHARD_SITE)
+        with pytest.raises(InjectedFault):
+            db.batch_write_serialized(batch, BEST)
+        assert db.stats()["remembered_rows"] == 0
+        assert db.muhash_digest() == digest == db.recompute_digest()
+        db.batch_write_serialized(batch, BEST)
+        assert db.last_flush["old_values"]["remembered"] == 0
+        assert db.last_flush["old_values"]["found"] == 100
+        assert db.count_coins() == 50
+        assert db.muhash_digest() == db.recompute_digest()
+        totals = db.stats()["old_values"]
+        # the aborted attempt counted what it took: 50 remembered, 50 read
+        assert totals["remembered"] == 50 and totals["found"] == 150
+    finally:
+        db.close()
+
+
+def _served_during_applies_case(tmp_path, n_shards, fault_harness):
+    """A read that overlaps a commit's applies (no caller makes one) may
+    have seen rows from before them: the commit's end forgets it, so the
+    next commit looks those rows up."""
+    db = _open(tmp_path, n_shards)
+    try:
+        db.batch_write_serialized(_entries(0, 50), BEST)
+        kv = db.shards[0].kv
+        real = kv.write_batch
+
+        def read_then_write(*args, **kwargs):
+            _read(db, range(0, 50))
+            return real(*args, **kwargs)
+
+        kv.write_batch = read_then_write
+        try:
+            db.batch_write_serialized(
+                [(_key(i), _coin(i + 7)) for i in range(0, 50)], BEST)
+        finally:
+            kv.write_batch = real
+        assert db.stats()["remembered_rows"] == 0
+        db.batch_write_serialized(_entries(0, 0, delete=range(0, 50)), BEST)
+        assert db.last_flush["old_values"] == {
+            "remembered": 0, "looked_up": 50, "found": 50}
+        assert db.muhash_digest() == db.recompute_digest()
+    finally:
+        db.close()
+
+
+@pytest.mark.parametrize("n_shards", [1, 4])
+@pytest.mark.parametrize("case", [
+    _served_half_case, _overwritten_case, _untouched_case,
+    _ingest_rows_case, _clear_coins_case, _replay_case,
+    _aborted_then_retried_case, _served_during_applies_case], ids=lambda c: c.__name__.strip("_"))
+def test_served_rows_are_a_commits_old_values(case, n_shards, tmp_path,
+                                              fault_harness):
+    case(tmp_path, n_shards, fault_harness)
 
 
 class TestSnapshot:

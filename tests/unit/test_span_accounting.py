@@ -349,6 +349,7 @@ def test_the_store_says_where_a_commit_went(imported):
             "store.manifest"} <= set(spans)
     assert "store.rows_lock_wait" not in spans
     assert spans["store.shard_write"]["n"] == 4  # one a shard
+    assert spans["store.old_reads"]["n"] == 4  # one a shard, served or not
     assert spans["store.commit"]["s"] >= spans["store.manifest"]["s"]
 
 
@@ -372,6 +373,37 @@ def test_a_shard_write_says_its_rows_and_statements(tmp_path, mode, name):
     assert sorted(w["shard"] for w in writes) == list(range(len(writes)))
     assert all(w["statements"] == 1 for w in writes)
     assert sum(w["rows"] for w in writes) == (40 + 2 * 3 if writes else 0)
+
+
+@pytest.mark.parametrize("name", ["off", "counters", "trace"])
+def test_an_old_read_says_what_it_remembered(tmp_path, mode, name):
+    """store.old_reads opens once a shard a commit, rows served or not, and
+    the ring's events split its keys into those the store remembered
+    serving and those it asked sqlite for; the other modes keep no event
+    and the commit takes the same old values."""
+    from bitcoincashplus_tpu.store.sharded import ShardedCoinsDB
+
+    mode(name)
+    rows = [(os.urandom(36), b"\x02\x05\x01\x51") for _ in range(40)]
+    db = ShardedCoinsDB(str(tmp_path), n_shards=2)
+    db.batch_write_serialized(rows, b"\x01" * 32)
+    assert len(db.get_serialized_many([k for k, _ in rows[:30]])) == 30
+    db.batch_write_serialized([(k, None) for k, _ in rows], b"\x02" * 32)
+    stats = db.stats()
+    db.close()
+    assert stats["last_flush"]["old_values"] == {
+        "remembered": 30, "looked_up": 10, "found": 10}
+    assert stats["old_values"]["remembered"] == 30
+    reads = [ev["args"] for ev in tm.TRACER.events()
+             if ev["name"] == "store.old_reads"]
+    assert len(reads) == (4 if name == "trace" else 0)
+    assert [r["shard"] for r in reads] == [0, 1, 0, 1][:len(reads)]
+    assert sum(r["keys"] for r in reads) == (80 if reads else 0)
+    assert sum(r["remembered"] for r in reads) == (30 if reads else 0)
+    # the first commit's keys were all the bloom's to refuse or let through
+    assert sum(r["looked_up"] for r in reads[2:]) == (10 if reads else 0)
+    if name != "off":
+        assert tm.span_totals()["store.old_reads"]["n"] == 4
 
 
 # ---------------------------------------------------------------------------
